@@ -44,13 +44,18 @@ def _device(device=None) -> "jax.Device":
 
 
 def memory_stats(device=None) -> dict:
-    """Raw PJRT memory counters for the device (empty dict on backends that
-    do not report, e.g. CPU)."""
+    """Raw PJRT memory counters for the device. The CPU backend keeps none
+    and reports ``{}``; on any other platform a device that reports nothing
+    is an error, never an empty answer."""
     d = _device(device)
-    try:
-        return dict(d.memory_stats() or {})
-    except Exception:
+    st = d.memory_stats()
+    if st is None:
+        if d.platform != "cpu":
+            raise RuntimeError(
+                f"{d.platform} device {d.id} ({d.device_kind}) reports no "
+                "memory_stats()")
         return {}
+    return dict(st)
 
 
 def _observe(d) -> dict:

@@ -53,7 +53,7 @@ virtual chunk). Two schedules exist:
   active chunk's weights are selected from the stacked [C, P, ...] arrays
   with ``lax.dynamic_index_in_dim`` — one fused program per tick, no
   ``lax.switch`` over per-chunk branches (the r5 switch formulation paid
-  +43% steady-state per-microbatch time; see PROFILE_r05 §1 / r06 §1).
+  +43% steady-state per-microbatch time, measured at r5 and r6).
   Requires ``num_micro % P == 0``. Chunk-program homogeneity is a hard
   constructor invariant (every schedule path runs ONE body program per
   tick); ``PADDLE_TPU_VPP_INTERLEAVED_IMPL=switch`` selects ``lax.switch``
@@ -416,7 +416,7 @@ class CompiledPipelineTrainStep:
     from the stacked ``[C, P, ...]`` parameters with
     ``lax.dynamic_index_in_dim`` instead of ``lax.switch`` over per-chunk
     branches, which erased the r5 switch tick's +43% steady-state
-    per-microbatch tax (PROFILE_r06 §1). Chunk-sequential rings remain the
+    per-microbatch tax (measured at r6). Chunk-sequential rings remain the
     fallback (and can be forced with ``PADDLE_TPU_VPP_INTERLEAVED=0``);
     ``PADDLE_TPU_VPP_INTERLEAVED_IMPL=switch`` selects ``lax.switch``
     weight selection for A/B profiling of the branch cost. Optimizer
@@ -551,10 +551,10 @@ class CompiledPipelineTrainStep:
             # already a constructor invariant.
             # r5 shipped it opt-in because its per-tick lax.switch over
             # chunk programs cost +43% steady-state per-microbatch time
-            # (PROFILE_r05 §1); the r6 tick instead gathers the active
+            # (measured at r5); the r6 tick instead gathers the active
             # chunk's weights from the stacked [C, P, ...] arrays with
             # lax.dynamic_index_in_dim — one fused, branch-free tick body
-            # (VERDICT r5 rec #8, measured in PROFILE_r06 §1).
+            # (measured at r6).
             # Env overrides:
             #   PADDLE_TPU_VPP_INTERLEAVED=0  force chunk-sequential rings
             #   PADDLE_TPU_VPP_INTERLEAVED=1  request interleaved (warns
@@ -577,8 +577,8 @@ class CompiledPipelineTrainStep:
             use_indexed = (_os.environ.get(
                 "PADDLE_TPU_VPP_INTERLEAVED_IMPL", "indexed") != "switch")
             if interleave:
-                # ---- explicit interleaved-VPP ordering (r5, VERDICT item
-                # 5): ONE scan whose stage-0 feed alternates chunks in
+                # ---- explicit interleaved-VPP ordering (r5):
+                # ONE scan whose stage-0 feed alternates chunks in
                 # groups of P microbatches — (c, m)'s dependency, chunk
                 # c-1's exit of the same microbatch, is fed exactly P ticks
                 # earlier and rides the ring's P-1→0 wrap back to stage 0

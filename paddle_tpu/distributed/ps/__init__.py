@@ -230,7 +230,7 @@ class ShardedEmbedding:
 
     def pull_async(self, ids):
         """Prefetch rows on a background thread so the trainer overlaps the
-        sparse lookup with the XLA step (VERDICT r4: trainer-side lookups
+        sparse lookup with the XLA step (trainer-side lookups
         didn't overlap). Returns a future; ``.result()`` gives the same
         array ``pull`` would. Call :meth:`close` (or drain futures) before
         ``rpc.shutdown()`` so in-flight prefetches don't race teardown."""

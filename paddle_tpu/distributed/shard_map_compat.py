@@ -1,60 +1,30 @@
-"""Version-compat shard_map: jax ≥0.8 spells the replication check
-``check_vma`` on ``jax.shard_map``; older releases have
-``jax.experimental.shard_map`` with ``check_rep``. One shim, used by the
-eager collectives and the compiled pipeline."""
+"""The repo's two spellings of ``jax.shard_map``: fully manual, and manual
+over some mesh axes with the rest left to GSPMD. The replication check is
+off in both (``check_vma=False``). Used by the eager collectives, ring
+attention, the expert all-to-all and the compiled pipeline."""
 from __future__ import annotations
+
+import jax
 
 __all__ = ["shard_map_compat", "shard_map_manual",
            "partial_manual_supported"]
 
 
 def partial_manual_supported(mesh, manual_axes) -> bool:
-    """Whether a partial-manual shard_map over ``manual_axes`` can run on
-    this jax. Old jax (no top-level ``jax.shard_map``) ABORTS XLA's SPMD
-    partitioner — a fatal check, not an exception — on collectives
-    (ppermute/all_to_all/all_gather/axis_index) and on any backward pass
-    whenever a size>1 AUTO axis coexists with the manual set. Callers must
-    refuse such meshes up front; a compiled step must never be able to
-    take the whole process down."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return True
-    manual = frozenset(manual_axes)
-    return all(size <= 1 or name in manual
-               for name, size in mesh.shape.items())
+    """Whether a partial-manual shard_map over ``manual_axes`` can run: on
+    the installed jax, always. The callers' branches for the other answer
+    are ROADMAP D6's to delete, and this function goes with them."""
+    return True
 
 
 def shard_map_manual(fn, mesh, in_specs, out_specs, manual_axes):
     """Partial-manual shard_map: ``manual_axes`` go manual, every other
-    mesh axis stays auto (GSPMD). jax ≥0.8 spells this
-    ``jax.shard_map(..., axis_names=manual_axes, check_vma=False)``; older
-    releases take the complement set via
-    ``jax.experimental.shard_map(..., auto=<other axes>, check_rep=False)``.
-    """
-    import jax
-
-    manual = frozenset(manual_axes)
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    except (AttributeError, ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        auto = frozenset(a for a in mesh.axis_names if a not in manual)
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False, auto=auto)
+    mesh axis stays auto (GSPMD)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(manual_axes), check_vma=False)
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except (ImportError, TypeError):  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

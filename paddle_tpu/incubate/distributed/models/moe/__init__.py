@@ -108,7 +108,7 @@ class MoELayer(nn.Layer):
         mode = self.dispatch_mode
 
         def f_ragged(xv, gv, w1, b1, w2, b2):
-            """Sort-based ragged routing (VERDICT r2 item 7b; reference
+            """Sort-based ragged routing (reference
             analog: the global_scatter/global_gather all-to-all of
             moe_layer.py:263). No [N, E, C] combine tensor: token slots are
             sorted by expert, scattered into the [E*C, d] expert buffer,

@@ -1213,8 +1213,11 @@ class ServingFleet:
     otherwise the fleet starts its own in-process ``KVServer``.
     ``cpu_workers=True`` (default) pins spawned workers to
     ``JAX_PLATFORMS=cpu`` exactly like the standalone-serving test
-    subprocesses — pass False to let workers use the host's accelerator
-    config."""
+    subprocesses.  With False a worker takes the host's accelerator, and
+    a chip belongs to one process at a time: such workers need one chip
+    each, and a fleet parent that has touched JAX already holds them all,
+    so its workers fail or hang.  On one chip host drive several replicas
+    from one process instead (``ServingFrontend([engine, ...])``)."""
 
     def __init__(self, worker_spec: Dict, num_workers: int = 0, *,
                  master_endpoint: Optional[str] = None,

@@ -1,5 +1,6 @@
 """paddle_tpu.jit (parity: python/paddle/jit)."""
 from . import trace_state  # noqa: F401
+from .compile_cache import use_compile_cache  # noqa: F401
 from .api import InputSpec, StaticFunction, TrainStep, ignore_module, not_to_static, to_static  # noqa: F401
 from .serialization import load, save  # noqa: F401
 

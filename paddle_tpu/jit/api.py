@@ -435,10 +435,38 @@ class TrainStep:
         self._buffers = [b for b in model.buffers() if b is not None]
         optimizer._ensure_state()
         self._pid2idx = {id(p): i for i, p in enumerate(self._params)}
+        self._commit_state_to_mesh()
         self._compiled = None
         self._multi_cache: Dict[Any, Any] = {}
         self._step_raw = None
         self._donate = donate
+
+    def _commit_state_to_mesh(self):
+        """Under a fleet mesh, state that no layer placed (norm weights, rope
+        buffers, optimizer scalars) sits on one device, and the step returns
+        it committed to the mesh: the second call would see new input
+        shardings and compile the whole step again (measured on four v5e
+        chips: 81 s, then 75 s). Replicating it up front gives the first call
+        the shardings every later call has."""
+        from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+        from ..distributed.topology import get_hybrid_communicate_group
+
+        hcg = get_hybrid_communicate_group()
+        if hcg is None or hcg.mesh.size == 1 or jax.process_count() > 1:
+            return
+        replicated = NamedSharding(hcg.mesh, PartitionSpec())
+
+        def put(v):
+            if isinstance(getattr(v, "sharding", None), SingleDeviceSharding):
+                return jax.device_put(v, replicated)
+            return v
+
+        for t in self._params + self._buffers:
+            t._value = put(t._value)
+        accs, masters = self._get_opt_state()
+        self._put_opt_state(jax.tree_util.tree_map(put, accs),
+                            jax.tree_util.tree_map(put, masters))
 
     # -------------------------------------------------- state pytree helpers
     def _get_opt_state(self):
@@ -630,7 +658,7 @@ class TrainStep:
         slice per step); the whole schedule executes as a ``lax.scan`` over
         that dim, so per-dispatch host/marshalling overhead is paid once per
         K steps instead of per step (decisive for models with many small
-        parameter tensors, and for remote/tunneled accelerators). Returns the
+        parameter tensors). Returns the
         per-step losses as a [K] tensor. The learning rate is evaluated once
         and held constant across the window (scheduler advances by K after).
         """

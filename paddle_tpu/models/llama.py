@@ -245,7 +245,7 @@ class LlamaAttention(nn.Layer):
             if mode == "pallas":
                 # kept for study: measured SLOWER than the einsum path on
                 # v5e (299-366 vs 610-688 GB/s — per-head M=1 MXU dots don't
-                # pipeline; see PROFILE_r04.md)
+                # pipeline; r4)
                 from ..ops.pallas.decode_attention import decode_attention, kv_ring_write
 
                 def fused(qv, kv_, vv, kb, vb, p):

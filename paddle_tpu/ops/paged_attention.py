@@ -13,7 +13,7 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   are XLA dynamic-(update-)slice/gather ops that tile fine on TPU; attention
   is one fp32-softmax einsum chain the MXU eats. A hand-written Pallas paged
   kernel was deliberately NOT used: r4 measured XLA's einsum decode path at
-  610-688 GB/s vs 299-366 for the Pallas small-M-dot kernel (PROFILE_r04.md).
+  610-688 GB/s vs 299-366 for the Pallas small-M-dot kernel.
 - Everything is static-shape: the query side is a packed token buffer
   ``[T, ...]`` (mixed prefill+decode chunks), the key side is
   ``blocks_per_seq * block_size`` — both fixed by the serving engine, so

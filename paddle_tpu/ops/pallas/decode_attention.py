@@ -28,13 +28,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -73,9 +68,6 @@ def kv_ring_write(buf, new, pos, *, interpret=False):
     buf: [B, L, KVH, D] (ALIASED — returned buffer reuses the input's
     memory); new: [B, 1, KVH, D]; pos: scalar int32.
     """
-    if not _HAS_PALLAS:
-        return jax.lax.dynamic_update_slice(
-            buf, new.astype(buf.dtype), (0, pos.astype(jnp.int32), 0, 0))
     b, l, kvh, d = buf.shape
     pos_arr = jnp.reshape(pos, (1,)).astype(jnp.int32)
 
@@ -162,7 +154,7 @@ def decode_attention(q, kbuf, vbuf, pos, scale=None, *, block_l: int = 256,
     b, s, h, d = q.shape
     l, kvh = kbuf.shape[1], kbuf.shape[2]
     scale = scale or 1.0 / math.sqrt(d)
-    if not _HAS_PALLAS or s != 1 or h % kvh != 0:
+    if s != 1 or h % kvh != 0:
         return ref_decode_attention(q, kbuf, vbuf, pos, scale)
     bl = min(block_l, l)
     if l % bl != 0:

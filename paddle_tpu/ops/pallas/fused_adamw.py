@@ -16,13 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # flattened [rows, 512] tiles, 256 rows per block → 512KB fp32 per operand
 _LANES = 512
@@ -47,7 +42,7 @@ def _kernel(scal_ref, g_ref, w_ref, m_ref, v_ref, p_out, w_out, m_out, v_out,
 
 
 def fused_adamw_supported(n: int) -> bool:
-    return _HAS_PALLAS and n % (_LANES * _ROWS) == 0
+    return n % (_LANES * _ROWS) == 0
 
 
 def fused_adamw(param, master, m, v, grad, lr, beta1_pow_t, beta2_pow_t, *,

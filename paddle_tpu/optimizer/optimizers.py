@@ -119,8 +119,8 @@ class AdamW(Adam):
         OPT-IN (PADDLE_TPU_FUSED_ADAMW=1): measured INSIDE the full compiled
         train step the custom-call boundary costs more than the fusion wins
         (flagship 0.4163 vs 0.4408 MFU — XLA fuses the optimizer chain with
-        its surroundings better than an isolated microbench suggests; see
-        PROFILE_r04.md). Exact same math — golden-tested vs the jnp path."""
+        its surroundings better than an isolated microbench suggests, r4).
+        Exact same math — golden-tested vs the jnp path."""
         import os
 
         import jax as _jax

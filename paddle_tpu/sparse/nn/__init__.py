@@ -110,7 +110,7 @@ class _GatherConv(Layer):
     The neighbor table (out-site × kernel-offset → input-slot or miss) is
     built once per pattern with numpy sort/searchsorted and cached; the
     VALUE path is one traced gather + one dense [nnz·K, Cin]×[K·Cin, Cout]
-    matmul on the MXU — fully jit-safe (VERDICT r3 item 8: no host nonzero,
+    matmul on the MXU — fully jit-safe (no host nonzero,
     no densify) and scaling with nnz, not spatial volume.
     """
 

@@ -1,0 +1,122 @@
+"""The Pallas kernels of the main paths, compiled at real widths for a TPU
+v5e that is described and not attached (libtpu's compiler is installed
+wherever jax[tpu] is). Interpret mode cannot see what this sees: a block
+that does not fit VMEM, a slice the tiling refuses. Nothing runs, so this
+says nothing about results or speed, and a pass here is not a chip run.
+
+Named to sort first: tier-1 is cut by its clock, and a file late in the
+alphabet guards nothing.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile against
+        pytest.skip(f"cannot describe a TPU v5e topology: {e!r}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A described-device executable is written to the persistent cache but
+    cannot be read back without a chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("seq", [2048, 4096])
+@pytest.mark.parametrize("kv_rep", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("kind", ["fwd", "bwd"])
+def test_flash_attention(chip, kind, kv_rep, seq):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas.autotune import get_flash_blocks
+
+    bh, d = 32, 128
+    scale = d ** -0.5
+    bq, bk = get_flash_blocks(kind, seq, seq, d)
+    q = ((bh, seq, d), BF16)
+    kv = ((bh // kv_rep, seq, d), BF16)
+    if kind == "fwd":
+        _compile(lambda q, k, v: fa._pallas_fwd(
+            q, k, v, True, scale, bq, bk, False, kv_rep=kv_rep),
+            chip, q, kv, kv)
+    else:
+        _compile(lambda q, k, v, o, lse, g: fa._pallas_bwd(
+            q, k, v, o, lse, g, True, scale, bq, bk, False, kv_rep=kv_rep),
+            chip, q, kv, kv, q, ((bh, seq), jnp.float32), q)
+
+
+@pytest.mark.parametrize("rows", [16384, 2048])
+def test_rms_norm(chip, rows):
+    from paddle_tpu.ops.pallas import fused_norm as fn
+
+    x = ((rows, 4096), BF16)
+    _compile(lambda x, w: fn._pallas_rms(x, w, 1e-6, False),
+             chip, x, ((4096,), BF16))
+
+
+@pytest.mark.parametrize("hidden,dtype", [(4096, BF16), (2560, BF16),
+                                          (8192, BF16), (4096, jnp.float32)])
+def test_rms_norm_residual(chip, hidden, dtype):
+    """[16384, 4096] bf16 with the old fixed 256-row block asked for 16.01M
+    of a 16.00M scoped VMEM limit."""
+    from paddle_tpu.ops.pallas import fused_norm as fn
+
+    x = ((16384, hidden), dtype)
+    _compile(lambda x, r, w: fn._pallas_rms_residual(x, r, w, 1e-6, False),
+             chip, x, x, ((hidden,), dtype))
+
+
+@pytest.mark.parametrize("m", [8, 512])
+def test_int8_matmul(chip, m, monkeypatch):
+    from paddle_tpu.ops.pallas import int8_matmul as im
+
+    # under a described-device compile the default backend is still the CPU,
+    # where _int8_mm_impl takes its jnp branch: steer it to the kernel here
+    monkeypatch.setattr(im, "on_tpu", lambda: True)
+    _compile(lambda x, q, s: im._int8_mm_impl(x, q, s, False), chip,
+             ((m, 4096), BF16), ((4096, 11008), jnp.int8),
+             ((11008,), jnp.float32))
+
+
+def test_decode_attention(chip):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention,
+        kv_ring_write,
+    )
+
+    b, ring, h, d = 8, 2048, 32, 128
+    buf = ((b, ring, h, d), BF16)
+    row = ((b, 1, h, d), BF16)
+    pos = ((), jnp.int32)
+    _compile(decode_attention, chip, row, buf, buf, pos)
+    _compile(kv_ring_write, chip, buf, row, pos)

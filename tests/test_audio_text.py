@@ -1,4 +1,4 @@
-"""paddle.audio + paddle.text parity tests (VERDICT r1 item 6 tail)."""
+"""paddle.audio + paddle.text parity tests."""
 import os
 
 import numpy as np

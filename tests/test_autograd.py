@@ -210,7 +210,7 @@ class TestPyLayer:
 
 
 class TestDoubleGrad:
-    """create_graph=True: vjp-of-vjp through the tape (VERDICT r1 item 10)."""
+    """create_graph=True: vjp-of-vjp through the tape."""
 
     def test_second_derivative_scalar(self):
         x = P.to_tensor(np.float32(2.0))

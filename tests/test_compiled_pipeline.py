@@ -1,5 +1,5 @@
 """Compiled pipeline: full microbatch schedule in one XLA program
-(VERDICT r2 item 2; reference analog: pipeline_scheduler_pass/)."""
+(reference analog: pipeline_scheduler_pass/)."""
 import numpy as np
 import pytest
 
@@ -133,7 +133,7 @@ def _init4d(dp, mp, pp):
 
 
 class TestCompiledPipelineRealModel:
-    """VERDICT r3 item 1: the compiled pipeline must run the real llama —
+    """The compiled pipeline must run the real llama —
     heterogeneous stages (embed head / lm-head tail), tied embeddings, and
     optimizers with existing state / multiple groups."""
 
@@ -319,8 +319,8 @@ class TestCompiledVPP:
         assert any(tuple(v.shape[:2]) == (2, 2) for v in accs.values())
 
     def test_vpp_interleaved_matches_chunk_sequential(self, monkeypatch):
-        """r6: the branch-free interleaved ordering (AUTOMATIC when legal —
-        PROFILE_r06.md §1) computes the SAME loss as the chunk-sequential
+        """r6: the branch-free interleaved ordering (AUTOMATIC when legal)
+        computes the SAME loss as the chunk-sequential
         rings (forced with PADDLE_TPU_VPP_INTERLEAVED=0) and as the r5
         lax.switch interleaved tick
         (PADDLE_TPU_VPP_INTERLEAVED_IMPL=switch)."""

@@ -1,7 +1,7 @@
 """Pallas decode-attention kernel (ops/pallas/decode_attention.py): interpret-
 mode parity vs the jnp reference, ring-write aliasing semantics, GQA
 indexing. (On the real chip the EINSUM decode path is the default — measured
-faster than this kernel on v5e; see PROFILE_r04.md — but the kernel must stay
+faster than this kernel on v5e at r4 — but the kernel must stay
 numerically correct.)"""
 import numpy as np
 import pytest

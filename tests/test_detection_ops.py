@@ -1,4 +1,4 @@
-"""Detection-tail op tests (VERDICT r4 item 8): yolo_loss vs a numpy oracle
+"""Detection-tail op tests: yolo_loss vs a numpy oracle
 of the published YOLOv3 loss, generate_proposals decode/NMS behavior,
 decode_jpeg roundtrip, deform_conv2d groups>1."""
 import io

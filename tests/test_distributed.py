@@ -226,7 +226,7 @@ class TestShardedTraining:
 
 class TestCrossTopologyCheckpoint:
     """Save under {dp=8}, load under {dp=2, mp=2, sharding=2} and train
-    (VERDICT r2 item 7a; reference: distributed/checkpoint/load_state_dict.py
+    (reference: distributed/checkpoint/load_state_dict.py
     resharding-on-load across parallel configs)."""
 
     def test_dp8_to_hybrid_reshard_and_train(self, tmp_path):

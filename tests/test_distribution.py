@@ -1,4 +1,4 @@
-"""paddle.distribution parity tests (VERDICT r1 item 8).
+"""paddle.distribution parity tests.
 
 log_prob checked against scipy.stats, KL closed forms against Monte-Carlo
 estimates, transforms against round-trip + autodiff log-det, rsample

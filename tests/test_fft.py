@@ -1,4 +1,4 @@
-"""paddle.fft parity tests (VERDICT r1 item 8): values vs numpy.fft,
+"""paddle.fft parity tests: values vs numpy.fft,
 gradients vs finite differences / known identities."""
 import numpy as np
 import pytest

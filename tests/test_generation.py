@@ -134,8 +134,8 @@ def test_static_cache_rejects_beyond_rope_table():
 
 class TestDecodeAttentionPaths:
     """The fused decode path (native-layout einsum + fused qkv/gate-up) must
-    be numerically equivalent to the sdpa reference path (VERDICT r3 item 2:
-    numerics matched vs the current path)."""
+    be numerically equivalent to the sdpa reference path (numerics
+    matched vs the current path)."""
 
     def _greedy(self, monkeypatch, mode):
         import paddle_tpu as P

@@ -1,5 +1,5 @@
 """Golden-value tests: the nn/functional op tail vs torch CPU references
-(VERDICT r2 weak 9 — the tail had only smoke asserts; reference's own OpTest
+(the tail had only smoke asserts; reference's own OpTest
 compares against authoritative numerics, test/legacy_test/op_test.py:2119).
 
 torch (CPU build) is part of the image; it provides independent ground truth

@@ -1,4 +1,4 @@
-"""OpTest-style numeric gradient harness (VERDICT r1 item 9).
+"""OpTest-style numeric gradient harness.
 
 The reference checks every op's analytic gradient against central finite
 differences (/root/reference/test/legacy_test/op_test.py:148

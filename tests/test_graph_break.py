@@ -1,4 +1,4 @@
-"""to_static graph-break fallback (VERDICT r2 item 5; reference analog: SOT's
+"""to_static graph-break fallback (reference analog: SOT's
 resume-eager at untraceable bytecode, opcode_executor.py:1594)."""
 import warnings
 
@@ -86,7 +86,7 @@ def test_mixed_signatures_break_independently():
 
 class MidBreakNet(nn.Layer):
     """A .numpy() host read in the MIDDLE of the model: prefix and suffix
-    must become separate compiled segments (VERDICT r3 item 6)."""
+    must become separate compiled segments."""
 
     def __init__(self):
         super().__init__()

@@ -278,7 +278,7 @@ class TestSaveLoad:
 
 class TestTrainStepOptimizerParity:
     """TrainStep must trace the framework's own optimizers: one compiled step
-    == one eager step for every optimizer (VERDICT r1 item 3)."""
+    == one eager step for every optimizer."""
 
     OPTS = [
         ("SGD", lambda ps: P.optimizer.SGD(0.05, parameters=ps)),

@@ -1,4 +1,4 @@
-"""Launcher + elastic integration tests (VERDICT r1 item 6).
+"""Launcher + elastic integration tests.
 
 A 2-process CPU job trains with checkpointing; the first run crashes one
 worker mid-training; the launcher restarts the pod and the job resumes from
@@ -248,7 +248,7 @@ class TestElasticScaleOut:
     # contention Fs (and their runtime).
     @pytest.mark.slow
     def test_2_nodes_grow_to_3_with_late_joiner(self, tmp_path):
-        """VERDICT r4 item 6: a late node joining a running nnodes=2:3 job
+        """A late node joining a running nnodes=2:3 job
         bumps the rendezvous epoch; the incumbents re-rendezvous, rank envs
         are rewritten at world 3, and training resumes from checkpoints."""
         import socket
@@ -399,7 +399,7 @@ class TestElasticScaleIn:
     # TestElasticScaleOut note; gated by the CI 'parallel' shard instead
     @pytest.mark.slow
     def test_3_nodes_scale_in_to_2_and_resume(self, tmp_path):
-        """VERDICT r3 item 10: killing one node of an elastic nnodes=2:3 job
+        """Killing one node of an elastic nnodes=2:3 job
         makes the survivors detect the lost heartbeat, rewrite rank envs,
         and resume training at world_size=2 from the last checkpoint."""
         import signal

@@ -1,4 +1,4 @@
-"""Multi-controller compiled execution (VERDICT r4 item 2): 2 OS processes
+"""Multi-controller compiled execution: 2 OS processes
 x 4 virtual CPU devices cooperate in ONE compiled program, launched through
 the repo's own launcher (reference analog:
 test/legacy_test/test_parallel_dygraph_dataparallel.py:30 — N local
@@ -42,16 +42,6 @@ def _launch(tmp_path, phase):
 
 
 class TestMultiProcess:
-    # same container limitation test_eager_comm xfails against (r10
-    # triage): the workers die in VocabParallelEmbedding's device_put
-    # with "Multiprocess computations aren't implemented on the CPU
-    # backend" (jax 0.4.37).  Surfaced in r11 when tier-1 first ran this
-    # file inside the budget; lifted by the ROADMAP item-5 jax upgrade.
-    @pytest.mark.xfail(
-        strict=False,
-        reason="container jaxlib CPU backend: 'Multiprocess computations "
-               "aren't implemented on the CPU backend' (jax 0.4.37); "
-               "lifted by the ROADMAP item-5 jax upgrade")
     def test_two_process_gspmd_train_and_checkpoint_resume(self, tmp_path):
         _launch(tmp_path, "train")
         res = [json.load(open(tmp_path / f"result_{r}.json")) for r in (0, 1)]

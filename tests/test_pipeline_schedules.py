@@ -1,4 +1,4 @@
-"""Pipeline schedule tests (VERDICT r1 item 5): explicit 1F1B / VPP / ZB-H1
+"""Pipeline schedule tests: explicit 1F1B / VPP / ZB-H1
 programs, liveness properties, microbatch-gradient equivalence vs no-PP, and
 VPP being genuinely distinct from 1F1B."""
 import numpy as np

@@ -1,4 +1,4 @@
-"""Local-path pretrained-weight loading mechanics (VERDICT r2 item 8)."""
+"""Local-path pretrained-weight loading mechanics."""
 import numpy as np
 import pytest
 

@@ -1,6 +1,6 @@
 """Continuous-batching serving engine tests: greedy parity with
 models.generate, mixed-length admission/retirement across steps WITHOUT
-recompilation, and block-pool recycling (VERDICT r4 item 1 done-criteria)."""
+recompilation, and block-pool recycling."""
 import numpy as np
 import pytest
 
@@ -180,7 +180,7 @@ class TestServingEngine:
         assert out[rid] == full[:3]
 
     def test_int8_paged_cache(self, model):
-        """int8 cache-quant serving (VERDICT r4 item 1 tail): uint8 paged
+        """int8 cache-quant serving: uint8 paged
         blocks + per-(slot, kv-head) dynamic scales frozen at prefill;
         outputs stay token-identical to the fp engine on this model."""
         import jax.numpy as jnp

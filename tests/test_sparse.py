@@ -1,4 +1,4 @@
-"""paddle.sparse parity tests (VERDICT r1 item 6): COO/CSR round-trips,
+"""paddle.sparse parity tests: COO/CSR round-trips,
 value ops, spmm/sddmm vs dense reference, gradient flow to values."""
 import numpy as np
 import pytest
@@ -265,7 +265,7 @@ class TestReviewRegressions:
 
 
 class TestGatherConvJitSafe:
-    """VERDICT r3 item 8: sparse convs must run under jax.jit (no host
+    """Sparse convs must run under jax.jit (no host
     nonzero / densify on the value path) and match the dense reference."""
 
     def test_subm_conv_under_jit(self):

@@ -1,4 +1,4 @@
-"""paddle.static parity tests (VERDICT r1: static graph API was absent).
+"""paddle.static parity tests (static graph API was absent).
 
 Program capture at the dispatch chokepoint, Executor replay under jit,
 feed/fetch, parameters-as-constants, and the minimize() training loop."""
@@ -164,7 +164,7 @@ class TestExecutorDiagnostics:
 
 
 class TestProgramPasses:
-    """Pass layer over the captured Program (VERDICT r3 §1: the Program was
+    """Pass layer over the captured Program (the Program was
     replay-only; PIR analog: pass_manager.h + transforms/general/)."""
 
     def test_ir_dump(self, _static_mode=None):

@@ -1,10 +1,10 @@
-"""Honest-surface accounting (VERDICT r4 weak #2): every public name that
+"""Honest-surface accounting: every public name that
 resolves but raises NotImplementedError is listed HERE, and the ledger must
 only SHRINK. A name leaving stub-hood must be deleted from the ledger (the
 test fails if a listed name stops raising), so "surface closed" claims stay
 behavioral, not hasattr-deep.
 
-History: r4's honest stub list (VERDICT copy-paste section) had 12 entries.
+History: r4's honest stub list had 12 entries.
 r5 graduated: block_multihead_attention, fused_multi_transformer,
 static.py_func (see GRADUATED below; more move as the round progresses).
 """
@@ -16,8 +16,8 @@ import paddle_tpu as P
 pytestmark = pytest.mark.quick
 
 # (import path, attribute, minimal call) — call must raise NotImplementedError.
-# r5 closed EVERY entry from r4's honest stub list (VERDICT copy-paste
-# section): the ledger is empty.
+# r5 closed EVERY entry from r4's honest stub list: the ledger is
+# empty.
 KNOWN_STUBS = []
 
 # r4 stubs that must now be REAL (regression guard: resolving is no longer

@@ -1,4 +1,4 @@
-"""Varlen / sparse-mask flash attention tests (VERDICT r4 item 4):
+"""Varlen / sparse-mask flash attention tests:
 parity vs a dense-mask oracle and a packed-2-sequences training test."""
 import numpy as np
 import pytest
@@ -106,7 +106,7 @@ class TestVarlenQkvPacked:
             np.testing.assert_allclose(out[b * S + L:(b + 1) * S], 0.0)
 
     def test_packed_two_sequences_training(self):
-        """VERDICT done-criterion: train through the varlen path with two
+        """Done-criterion: train through the varlen path with two
         packed sequences — grads flow and the loss drops."""
         rng = np.random.RandomState(3)
         H, D, E = 2, 8, 16

@@ -7,7 +7,7 @@ Four checks; the first two run against the PREVIOUS round's recordings:
 
 1. Headline: the newest BENCH_r*.json's ``vs_baseline`` ratio must not drop
    more than --tolerance (default 10%), and the pinned workload must not
-   drift (VERDICT r4 item 3).
+   drift.
 2. Ladder (r6, ISSUE #1): EVERY rung of the newest BENCH_LADDER_r*.json is
    compared against the same rung in the previous round within the
    per-rung tolerance recorded in tools/ladder_tolerances.json. Direction
@@ -143,7 +143,7 @@ def check_headline(rounds, tolerance: float) -> int:
     cw = (cur.get("extra") or {}).get("workload")
     if pw is not None and cw is not None and pw != cw:
         # the headline series is only meaningful on a pinned workload — a
-        # drifted config is a FAILURE, not a skip (VERDICT r4 item 3)
+        # drifted config is a FAILURE, not a skip
         print(f"perf-gate: FAIL — workload configs differ between r{pn} "
               f"{pw} and r{cn} {cw}; the headline metric must be measured "
               "on the pinned workload (set PADDLE_TPU_BENCH_* back, or "

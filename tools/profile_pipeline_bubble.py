@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Measure the compiled pipeline's ACTUAL bubble vs the synchronous bound
-(VERDICT r4 item 5).
+"""Measure the compiled pipeline's ACTUAL bubble vs the synchronous bound.
 
 Method (slope/intercept decomposition — the only sound way to separate
 bubble from per-microbatch work without per-op tracing): run the SAME

@@ -262,6 +262,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..profiler import RecordEvent
 from .ha import HANDOFF_FLUSH, FrontendLease, StaleEpoch
 from .journal import (ADMIT, EPOCH, PROGRESS, TERMINAL, JournalSuperseded,
                       RequestJournal)
@@ -1100,60 +1101,62 @@ class ServingFrontend:
         live replica, harvest tokens and completions, sample metrics.
         Raises the typed ``StaleEpoch`` once this frontend is deposed —
         the driver must stop and defer to the current incarnation."""
-        if self._deposed:
-            raise StaleEpoch(
-                f"frontend deposed ({self._deposed_reason}) — stop "
-                "stepping and defer to the current incarnation")
-        if self._handed_off:
-            raise HandedOff("frontend handed off — drive the successor")
-        if self.lease is not None:
-            self._maintain_lease()
-        live = [r for r in self._replicas if r.alive]
-        if not live:
-            for req in list(self._queue):
-                self._queue.remove(req)
-                self._finish(req, RequestStatus.FAILED, "no live replicas")
-            self._sample_gauges()
-            return
-        self._shed_expired()
-        self._update_brownout()
-        self._dispatch()
-        stepping = [rep for rep in self._replicas
-                    if rep.alive and (rep.engine.num_active
-                                      or rep.engine._queue)]
-        # remote replicas overlap their engine steps: begin_step issues the
-        # RPC asynchronously, step() below collects it — fleet step latency
-        # is the max of the workers' round trips, not the sum.  In-process
-        # engines have no begin_step and run synchronously as before.
-        for rep in stepping:
-            begin = getattr(rep.engine, "begin_step", None)
-            if begin is not None:
-                try:
-                    begin()
-                # graft-lint: disable=typed-termination — begin_step is a
-                # concurrency prefetch; a faulting replica raises the same
-                # fault from step() below, where failover handles it typed
-                except Exception:  # noqa: BLE001 — surfaced by step() below
-                    pass
-        self._in_step = True
-        try:
+        with RecordEvent("frontend.step"):
+            if self._deposed:
+                raise StaleEpoch(
+                    f"frontend deposed ({self._deposed_reason}) — stop "
+                    "stepping and defer to the current incarnation")
+            if self._handed_off:
+                raise HandedOff("frontend handed off — drive the successor")
+            if self.lease is not None:
+                self._maintain_lease()
+            live = [r for r in self._replicas if r.alive]
+            if not live:
+                for req in list(self._queue):
+                    self._queue.remove(req)
+                    self._finish(req, RequestStatus.FAILED, "no live replicas")
+                self._sample_gauges()
+                return
+            with RecordEvent("frontend.dispatch"):
+                self._shed_expired()
+                self._update_brownout()
+                self._dispatch()
+            stepping = [rep for rep in self._replicas
+                        if rep.alive and (rep.engine.num_active
+                                          or rep.engine._queue)]
+            # remote replicas overlap their engine steps: begin_step issues the
+            # RPC asynchronously, step() below collects it — fleet step latency
+            # is the max of the workers' round trips, not the sum.  In-process
+            # engines have no begin_step and run synchronously as before.
             for rep in stepping:
-                self._step_replica(rep)
-        finally:
-            self._in_step = False
-            self._flush_step_records()
-        if self.tracer is not None:
-            # graft engine/worker-side span events (prefill done, megastep
-            # boundaries) onto the fleet-wide trees; a RemoteReplica's pop
-            # is a local buffer drain, so no RPC fault can fire here
-            for rep in self._replicas:
-                fn = getattr(rep.engine, "pop_trace_events", None)
-                if fn is not None:
-                    self.tracer.absorb(fn())
-        self._sample_gauges()
-        if (self._journaling
-                and self._records_since_compact >= self.journal_compact_every):
-            self._compact_journal()
+                begin = getattr(rep.engine, "begin_step", None)
+                if begin is not None:
+                    try:
+                        begin()
+                    # graft-lint: disable=typed-termination — begin_step is a
+                    # concurrency prefetch; a faulting replica raises the same
+                    # fault from step() below, where failover handles it typed
+                    except Exception:  # noqa: BLE001 — surfaced by step() below
+                        pass
+            self._in_step = True
+            try:
+                for rep in stepping:
+                    self._step_replica(rep)
+            finally:
+                self._in_step = False
+                self._flush_step_records()
+            if self.tracer is not None:
+                # graft engine/worker-side span events (prefill done, megastep
+                # boundaries) onto the fleet-wide trees; a RemoteReplica's pop
+                # is a local buffer drain, so no RPC fault can fire here
+                for rep in self._replicas:
+                    fn = getattr(rep.engine, "pop_trace_events", None)
+                    if fn is not None:
+                        self.tracer.absorb(fn())
+            self._sample_gauges()
+            if (self._journaling
+                    and self._records_since_compact >= self.journal_compact_every):
+                self._compact_journal()
 
     def run(self, max_steps: int = 10_000) -> Dict[int, RequestResult]:
         """Drive ``step()`` until every submitted request has a result.
@@ -2258,79 +2261,80 @@ class ServingFrontend:
         except Exception as e:  # noqa: BLE001 — any replica fault fails over
             self._kill_replica(rep, e)
             return
-        self.metrics.inc("engine_steps_total")
-        lp_fn = getattr(rep.engine, "pop_token_logprobs", None)
-        lps = lp_fn() if lp_fn is not None else {}
-        if getattr(rep.engine, "capture_sample_probs", False):
-            # the frontend has no per-token consumer for the [V]-sized
-            # distributions — drain them so a capture-enabled engine
-            # driven by a long-lived frontend doesn't accumulate one
-            # array per emitted token forever (spec-decode verifiers
-            # harvest by driving the engine directly)
-            rep.engine.pop_sample_probs()
-        t = self._clock()
-        for erid, toks in emitted.items():
-            req = rep.requests.get(erid)
-            if req is None:
-                continue
-            if not toks:
-                continue
-            if req.prefill_pass:
-                # the pass's sampled token is scaffolding, not output —
-                # decode re-emits it token-identically (sample_offset=0
-                # restarts the seeded stream from the same prefix)
-                continue
-            # weights-version attribution (ISSUE 18): stamp the version
-            # that generated THIS burst — last writer wins, so a request
-            # completing entirely on one version reports exactly it
-            req.weights_version = getattr(rep.engine, "weights_version",
-                                          None)
-            tid = req.trace.trace_id if req.trace is not None else None
-            if req.first_token_t is None:
-                req.first_token_t = t
-                self.metrics.observe("ttft_seconds", t - req.submit_t,
-                                     trace_id=tid)
-            elif req.last_token_t is not None:
-                # inter-token latency: a megastep delivers its K tokens in
-                # one burst, so the per-token value is the boundary-to-
-                # boundary gap amortized over the burst
-                self.metrics.observe(
-                    "token_latency_seconds",
-                    (t - req.last_token_t) / len(toks), trace_id=tid)
-            req.last_token_t = t
-            req.generated.extend(toks)
-            if req.sampling.logprobs:
-                req.logprob_values.extend(lps.get(erid, ()))
-            if req.on_token is not None:
-                try:
-                    for tok in toks:
-                        req.on_token(req.rid, tok)
-                except Exception:  # noqa: BLE001 — caller bug, not ours
-                    # a raising stream callback must not kill the replica
-                    # or wedge the step loop: disable it for this request
-                    req.on_token = None
-                    self.metrics.inc("stream_callback_errors_total")
-            self.metrics.note_tokens(len(toks), t)
-            if req.admitted and self._journaling:
-                # megastep-boundary progress marker, group-committed at
-                # the end of this step(): observability, the live retry-
-                # budget count, and the REMAINING deadline (recovery
-                # re-prefills from the prompt — tokens replay — but
-                # attempts and the SLO clock must survive the crash)
-                self._step_records.append(self._progress_record(req))
-        for erid in rep.engine.pop_finished():
-            req = rep.requests.pop(erid, None)
-            if req is None:
-                continue
-            req.replica = None
-            req.engine_rid = None
-            if req.prefill_pass:
-                # not a terminal: the pass computed + cached the prompt's
-                # KV; publish the chain, stream it to a decode replica,
-                # then hand the request over for the real generation
-                self._complete_prefill_pass(req, rep)
-                continue
-            self._finish(req, RequestStatus.COMPLETED)
+        with RecordEvent("frontend.deliver"):
+            self.metrics.inc("engine_steps_total")
+            lp_fn = getattr(rep.engine, "pop_token_logprobs", None)
+            lps = lp_fn() if lp_fn is not None else {}
+            if getattr(rep.engine, "capture_sample_probs", False):
+                # the frontend has no per-token consumer for the [V]-sized
+                # distributions — drain them so a capture-enabled engine
+                # driven by a long-lived frontend doesn't accumulate one
+                # array per emitted token forever (spec-decode verifiers
+                # harvest by driving the engine directly)
+                rep.engine.pop_sample_probs()
+            t = self._clock()
+            for erid, toks in emitted.items():
+                req = rep.requests.get(erid)
+                if req is None:
+                    continue
+                if not toks:
+                    continue
+                if req.prefill_pass:
+                    # the pass's sampled token is scaffolding, not output —
+                    # decode re-emits it token-identically (sample_offset=0
+                    # restarts the seeded stream from the same prefix)
+                    continue
+                # weights-version attribution (ISSUE 18): stamp the version
+                # that generated THIS burst — last writer wins, so a request
+                # completing entirely on one version reports exactly it
+                req.weights_version = getattr(rep.engine, "weights_version",
+                                              None)
+                tid = req.trace.trace_id if req.trace is not None else None
+                if req.first_token_t is None:
+                    req.first_token_t = t
+                    self.metrics.observe("ttft_seconds", t - req.submit_t,
+                                         trace_id=tid)
+                elif req.last_token_t is not None:
+                    # inter-token latency: a megastep delivers its K tokens in
+                    # one burst, so the per-token value is the boundary-to-
+                    # boundary gap amortized over the burst
+                    self.metrics.observe(
+                        "token_latency_seconds",
+                        (t - req.last_token_t) / len(toks), trace_id=tid)
+                req.last_token_t = t
+                req.generated.extend(toks)
+                if req.sampling.logprobs:
+                    req.logprob_values.extend(lps.get(erid, ()))
+                if req.on_token is not None:
+                    try:
+                        for tok in toks:
+                            req.on_token(req.rid, tok)
+                    except Exception:  # noqa: BLE001 — caller bug, not ours
+                        # a raising stream callback must not kill the replica
+                        # or wedge the step loop: disable it for this request
+                        req.on_token = None
+                        self.metrics.inc("stream_callback_errors_total")
+                self.metrics.note_tokens(len(toks), t)
+                if req.admitted and self._journaling:
+                    # megastep-boundary progress marker, group-committed at
+                    # the end of this step(): observability, the live retry-
+                    # budget count, and the REMAINING deadline (recovery
+                    # re-prefills from the prompt — tokens replay — but
+                    # attempts and the SLO clock must survive the crash)
+                    self._step_records.append(self._progress_record(req))
+            for erid in rep.engine.pop_finished():
+                req = rep.requests.pop(erid, None)
+                if req is None:
+                    continue
+                req.replica = None
+                req.engine_rid = None
+                if req.prefill_pass:
+                    # not a terminal: the pass computed + cached the prompt's
+                    # KV; publish the chain, stream it to a decode replica,
+                    # then hand the request over for the real generation
+                    self._complete_prefill_pass(req, rep)
+                    continue
+                self._finish(req, RequestStatus.COMPLETED)
 
     def _complete_prefill_pass(self, req: _FrontendRequest, rep: _Replica):
         """Prefill pass finished on ``rep``: publish the prompt's block

@@ -95,6 +95,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.paged_attention import blha_attention
+from ..profiler import RecordEvent
 from .faults import register_failpoint
 
 __all__ = ["BlockManager", "ServingRequest", "ServingEngine",
@@ -178,6 +179,7 @@ class SamplingParams:
                 "logprobs": self.logprobs, "spec": self.spec}
 
 
+@jax.named_scope("sample")
 def _sample_tokens(logits, temps, top_ks, top_ps, seeds, sample_pos,
                    return_probs: bool = False):
     """In-graph next-token selection for one batch of logits rows [B, V].
@@ -505,6 +507,39 @@ class ServingRequest:
 _PROGRAM_CACHE: Dict[tuple, dict] = {}
 
 
+# engine.<span> -> the phase_seconds keys its seconds are added to.
+# ``execute`` stays what it was, launch + wait; ``launch`` is the part of it
+# in which the host works (transfers, dispatch) and the device may wait.
+_PHASE_KEYS = {"admit": ("schedule",), "schedule": ("schedule",),
+               "launch": ("launch", "execute"), "wait": ("execute",),
+               "harvest": ("harvest",)}
+
+
+class _Phase:
+    """One part of an engine step, measured once: a ``RecordEvent``
+    ``engine.<name>`` on the profiler's clock, and its seconds on the
+    engine's injected clock added to ``phase_seconds``."""
+
+    __slots__ = ("eng", "name", "attrs", "span", "t0", "seconds")
+
+    def __init__(self, eng, name, attrs):
+        self.eng, self.name, self.attrs = eng, name, attrs
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self.t0 = self.eng._clock()
+        self.span = RecordEvent("engine." + self.name, **self.attrs)
+        self.span.begin()
+        return self
+
+    def __exit__(self, *exc):
+        self.span.end()
+        self.seconds = self.eng._clock() - self.t0
+        for key in _PHASE_KEYS[self.name]:
+            self.eng.phase_seconds[key] += self.seconds
+        return False
+
+
 def _np_dtype(name: str) -> np.dtype:
     """Numpy dtype for a cache dtype's string form.  ``bfloat16`` (and
     friends) only resolve once ml_dtypes' registrations are imported —
@@ -683,9 +718,12 @@ class ServingEngine:
         self._clock = clock
         # cumulative host-side seconds per step phase (schedule = admission
         # + batch marshalling, execute = compiled call + device sync,
-        # harvest = token/unblocking bookkeeping); surfaced via
-        # state_summary() for megastep cost attribution
-        self.phase_seconds = {"schedule": 0.0, "execute": 0.0, "harvest": 0.0}
+        # harvest = token/unblocking bookkeeping; launch = the part of
+        # execute before the blocking reads: transfers and dispatch);
+        # written by _phase alone, surfaced via state_summary()
+        self.phase_seconds = {"schedule": 0.0, "execute": 0.0, "harvest": 0.0,
+                              "launch": 0.0}
+        self.launches = 0           # compiled-program launches (monotone)
         # Programs are shared process-wide across engines with identical
         # trace-shaping config (see _PROGRAM_CACHE): a fresh engine over
         # an already-served geometry starts with warm compile caches.
@@ -820,14 +858,17 @@ class ServingEngine:
             # hidden sequence: ``forward`` heads only each slot's last
             # packed token, the spec-verify program (ISSUE 19) heads every
             # draft position — one set of layer math, two consumers.
-            hidden = weights["embed"][token_ids]  # [T, E]
+            with jax.named_scope("embed"):
+                hidden = weights["embed"][token_ids]  # [T, E]
             new_scales = []
             for li, lw in enumerate(weights["layers"]):
-                h = rms(hidden, lw["ln1"])
-                q = h @ lw["wq"]
-                k = h @ lw["wk"]
-                v = h @ lw["wv"]
-                qkv = jnp.concatenate([q, k, v], axis=-1)
+                with jax.named_scope("norm"):
+                    h = rms(hidden, lw["ln1"])
+                with jax.named_scope("attn_proj"):
+                    q = h @ lw["wq"]
+                    k = h @ lw["wk"]
+                    v = h @ lw["wv"]
+                    qkv = jnp.concatenate([q, k, v], axis=-1)
                 sc = scales[li] if scales is not None else {}
                 out, kc, vc, kq, vq, kd, vd = blha_attention(
                     qkv, key_caches[li], value_caches[li], enc, dec, now,
@@ -843,12 +884,16 @@ class ServingEngine:
                 value_caches[li] = vc
                 if scales is not None:
                     new_scales.append({"kq": kq, "vq": vq, "kd": kd, "vd": vd})
-                hidden = hidden + out @ lw["wo"]
-                h2 = rms(hidden, lw["ln2"])
-                g = h2 @ lw["wg"]
-                u = h2 @ lw["wu"]
-                hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
-            hidden = rms(hidden, weights["norm"])
+                with jax.named_scope("attn_out"):
+                    hidden = hidden + out @ lw["wo"]
+                with jax.named_scope("norm"):
+                    h2 = rms(hidden, lw["ln2"])
+                with jax.named_scope("mlp"):
+                    g = h2 @ lw["wg"]
+                    u = h2 @ lw["wu"]
+                    hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
+            with jax.named_scope("norm"):
+                hidden = rms(hidden, weights["norm"])
             return hidden, key_caches, value_caches, new_scales
 
         def forward(weights, key_caches, value_caches, rope, token_ids,
@@ -857,8 +902,9 @@ class ServingEngine:
                 weights, key_caches, value_caches, rope, token_ids, enc,
                 dec, now, cu, bt, mq, scales)
             # one logits row per batch slot: its LAST packed token
-            rows = jnp.clip(cu[1:] - 1, 0, token_ids.shape[0] - 1)
-            logits = hidden[rows] @ weights["head"]  # [B, V]
+            with jax.named_scope("head"):
+                rows = jnp.clip(cu[1:] - 1, 0, token_ids.shape[0] - 1)
+                logits = hidden[rows] @ weights["head"]  # [B, V]
             return logits, kcs, vcs, new_scales
 
         return forward, trunk
@@ -919,29 +965,31 @@ class ServingEngine:
             def body(carry, _):
                 (toks, kcs, vcs, dec, active, remaining, sample_pos, dl,
                  scales) = carry
-                packed = toks[occ_idx]    # slot-order -> packed layout
+                with jax.named_scope("scan_carry"):
+                    packed = toks[occ_idx]    # slot-order -> packed layout
                 logits, kcs, vcs, ns = fwd(weights, kcs, vcs, rope, packed,
                                            enc, dec, now, cu, bt, 1, scales)
                 scales = ns if scales is not None else None
                 nxt, lps, probs = _sample_tokens(
                     logits, temps, top_ks, top_ps, seeds, sample_pos,
                     return_probs=with_probs)
-                # a row is ALIVE while unfinished and inside its deadline
-                # budget; deadline-frozen rows stay active host-side (the
-                # control plane finalizes the typed shed at harvest) but
-                # emit nothing and advance nothing in-graph
-                alive = active & (dl > 0)
-                valid = alive
-                fin = alive & ((nxt == eos) | (remaining <= 1))
-                adv = alive & jnp.logical_not(fin)
-                # freeze finished/frozen rows: token/position/sample-index
-                # only advance while the row stays alive
-                toks = jnp.where(adv, nxt, toks)
-                dec = dec + adv.astype(jnp.int32)
-                remaining = remaining - alive.astype(jnp.int32)
-                sample_pos = sample_pos + alive.astype(jnp.int32)
-                dl = dl - alive.astype(jnp.int32)
-                active = active & jnp.logical_not(fin)
+                with jax.named_scope("scan_carry"):
+                    # a row is ALIVE while unfinished and inside its deadline
+                    # budget; deadline-frozen rows stay active host-side (the
+                    # control plane finalizes the typed shed at harvest) but
+                    # emit nothing and advance nothing in-graph
+                    alive = active & (dl > 0)
+                    valid = alive
+                    fin = alive & ((nxt == eos) | (remaining <= 1))
+                    adv = alive & jnp.logical_not(fin)
+                    # freeze finished/frozen rows: token/position/sample-index
+                    # only advance while the row stays alive
+                    toks = jnp.where(adv, nxt, toks)
+                    dec = dec + adv.astype(jnp.int32)
+                    remaining = remaining - alive.astype(jnp.int32)
+                    sample_pos = sample_pos + alive.astype(jnp.int32)
+                    dl = dl - alive.astype(jnp.int32)
+                    active = active & jnp.logical_not(fin)
                 return ((toks, kcs, vcs, dec, active, remaining,
                          sample_pos, dl, scales), (nxt, valid, lps, probs))
 
@@ -997,49 +1045,51 @@ class ServingEngine:
             def body(carry, _):
                 (toks, kcs, vcs, cached, pp, active, remaining,
                  sample_pos, dl) = carry
-                alive = active & (dl > 0)
-                prefilling = pp < plen
-                n_pre = jnp.minimum(plen - pp, C)
-                now_t = jnp.where(
-                    alive, jnp.where(prefilling, n_pre, 1), 0
-                ).astype(jnp.int32)
-                cu = jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int32),
-                     jnp.cumsum(now_t).astype(jnp.int32)])
-                # per-row tokens this iteration [B, C]: the next prompt
-                # chunk for prefilling rows, the carried token at column
-                # 0 for decode rows
-                chunk = jax.vmap(chunk_at)(prompt_buf, pp - pp0)
-                dec_row = jnp.zeros((B, C), jnp.int32).at[:, 0].set(toks)
-                row_toks = jnp.where(prefilling[:, None], chunk, dec_row)
-                # exact-pack into the [T] buffer (scatter; OOB -> drop):
-                # slot b's tokens land at cu[b] .. cu[b]+now_t[b]-1, so
-                # the packed layout is identical to the single-step path
-                j = jnp.arange(C, dtype=jnp.int32)[None, :]
-                flat = jnp.where(j < now_t[:, None], cu[:-1][:, None] + j,
-                                 T)
-                buf = jnp.zeros((T,), jnp.int32).at[flat.reshape(-1)].set(
-                    row_toks.reshape(-1), mode="drop")
+                with jax.named_scope("scan_carry"):
+                    alive = active & (dl > 0)
+                    prefilling = pp < plen
+                    n_pre = jnp.minimum(plen - pp, C)
+                    now_t = jnp.where(
+                        alive, jnp.where(prefilling, n_pre, 1), 0
+                    ).astype(jnp.int32)
+                    cu = jnp.concatenate(
+                        [jnp.zeros((1,), jnp.int32),
+                         jnp.cumsum(now_t).astype(jnp.int32)])
+                    # per-row tokens this iteration [B, C]: the next prompt
+                    # chunk for prefilling rows, the carried token at column
+                    # 0 for decode rows
+                    chunk = jax.vmap(chunk_at)(prompt_buf, pp - pp0)
+                    dec_row = jnp.zeros((B, C), jnp.int32).at[:, 0].set(toks)
+                    row_toks = jnp.where(prefilling[:, None], chunk, dec_row)
+                    # exact-pack into the [T] buffer (scatter; OOB -> drop):
+                    # slot b's tokens land at cu[b] .. cu[b]+now_t[b]-1, so
+                    # the packed layout is identical to the single-step path
+                    j = jnp.arange(C, dtype=jnp.int32)[None, :]
+                    flat = jnp.where(j < now_t[:, None], cu[:-1][:, None] + j,
+                                     T)
+                    buf = jnp.zeros((T,), jnp.int32).at[flat.reshape(-1)].set(
+                        row_toks.reshape(-1), mode="drop")
                 logits, kcs, vcs, _ = fwd(weights, kcs, vcs, rope, buf,
                                           enc, cached, now_t, cu, bt, C,
                                           None)
                 nxt, lps, probs = _sample_tokens(
                     logits, temps, top_ks, top_ps, seeds, sample_pos,
                     return_probs=with_probs)
-                # a row emits on decode iterations and on the iteration
-                # whose chunk finishes the prompt (its last packed token
-                # is the prompt's last token -> first sampled token)
-                finishing = prefilling & (pp + n_pre >= plen)
-                emits = alive & (jnp.logical_not(prefilling) | finishing)
-                fin = emits & ((nxt == eos) | (remaining <= 1))
-                adv = emits & jnp.logical_not(fin)
-                toks = jnp.where(adv, nxt, toks)
-                cached = cached + now_t
-                pp = pp + jnp.where(alive & prefilling, n_pre, 0)
-                remaining = remaining - emits.astype(jnp.int32)
-                sample_pos = sample_pos + emits.astype(jnp.int32)
-                dl = dl - alive.astype(jnp.int32)
-                active = active & jnp.logical_not(fin)
+                with jax.named_scope("scan_carry"):
+                    # a row emits on decode iterations and on the iteration
+                    # whose chunk finishes the prompt (its last packed token
+                    # is the prompt's last token -> first sampled token)
+                    finishing = prefilling & (pp + n_pre >= plen)
+                    emits = alive & (jnp.logical_not(prefilling) | finishing)
+                    fin = emits & ((nxt == eos) | (remaining <= 1))
+                    adv = emits & jnp.logical_not(fin)
+                    toks = jnp.where(adv, nxt, toks)
+                    cached = cached + now_t
+                    pp = pp + jnp.where(alive & prefilling, n_pre, 0)
+                    remaining = remaining - emits.astype(jnp.int32)
+                    sample_pos = sample_pos + emits.astype(jnp.int32)
+                    dl = dl - alive.astype(jnp.int32)
+                    active = active & jnp.logical_not(fin)
                 return ((toks, kcs, vcs, cached, pp, active, remaining,
                          sample_pos, dl), (nxt, emits, lps, probs))
 
@@ -1098,11 +1148,13 @@ class ServingEngine:
             # packed token cu[b] + j; rows whose draft is shorter than
             # spec_k clamp to their last fed token (masked out of the
             # accept below, so the garbage never commits)
-            j = jnp.arange(Kp1, dtype=jnp.int32)[None, :]
-            idx = jnp.clip(cu[:-1][:, None] + jnp.minimum(j, dlen[:, None]),
-                           0, token_ids.shape[0] - 1)
-            lg = (hidden[idx.reshape(-1)] @ weights["head"]).reshape(
-                B, Kp1, -1)
+            with jax.named_scope("head"):
+                j = jnp.arange(Kp1, dtype=jnp.int32)[None, :]
+                idx = jnp.clip(
+                    cu[:-1][:, None] + jnp.minimum(j, dlen[:, None]),
+                    0, token_ids.shape[0] - 1)
+                lg = (hidden[idx.reshape(-1)] @ weights["head"]).reshape(
+                    B, Kp1, -1)
             # redraw every position under the non-spec key stream (the
             # sample index advances by exactly one per position; Kp1 is
             # a small static constant, so a host loop over positions
@@ -1116,14 +1168,15 @@ class ServingEngine:
                 lpss.append(l_j)
                 if p_j is not None:
                     prbs.append(p_j)
-            nxt = jnp.stack(nxts, axis=1)                    # [B, Kp1]
-            lps = jnp.stack(lpss, axis=1)                    # [B, Kp1]
-            probs = jnp.stack(prbs, axis=1) if prbs else None
-            # accepted = longest draft prefix the redraw reproduces
-            jk = jnp.arange(sk, dtype=jnp.int32)[None, :]
-            match = (nxt[:, :sk] == draft) & (jk < dlen[:, None])
-            acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
-                          axis=1).astype(jnp.int32)
+            with jax.named_scope("scan_carry"):
+                nxt = jnp.stack(nxts, axis=1)                    # [B, Kp1]
+                lps = jnp.stack(lpss, axis=1)                    # [B, Kp1]
+                probs = jnp.stack(prbs, axis=1) if prbs else None
+                # accepted = longest draft prefix the redraw reproduces
+                jk = jnp.arange(sk, dtype=jnp.int32)[None, :]
+                match = (nxt[:, :sk] == draft) & (jk < dlen[:, None])
+                acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
+                              axis=1).astype(jnp.int32)
             return kcs, vcs, nxt, lps, probs, acc
 
         return jax.jit(spec_verify, donate_argnums=(1, 2))
@@ -1450,6 +1503,20 @@ class ServingEngine:
         seeds[slot] = sp.seed
         spos[slot] = req.sample_offset + len(req.generated)
 
+    def _phase(self, name: str, **attrs) -> _Phase:
+        """``with self._phase("schedule"):`` — the one place a step's
+        phases are measured (span and ``phase_seconds`` together)."""
+        return _Phase(self, name, attrs)
+
+    def _launch_phase(self, kind: str, k: int) -> _Phase:
+        """``engine.launch`` of one compiled program: ``k`` iterations of
+        ``kind``; ``launch`` counts launches (the ``FlightRecorder``'s
+        ``megastep`` events carry it too) and ``t_mono`` is this engine's
+        clock, so that a recorder event's ``t`` can be placed on the trace."""
+        self.launches += 1
+        return self._phase("launch", kind=kind, k=k, launch=self.launches,
+                           t_mono=self._clock())
+
     def step(self) -> Dict[int, List[int]]:
         """One engine iteration: schedule -> compiled step(s) -> retire.
         Returns tokens appended this step, {rid: [tok, ...]}.
@@ -1463,27 +1530,41 @@ class ServingEngine:
         returned lists then carry up to K tokens per request and the
         host — admission included — only observes the engine at megastep
         boundaries.  Prefill-only batches (plus int8 one-shot prefill
-        and ``megastep_k=1``) run the single-step program."""
-        t0 = self._clock()
-        self._try_admit()
-        if not self._active:
-            self.phase_seconds["schedule"] += self._clock() - t0
-            return {}
-        if self._faults is not None:
-            from .faults import prompt_signature
+        and ``megastep_k=1``) run the single-step program.
 
-            # detail carries each active request's prompt signature so a
-            # poison spec (match="p<t0>-<t1>-...") fires exactly when its
-            # request is scheduled — and keeps firing on whichever replica
-            # the request is retried on (the resumed prefill keeps the
-            # original prompt as its head)
-            self._faults.fire(
-                "engine.step",
-                detail=" ".join(prompt_signature(r.prompt)
-                                for r in self._active.values()))
-        enc = np.zeros((self.B,), np.int32)
-        dec = np.zeros((self.B,), np.int32)
-        now = np.zeros((self.B,), np.int32)
+        Spans (``RecordEvent``, children of ``engine.step``):
+        ``engine.admit``, ``engine.schedule``, ``engine.launch``,
+        ``engine.wait``, ``engine.harvest``."""
+        with RecordEvent("engine.step"):
+            with self._phase("admit"):
+                self._try_admit()
+            if not self._active:
+                return {}
+            if self._faults is not None:
+                from .faults import prompt_signature
+
+                # detail carries each active request's prompt signature so a
+                # poison spec (match="p<t0>-<t1>-...") fires exactly when its
+                # request is scheduled — and keeps firing on whichever replica
+                # the request is retried on (the resumed prefill keeps the
+                # original prompt as its head)
+                self._faults.fire(
+                    "engine.step",
+                    detail=" ".join(prompt_signature(r.prompt)
+                                    for r in self._active.values()))
+            with self._phase("schedule"):
+                sched, launch = self._route()
+            if launch is not None:
+                return launch()
+            if not sched:
+                return {}
+            return self._single_step(sched)
+
+    def _route(self):
+        """Pick this step's rows and program: ``(sched, launch)`` where
+        ``sched`` is [(req, n_tokens, finishes_prefill)] and ``launch``
+        runs the armed scan or verify program (None: the single-step
+        program takes ``sched``)."""
         budget = self.T
         sched: List[tuple] = []  # (req, n_tokens, finishes_prefill)
         # decode first (latency), then fill with prefill chunks.  Rows
@@ -1517,8 +1598,7 @@ class ServingEngine:
                     self._faults.fire("engine.prefill_chunk",
                                       detail=prompt_signature(req.prompt))
         if not sched:
-            self.phase_seconds["schedule"] += self._clock() - t0
-            return {}
+            return sched, None
         # pure-decode steps run the tight [B]-token program (mq=1); steps
         # carrying prefill chunks run the [T]-token program (mq=T) — decide
         # first, allocate the one token buffer the program actually takes
@@ -1548,13 +1628,11 @@ class ServingEngine:
                         # path — token-identical, never a wrong token
                         armed = False
                 if armed:
-                    self.phase_seconds["schedule"] += self._clock() - t0
-                    return self._spec_step(spec_rows, drafts)
+                    return sched, partial(self._spec_step, spec_rows, drafts)
         if (decode_only and self.megastep_k > 1
                 and max(r.max_new_tokens - len(r.generated)
                         for r, _, _ in sched) > 1):
-            self.phase_seconds["schedule"] += self._clock() - t0
-            return self._megastep([s[0] for s in sched])
+            return sched, partial(self._megastep, [s[0] for s in sched])
         # MIXED-PHASE arming (ISSUE 16): any decoding row + any prefilling
         # row -> run both phases inside one scan instead of falling back
         # to per-token host stepping.  int8 keeps one-shot prefill
@@ -1575,101 +1653,110 @@ class ServingEngine:
                         pre_rows.append(r)
                         budget_m -= cost
             if pre_rows:
-                self.phase_seconds["schedule"] += self._clock() - t0
-                return self._megastep_mixed(dec_rows, pre_rows)
-        tokens = np.zeros((self.B if decode_only else self.T,), np.int32)
-        # stable slot order so cu_seqlens is monotone over batch rows
-        sched.sort(key=lambda s: s[0].slot)
-        cu = np.zeros((self.B + 1,), np.int32)
-        temps = np.zeros((self.B,), np.float32)
-        top_ks = np.zeros((self.B,), np.int32)
-        top_ps = np.ones((self.B,), np.float32)
-        seeds = np.zeros((self.B,), np.int32)
-        spos = np.zeros((self.B,), np.int32)
-        per_slot = {s[0].slot: s for s in sched}
-        pos = 0
-        for slot in range(self.B):
-            cu[slot + 1] = pos
-            if slot not in per_slot:
-                continue
-            req, n, _ = per_slot[slot]
-            self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
-                                spos)
-            if req.in_prefill:
-                chunk = req.prompt[req.prefill_pos:req.prefill_pos + n]
-                enc[slot] = n
-                dec[slot] = req.prefill_pos
-                self.prefill_tokens_computed += n
-            else:
-                chunk = [req.generated[-1] if req.generated
-                         else req.prompt[-1]]
-                # cached tokens = prompt + generated[:-1]; the latest sampled
-                # token is only being fed (and cached) THIS step
-                dec[slot] = req.context_len - 1
-            now[slot] = n
-            tokens[pos:pos + n] = chunk
-            pos += n
-            cu[slot + 1] = pos
+                return sched, partial(self._megastep_mixed, dec_rows,
+                                      pre_rows)
+        return sched, None
 
-        t1 = self._clock()
-        self.phase_seconds["schedule"] += t1 - t0
-        had_cache = self._step_fn._cache_size() if hasattr(self._step_fn, "_cache_size") else None
-        nxt, lps, probs, self.key_caches, self.value_caches, new_scales = \
-            self._step_fn(
-                self._weights, self.key_caches, self.value_caches,
-                self._rope, jnp.asarray(tokens), jnp.asarray(enc),
-                jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu),
-                jnp.asarray(self.block_tables), jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps),
-                jnp.asarray(seeds), jnp.asarray(spos),
-                mq=1 if decode_only else self.T, scales=self.cache_scales)
-        if self.cache_scales is not None:
-            self.cache_scales = new_scales
-        if had_cache is not None:
-            self.compile_count += self._step_fn._cache_size() - had_cache
-        nxt = np.asarray(nxt)
-        lps = np.asarray(lps)
-        probs = np.asarray(probs) if probs is not None else None
-        t2 = self._clock()
-        self.phase_seconds["execute"] += t2 - t1
+    def _single_step(self, sched: List[tuple]) -> Dict[int, List[int]]:
+        """Run ``sched`` through the single-step program: prefill-only
+        batches, int8 one-shot prefill, ``megastep_k=1``."""
+        with self._phase("schedule"):
+            enc = np.zeros((self.B,), np.int32)
+            dec = np.zeros((self.B,), np.int32)
+            now = np.zeros((self.B,), np.int32)
+            # pure-decode steps run the tight [B]-token program (mq=1);
+            # steps carrying prefill chunks run the [T]-token program (mq=T)
+            decode_only = all(not r.in_prefill for r, _, _ in sched)
+            tokens = np.zeros((self.B if decode_only else self.T,), np.int32)
+            # stable slot order so cu_seqlens is monotone over batch rows
+            sched.sort(key=lambda s: s[0].slot)
+            cu = np.zeros((self.B + 1,), np.int32)
+            temps = np.zeros((self.B,), np.float32)
+            top_ks = np.zeros((self.B,), np.int32)
+            top_ps = np.ones((self.B,), np.float32)
+            seeds = np.zeros((self.B,), np.int32)
+            spos = np.zeros((self.B,), np.int32)
+            per_slot = {s[0].slot: s for s in sched}
+            pos = 0
+            for slot in range(self.B):
+                cu[slot + 1] = pos
+                if slot not in per_slot:
+                    continue
+                req, n, _ = per_slot[slot]
+                self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
+                                    spos)
+                if req.in_prefill:
+                    chunk = req.prompt[req.prefill_pos:req.prefill_pos + n]
+                    enc[slot] = n
+                    dec[slot] = req.prefill_pos
+                    self.prefill_tokens_computed += n
+                else:
+                    chunk = [req.generated[-1] if req.generated
+                             else req.prompt[-1]]
+                    # cached tokens = prompt + generated[:-1]; the latest sampled
+                    # token is only being fed (and cached) THIS step
+                    dec[slot] = req.context_len - 1
+                now[slot] = n
+                tokens[pos:pos + n] = chunk
+                pos += n
+                cu[slot + 1] = pos
 
-        emitted: Dict[int, List[int]] = {}
-        for req, n, finishes in sched:
-            if req.in_prefill:
-                req.prefill_pos += n
-                req.chunks_fed += 1
-                self.prefill_chunks += 1
-                if self.trace_recorder is not None and req.trace is not None:
-                    self.trace_recorder.record(
-                        req.trace["trace"], req.trace["span"],
-                        req.trace.get("parent"), "prefill_chunk",
-                        rid=req.trace.get("rid"),
-                        chunk=req.chunks_fed - 1, tokens=n)
-                if not finishes:
-                    continue  # mid-prompt chunk: sampled token is meaningless
-                if self.trace_recorder is not None and req.trace is not None:
-                    self.trace_recorder.record(
-                        req.trace["trace"], req.trace["span"],
-                        req.trace.get("parent"), "prefill",
-                        rid=req.trace.get("rid"),
-                        prompt_len=len(req.prompt))
-            tok = int(nxt[req.slot])
-            req.generated.append(tok)
-            if req.sampling.logprobs:
-                req.logprob_values.append(float(lps[req.slot]))
-                self._emitted_logprobs.setdefault(req.rid, []).append(
-                    float(lps[req.slot]))
-            if probs is not None:
-                # .copy(): probs[slot] is a view pinning the whole [B,V]
-                # step array alive (the megastep path's fancy-indexing
-                # already copies)
-                self._emitted_sample_probs.setdefault(req.rid, []).append(
-                    probs[req.slot].copy())
-            emitted.setdefault(req.rid, []).append(tok)
-            hit_eos = (req.eos_token_id is not None and tok == req.eos_token_id)
-            if hit_eos or len(req.generated) >= req.max_new_tokens:
-                self._retire(req)
-        self.phase_seconds["harvest"] += self._clock() - t2
+        with self._launch_phase("step", 1):
+            had_cache = self._step_fn._cache_size() if hasattr(self._step_fn, "_cache_size") else None
+            nxt, lps, probs, self.key_caches, self.value_caches, new_scales = \
+                self._step_fn(
+                    self._weights, self.key_caches, self.value_caches,
+                    self._rope, jnp.asarray(tokens), jnp.asarray(enc),
+                    jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu),
+                    jnp.asarray(self.block_tables), jnp.asarray(temps),
+                    jnp.asarray(top_ks), jnp.asarray(top_ps),
+                    jnp.asarray(seeds), jnp.asarray(spos),
+                    mq=1 if decode_only else self.T, scales=self.cache_scales)
+            if self.cache_scales is not None:
+                self.cache_scales = new_scales
+            if had_cache is not None:
+                self.compile_count += self._step_fn._cache_size() - had_cache
+        with self._phase("wait"):
+            nxt = np.asarray(nxt)
+            lps = np.asarray(lps)
+            probs = np.asarray(probs) if probs is not None else None
+        with self._phase("harvest"):
+            emitted: Dict[int, List[int]] = {}
+            for req, n, finishes in sched:
+                if req.in_prefill:
+                    req.prefill_pos += n
+                    req.chunks_fed += 1
+                    self.prefill_chunks += 1
+                    if self.trace_recorder is not None and req.trace is not None:
+                        self.trace_recorder.record(
+                            req.trace["trace"], req.trace["span"],
+                            req.trace.get("parent"), "prefill_chunk",
+                            rid=req.trace.get("rid"),
+                            chunk=req.chunks_fed - 1, tokens=n)
+                    if not finishes:
+                        continue  # mid-prompt chunk: sampled token is meaningless
+                    if self.trace_recorder is not None and req.trace is not None:
+                        self.trace_recorder.record(
+                            req.trace["trace"], req.trace["span"],
+                            req.trace.get("parent"), "prefill",
+                            rid=req.trace.get("rid"),
+                            prompt_len=len(req.prompt))
+                tok = int(nxt[req.slot])
+                req.generated.append(tok)
+                if req.sampling.logprobs:
+                    req.logprob_values.append(float(lps[req.slot]))
+                    self._emitted_logprobs.setdefault(req.rid, []).append(
+                        float(lps[req.slot]))
+                if probs is not None:
+                    # .copy(): probs[slot] is a view pinning the whole [B,V]
+                    # step array alive (the megastep path's fancy-indexing
+                    # already copies)
+                    self._emitted_sample_probs.setdefault(req.rid, []).append(
+                        probs[req.slot].copy())
+                emitted.setdefault(req.rid, []).append(tok)
+                hit_eos = (req.eos_token_id is not None and tok == req.eos_token_id)
+                if hit_eos or len(req.generated) >= req.max_new_tokens:
+                    self._retire(req)
         return emitted
 
     def _deadline_budgets(self, by_slot: Dict[int, "ServingRequest"]
@@ -1761,98 +1848,96 @@ class ServingEngine:
         exactly 1.0 when nothing accepts and < 1.0 iff speculation
         pays), ``spec_draft_tokens`` counts proposals,
         ``spec_accepted_tokens`` counts committed draft tokens."""
-        t0 = self._clock()
-        B, sk = self.B, self.spec_k
-        Kp1 = sk + 1
-        tokens = np.zeros((B * Kp1,), np.int32)
-        dec = np.zeros((B,), np.int32)
-        now = np.zeros((B,), np.int32)
-        cu = np.zeros((B + 1,), np.int32)
-        dlen = np.zeros((B,), np.int32)
-        draft_a = np.zeros((B, sk), np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        top_ps = np.ones((B,), np.float32)
-        seeds = np.zeros((B,), np.int32)
-        spos = np.zeros((B,), np.int32)
-        reqs = sorted(reqs, key=lambda r: r.slot)
-        by_slot = {r.slot: r for r in reqs}
-        pos = 0
-        for slot in range(B):
-            cu[slot + 1] = pos
-            req = by_slot.get(slot)
-            if req is None:
-                continue
-            d = drafts.get(req.rid, [])
-            row = [req.generated[-1] if req.generated else req.prompt[-1]]
-            row.extend(int(t) for t in d)
-            tokens[pos:pos + len(row)] = row
-            dec[slot] = req.context_len - 1
-            now[slot] = len(row)
-            dlen[slot] = len(d)
-            draft_a[slot, :len(d)] = d
-            self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
-                                spos)
-            pos += len(row)
-            cu[slot + 1] = pos
-        t1 = self._clock()
-        self.phase_seconds["schedule"] += t1 - t0
-        if self._spec_fn is None:
-            if "spec" not in self._programs:
-                self._programs["spec"] = self._build_spec_verify()
-            self._spec_fn = self._programs["spec"]
-        had = (self._spec_fn._cache_size()
-               if hasattr(self._spec_fn, "_cache_size") else None)
-        kcs, vcs, nxt, lps, probs, acc = self._spec_fn(
-            self._weights, self.key_caches, self.value_caches, self._rope,
-            jnp.asarray(tokens), jnp.asarray(dec), jnp.asarray(now),
-            jnp.asarray(cu), jnp.asarray(self.block_tables),
-            jnp.asarray(dlen), jnp.asarray(draft_a), jnp.asarray(temps),
-            jnp.asarray(top_ks), jnp.asarray(top_ps), jnp.asarray(seeds),
-            jnp.asarray(spos))
-        self.key_caches, self.value_caches = kcs, vcs
-        if had is not None:
-            self.compile_count += self._spec_fn._cache_size() - had
-        nxt = np.asarray(nxt)       # [B, spec_k+1] redraws
-        lps = np.asarray(lps)
-        probs = np.asarray(probs) if probs is not None else None
-        acc = np.asarray(acc)       # [B] accepted draft-prefix lengths
-        t2 = self._clock()
-        self.phase_seconds["execute"] += t2 - t1
+        with self._phase("schedule"):
+            B, sk = self.B, self.spec_k
+            Kp1 = sk + 1
+            tokens = np.zeros((B * Kp1,), np.int32)
+            dec = np.zeros((B,), np.int32)
+            now = np.zeros((B,), np.int32)
+            cu = np.zeros((B + 1,), np.int32)
+            dlen = np.zeros((B,), np.int32)
+            draft_a = np.zeros((B, sk), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            top_ps = np.ones((B,), np.float32)
+            seeds = np.zeros((B,), np.int32)
+            spos = np.zeros((B,), np.int32)
+            reqs = sorted(reqs, key=lambda r: r.slot)
+            by_slot = {r.slot: r for r in reqs}
+            pos = 0
+            for slot in range(B):
+                cu[slot + 1] = pos
+                req = by_slot.get(slot)
+                if req is None:
+                    continue
+                d = drafts.get(req.rid, [])
+                row = [req.generated[-1] if req.generated else req.prompt[-1]]
+                row.extend(int(t) for t in d)
+                tokens[pos:pos + len(row)] = row
+                dec[slot] = req.context_len - 1
+                now[slot] = len(row)
+                dlen[slot] = len(d)
+                draft_a[slot, :len(d)] = d
+                self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
+                                    spos)
+                pos += len(row)
+                cu[slot + 1] = pos
+        with self._launch_phase("spec", Kp1):
+            if self._spec_fn is None:
+                if "spec" not in self._programs:
+                    self._programs["spec"] = self._build_spec_verify()
+                self._spec_fn = self._programs["spec"]
+            had = (self._spec_fn._cache_size()
+                   if hasattr(self._spec_fn, "_cache_size") else None)
+            kcs, vcs, nxt, lps, probs, acc = self._spec_fn(
+                self._weights, self.key_caches, self.value_caches, self._rope,
+                jnp.asarray(tokens), jnp.asarray(dec), jnp.asarray(now),
+                jnp.asarray(cu), jnp.asarray(self.block_tables),
+                jnp.asarray(dlen), jnp.asarray(draft_a), jnp.asarray(temps),
+                jnp.asarray(top_ks), jnp.asarray(top_ps), jnp.asarray(seeds),
+                jnp.asarray(spos))
+            self.key_caches, self.value_caches = kcs, vcs
+            if had is not None:
+                self.compile_count += self._spec_fn._cache_size() - had
+        with self._phase("wait"):
+            nxt = np.asarray(nxt)       # [B, spec_k+1] redraws
+            lps = np.asarray(lps)
+            probs = np.asarray(probs) if probs is not None else None
+            acc = np.asarray(acc)       # [B] accepted draft-prefix lengths
 
-        emitted: Dict[int, List[int]] = {}
-        for req in reqs:
-            s = req.slot
-            new = [int(t) for t in nxt[s, :int(acc[s]) + 1]]
-            if req.eos_token_id is not None and req.eos_token_id in new:
-                # the non-spec engine stops AT the EOS: accepted draft
-                # tokens past it were never going to be generated
-                new = new[:new.index(req.eos_token_id) + 1]
-            d = int(dlen[s])
-            req.generated.extend(new)
-            if req.sampling.logprobs:
-                row_lps = [float(v) for v in lps[s, :len(new)]]
-                req.logprob_values.extend(row_lps)
-                self._emitted_logprobs.setdefault(req.rid, []).extend(
-                    row_lps)
-            if probs is not None:
-                self._emitted_sample_probs.setdefault(req.rid, []).extend(
-                    probs[s, j].copy() for j in range(len(new)))
-            emitted[req.rid] = new
-            self.spec_verify_forwards += 1
-            self.spec_draft_tokens += d
-            self.spec_accepted_tokens += len(new) - 1
-            if self.trace_recorder is not None and req.trace is not None:
-                self.trace_recorder.record(
-                    req.trace["trace"], req.trace["span"],
-                    req.trace.get("parent"), "spec_verify",
-                    rid=req.trace.get("rid"), drafted=d,
-                    accepted=len(new) - 1, tokens=len(new))
-            hit_eos = (req.eos_token_id is not None
-                       and new[-1] == req.eos_token_id)
-            if hit_eos or len(req.generated) >= req.max_new_tokens:
-                self._retire(req)
-        self.phase_seconds["harvest"] += self._clock() - t2
+        with self._phase("harvest"):
+            emitted: Dict[int, List[int]] = {}
+            for req in reqs:
+                s = req.slot
+                new = [int(t) for t in nxt[s, :int(acc[s]) + 1]]
+                if req.eos_token_id is not None and req.eos_token_id in new:
+                    # the non-spec engine stops AT the EOS: accepted draft
+                    # tokens past it were never going to be generated
+                    new = new[:new.index(req.eos_token_id) + 1]
+                d = int(dlen[s])
+                req.generated.extend(new)
+                if req.sampling.logprobs:
+                    row_lps = [float(v) for v in lps[s, :len(new)]]
+                    req.logprob_values.extend(row_lps)
+                    self._emitted_logprobs.setdefault(req.rid, []).extend(
+                        row_lps)
+                if probs is not None:
+                    self._emitted_sample_probs.setdefault(req.rid, []).extend(
+                        probs[s, j].copy() for j in range(len(new)))
+                emitted[req.rid] = new
+                self.spec_verify_forwards += 1
+                self.spec_draft_tokens += d
+                self.spec_accepted_tokens += len(new) - 1
+                if self.trace_recorder is not None and req.trace is not None:
+                    self.trace_recorder.record(
+                        req.trace["trace"], req.trace["span"],
+                        req.trace.get("parent"), "spec_verify",
+                        rid=req.trace.get("rid"), drafted=d,
+                        accepted=len(new) - 1, tokens=len(new))
+                hit_eos = (req.eos_token_id is not None
+                           and new[-1] == req.eos_token_id)
+                if hit_eos or len(req.generated) >= req.max_new_tokens:
+                    self._retire(req)
         return emitted
 
     def _megastep(self, reqs: List[ServingRequest]) -> Dict[int, List[int]]:
@@ -1870,107 +1955,106 @@ class ServingEngine:
             self._faults.fire(
                 "engine.megastep",
                 detail=" ".join(prompt_signature(r.prompt) for r in reqs))
-        t0 = self._clock()
-        kmax = max(r.max_new_tokens - len(r.generated) for r in reqs)
-        K = 1
-        while K < min(self.megastep_k, kmax):
-            K *= 2
-        K = min(K, self.megastep_k)
-        B = self.B
-        toks = np.zeros((B,), np.int32)
-        dec = np.zeros((B,), np.int32)
-        now = np.zeros((B,), np.int32)
-        occ_idx = np.zeros((B,), np.int32)
-        cu = np.zeros((B + 1,), np.int32)
-        active = np.zeros((B,), bool)
-        remaining = np.zeros((B,), np.int32)
-        eos = np.full((B,), -1, np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        top_ps = np.ones((B,), np.float32)
-        seeds = np.zeros((B,), np.int32)
-        spos = np.zeros((B,), np.int32)
-        reqs = sorted(reqs, key=lambda r: r.slot)
-        by_slot = {r.slot: r for r in reqs}
-        pos = 0
-        for slot in range(B):
-            req = by_slot.get(slot)
-            if req is not None:
-                occ_idx[pos] = slot
-                toks[slot] = (req.generated[-1] if req.generated
-                              else req.prompt[-1])
-                dec[slot] = req.context_len - 1
-                now[slot] = 1
-                active[slot] = True
-                remaining[slot] = req.max_new_tokens - len(req.generated)
-                if req.eos_token_id is not None:
-                    eos[slot] = req.eos_token_id
-                self._fill_sampling(req, slot, temps, top_ks, top_ps,
-                                    seeds, spos)
-                pos += 1
-            cu[slot + 1] = pos
-        dl = self._deadline_budgets(by_slot)
-        t1 = self._clock()
-        self.phase_seconds["schedule"] += t1 - t0
-        if self._mega_fn is None:
-            if "mega" not in self._programs:
-                self._programs["mega"] = self._build_megastep()
-            self._mega_fn = self._programs["mega"]
-        had = (self._mega_fn._cache_size()
-               if hasattr(self._mega_fn, "_cache_size") else None)
-        kcs, vcs, new_scales, toks_o, valid_o, lps_o, probs_o = \
-            self._mega_fn(
-                self._weights, self.key_caches, self.value_caches,
-                self._rope, jnp.asarray(toks), jnp.asarray(dec),
-                jnp.asarray(now), jnp.asarray(cu), jnp.asarray(occ_idx),
-                jnp.asarray(self.block_tables), jnp.asarray(active),
-                jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
-                jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), jnp.asarray(seeds),
-                jnp.asarray(spos), self.cache_scales, K=K)
-        self.key_caches, self.value_caches = kcs, vcs
-        if self.cache_scales is not None:
-            self.cache_scales = new_scales
-        compiled = False
-        if had is not None:
-            grew = self._mega_fn._cache_size() - had
-            self.compile_count += grew
-            compiled = grew > 0
-        toks_o = np.asarray(toks_o)       # [K, B]
-        valid_o = np.asarray(valid_o)
-        lps_o = np.asarray(lps_o)
-        probs_o = np.asarray(probs_o) if probs_o is not None else None
+        with self._phase("schedule"):
+            kmax = max(r.max_new_tokens - len(r.generated) for r in reqs)
+            K = 1
+            while K < min(self.megastep_k, kmax):
+                K *= 2
+            K = min(K, self.megastep_k)
+            B = self.B
+            toks = np.zeros((B,), np.int32)
+            dec = np.zeros((B,), np.int32)
+            now = np.zeros((B,), np.int32)
+            occ_idx = np.zeros((B,), np.int32)
+            cu = np.zeros((B + 1,), np.int32)
+            active = np.zeros((B,), bool)
+            remaining = np.zeros((B,), np.int32)
+            eos = np.full((B,), -1, np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            top_ps = np.ones((B,), np.float32)
+            seeds = np.zeros((B,), np.int32)
+            spos = np.zeros((B,), np.int32)
+            reqs = sorted(reqs, key=lambda r: r.slot)
+            by_slot = {r.slot: r for r in reqs}
+            pos = 0
+            for slot in range(B):
+                req = by_slot.get(slot)
+                if req is not None:
+                    occ_idx[pos] = slot
+                    toks[slot] = (req.generated[-1] if req.generated
+                                  else req.prompt[-1])
+                    dec[slot] = req.context_len - 1
+                    now[slot] = 1
+                    active[slot] = True
+                    remaining[slot] = req.max_new_tokens - len(req.generated)
+                    if req.eos_token_id is not None:
+                        eos[slot] = req.eos_token_id
+                    self._fill_sampling(req, slot, temps, top_ks, top_ps,
+                                        seeds, spos)
+                    pos += 1
+                cu[slot + 1] = pos
+            dl = self._deadline_budgets(by_slot)
+        with self._launch_phase("mega", K) as launch:
+            if self._mega_fn is None:
+                if "mega" not in self._programs:
+                    self._programs["mega"] = self._build_megastep()
+                self._mega_fn = self._programs["mega"]
+            had = (self._mega_fn._cache_size()
+                   if hasattr(self._mega_fn, "_cache_size") else None)
+            kcs, vcs, new_scales, toks_o, valid_o, lps_o, probs_o = \
+                self._mega_fn(
+                    self._weights, self.key_caches, self.value_caches,
+                    self._rope, jnp.asarray(toks), jnp.asarray(dec),
+                    jnp.asarray(now), jnp.asarray(cu), jnp.asarray(occ_idx),
+                    jnp.asarray(self.block_tables), jnp.asarray(active),
+                    jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(top_ps), jnp.asarray(seeds),
+                    jnp.asarray(spos), self.cache_scales, K=K)
+            self.key_caches, self.value_caches = kcs, vcs
+            if self.cache_scales is not None:
+                self.cache_scales = new_scales
+            compiled = False
+            if had is not None:
+                grew = self._mega_fn._cache_size() - had
+                self.compile_count += grew
+                compiled = grew > 0
+        with self._phase("wait") as wait:
+            toks_o = np.asarray(toks_o)       # [K, B]
+            valid_o = np.asarray(valid_o)
+            lps_o = np.asarray(lps_o)
+            probs_o = np.asarray(probs_o) if probs_o is not None else None
         self.megasteps += 1
-        t2 = self._clock()
-        self.phase_seconds["execute"] += t2 - t1
-        self._update_tau(t2 - t1, K, compiled)
+        self._update_tau(launch.seconds + wait.seconds, K, compiled)
 
-        emitted: Dict[int, List[int]] = {}
-        for req in reqs:
-            s = req.slot
-            col = valid_o[:, s]
-            new = [int(t) for t in toks_o[:, s][col]]
-            req.generated.extend(new)
-            if req.sampling.logprobs:
-                row_lps = [float(v) for v in lps_o[:, s][col]]
-                req.logprob_values.extend(row_lps)
-                self._emitted_logprobs.setdefault(req.rid, []).extend(row_lps)
-            if probs_o is not None and new:
-                self._emitted_sample_probs.setdefault(req.rid, []).extend(
-                    probs_o[:, s][col])   # [n_valid, V]
-            emitted[req.rid] = new
-            self.megastep_tokens += len(new)
-            if self.trace_recorder is not None and req.trace is not None:
-                self.trace_recorder.record(
-                    req.trace["trace"], req.trace["span"],
-                    req.trace.get("parent"), "megastep",
-                    rid=req.trace.get("rid"), tokens=len(new), k=K)
-            hit_eos = (req.eos_token_id is not None and new
-                       and new[-1] == req.eos_token_id)
-            if hit_eos or len(req.generated) >= req.max_new_tokens:
-                self._retire(req)
-        self._free_frozen(reqs, dl, K)
-        self.phase_seconds["harvest"] += self._clock() - t2
+        with self._phase("harvest"):
+            emitted: Dict[int, List[int]] = {}
+            for req in reqs:
+                s = req.slot
+                col = valid_o[:, s]
+                new = [int(t) for t in toks_o[:, s][col]]
+                req.generated.extend(new)
+                if req.sampling.logprobs:
+                    row_lps = [float(v) for v in lps_o[:, s][col]]
+                    req.logprob_values.extend(row_lps)
+                    self._emitted_logprobs.setdefault(req.rid, []).extend(row_lps)
+                if probs_o is not None and new:
+                    self._emitted_sample_probs.setdefault(req.rid, []).extend(
+                        probs_o[:, s][col])   # [n_valid, V]
+                emitted[req.rid] = new
+                self.megastep_tokens += len(new)
+                if self.trace_recorder is not None and req.trace is not None:
+                    self.trace_recorder.record(
+                        req.trace["trace"], req.trace["span"],
+                        req.trace.get("parent"), "megastep",
+                        rid=req.trace.get("rid"), tokens=len(new), k=K,
+                        launch=self.launches)
+                hit_eos = (req.eos_token_id is not None and new
+                           and new[-1] == req.eos_token_id)
+                if hit_eos or len(req.generated) >= req.max_new_tokens:
+                    self._retire(req)
+            self._free_frozen(reqs, dl, K)
         return emitted
 
     def _megastep_mixed(self, dec_reqs: List[ServingRequest],
@@ -2000,131 +2084,130 @@ class ServingEngine:
                 # per prompt entering the scan chunked
                 self._faults.fire("engine.prefill_chunk",
                                   detail=prompt_signature(r.prompt))
-        t0 = self._clock()
-        C = self.pc
-        K = self.megastep_k
-        B = self.B
-        toks = np.zeros((B,), np.int32)
-        cached = np.zeros((B,), np.int32)
-        pp = np.zeros((B,), np.int32)
-        pp0 = np.zeros((B,), np.int32)
-        plen = np.zeros((B,), np.int32)
-        prompt_buf = np.zeros((B, K * C), np.int32)
-        active = np.zeros((B,), bool)
-        remaining = np.zeros((B,), np.int32)
-        eos = np.full((B,), -1, np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        top_ps = np.ones((B,), np.float32)
-        seeds = np.zeros((B,), np.int32)
-        spos = np.zeros((B,), np.int32)
-        by_slot = {r.slot: r for r in reqs}
-        for slot, req in by_slot.items():
-            active[slot] = True
-            remaining[slot] = req.max_new_tokens - len(req.generated)
-            if req.eos_token_id is not None:
-                eos[slot] = req.eos_token_id
-            self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
-                                spos)
-            if req.in_prefill:
-                # the prompt window this scan can reach: K chunks of C
-                pp[slot] = pp0[slot] = cached[slot] = req.prefill_pos
-                plen[slot] = len(req.prompt)
-                window = req.prompt[req.prefill_pos:
-                                    req.prefill_pos + K * C]
-                prompt_buf[slot, :len(window)] = window
-            else:
-                toks[slot] = (req.generated[-1] if req.generated
-                              else req.prompt[-1])
-                cached[slot] = req.context_len - 1
-                # pp == plen marks the row as decoding from iteration 0
-                pp[slot] = pp0[slot] = plen[slot] = len(req.prompt)
-        dl = self._deadline_budgets(by_slot)
-        t1 = self._clock()
-        self.phase_seconds["schedule"] += t1 - t0
-        if self._mixed_fn is None:
-            if "mixed" not in self._programs:
-                self._programs["mixed"] = self._build_mixed_megastep()
-            self._mixed_fn = self._programs["mixed"]
-        had = (self._mixed_fn._cache_size()
-               if hasattr(self._mixed_fn, "_cache_size") else None)
-        kcs, vcs, pp_f, toks_o, emits_o, lps_o, probs_o = self._mixed_fn(
-            self._weights, self.key_caches, self.value_caches, self._rope,
-            jnp.asarray(toks), jnp.asarray(cached), jnp.asarray(pp),
-            jnp.asarray(pp0), jnp.asarray(plen), jnp.asarray(prompt_buf),
-            jnp.asarray(self.block_tables), jnp.asarray(active),
-            jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            jnp.asarray(seeds), jnp.asarray(spos), K=K)
-        self.key_caches, self.value_caches = kcs, vcs
-        compiled = False
-        if had is not None:
-            grew = self._mixed_fn._cache_size() - had
-            self.compile_count += grew
-            compiled = grew > 0
-        pp_f = np.asarray(pp_f)           # [B] final prefill positions
-        toks_o = np.asarray(toks_o)       # [K, B]
-        emits_o = np.asarray(emits_o)
-        lps_o = np.asarray(lps_o)
-        probs_o = np.asarray(probs_o) if probs_o is not None else None
+        with self._phase("schedule"):
+            C = self.pc
+            K = self.megastep_k
+            B = self.B
+            toks = np.zeros((B,), np.int32)
+            cached = np.zeros((B,), np.int32)
+            pp = np.zeros((B,), np.int32)
+            pp0 = np.zeros((B,), np.int32)
+            plen = np.zeros((B,), np.int32)
+            prompt_buf = np.zeros((B, K * C), np.int32)
+            active = np.zeros((B,), bool)
+            remaining = np.zeros((B,), np.int32)
+            eos = np.full((B,), -1, np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            top_ps = np.ones((B,), np.float32)
+            seeds = np.zeros((B,), np.int32)
+            spos = np.zeros((B,), np.int32)
+            by_slot = {r.slot: r for r in reqs}
+            for slot, req in by_slot.items():
+                active[slot] = True
+                remaining[slot] = req.max_new_tokens - len(req.generated)
+                if req.eos_token_id is not None:
+                    eos[slot] = req.eos_token_id
+                self._fill_sampling(req, slot, temps, top_ks, top_ps, seeds,
+                                    spos)
+                if req.in_prefill:
+                    # the prompt window this scan can reach: K chunks of C
+                    pp[slot] = pp0[slot] = cached[slot] = req.prefill_pos
+                    plen[slot] = len(req.prompt)
+                    window = req.prompt[req.prefill_pos:
+                                        req.prefill_pos + K * C]
+                    prompt_buf[slot, :len(window)] = window
+                else:
+                    toks[slot] = (req.generated[-1] if req.generated
+                                  else req.prompt[-1])
+                    cached[slot] = req.context_len - 1
+                    # pp == plen marks the row as decoding from iteration 0
+                    pp[slot] = pp0[slot] = plen[slot] = len(req.prompt)
+            dl = self._deadline_budgets(by_slot)
+        with self._launch_phase("mixed", K) as launch:
+            if self._mixed_fn is None:
+                if "mixed" not in self._programs:
+                    self._programs["mixed"] = self._build_mixed_megastep()
+                self._mixed_fn = self._programs["mixed"]
+            had = (self._mixed_fn._cache_size()
+                   if hasattr(self._mixed_fn, "_cache_size") else None)
+            kcs, vcs, pp_f, toks_o, emits_o, lps_o, probs_o = self._mixed_fn(
+                self._weights, self.key_caches, self.value_caches, self._rope,
+                jnp.asarray(toks), jnp.asarray(cached), jnp.asarray(pp),
+                jnp.asarray(pp0), jnp.asarray(plen), jnp.asarray(prompt_buf),
+                jnp.asarray(self.block_tables), jnp.asarray(active),
+                jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
+                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
+                jnp.asarray(seeds), jnp.asarray(spos), K=K)
+            self.key_caches, self.value_caches = kcs, vcs
+            compiled = False
+            if had is not None:
+                grew = self._mixed_fn._cache_size() - had
+                self.compile_count += grew
+                compiled = grew > 0
+        with self._phase("wait") as wait:
+            pp_f = np.asarray(pp_f)           # [B] final prefill positions
+            toks_o = np.asarray(toks_o)       # [K, B]
+            emits_o = np.asarray(emits_o)
+            lps_o = np.asarray(lps_o)
+            probs_o = np.asarray(probs_o) if probs_o is not None else None
         self.megasteps += 1
         self.megasteps_mixed += 1
-        t2 = self._clock()
-        self.phase_seconds["execute"] += t2 - t1
-        self._update_tau(t2 - t1, K, compiled)
+        self._update_tau(launch.seconds + wait.seconds, K, compiled)
 
-        emitted: Dict[int, List[int]] = {}
-        for req in sorted(reqs, key=lambda r: r.slot):
-            s = req.slot
-            col = emits_o[:, s]
-            new = [int(t) for t in toks_o[:, s][col]]
-            fed = int(pp_f[s]) - req.prefill_pos
-            if fed > 0:
-                # reconstruct the chunk boundaries the scan crossed (all
-                # full C except a completing tail) for counters + spans
-                req.prefill_pos += fed
-                self.prefill_tokens_computed += fed
-                nch = -(-fed // C)
-                for i in range(nch):
-                    ntok = min(C, fed - i * C)
-                    req.chunks_fed += 1
-                    self.prefill_chunks += 1
-                    if (self.trace_recorder is not None
+        with self._phase("harvest"):
+            emitted: Dict[int, List[int]] = {}
+            for req in sorted(reqs, key=lambda r: r.slot):
+                s = req.slot
+                col = emits_o[:, s]
+                new = [int(t) for t in toks_o[:, s][col]]
+                fed = int(pp_f[s]) - req.prefill_pos
+                if fed > 0:
+                    # reconstruct the chunk boundaries the scan crossed (all
+                    # full C except a completing tail) for counters + spans
+                    req.prefill_pos += fed
+                    self.prefill_tokens_computed += fed
+                    nch = -(-fed // C)
+                    for i in range(nch):
+                        ntok = min(C, fed - i * C)
+                        req.chunks_fed += 1
+                        self.prefill_chunks += 1
+                        if (self.trace_recorder is not None
+                                and req.trace is not None):
+                            self.trace_recorder.record(
+                                req.trace["trace"], req.trace["span"],
+                                req.trace.get("parent"), "prefill_chunk",
+                                rid=req.trace.get("rid"),
+                                chunk=req.chunks_fed - 1, tokens=ntok)
+                    if (not req.in_prefill and self.trace_recorder is not None
                             and req.trace is not None):
                         self.trace_recorder.record(
                             req.trace["trace"], req.trace["span"],
-                            req.trace.get("parent"), "prefill_chunk",
+                            req.trace.get("parent"), "prefill",
                             rid=req.trace.get("rid"),
-                            chunk=req.chunks_fed - 1, tokens=ntok)
-                if (not req.in_prefill and self.trace_recorder is not None
-                        and req.trace is not None):
+                            prompt_len=len(req.prompt))
+                req.generated.extend(new)
+                if req.sampling.logprobs:
+                    row_lps = [float(v) for v in lps_o[:, s][col]]
+                    req.logprob_values.extend(row_lps)
+                    self._emitted_logprobs.setdefault(req.rid, []).extend(
+                        row_lps)
+                if probs_o is not None and new:
+                    self._emitted_sample_probs.setdefault(req.rid, []).extend(
+                        probs_o[:, s][col])   # [n_valid, V]
+                emitted[req.rid] = new
+                self.megastep_tokens += len(new)
+                if self.trace_recorder is not None and req.trace is not None:
                     self.trace_recorder.record(
                         req.trace["trace"], req.trace["span"],
-                        req.trace.get("parent"), "prefill",
-                        rid=req.trace.get("rid"),
-                        prompt_len=len(req.prompt))
-            req.generated.extend(new)
-            if req.sampling.logprobs:
-                row_lps = [float(v) for v in lps_o[:, s][col]]
-                req.logprob_values.extend(row_lps)
-                self._emitted_logprobs.setdefault(req.rid, []).extend(
-                    row_lps)
-            if probs_o is not None and new:
-                self._emitted_sample_probs.setdefault(req.rid, []).extend(
-                    probs_o[:, s][col])   # [n_valid, V]
-            emitted[req.rid] = new
-            self.megastep_tokens += len(new)
-            if self.trace_recorder is not None and req.trace is not None:
-                self.trace_recorder.record(
-                    req.trace["trace"], req.trace["span"],
-                    req.trace.get("parent"), "megastep",
-                    rid=req.trace.get("rid"), tokens=len(new), k=K)
-            hit_eos = (req.eos_token_id is not None and new
-                       and new[-1] == req.eos_token_id)
-            if hit_eos or len(req.generated) >= req.max_new_tokens:
-                self._retire(req)
-        self._free_frozen(reqs, dl, K)
-        self.phase_seconds["harvest"] += self._clock() - t2
+                        req.trace.get("parent"), "megastep",
+                        rid=req.trace.get("rid"), tokens=len(new), k=K,
+                        launch=self.launches)
+                hit_eos = (req.eos_token_id is not None and new
+                           and new[-1] == req.eos_token_id)
+                if hit_eos or len(req.generated) >= req.max_new_tokens:
+                    self._retire(req)
+            self._free_frozen(reqs, dl, K)
         return emitted
 
     def run(self, max_steps: int = 10_000) -> Dict[int, List[int]]:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..autograd import tape
 from ..framework.random import default_generator
+from ..profiler import RecordEvent
 from ..tensor.tensor import Tensor
 from . import trace_state
 
@@ -527,10 +528,11 @@ class TrainStep:
 
             found_inf = None
             if scaler:
-                inv = (1.0 / scaler_state["scale"])
-                grads = [g * inv.astype(g.dtype) for g in grads]
-                nonfinite = sum(jnp.sum(~jnp.isfinite(g)) for g in grads)
-                found_inf = nonfinite > 0
+                with jax.named_scope("grad_unscale"):
+                    inv = (1.0 / scaler_state["scale"])
+                    grads = [g * inv.astype(g.dtype) for g in grads]
+                    nonfinite = sum(jnp.sum(~jnp.isfinite(g)) for g in grads)
+                    found_inf = nonfinite > 0
 
             # ZeRO stage >= 2: constrain grads to the sharding axis so XLA
             # emits reduce-scatter instead of all-reduce (auto_parallel
@@ -547,7 +549,7 @@ class TrainStep:
             self._put_opt_state(accs, masters)
             grad_of = {id(p): g for p, g in zip(params, grads)}
             try:
-                with _SwapValues(params, list(param_vals)):
+                with _SwapValues(params, list(param_vals)), jax.named_scope("optimizer"):
                     for group in opt._param_groups:
                         pg = [
                             (p, Tensor(grad_of[id(p)], stop_gradient=True))
@@ -608,43 +610,45 @@ class TrainStep:
 
     # ------------------------------------------------------------- call
     def __call__(self, *batch):
-        batch_tensors, spec = flatten_tensors(batch)
-        first_call = self._compiled is None
-        if first_call:
-            self._spec = spec
-            self._spec_sig = _spec_signature(spec)
-            self._compiled = self._build(spec)
-        elif _spec_signature(spec) != self._spec_sig:
-            raise ValueError(
-                "TrainStep is specialized to the batch structure of its first "
-                "call; build a new TrainStep for a different structure")
-        batch_vals = tuple(t._value for t in batch_tensors)
-        rng_key = default_generator().next_key()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        buf_vals = [b._value for b in self._buffers]
-        accs, masters = self._get_opt_state()
-        if first_call:
-            from .hlo_dump import dump_dir, maybe_dump
+        with RecordEvent("train_step.call", step=self.optimizer._step_count,
+                         steps=1):
+            batch_tensors, spec = flatten_tensors(batch)
+            first_call = self._compiled is None
+            if first_call:
+                self._spec = spec
+                self._spec_sig = _spec_signature(spec)
+                self._compiled = self._build(spec)
+            elif _spec_signature(spec) != self._spec_sig:
+                raise ValueError(
+                    "TrainStep is specialized to the batch structure of its first "
+                    "call; build a new TrainStep for a different structure")
+            batch_vals = tuple(t._value for t in batch_tensors)
+            rng_key = default_generator().next_key()
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            buf_vals = [b._value for b in self._buffers]
+            accs, masters = self._get_opt_state()
+            if first_call:
+                from .hlo_dump import dump_dir, maybe_dump
 
-            if dump_dir():
-                maybe_dump("train_step", self._compiled,
-                           ([p._value for p in self._params], accs, masters, buf_vals,
-                            self._scaler_state(), rng_key, batch_vals, lr))
-        loss, new_params, new_accs, new_masters, buf_out, new_scaler = self._compiled(
-            [p._value for p in self._params], accs, masters, buf_vals,
-            self._scaler_state(), rng_key, batch_vals, lr,
-        )
-        for p, v in zip(self._params, new_params):
-            p._value = v
-        self._put_opt_state(new_accs, new_masters)
-        for b, v in zip(self._buffers, buf_out):
-            b._value = v
-        if self.scaler is not None and new_scaler:
-            self.scaler._scale = new_scaler["scale"]
-            self.scaler._good_steps = new_scaler["good"]
-            self.scaler._bad_steps = new_scaler["bad"]
-        self.optimizer._step_count += 1
-        return Tensor(loss)
+                if dump_dir():
+                    maybe_dump("train_step", self._compiled,
+                               ([p._value for p in self._params], accs, masters, buf_vals,
+                                self._scaler_state(), rng_key, batch_vals, lr))
+            loss, new_params, new_accs, new_masters, buf_out, new_scaler = self._compiled(
+                [p._value for p in self._params], accs, masters, buf_vals,
+                self._scaler_state(), rng_key, batch_vals, lr,
+            )
+            for p, v in zip(self._params, new_params):
+                p._value = v
+            self._put_opt_state(new_accs, new_masters)
+            for b, v in zip(self._buffers, buf_out):
+                b._value = v
+            if self.scaler is not None and new_scaler:
+                self.scaler._scale = new_scaler["scale"]
+                self.scaler._good_steps = new_scaler["good"]
+                self.scaler._bad_steps = new_scaler["bad"]
+            self.optimizer._step_count += 1
+            return Tensor(loss)
 
     def sync_to_model(self):
         """Params are written back after every step; kept for API compat."""
@@ -677,51 +681,53 @@ class TrainStep:
             raise ValueError(
                 "TrainStep is specialized to the batch structure of its first "
                 "call; build a new TrainStep for a different structure")
-        multi = self._multi_cache.get(spec_sig)
-        if multi is None:
-            step_raw = self._step_raw
+        with RecordEvent("train_step.call", step=self.optimizer._step_count,
+                         steps=K):
+            multi = self._multi_cache.get(spec_sig)
+            if multi is None:
+                step_raw = self._step_raw
 
-            def multi_fn(param_vals, accs, masters, buf_vals, scaler_state,
-                         base_key, batch_stack_vals, lr):
-                # K comes from the stack itself (jit retraces per shape), so
-                # the structure-keyed cache serves any window length
-                n_steps = batch_stack_vals[0].shape[0]
+                def multi_fn(param_vals, accs, masters, buf_vals, scaler_state,
+                             base_key, batch_stack_vals, lr):
+                    # K comes from the stack itself (jit retraces per shape), so
+                    # the structure-keyed cache serves any window length
+                    n_steps = batch_stack_vals[0].shape[0]
 
-                def body(carry, xs):
-                    pv, ac, ms, bv, ss = carry
-                    i, batch_vals = xs
-                    key = jax.random.fold_in(base_key, i)
-                    loss, pv, ac, ms, bv, ss = step_raw(
-                        pv, ac, ms, bv, ss, key, batch_vals, lr)
-                    return (pv, ac, ms, bv, ss), loss
+                    def body(carry, xs):
+                        pv, ac, ms, bv, ss = carry
+                        i, batch_vals = xs
+                        key = jax.random.fold_in(base_key, i)
+                        loss, pv, ac, ms, bv, ss = step_raw(
+                            pv, ac, ms, bv, ss, key, batch_vals, lr)
+                        return (pv, ac, ms, bv, ss), loss
 
-                carry0 = (list(param_vals), accs, masters, list(buf_vals),
-                          scaler_state)
-                (pv, ac, ms, bv, ss), losses = jax.lax.scan(
-                    body, carry0, (jnp.arange(n_steps), tuple(batch_stack_vals)))
-                return losses, pv, ac, ms, bv, ss
+                    carry0 = (list(param_vals), accs, masters, list(buf_vals),
+                              scaler_state)
+                    (pv, ac, ms, bv, ss), losses = jax.lax.scan(
+                        body, carry0, (jnp.arange(n_steps), tuple(batch_stack_vals)))
+                    return losses, pv, ac, ms, bv, ss
 
-            donate = (0, 1, 2, 3) if self._donate else ()
-            multi = jax.jit(multi_fn, donate_argnums=donate)
-            self._multi_cache[spec_sig] = multi
+                donate = (0, 1, 2, 3) if self._donate else ()
+                multi = jax.jit(multi_fn, donate_argnums=donate)
+                self._multi_cache[spec_sig] = multi
 
-        batch_vals = tuple(t._value for t in batch_tensors)
-        base_key = default_generator().next_key()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        accs, masters = self._get_opt_state()
-        losses, new_params, new_accs, new_masters, buf_out, new_scaler = multi(
-            [p._value for p in self._params], accs, masters,
-            [b._value for b in self._buffers], self._scaler_state(),
-            base_key, batch_vals, lr,
-        )
-        for p, v in zip(self._params, new_params):
-            p._value = v
-        self._put_opt_state(new_accs, new_masters)
-        for b, v in zip(self._buffers, buf_out):
-            b._value = v
-        if self.scaler is not None and new_scaler:
-            self.scaler._scale = new_scaler["scale"]
-            self.scaler._good_steps = new_scaler["good"]
-            self.scaler._bad_steps = new_scaler["bad"]
-        self.optimizer._step_count += K
-        return Tensor(losses)
+            batch_vals = tuple(t._value for t in batch_tensors)
+            base_key = default_generator().next_key()
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            accs, masters = self._get_opt_state()
+            losses, new_params, new_accs, new_masters, buf_out, new_scaler = multi(
+                [p._value for p in self._params], accs, masters,
+                [b._value for b in self._buffers], self._scaler_state(),
+                base_key, batch_vals, lr,
+            )
+            for p, v in zip(self._params, new_params):
+                p._value = v
+            self._put_opt_state(new_accs, new_masters)
+            for b, v in zip(self._buffers, buf_out):
+                b._value = v
+            if self.scaler is not None and new_scaler:
+                self.scaler._scale = new_scaler["scale"]
+                self.scaler._good_steps = new_scaler["good"]
+                self.scaler._bad_steps = new_scaler["bad"]
+            self.optimizer._step_count += K
+            return Tensor(losses)

@@ -166,65 +166,70 @@ class LlamaAttention(nn.Layer):
         b, s = hidden.shape[0], hidden.shape[1]
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         fuse_train = os.environ.get("PADDLE_TPU_FUSED_QKV", "0") == "1"
-        if ((s == 1 and cache is not None) or fuse_train) and not self._mp_active():
-            # decode step: ONE fused qkv matmul — the weight concat is loop-
-            # invariant, so XLA hoists it out of the decode scan and the step
-            # streams one [h, (nh+2·nkv)·hd] weight (measured 621→773 GB/s
-            # vs three separate matmuls at decode shapes)
-            def qkv_fused(hv, wq, wk, wv):
-                w = jnp.concatenate([wq, wk, wv], axis=1)
-                return hv @ w.astype(hv.dtype)
+        with jax.named_scope("attn_proj"):
+            if ((s == 1 and cache is not None) or fuse_train) and not self._mp_active():
+                # decode step: ONE fused qkv matmul — the weight concat is loop-
+                # invariant, so XLA hoists it out of the decode scan and the step
+                # streams one [h, (nh+2·nkv)·hd] weight (measured 621→773 GB/s
+                # vs three separate matmuls at decode shapes)
+                def qkv_fused(hv, wq, wk, wv):
+                    w = jnp.concatenate([wq, wk, wv], axis=1)
+                    return hv @ w.astype(hv.dtype)
 
-            qkv = apply(qkv_fused, hidden, self.q_proj.weight, self.k_proj.weight,
-                        self.v_proj.weight, op_name="qkv_fused")
-            qd, kd = nh * hd, nkv * hd
-            q = M.reshape(qkv[:, :, :qd], [b, s, nh, hd])
-            k = M.reshape(qkv[:, :, qd:qd + kd], [b, s, nkv, hd])
-            v = M.reshape(qkv[:, :, qd + kd:], [b, s, nkv, hd])
-        else:
-            q = M.reshape(self.q_proj(hidden), [b, s, nh, hd])
-            k = M.reshape(self.k_proj(hidden), [b, s, nkv, hd])
-            v = M.reshape(self.v_proj(hidden), [b, s, nkv, hd])
+                qkv = apply(qkv_fused, hidden, self.q_proj.weight, self.k_proj.weight,
+                            self.v_proj.weight, op_name="qkv_fused")
+                qd, kd = nh * hd, nkv * hd
+                q = M.reshape(qkv[:, :, :qd], [b, s, nh, hd])
+                k = M.reshape(qkv[:, :, qd:qd + kd], [b, s, nkv, hd])
+                v = M.reshape(qkv[:, :, qd + kd:], [b, s, nkv, hd])
+            else:
+                q = M.reshape(self.q_proj(hidden), [b, s, nh, hd])
+                k = M.reshape(self.k_proj(hidden), [b, s, nkv, hd])
+                v = M.reshape(self.v_proj(hidden), [b, s, nkv, hd])
         if cache is not None and len(cache) == 3:
-            return self._static_cache_attn(q, k, v, cos, sin, cache, b, s)
-        offset = 0
-        if cache is not None:
-            offset = cache[0].shape[1]
-        q, k = apply_rotary_pos_emb(q, k, cos, sin, position_offset=offset)
-        new_cache = None
-        if cache is not None:
-            k = M.concat([cache[0], k], axis=1)
-            v = M.concat([cache[1], v], axis=1)
-            new_cache = (k, v)
-        ring_mesh = self._sep_mesh() if (cache is None and attn_mask is None) else None
-        if ring_mesh is not None:
-            # sequence parallelism: exact blockwise ring attention over 'sep'
-            from ..ops.ring_attention import ring_attention
+            with jax.named_scope("attention"):
+                return self._static_cache_attn(q, k, v, cos, sin, cache, b, s)
+        with jax.named_scope("attention"):
+            offset = 0
+            if cache is not None:
+                offset = cache[0].shape[1]
+            q, k = apply_rotary_pos_emb(q, k, cos, sin, position_offset=offset)
+            new_cache = None
+            if cache is not None:
+                k = M.concat([cache[0], k], axis=1)
+                v = M.concat([cache[1], v], axis=1)
+                new_cache = (k, v)
+            ring_mesh = self._sep_mesh() if (cache is None and attn_mask is None) else None
+            if ring_mesh is not None:
+                # sequence parallelism: exact blockwise ring attention over 'sep'
+                from ..ops.ring_attention import ring_attention
 
-            hcg = _hcg()
-            b_ax = "dp" if hcg.axis_size("dp") > 1 else None
-            mp_deg = hcg.axis_size("mp")
-            h_ax = "mp" if mp_deg > 1 else None
-            rep = self.num_heads // self.num_kv_heads
+                hcg = _hcg()
+                b_ax = "dp" if hcg.axis_size("dp") > 1 else None
+                mp_deg = hcg.axis_size("mp")
+                h_ax = "mp" if mp_deg > 1 else None
+                rep = self.num_heads // self.num_kv_heads
 
-            def ring_fn(qv, kv, vv):
-                # GQA KV heads are indexed inside the ring/flash kernels;
-                # only when the KV head count cannot be sharded on mp do we
-                # fall back to repeating them up front
-                if rep > 1 and h_ax is not None and self.num_kv_heads % mp_deg:
-                    kv = jnp.repeat(kv, rep, axis=2)
-                    vv = jnp.repeat(vv, rep, axis=2)
-                return ring_attention(qv, kv, vv, mesh=ring_mesh, axis_name="sep",
-                                      causal=True, batch_axis=b_ax, head_axis=h_ax)
+                def ring_fn(qv, kv, vv):
+                    # GQA KV heads are indexed inside the ring/flash kernels;
+                    # only when the KV head count cannot be sharded on mp do we
+                    # fall back to repeating them up front
+                    if rep > 1 and h_ax is not None and self.num_kv_heads % mp_deg:
+                        kv = jnp.repeat(kv, rep, axis=2)
+                        vv = jnp.repeat(vv, rep, axis=2)
+                    return ring_attention(qv, kv, vv, mesh=ring_mesh, axis_name="sep",
+                                          causal=True, batch_axis=b_ax, head_axis=h_ax)
 
-            out = apply(ring_fn, q, k, v, op_name="ring_attention")
-        elif attn_mask is None and cache is None:
-            out, _ = F.flash_attention(q, k, v, causal=True)
-        else:
-            out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
-                                                 is_causal=attn_mask is None)
+                out = apply(ring_fn, q, k, v, op_name="ring_attention")
+            elif attn_mask is None and cache is None:
+                with jax.named_scope("flash_attention"):
+                    out, _ = F.flash_attention(q, k, v, causal=True)
+            else:
+                out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
+                                                     is_causal=attn_mask is None)
         out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
-        out = self.o_proj(out)
+        with jax.named_scope("attn_out"):
+            out = self.o_proj(out)
         if cache is not None:
             return out, new_cache
         return out
@@ -353,11 +358,16 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, hidden, cos, sin, attn_mask=None, cache=None):
         residual = hidden
-        attn_out = self.self_attn(self.input_layernorm(hidden), cos, sin, attn_mask, cache)
+        with jax.named_scope("norm"):
+            normed = self.input_layernorm(hidden)
+        attn_out = self.self_attn(normed, cos, sin, attn_mask, cache)
         if cache is not None:
             attn_out, new_cache = attn_out
         hidden = residual + attn_out
-        hidden = hidden + self.mlp(self.post_attention_layernorm(hidden))
+        with jax.named_scope("norm"):
+            normed = self.post_attention_layernorm(hidden)
+        with jax.named_scope("mlp"):
+            hidden = hidden + self.mlp(normed)
         if cache is not None:
             return hidden, new_cache
         return hidden
@@ -375,9 +385,10 @@ class LlamaModel(nn.Layer):
         self.register_buffer("rope_sin", Tensor(sin), persistable=False)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
-        hidden = self.embed_tokens(input_ids)
-        if self.config.dtype == "bfloat16":
-            hidden = hidden.astype("bfloat16")
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
+            if self.config.dtype == "bfloat16":
+                hidden = hidden.astype("bfloat16")
         hcg = _hcg()
         if hcg is not None and hcg.axis_size("sep") > 1 and caches is None:
             sep = hcg.axis_size("sep")
@@ -410,7 +421,8 @@ class LlamaModel(nn.Layer):
                     hidden = recompute(layer, hidden, cos, sin, attn_mask)
             else:
                 hidden = layer(hidden, cos, sin, attn_mask)
-        hidden = self.norm(hidden)
+        with jax.named_scope("norm"):
+            hidden = self.norm(hidden)
         if caches is not None:
             return hidden, new_caches
         return hidden
@@ -432,11 +444,12 @@ class LlamaForCausalLM(nn.Layer):
     def forward(self, input_ids, attn_mask=None, caches=None):
         out = self.llama(input_ids, attn_mask, caches)
         hidden = out[0] if caches is not None else out
-        if self.lm_head is None:
-            logits = F.linear(hidden, Tensor(self.llama.embed_tokens.weight._value.T,
-                                             stop_gradient=self.llama.embed_tokens.weight.stop_gradient))
-        else:
-            logits = self.lm_head(hidden)
+        with jax.named_scope("head"):
+            if self.lm_head is None:
+                logits = F.linear(hidden, Tensor(self.llama.embed_tokens.weight._value.T,
+                                                 stop_gradient=self.llama.embed_tokens.weight.stop_gradient))
+            else:
+                logits = self.lm_head(hidden)
         if caches is not None:
             return logits, out[1]
         return logits
@@ -453,8 +466,9 @@ class LlamaForCausalLM(nn.Layer):
                        stop_gradient=self.llama.embed_tokens.weight.stop_gradient)
         else:
             w = self.lm_head.weight
-        return apply(lambda h, wv, y: _chunked_lm_loss(h, wv, y, n_chunks),
-                     hidden, w, labels, op_name="fused_lm_loss")
+        with jax.named_scope("loss"):
+            return apply(lambda h, wv, y: _chunked_lm_loss(h, wv, y, n_chunks),
+                         hidden, w, labels, op_name="fused_lm_loss")
 
     @property
     def num_params(self) -> int:
@@ -558,12 +572,13 @@ class LlamaPretrainingCriterion(nn.Layer):
         super().__init__()
 
     def forward(self, logits, labels):
-        shift_logits = logits[:, :-1, :]
-        shift_labels = labels[:, 1:]
-        return F.cross_entropy(
-            M.reshape(shift_logits, [-1, shift_logits.shape[-1]]),
-            M.reshape(shift_labels, [-1]),
-        )
+        with jax.named_scope("loss"):
+            shift_logits = logits[:, :-1, :]
+            shift_labels = labels[:, 1:]
+            return F.cross_entropy(
+                M.reshape(shift_logits, [-1, shift_logits.shape[-1]]),
+                M.reshape(shift_labels, [-1]),
+            )
 
 
 def _chunked_lm_loss(hidden, w, labels, n_chunks: int):

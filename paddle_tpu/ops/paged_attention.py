@@ -105,6 +105,7 @@ def build_padding_metadata(seq_lens_this_time):
     "num_heads", "kv_num_heads", "head_dim", "block_size", "max_q_len",
     "use_neox_style", "cache_quant", "round_ties_away", "compute_dtype",
     "has_out_quant"))
+@jax.named_scope("paged_attention")
 def blha_attention(
     qkv,                       # [T, (H+2*KV)*D] float/bf16 (or int32 w/ qkv_out_scale)
     key_cache,                 # [NB, KV, bs, D] (uint8 when cache_quant)
@@ -148,6 +149,11 @@ def blha_attention(
              k_quant_scales', v_quant_scales', k_dequant_scales',
              v_dequant_scales') — scale arrays pass through unchanged except
     in dynamic quant mode, where prefill rows refresh them.
+
+    Scopes (children of ``paged_attention``): ``rope``, ``kv_write``,
+    ``kv_gather`` (the gather, its dequant and the pre-cache concat),
+    ``scores`` (QK^T, masks, softmax), ``values`` (PV and the return to the
+    packed buffer); what is under none is unpacking and token coordinates.
     """
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]
@@ -176,144 +182,149 @@ def blha_attention(
     abs_pos = ctx + local
     valid = (tok < total) & (local < seq_lens_this_time[b_idx])
 
-    # ---- 3. rope at absolute positions ---------------------------------
-    if rope_emb is not None:
-        rb = jnp.minimum(b_idx, rope_emb.shape[1] - 1)
-        rp = jnp.clip(abs_pos, 0, rope_emb.shape[2] - 1)
-        cos_t = rope_emb[0, rb, rp, 0][:, None, :]  # [T, 1, D/2]
-        sin_t = rope_emb[1, rb, rp, 0][:, None, :]
-        q = rope_rotate(q, cos_t, sin_t, use_neox_style)
-        k = rope_rotate(k, cos_t, sin_t, use_neox_style)
+    with jax.named_scope("rope"):
+        # ---- 3. rope at absolute positions ---------------------------------
+        if rope_emb is not None:
+            rb = jnp.minimum(b_idx, rope_emb.shape[1] - 1)
+            rp = jnp.clip(abs_pos, 0, rope_emb.shape[2] - 1)
+            cos_t = rope_emb[0, rb, rp, 0][:, None, :]  # [T, 1, D/2]
+            sin_t = rope_emb[1, rb, rp, 0][:, None, :]
+            q = rope_rotate(q, cos_t, sin_t, use_neox_style)
+            k = rope_rotate(k, cos_t, sin_t, use_neox_style)
 
-    # ---- 4. (dynamic quant) refresh per-(seq, head) scales -------------
-    if cache_quant == "dynamic":
-        # prefill rows recompute absmax over this step's K/V (the reference
-        # computes scales during the encoder pass and reuses them in decode)
-        k_pad0 = jnp.zeros((B, max_q_len, KV, D), jnp.float32)
-        v_pad0 = jnp.zeros((B, max_q_len, KV, D), jnp.float32)
+    with jax.named_scope("kv_write"):
+        # ---- 4. (dynamic quant) refresh per-(seq, head) scales -------------
+        if cache_quant == "dynamic":
+            # prefill rows recompute absmax over this step's K/V (the reference
+            # computes scales during the encoder pass and reuses them in decode)
+            k_pad0 = jnp.zeros((B, max_q_len, KV, D), jnp.float32)
+            v_pad0 = jnp.zeros((B, max_q_len, KV, D), jnp.float32)
+            bs_idx = jnp.where(valid, b_idx, B)
+            lc_idx = jnp.where(valid & (local < max_q_len), local, max_q_len)
+            k_pad0 = k_pad0.at[bs_idx, lc_idx].set(
+                k.astype(jnp.float32), mode="drop")
+            v_pad0 = v_pad0.at[bs_idx, lc_idx].set(
+                v.astype(jnp.float32), mode="drop")
+            k_absmax = jnp.max(jnp.abs(k_pad0), axis=(1, 3))  # [B, KV]
+            v_absmax = jnp.max(jnp.abs(v_pad0), axis=(1, 3))
+            is_prefill = (seq_lens_encoder > 0)[:, None]
+            new_kq = jnp.where(is_prefill, quant_max_bound / jnp.maximum(k_absmax, 1e-6),
+                               cache_k_quant_scales)
+            new_vq = jnp.where(is_prefill, quant_max_bound / jnp.maximum(v_absmax, 1e-6),
+                               cache_v_quant_scales)
+            new_kd = jnp.where(is_prefill, jnp.maximum(k_absmax, 1e-6) / quant_max_bound,
+                               cache_k_dequant_scales)
+            new_vd = jnp.where(is_prefill, jnp.maximum(v_absmax, 1e-6) / quant_max_bound,
+                               cache_v_dequant_scales)
+            cache_k_quant_scales, cache_v_quant_scales = new_kq, new_vq
+            cache_k_dequant_scales, cache_v_dequant_scales = new_kd, new_vd
+
+        # ---- 5. scatter K/V into the block pool ----------------------------
+        nb = key_cache.shape[0]
+        blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
+        blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)  # OOB -> drop
+        slot = abs_pos % bs
+        if cache_quant != "none":
+            if cache_quant == "static":
+                ksc = cache_k_quant_scales[None, :, None]          # [1, KV, 1]
+                vsc = cache_v_quant_scales[None, :, None]
+            else:
+                ksc = cache_k_quant_scales[b_idx][:, :, None]      # [T, KV, 1]
+                vsc = cache_v_quant_scales[b_idx][:, :, None]
+            k_store = _quantize_u8(k, ksc, round_ties_away, quant_max_bound,
+                                   quant_min_bound)
+            v_store = _quantize_u8(v, vsc, round_ties_away, quant_max_bound,
+                                   quant_min_bound)
+        else:
+            k_store = k.astype(key_cache.dtype)
+            v_store = v.astype(value_cache.dtype)
+        key_cache = key_cache.at[blk, :, slot, :].set(k_store, mode="drop")
+        value_cache = value_cache.at[blk, :, slot, :].set(v_store, mode="drop")
+
+    with jax.named_scope("kv_gather"):
+        # ---- 6. gather each sequence's context back ------------------------
+        k_all = paged_gather_kv(key_cache, block_tables)   # [B, KV, L, D]
+        v_all = paged_gather_kv(value_cache, block_tables)
+        if cache_quant != "none":
+            if cache_quant == "static":
+                kd = cache_k_dequant_scales[None, :, None, None]
+                vd = cache_v_dequant_scales[None, :, None, None]
+            else:
+                kd = cache_k_dequant_scales[:, :, None, None]
+                vd = cache_v_dequant_scales[:, :, None, None]
+            k_all = (k_all.astype(jnp.float32) - 128.0) * kd
+            v_all = (v_all.astype(jnp.float32) - 128.0) * vd
+            # overlay this step's K/V at full precision: the reference kernel
+            # attends the fresh tokens unquantized (only the stored cache is
+            # int8), which keeps prefill outputs exact
+            ov_b = jnp.where(valid, b_idx, B)
+            ov_p = jnp.where(valid, abs_pos, L)
+            k_all = k_all.at[ov_b, :, ov_p].set(k.astype(k_all.dtype), mode="drop")
+            v_all = v_all.at[ov_b, :, ov_p].set(v.astype(v_all.dtype), mode="drop")
+        pre_len = 0
+        if pre_key_cache is not None:
+            pre_len = pre_key_cache.shape[2]
+            k_all = jnp.concatenate([pre_key_cache.astype(k_all.dtype), k_all], axis=2)
+            v_all = jnp.concatenate([pre_value_cache.astype(v_all.dtype), v_all], axis=2)
+        Lf = pre_len + L
+
+    with jax.named_scope("scores"):
+        # ---- 7. padded-batch attention -------------------------------------
+        S = max_q_len
         bs_idx = jnp.where(valid, b_idx, B)
-        lc_idx = jnp.where(valid & (local < max_q_len), local, max_q_len)
-        k_pad0 = k_pad0.at[bs_idx, lc_idx].set(
-            k.astype(jnp.float32), mode="drop")
-        v_pad0 = v_pad0.at[bs_idx, lc_idx].set(
-            v.astype(jnp.float32), mode="drop")
-        k_absmax = jnp.max(jnp.abs(k_pad0), axis=(1, 3))  # [B, KV]
-        v_absmax = jnp.max(jnp.abs(v_pad0), axis=(1, 3))
-        is_prefill = (seq_lens_encoder > 0)[:, None]
-        new_kq = jnp.where(is_prefill, quant_max_bound / jnp.maximum(k_absmax, 1e-6),
-                           cache_k_quant_scales)
-        new_vq = jnp.where(is_prefill, quant_max_bound / jnp.maximum(v_absmax, 1e-6),
-                           cache_v_quant_scales)
-        new_kd = jnp.where(is_prefill, jnp.maximum(k_absmax, 1e-6) / quant_max_bound,
-                           cache_k_dequant_scales)
-        new_vd = jnp.where(is_prefill, jnp.maximum(v_absmax, 1e-6) / quant_max_bound,
-                           cache_v_dequant_scales)
-        cache_k_quant_scales, cache_v_quant_scales = new_kq, new_vq
-        cache_k_dequant_scales, cache_v_dequant_scales = new_kd, new_vd
+        lc_idx = jnp.where(valid & (local < S), local, S)
+        q_pad = jnp.zeros((B, S, H, D), q.dtype).at[bs_idx, lc_idx].set(
+            q, mode="drop")
+        group = H // KV
+        qg = q_pad.reshape(B, S, KV, group, D).astype(jnp.float32)
+        kf = k_all.astype(jnp.float32)
+        logits = jnp.einsum("bskgd,bkld->bkgsl", qg, kf) / (D ** 0.5)
 
-    # ---- 5. scatter K/V into the block pool ----------------------------
-    nb = key_cache.shape[0]
-    blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
-    blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)  # OOB -> drop
-    slot = abs_pos % bs
-    if cache_quant != "none":
-        if cache_quant == "static":
-            ksc = cache_k_quant_scales[None, :, None]          # [1, KV, 1]
-            vsc = cache_v_quant_scales[None, :, None]
-        else:
-            ksc = cache_k_quant_scales[b_idx][:, :, None]      # [T, KV, 1]
-            vsc = cache_v_quant_scales[b_idx][:, :, None]
-        k_store = _quantize_u8(k, ksc, round_ties_away, quant_max_bound,
-                               quant_min_bound)
-        v_store = _quantize_u8(v, vsc, round_ties_away, quant_max_bound,
-                               quant_min_bound)
-    else:
-        k_store = k.astype(key_cache.dtype)
-        v_store = v.astype(value_cache.dtype)
-    key_cache = key_cache.at[blk, :, slot, :].set(k_store, mode="drop")
-    value_cache = value_cache.at[blk, :, slot, :].set(v_store, mode="drop")
+        # causal visibility: query at absolute position p sees keys [0, p] of
+        # its own context plus the whole pre-cache prefix
+        qpos = (seq_lens_decoder[:, None]
+                + jnp.arange(S, dtype=jnp.int32)[None, :])  # [B, S] (rows past the real length are masked on output)
+        kpos = jnp.arange(Lf, dtype=jnp.int32)[None, None, :] - pre_len  # [1,1,Lf]
+        vis = kpos <= qpos[:, :, None]                                   # [B, S, Lf]
+        neg = jnp.asarray(-1e30, jnp.float32)
+        logits = jnp.where(vis[:, None, None, :, :], logits, neg)
 
-    # ---- 6. gather each sequence's context back ------------------------
-    k_all = paged_gather_kv(key_cache, block_tables)   # [B, KV, L, D]
-    v_all = paged_gather_kv(value_cache, block_tables)
-    if cache_quant != "none":
-        if cache_quant == "static":
-            kd = cache_k_dequant_scales[None, :, None, None]
-            vd = cache_v_dequant_scales[None, :, None, None]
-        else:
-            kd = cache_k_dequant_scales[:, :, None, None]
-            vd = cache_v_dequant_scales[:, :, None, None]
-        k_all = (k_all.astype(jnp.float32) - 128.0) * kd
-        v_all = (v_all.astype(jnp.float32) - 128.0) * vd
-        # overlay this step's K/V at full precision: the reference kernel
-        # attends the fresh tokens unquantized (only the stored cache is
-        # int8), which keeps prefill outputs exact
-        ov_b = jnp.where(valid, b_idx, B)
-        ov_p = jnp.where(valid, abs_pos, L)
-        k_all = k_all.at[ov_b, :, ov_p].set(k.astype(k_all.dtype), mode="drop")
-        v_all = v_all.at[ov_b, :, ov_p].set(v.astype(v_all.dtype), mode="drop")
-    pre_len = 0
-    if pre_key_cache is not None:
-        pre_len = pre_key_cache.shape[2]
-        k_all = jnp.concatenate([pre_key_cache.astype(k_all.dtype), k_all], axis=2)
-        v_all = jnp.concatenate([pre_value_cache.astype(v_all.dtype), v_all], axis=2)
-    Lf = pre_len + L
+        def _add_mask(lg, m):
+            # m: [B, 1|H, Sq, Lm] additive; key axis aligned at column 0 (the
+            # pre-cache prefix occupies the first ``pre_len`` columns, matching
+            # the reference's create_attn_mask layout)
+            m = m.astype(jnp.float32)
+            if m.shape[1] == 1:
+                m = jnp.broadcast_to(m, (B, H, m.shape[2], m.shape[3]))
+            mh = m.reshape(B, KV, group, m.shape[2], m.shape[3])
+            Lm, Sq = m.shape[3], m.shape[2]
+            if Lm < Lf:
+                mh = jnp.pad(mh, ((0, 0),) * 4 + ((0, Lf - Lm),))
+            elif Lm > Lf:
+                mh = mh[..., :Lf]
+            if Sq < S:
+                mh = jnp.pad(mh, ((0, 0),) * 3 + ((0, S - Sq), (0, 0)))
+            elif Sq > S:
+                mh = mh[..., :S, :]
+            return lg + mh
 
-    # ---- 7. padded-batch attention -------------------------------------
-    S = max_q_len
-    bs_idx = jnp.where(valid, b_idx, B)
-    lc_idx = jnp.where(valid & (local < S), local, S)
-    q_pad = jnp.zeros((B, S, H, D), q.dtype).at[bs_idx, lc_idx].set(
-        q, mode="drop")
-    group = H // KV
-    qg = q_pad.reshape(B, S, KV, group, D).astype(jnp.float32)
-    kf = k_all.astype(jnp.float32)
-    logits = jnp.einsum("bskgd,bkld->bkgsl", qg, kf) / (D ** 0.5)
+        if mask is not None:
+            # encoder-phase custom mask applies to prefill rows only
+            enc_rows = (seq_lens_encoder > 0)[:, None, None, None, None]
+            logits = jnp.where(enc_rows, _add_mask(logits, mask), logits)
+        if tgt_mask is not None:
+            dec_rows = ((seq_lens_encoder <= 0) &
+                        (seq_lens_this_time > 0))[:, None, None, None, None]
+            logits = jnp.where(dec_rows, _add_mask(logits, tgt_mask), logits)
 
-    # causal visibility: query at absolute position p sees keys [0, p] of
-    # its own context plus the whole pre-cache prefix
-    qpos = (seq_lens_decoder[:, None]
-            + jnp.arange(S, dtype=jnp.int32)[None, :])  # [B, S] (rows past the real length are masked on output)
-    kpos = jnp.arange(Lf, dtype=jnp.int32)[None, None, :] - pre_len  # [1,1,Lf]
-    vis = kpos <= qpos[:, :, None]                                   # [B, S, Lf]
-    neg = jnp.asarray(-1e30, jnp.float32)
-    logits = jnp.where(vis[:, None, None, :, :], logits, neg)
+        p = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("values"):
+        out_pad = jnp.einsum("bkgsl,bkld->bskgd", p, v_all.astype(jnp.float32))
+        out_pad = out_pad.reshape(B, S, H, D)
 
-    def _add_mask(lg, m):
-        # m: [B, 1|H, Sq, Lm] additive; key axis aligned at column 0 (the
-        # pre-cache prefix occupies the first ``pre_len`` columns, matching
-        # the reference's create_attn_mask layout)
-        m = m.astype(jnp.float32)
-        if m.shape[1] == 1:
-            m = jnp.broadcast_to(m, (B, H, m.shape[2], m.shape[3]))
-        mh = m.reshape(B, KV, group, m.shape[2], m.shape[3])
-        Lm, Sq = m.shape[3], m.shape[2]
-        if Lm < Lf:
-            mh = jnp.pad(mh, ((0, 0),) * 4 + ((0, Lf - Lm),))
-        elif Lm > Lf:
-            mh = mh[..., :Lf]
-        if Sq < S:
-            mh = jnp.pad(mh, ((0, 0),) * 3 + ((0, S - Sq), (0, 0)))
-        elif Sq > S:
-            mh = mh[..., :S, :]
-        return lg + mh
-
-    if mask is not None:
-        # encoder-phase custom mask applies to prefill rows only
-        enc_rows = (seq_lens_encoder > 0)[:, None, None, None, None]
-        logits = jnp.where(enc_rows, _add_mask(logits, mask), logits)
-    if tgt_mask is not None:
-        dec_rows = ((seq_lens_encoder <= 0) &
-                    (seq_lens_this_time > 0))[:, None, None, None, None]
-        logits = jnp.where(dec_rows, _add_mask(logits, tgt_mask), logits)
-
-    p = jax.nn.softmax(logits, axis=-1)
-    out_pad = jnp.einsum("bkgsl,bkld->bskgd", p, v_all.astype(jnp.float32))
-    out_pad = out_pad.reshape(B, S, H, D)
-
-    # ---- 8. gather back to the packed token buffer ---------------------
-    out = out_pad.at[bs_idx, lc_idx].get(mode="fill", fill_value=0)  # [T, H, D]
-    out = out.reshape(T, H * D)
+        # ---- 8. gather back to the packed token buffer -----------------
+        out = out_pad.at[bs_idx, lc_idx].get(mode="fill", fill_value=0)  # [T, H, D]
+        out = out.reshape(T, H * D)
     # smooth-quant epilogue: (x + shift) * smooth — the reference kernel's
     # order (shift first, then the per-channel smoothing scale)
     if out_shift is not None:

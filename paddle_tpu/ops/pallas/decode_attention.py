@@ -86,6 +86,7 @@ def kv_ring_write(buf, new, pos, *, interpret=False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         input_output_aliases={2: 0},  # buf aliases the output (0=pos, 1=new)
+        name="kv_ring_write",
         interpret=interpret,
     )(pos_arr, new.astype(buf.dtype), buf)
 
@@ -183,5 +184,6 @@ def decode_attention(q, kbuf, vbuf, pos, scale=None, *, block_l: int = 256,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(pos_arr, q, kbuf, vbuf)

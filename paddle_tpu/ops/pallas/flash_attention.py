@@ -170,6 +170,7 @@ def _pallas_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse3[..., 0]
@@ -297,6 +298,7 @@ def _pallas_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, g, lse3, delta3)
 
@@ -323,6 +325,7 @@ def _pallas_bwd(q, k, v, o, lse, g, causal: bool, scale: float,
             jax.ShapeDtypeStruct((bh, sk, d), acc_dt),
             jax.ShapeDtypeStruct((bh, sk, d), acc_dt),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(k, v, q, g, lse3, delta3)
     if kv_rep > 1:

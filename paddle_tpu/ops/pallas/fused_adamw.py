@@ -86,6 +86,7 @@ def fused_adamw(param, master, m, v, grad, lr, beta1_pow_t, beta2_pow_t, *,
         ],
         # master/m/v update in place (operand order: scal, g, w, m, v)
         input_output_aliases={2: 1, 3: 2, 4: 3},
+        name="fused_adamw",
         interpret=interpret,
     )(scal, r2(grad), r2(master, jnp.float32), r2(m), r2(v))
     return (p_new.reshape(shape), w_new.reshape(shape),

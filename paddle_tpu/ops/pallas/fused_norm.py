@@ -61,6 +61,7 @@ def _pallas_rms(x2, w, eps, interpret):
                   pl.BlockSpec((h,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
+        name="rms_norm",
         interpret=interpret,
     )(x2, w)
 
@@ -78,6 +79,7 @@ def _pallas_rms_residual(x2, r2, w, eps, interpret):
                    pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n, h), x2.dtype),
                    jax.ShapeDtypeStruct((n, h), x2.dtype)],
+        name="rms_norm_residual",
         interpret=interpret,
     )(x2, r2, w)
 

@@ -74,6 +74,7 @@ def _rope_one_pallas(x, cos, sin, interpret):
         ],
         out_specs=pl.BlockSpec((1, bs, h, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="fused_rope",
         interpret=interpret,
     )(x, cos, sin)
 
@@ -168,6 +169,7 @@ def _swiglu_pallas(a2, b2, interpret):
                   pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), a2.dtype),
+        name="swiglu_fwd",
         interpret=interpret,
     )(a2, b2)
 
@@ -182,6 +184,7 @@ def _swiglu_bwd_pallas(a2, b2, g2, interpret):
         out_specs=[pl.BlockSpec((br, h), lambda i: (i, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((n, h), a2.dtype),
                    jax.ShapeDtypeStruct((n, h), b2.dtype)],
+        name="swiglu_bwd",
         interpret=interpret,
     )(a2, b2, g2)
 

@@ -81,6 +81,7 @@ def _int8_mm_impl(x2, qw, scale, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="int8_matmul",
         interpret=interpret,
     )(x2, qw, scale.reshape(1, n))
 

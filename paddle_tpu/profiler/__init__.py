@@ -6,6 +6,31 @@ TPU-native: device tracing is jax.profiler (XPlane → TensorBoard/Perfetto,
 replacing the reference's CUPTI tracer); host spans use
 jax.profiler.TraceAnnotation (the RecordEvent analog); the step-timer /
 throughput surface is reimplemented natively.
+
+What the program names, so that a trace taken by ``Profiler`` or by
+``jax.profiler.start_trace`` around a running engine or train loop reads in
+the program's words (the table with each name's reader is PERF.md section 3):
+
+Host spans, every one a ``RecordEvent`` on the profiler's clock:
+``frontend.step`` > ``frontend.dispatch``, ``engine.step``, ``frontend.deliver``;
+``engine.step`` > ``engine.admit``, ``engine.schedule``, ``engine.launch``
+(stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``),
+``engine.wait``, ``engine.harvest``; ``train_step.call`` (stats ``step``,
+``steps``).
+
+Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
+at run time), one vocabulary for every model family:
+``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write``
+``kv_gather`` ``scores`` ``values``, ``attn_out``, ``mlp``, ``norm``, ``head``,
+``sample``, ``scan_carry`` (the serving programs); ``attention`` >
+``flash_attention``, ``loss``, ``optimizer``, ``grad_unscale`` (the train
+step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
+``head``).
+
+Kernels (``pallas_call(name=)``, the name of the custom call's device event):
+``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``decode_attention``,
+``kv_ring_write``, ``fused_adamw``, ``rms_norm``, ``rms_norm_residual``,
+``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``, ``int8_matmul``.
 """
 from __future__ import annotations
 
@@ -71,26 +96,33 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 
 
 class RecordEvent:
-    """Host span (parity: paddle.profiler.RecordEvent / C++ RecordEvent)."""
+    """Host span (parity: paddle.profiler.RecordEvent / C++ RecordEvent).
+    ``attrs`` become the stats of the span's event in the profiler's trace;
+    the ``perf_counter`` record for ``Profiler.summary()`` is taken only
+    while a ``Profiler`` collects."""
 
     _active_sink = None
 
-    def __init__(self, name: str, event_type=None):
+    def __init__(self, name: str, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._jax_ann = None
+        self._t0 = None
 
     def begin(self):
-        self._t0 = time.perf_counter()
-        self._jax_ann = jax.profiler.TraceAnnotation(self.name)
+        if RecordEvent._active_sink is not None:
+            self._t0 = time.perf_counter()
+        self._jax_ann = jax.profiler.TraceAnnotation(self.name, **self._attrs)
         self._jax_ann.__enter__()
 
     def end(self):
         if self._jax_ann is not None:
             self._jax_ann.__exit__(None, None, None)
-        dt = time.perf_counter() - self._t0
+            self._jax_ann = None
         sink = RecordEvent._active_sink
-        if sink is not None:
-            sink.append((self.name, self._t0, dt))
+        if sink is not None and self._t0 is not None:
+            sink.append((self.name, self._t0, time.perf_counter() - self._t0))
+        self._t0 = None
 
     def __enter__(self):
         self.begin()

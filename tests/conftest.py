@@ -83,3 +83,36 @@ def serving_model():
         max_position_embeddings=256))
     m.eval()
     return m
+
+
+@pytest.fixture
+def host_spans(tmp_path):
+    """``with host_spans("engine.", "frontend.") as spans:`` profiles the
+    block the way the benchmark does (host spans on, no Python frames); after
+    it ``spans`` holds [(name, start_ns, end_ns, stats)] of the
+    ``RecordEvent``s with those prefixes, read back through ``ProfileData``."""
+    import contextlib
+    import glob
+
+    @contextlib.contextmanager
+    def traced(*prefixes):
+        import jax
+        from jax.profiler import ProfileData
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        spans = []
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            yield spans
+        finally:
+            jax.profiler.stop_trace()
+        pb = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        spans += sorted(
+            ((e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(pb[-1]).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith(prefixes)), key=lambda e: (e[1], -e[2]))
+
+    return traced

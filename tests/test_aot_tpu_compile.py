@@ -8,6 +8,7 @@ Named to sort first: tier-1 is cut by its clock, and a file late in the
 alphabet guards nothing.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -43,10 +44,16 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _compile(fn, chip, *shapes):
+def _compile(fn, chip, *shapes, names=()):
+    """``names``: the kernels' ``pallas_call(name=)``, which the compiled
+    program must give its custom calls: a device trace names the kernel's
+    event by it, and the benchmark's kernel metrics match that name."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]* custom-call\(", text), name
     return compiled
 
 
@@ -68,11 +75,12 @@ def test_flash_attention(chip, kind, kv_rep, seq):
     if kind == "fwd":
         _compile(lambda q, k, v: fa._pallas_fwd(
             q, k, v, True, scale, bq, bk, False, kv_rep=kv_rep),
-            chip, q, kv, kv)
+            chip, q, kv, kv, names=("flash_fwd",))
     else:
         _compile(lambda q, k, v, o, lse, g: fa._pallas_bwd(
             q, k, v, o, lse, g, True, scale, bq, bk, False, kv_rep=kv_rep),
-            chip, q, kv, kv, q, ((bh, seq), jnp.float32), q)
+            chip, q, kv, kv, q, ((bh, seq), jnp.float32), q,
+            names=("flash_bwd_dq", "flash_bwd_dkv"))
 
 
 @pytest.mark.parametrize("rows", [16384, 2048])

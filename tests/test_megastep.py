@@ -579,3 +579,177 @@ class TestMixedPhaseMegastep:
         assert sorted(a["chunk"] for a in chunks) == \
             list(range(len(chunks)))
         assert sum(a["tokens"] for a in chunks) == len(long)
+
+
+# ------------------------------------------- names, spans and phase seconds
+class _Recorded:
+    """Stands in for one of the engine's jitted programs: keeps the lowered
+    text of its first call, and ``on_call(outputs)`` may change what the
+    engine gets back."""
+
+    def __init__(self, fn, on_call=None):
+        self.fn, self.on_call, self.text = fn, on_call, None
+
+    def __call__(self, *args, **kwargs):
+        import jax
+
+        if self.text is None:
+            shapes = jax.tree_util.tree_map(
+                lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype)
+                           if hasattr(x, "shape") else x), (args, kwargs))
+            self.text = self.fn.lower(*shapes[0], **shapes[1]).as_text(
+                debug_info=True)
+        out = self.fn(*args, **kwargs)
+        return self.on_call(out) if self.on_call is not None else out
+
+
+PROGRAMS = {"step": ("_step_fn", "_build_step", 0),
+            "mega": ("_mega_fn", "_build_megastep", 3),
+            "mixed": ("_mixed_fn", "_build_mixed_megastep", 2),
+            "spec": ("_spec_fn", "_build_spec_verify", 2)}
+# what each program's lowered text has to name (PERF.md section 3: a
+# per-layer metric that matches a scope reads nothing once it is gone)
+FORWARD = ("embed", "norm", "attn_proj", "paged_attention", "attn_out", "mlp",
+           "head", "sample", "paged_attention/rope", "paged_attention/kv_write",
+           "paged_attention/kv_gather", "paged_attention/scores",
+           "paged_attention/values")
+SCOPES = {"step": FORWARD, "mega": FORWARD + ("scan_carry",),
+          "mixed": FORWARD + ("scan_carry",), "spec": FORWARD + ("scan_carry",)}
+
+
+def _recorded_engine(model, on_call=None, **kw):
+    """An engine whose four programs are ``_Recorded``; ``on_call(kind,
+    outputs)`` sees every launch's outputs."""
+    eng = ServingEngine(model, megastep_k=4, spec_k=2, **{**ENGINE, **kw})
+    for kind, (attr, build, _) in PROGRAMS.items():
+        fn = eng._programs.setdefault(kind, None) or getattr(eng, build)()
+        eng._programs[kind] = fn
+        setattr(eng, attr, _Recorded(
+            fn, None if on_call is None else (lambda out, k=kind: on_call(k, out))))
+    return eng
+
+
+def _drive_all_programs(eng):
+    """Prefill step, mixed scan (a prompt arrives while a row decodes),
+    decode scan, and a verify launch (a repetitive prompt drafts)."""
+    no_spec = SamplingParams(spec=False)
+    eng.add_request([3, 17, 101], max_new_tokens=12, sampling=no_spec)
+    eng.step()
+    eng.add_request([40, 41, 42, 43, 44, 45, 46, 47, 48, 49], max_new_tokens=6,
+                    sampling=no_spec)
+    eng.run()
+    eng.add_request([1, 2, 3, 1, 2, 3, 1, 2], max_new_tokens=48)
+    eng.run()
+
+
+@pytest.fixture(scope="module")
+def program_texts(model):
+    eng = _recorded_engine(model)
+    _drive_all_programs(eng)
+    return {kind: getattr(eng, attr).text for kind, (attr, _, _) in PROGRAMS.items()}
+
+
+class TestNamesSpansAndPhases:
+    @pytest.mark.parametrize("kind", sorted(PROGRAMS))
+    def test_lowered_program_names_its_scopes(self, program_texts, kind):
+        import re
+
+        text = program_texts[kind]
+        assert text, f"the {kind} program never ran"
+        missing = [s for s in SCOPES[kind]
+                   if not re.search(rf'["/(]{s}[/)"]', text)]
+        assert not missing, f"{kind}: no operation under {missing}"
+
+    @pytest.mark.parametrize("kind", sorted(PROGRAMS))
+    def test_phase_seconds_on_an_injected_clock(self, model, kind):
+        """``execute`` is launch + wait, and the three old keys sum to the
+        steps' wall time: admission takes 1 s, a launch 4 s, the blocking
+        read of its first output 2 s, nothing else any time."""
+        clock = FakeClock()
+        launches = []
+
+        class SlowRead:
+            def __init__(self, value):
+                self.value = value
+
+            def __array__(self, dtype=None, copy=None):
+                clock.advance(2.0)
+                return np.asarray(self.value)
+
+        def on_call(k, out):
+            clock.advance(4.0)
+            launches.append(k)
+            out = list(out)
+            out[PROGRAMS[k][2]] = SlowRead(out[PROGRAMS[k][2]])
+            return tuple(out)
+
+        eng = _recorded_engine(model, on_call, clock=clock)
+        admit = eng._try_admit
+        admits = []
+
+        def slow_admit():
+            # harvest admits again when a frozen row frees its slot: that
+            # call is inside engine.harvest and costs nothing here
+            if not admits or admits[-1] != eng.launches:
+                clock.advance(1.0)
+            admits.append(eng.launches)
+            return admit()
+
+        eng._try_admit = slow_admit
+        t_start = clock()
+        _drive_all_programs(eng)
+        assert kind in launches
+        ps = eng.phase_seconds
+        n = len(launches)
+        assert eng.launches == n
+        assert ps["launch"] == pytest.approx(4.0 * n)
+        assert ps["execute"] == pytest.approx(ps["launch"] + 2.0 * n)
+        assert (ps["schedule"] + ps["execute"] + ps["harvest"]
+                == pytest.approx(clock() - t_start))
+        assert eng.state_summary()["phase_seconds"]["launch"] == ps["launch"]
+
+    def test_spans_nest_in_order_with_their_attributes(self, model, host_spans):
+        """A traced run read back through ``ProfileData``: inside each
+        ``frontend.step``, ``engine.step`` holds admit, schedule, launch,
+        wait, harvest in that order, and ``engine.launch`` carries kind, k,
+        launch and t_mono."""
+        clock = FakeClock()
+        eng = ServingEngine(model, megastep_k=4, clock=clock, **ENGINE)
+        fe = ServingFrontend([eng], clock=clock)
+        fe.submit([3, 17, 101], max_new_tokens=2)
+        fe.run()                                    # compiles, untraced
+        with host_spans("frontend.", "engine.") as events:
+            clock.advance(5.0)
+            fe.submit([3, 17, 101], max_new_tokens=6)
+            fe.step()
+            fe.submit([40, 41, 42, 43, 44, 45, 46, 47, 48, 49], max_new_tokens=4)
+            fe.run()
+
+        def inside(outer, name):
+            return [e for e in events if e[0] == name
+                    and outer[1] <= e[1] and e[2] <= outer[2]]
+
+        fe_steps = [e for e in events if e[0] == "frontend.step"]
+        eng_steps = [e for e in events if e[0] == "engine.step"]
+        assert fe_steps and len(eng_steps) == len(fe_steps)
+        launches = []
+        for f in fe_steps:
+            (step,) = inside(f, "engine.step")
+            (dispatch,) = inside(f, "frontend.dispatch")
+            (deliver,) = inside(f, "frontend.deliver")
+            assert dispatch[2] <= step[1] and step[2] <= deliver[1]
+            parts = [e for e in events if e[0] != "engine.step"
+                     and e[0].startswith("engine.")
+                     and step[1] <= e[1] and e[2] <= step[2]]
+            names = [e[0].split(".")[1] for e in parts]
+            assert names[0] == "admit" and names.count("launch") <= 1
+            if "launch" in names:
+                assert names == ["admit"] + ["schedule"] * (len(names) - 4) + [
+                    "launch", "wait", "harvest"]
+                assert all(a[2] <= b[1] for a, b in zip(parts, parts[1:]))
+                launches.append(parts[names.index("launch")][3])
+        assert {l["kind"] for l in launches} >= {"step", "mixed"}
+        assert [l["launch"] for l in launches] == list(
+            range(launches[0]["launch"], launches[0]["launch"] + len(launches)))
+        assert all(l["k"] == (1 if l["kind"] == "step" else 4) for l in launches)
+        assert all(l["t_mono"] == 5.0 for l in launches)

@@ -90,3 +90,118 @@ def test_device_cuda_parity_surface():
     assert cuda.get_device_name()
     assert isinstance(cuda.memory_allocated(), int)
     assert cuda.get_device_capability() == (0, 0)
+
+
+# ------------------------------------ the program's spans, scopes and names
+def test_record_event_takes_attributes_and_records_only_under_a_profiler(monkeypatch):
+    import paddle_tpu.profiler as prof
+
+    def no_clock():
+        raise AssertionError("RecordEvent read a clock with no Profiler collecting")
+
+    assert prof.RecordEvent._active_sink is None
+    monkeypatch.setattr(prof.time, "perf_counter", no_clock)
+    with prof.RecordEvent("engine.launch", kind="mixed", k=8, launch=3, t_mono=1.5):
+        pass
+    ev = prof.RecordEvent("plain")
+    ev.begin()
+    ev.end()
+    monkeypatch.undo()
+    with prof.Profiler(timer_only=True) as p:
+        with prof.RecordEvent("engine.wait", k=8):
+            pass
+    assert [name for name, _, _ in p._host_events] == ["engine.wait"]
+    assert prof.RecordEvent._active_sink is None
+
+
+TRAIN_SCOPES = ("embed", "norm", "attn_proj", "attention", "attention/flash_attention",
+                "attn_out", "mlp", "head", "loss", "optimizer")
+
+
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "grad_scaler"])
+def test_lowered_train_step_names_its_scopes(scaled):
+    """The guard that makes a refactor which drops a scope fail here instead
+    of silently zeroing a per-layer metric; forward, backward and the
+    recomputation keep the scope inside jax's jvp / transpose / checkpoint
+    wrappers."""
+    import re
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.topology import set_hybrid_communicate_group
+    from paddle_tpu.framework.random import default_generator
+    from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
+                                         LlamaPretrainingCriterion)
+
+    set_hybrid_communicate_group(None)
+    P.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=64, num_hidden_layers=1,
+        num_attention_heads=2, max_position_embeddings=64, recompute=True))
+    opt = P.optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    crit = LlamaPretrainingCriterion()
+    scaler = P.amp.GradScaler(init_loss_scaling=8.0) if scaled else None
+    step = P.jit.TrainStep(model, lambda m, ids: crit(m(ids), ids), opt, scaler=scaler)
+    ids = P.to_tensor(np.random.default_rng(0).integers(1, 128, (2, 16)).astype(np.int32))
+    assert np.isfinite(float(step(ids).numpy()))
+    accs, masters = step._get_opt_state()
+    text = step._compiled.lower(
+        [p._value for p in step._params], accs, masters,
+        [b._value for b in step._buffers], step._scaler_state(),
+        default_generator().next_key(), (ids._value,),
+        jnp.asarray(1e-3, jnp.float32)).as_text(debug_info=True)
+    want = TRAIN_SCOPES + (("grad_unscale",) if scaled else ())
+    missing = [s for s in want if not re.search(rf'["/(]{s}[/)"]', text)]
+    assert not missing, f"no operation under {missing}"
+    for wrapper in ("jvp(loss)", "transpose(jvp(loss))", "rematted_computation/mlp"):
+        assert wrapper in text
+
+
+KERNEL_NAMES = {
+    "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    "decode_attention.py": ["kv_ring_write", "decode_attention"],
+    "fused_adamw.py": ["fused_adamw"],
+    "fused_norm.py": ["rms_norm", "rms_norm_residual"],
+    "fused_ops.py": ["fused_rope", "swiglu_fwd", "swiglu_bwd"],
+    "int8_matmul.py": ["int8_matmul"],
+}
+
+
+@pytest.mark.parametrize("filename", sorted(KERNEL_NAMES))
+def test_every_pallas_call_has_its_own_name(filename):
+    """A kernel's device event is named by ``pallas_call(name=)``; without
+    one it takes the name of the transform it was traced under. The flash
+    kernels' names are what the benchmark's kernel metrics match."""
+    import ast
+
+    import paddle_tpu.ops.pallas as pallas
+
+    def names(path):
+        tree = ast.parse(open(path).read())
+        calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+                 and isinstance(n.func, ast.Attribute) and n.func.attr == "pallas_call"]
+        out = []
+        for c in sorted(calls, key=lambda c: c.lineno):
+            kw = {k.arg: k.value for k in c.keywords}
+            assert isinstance(kw.get("name"), ast.Constant), f"{path}:{c.lineno} has no name"
+            out.append(kw["name"].value)
+        return out
+
+    here = os.path.dirname(pallas.__file__)
+    assert names(os.path.join(here, filename)) == KERNEL_NAMES[filename]
+    every = [n for f in glob.glob(os.path.join(here, "*.py")) for n in names(f)]
+    assert len(every) == len(set(every)) == sum(map(len, KERNEL_NAMES.values()))
+
+
+def test_train_step_call_is_one_span_with_its_step_and_steps(host_spans):
+    model = nn.Linear(8, 4)
+    opt = P.optimizer.SGD(0.1, parameters=model.parameters())
+    step = P.jit.TrainStep(model, lambda m, x, y: P.nn.functional.mse_loss(m(x), y), opt)
+    x, y = P.randn([4, 8]), P.randn([4, 4])
+    step(x, y)                                          # compiles, untraced
+    with host_spans("train_step.") as spans:
+        step(x, y)
+        step.run_steps(P.randn([3, 4, 8]), P.randn([3, 4, 4]))
+    assert [(name, stats) for name, _, _, stats in spans] == [
+        ("train_step.call", {"step": 1, "steps": 1}), ("train_step.call", {"step": 2, "steps": 3})]
+    assert opt._step_count == 5

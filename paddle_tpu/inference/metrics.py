@@ -57,7 +57,11 @@ COUNTERS = (
     # mixed-phase megastep (ISSUE 16): scan launches that packed prefill
     # chunks alongside decode rows, and every prompt chunk fed (both the
     # in-scan chunks and single-step prefill feeds — the ratio
-    # prefill_chunks/megastep_mixed shows how much prefill rides the scan)
+    # prefill_chunks/megastep_mixed shows how much prefill rides the scan:
+    # 8.0 a launch, one chunk an iteration at megastep_k 8, while the
+    # first waiting prompt took the whole budget; 16.3 a launch, 2.04 an
+    # iteration, since a launch divides its budget among the prefilling
+    # rows by chunk — mistral7b.serve.batch on a TPU v5e, PERF.md PR 25)
     "megastep_mixed_total", "prefill_chunks_total",
     "stream_callback_errors_total",
     # durable control plane (ISSUE 11): write-ahead request journal,

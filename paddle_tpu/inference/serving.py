@@ -27,7 +27,10 @@ Design:
   MIXED scan (mq=block_size): each iteration processes, per row, either
   one decode token or one block-size prompt chunk — prompt chunks are fed
   as data through a ``prefill_pos`` carry against a host-staged prompt
-  window, so chunked prefill adds no shape axis and no recompile.  Under
+  window, so chunked prefill adds no shape axis and no recompile.  A
+  mixed launch books its token budget by chunk: one token a decoding
+  row, then one chunk a prefilling row in admission order while chunks
+  fit, so every waiting prompt that fits prefills in the same scan.  Under
   open-loop admission the megastep therefore never disarms just because
   some row is still prefilling (Sarathi/vLLM-style stall-free chunked
   prefill).  Rows that finish mid-scan (EOS or token budget) are masked:
@@ -1508,14 +1511,15 @@ class ServingEngine:
         phases are measured (span and ``phase_seconds`` together)."""
         return _Phase(self, name, attrs)
 
-    def _launch_phase(self, kind: str, k: int) -> _Phase:
+    def _launch_phase(self, kind: str, k: int, **attrs) -> _Phase:
         """``engine.launch`` of one compiled program: ``k`` iterations of
         ``kind``; ``launch`` counts launches (the ``FlightRecorder``'s
         ``megastep`` events carry it too) and ``t_mono`` is this engine's
-        clock, so that a recorder event's ``t`` can be placed on the trace."""
+        clock, so that a recorder event's ``t`` can be placed on the trace.
+        A mixed launch adds ``prefill_rows``, the rows it feeds chunks."""
         self.launches += 1
         return self._phase("launch", kind=kind, k=k, launch=self.launches,
-                           t_mono=self._clock())
+                           t_mono=self._clock(), **attrs)
 
     def step(self) -> Dict[int, List[int]]:
         """One engine iteration: schedule -> compiled step(s) -> retire.
@@ -1526,7 +1530,11 @@ class ServingEngine:
         ONE compiled ``lax.scan`` — the pure-decode scan when every row
         is decoding (int8 included; its scales ride the carry), the
         MIXED scan when prefilling rows share the batch (each iteration
-        feeds those rows one block-size prompt chunk as data).  The
+        feeds those rows one block-size prompt chunk as data).  A mixed
+        launch divides its ``token_budget`` by CHUNK: one token to each
+        decoding row, then one chunk to each prefilling row in admission
+        order while chunks fit, so every waiting prompt that fits rides
+        the scan, not the first alone (``_route``).  The
         returned lists then carry up to K tokens per request and the
         host — admission included — only observes the engine at megastep
         boundaries.  Prefill-only batches (plus int8 one-shot prefill
@@ -1564,45 +1572,71 @@ class ServingEngine:
         """Pick this step's rows and program: ``(sched, launch)`` where
         ``sched`` is [(req, n_tokens, finishes_prefill)] and ``launch``
         runs the armed scan or verify program (None: the single-step
-        program takes ``sched``)."""
-        budget = self.T
-        sched: List[tuple] = []  # (req, n_tokens, finishes_prefill)
-        # decode first (latency), then fill with prefill chunks.  Rows
-        # with slot < 0 are deadline-frozen and already released at a
-        # megastep harvest — they stay in _active only until the control
-        # plane finalizes the typed shed, and must never re-schedule.
-        for req in self._active.values():
-            if req.slot < 0:
-                continue
-            if not req.in_prefill and budget > 0:
-                sched.append((req, 1, False))
-                budget -= 1
-        for req in self._active.values():
-            if req.slot < 0:
-                continue
-            if req.in_prefill and budget > 0:
-                need = len(req.prompt) - req.prefill_pos
-                if self.cache_quant == "int8" and need > budget:
-                    # int8 dynamic scales freeze at prefill: the prefill must
-                    # land in ONE step, so wait for enough budget (bounded
-                    # wait — decoding slots retire and free it)
-                    continue
-                n = min(need, budget)
-                sched.append((req, n, req.prefill_pos + n >= len(req.prompt)))
-                budget -= n
-                if self._faults is not None:
-                    from .faults import prompt_signature
+        program takes ``sched``).
 
-                    # chunk-boundary failpoint, single-step path: fires
-                    # before any device mutation, once per prompt chunk
-                    self._faults.fire("engine.prefill_chunk",
-                                      detail=prompt_signature(req.prompt))
+        Two bookings of the ``token_budget``.  A launch that will be the
+        MIXED scan (a row decodes, a row prefills, ``megastep_k > 1``,
+        cache not int8, a chunk fits the buffer) gives each decoding row
+        one token and then DIVIDES the rest among the prefilling rows,
+        ONE CHUNK EACH (``min(block_size, prompt left)``, the most a row
+        packs into any one iteration), in admission order, until the next
+        row's chunk no longer fits: the scan feeds a row one chunk an
+        iteration whether one row prefills or ten, so a waiting prompt
+        costs the rows ahead of it nothing.  Every other launch books for
+        the single-step program: a prefilling row takes
+        ``min(prompt left, budget)`` whole, first come first served."""
+        # rows with slot < 0 are deadline-frozen and already released at a
+        # megastep harvest — they stay in _active only until the control
+        # plane finalizes the typed shed, and must never re-schedule
+        rows = [r for r in self._active.values() if r.slot >= 0]
+        # decode first (latency), then fill with prefill chunks
+        dec_rows = [r for r in rows if not r.in_prefill][:self.T]
+        waiting = [r for r in rows if r.in_prefill]
+        budget = self.T - len(dec_rows)
+        # MIXED-PHASE arming (ISSUE 16): any decoding row + any prefilling
+        # row -> run both phases inside one scan instead of falling back
+        # to per-token host stepping.  int8 keeps one-shot prefill
+        # (dynamic scales freeze at prefill, chunking would violate it);
+        # bs > T cannot exact-pack a full chunk into the token buffer.
+        if (dec_rows and waiting and self.megastep_k > 1
+                and self.cache_quant != "int8" and self.pc <= self.T):
+            pre_rows = []
+            left = budget
+            for r in waiting:
+                # worst-case packed tokens this row adds to any one
+                # iteration: its first chunk (chunks only shrink)
+                cost = min(self.pc, len(r.prompt) - r.prefill_pos)
+                if cost > left:
+                    break   # admission order: nobody overtakes this row
+                pre_rows.append(r)
+                left -= cost
+            if pre_rows:
+                return [], partial(self._megastep_mixed, dec_rows, pre_rows)
+        sched = [(r, 1, False) for r in dec_rows]
+        for req in waiting:
+            if budget <= 0:
+                break
+            need = len(req.prompt) - req.prefill_pos
+            if self.cache_quant == "int8" and need > budget:
+                # int8 dynamic scales freeze at prefill: the prefill must
+                # land in ONE step, so wait for enough budget (bounded
+                # wait — decoding slots retire and free it)
+                continue
+            n = min(need, budget)
+            sched.append((req, n, req.prefill_pos + n >= len(req.prompt)))
+            budget -= n
+            if self._faults is not None:
+                from .faults import prompt_signature
+
+                # chunk-boundary failpoint, single-step path: fires
+                # before any device mutation, once per prompt chunk
+                self._faults.fire("engine.prefill_chunk",
+                                  detail=prompt_signature(req.prompt))
         if not sched:
             return sched, None
         # pure-decode steps run the tight [B]-token program (mq=1); steps
-        # carrying prefill chunks run the [T]-token program (mq=T) — decide
-        # first, allocate the one token buffer the program actually takes
-        decode_only = all(not r.in_prefill for r, _, _ in sched)
+        # carrying prefill chunks run the [T]-token program (mq=T)
+        decode_only = len(sched) == len(dec_rows)
         # SPECULATIVE arming (ISSUE 19): pure-decode batches on a
         # spec_k > 0 engine try n-gram drafting first; one verify
         # forward then commits accepted+1 tokens per row.  int8 is
@@ -1610,9 +1644,8 @@ class ServingEngine:
         # launch with NO non-empty draft falls through — the megastep
         # is strictly better when there is nothing to verify.
         if (decode_only and self.spec_k > 0 and self.cache_quant != "int8"
-                and any(r.sampling.spec for r, _, _ in sched)):
-            spec_rows = [r for r, _, _ in sched]
-            drafts = self._draft(spec_rows)
+                and any(r.sampling.spec for r in dec_rows)):
+            drafts = self._draft(dec_rows)
             if any(drafts.values()):
                 armed = True
                 if self._faults is not None:
@@ -1621,40 +1654,18 @@ class ServingEngine:
                         self._faults.fire(
                             SPEC_VERIFY,
                             detail=" ".join(prompt_signature(r.prompt)
-                                            for r in spec_rows))
+                                            for r in dec_rows))
                     except Exception:
                         # degrade contract: a verify fault falls this
                         # step back to the non-spec megastep/single-step
                         # path — token-identical, never a wrong token
                         armed = False
                 if armed:
-                    return sched, partial(self._spec_step, spec_rows, drafts)
+                    return sched, partial(self._spec_step, dec_rows, drafts)
         if (decode_only and self.megastep_k > 1
                 and max(r.max_new_tokens - len(r.generated)
-                        for r, _, _ in sched) > 1):
-            return sched, partial(self._megastep, [s[0] for s in sched])
-        # MIXED-PHASE arming (ISSUE 16): any decoding row + any prefilling
-        # row -> run both phases inside one scan instead of falling back
-        # to per-token host stepping.  int8 keeps one-shot prefill
-        # (dynamic scales freeze at prefill, chunking would violate it);
-        # bs > T cannot exact-pack a full chunk into the token buffer.
-        if (self.megastep_k > 1 and self.cache_quant != "int8"
-                and self.pc <= self.T and not decode_only
-                and any(not r.in_prefill for r, _, _ in sched)):
-            dec_rows = [r for r, _, _ in sched if not r.in_prefill]
-            pre_rows = []
-            budget_m = self.T - len(dec_rows)
-            for r, _, _ in sched:
-                if r.in_prefill:
-                    # worst-case packed tokens this row adds to any one
-                    # iteration: its first chunk (chunks only shrink)
-                    cost = min(self.pc, len(r.prompt) - r.prefill_pos)
-                    if cost <= budget_m:
-                        pre_rows.append(r)
-                        budget_m -= cost
-            if pre_rows:
-                return sched, partial(self._megastep_mixed, dec_rows,
-                                      pre_rows)
+                        for r in dec_rows) > 1):
+            return sched, partial(self._megastep, dec_rows)
         return sched, None
 
     def _single_step(self, sched: List[tuple]) -> Dict[int, List[int]]:
@@ -2063,11 +2074,14 @@ class ServingEngine:
         """Run up to ``megastep_k`` MIXED-PHASE iterations in one
         compiled scan: ``dec_reqs`` decode one token per iteration while
         ``pre_reqs`` consume one block-size prompt chunk per iteration
-        (then decode in place once their prompt completes).  The caller
-        guarantees the worst-case packed-token total fits the [T]
-        buffer.  Unlike the pure-decode scan (power-of-two K buckets),
-        mixed launches ALWAYS run the full ``megastep_k`` bucket: one
-        compiled mixed program per engine.  Mixed arms under live
+        (then decode in place once their prompt completes).  ``pre_reqs``
+        are the prefilling rows ``_route`` divided the launch's token
+        budget among, one chunk each in admission order, so the
+        worst-case packed-token total fits the [T] buffer; the scan
+        feeds each of them a chunk an iteration.  Unlike the pure-decode
+        scan (power-of-two K buckets), mixed launches ALWAYS run the
+        full ``megastep_k`` bucket: one compiled mixed program per
+        engine.  Mixed arms under live
         admission, so a tail-sized launch (every row near completion)
         would compile a second multi-second XLA program mid-traffic —
         far costlier than the masked tail iterations it saves."""
@@ -2079,9 +2093,10 @@ class ServingEngine:
                 "engine.megastep",
                 detail=" ".join(prompt_signature(r.prompt) for r in reqs))
             for r in pre_reqs:
-                # chunk-boundary failpoint: fires BEFORE the compiled
-                # call (a fault never leaves half-committed tokens), once
-                # per prompt entering the scan chunked
+                # chunk-boundary failpoint, the mixed path's one site:
+                # fires BEFORE the compiled call (a fault never leaves
+                # half-committed tokens), once per prompt entering the
+                # scan chunked
                 self._faults.fire("engine.prefill_chunk",
                                   detail=prompt_signature(r.prompt))
         with self._phase("schedule"):
@@ -2124,7 +2139,8 @@ class ServingEngine:
                     # pp == plen marks the row as decoding from iteration 0
                     pp[slot] = pp0[slot] = plen[slot] = len(req.prompt)
             dl = self._deadline_budgets(by_slot)
-        with self._launch_phase("mixed", K) as launch:
+        with self._launch_phase("mixed", K,
+                                prefill_rows=len(pre_reqs)) as launch:
             if self._mixed_fn is None:
                 if "mixed" not in self._programs:
                     self._programs["mixed"] = self._build_mixed_megastep()

@@ -14,7 +14,8 @@ the program's words (the table with each name's reader is PERF.md section 3):
 Host spans, every one a ``RecordEvent`` on the profiler's clock:
 ``frontend.step`` > ``frontend.dispatch``, ``engine.step``, ``frontend.deliver``;
 ``engine.step`` > ``engine.admit``, ``engine.schedule``, ``engine.launch``
-(stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``),
+(stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``, and
+on a mixed launch ``prefill_rows``, the rows it feeds prompt chunks),
 ``engine.wait``, ``engine.harvest``; ``train_step.call`` (stats ``step``,
 ``steps``).
 

@@ -13,7 +13,9 @@ Contracts under test:
   token-identical to per-token stepping (greedy AND seeded), with the
   prefix cache entering prefill mid-chunk, across a preempt/resume that
   straddles a chunk boundary, and with ``prefill_chunk`` span events
-  attributing TTFT chunk by chunk;
+  attributing TTFT chunk by chunk; a mixed launch divides its token
+  budget by chunk (PR 25), so every waiting prompt that fits prefills
+  in the same launch, in admission order, and none is starved;
 * ``temperature=0`` sampling is the argmax path exactly (same tokens as
   the greedy engine), and seeded sampling is deterministic: same seed →
   same tokens across K values, across an engine rebuild (the worker-
@@ -579,6 +581,147 @@ class TestMixedPhaseMegastep:
         assert sorted(a["chunk"] for a in chunks) == \
             list(range(len(chunks)))
         assert sum(a["tokens"] for a in chunks) == len(long)
+
+    # ---- PR 25: a mixed launch divides its token budget by chunk ----
+    # one row decodes, three prompts of several chunks wait: four slots,
+    # room in the [T] buffer for the decode token and three chunks of 8
+    SHARED = dict(max_batch_size=4, token_budget=32)
+    WAITING = (list(range(40, 60)),           # 20 tokens: chunks 8, 8, 4
+               list(range(70, 94)),           # 24 tokens: 8, 8, 8
+               list(range(110, 128)))         # 18 tokens: 8, 8, 2
+
+    def _decoding_then_waiting(self, model, k, waiting=None, sampling=None,
+                               **kw):
+        """An engine with one row past its prefill and ``waiting``
+        admitted behind it: the state the next ``step()`` routes."""
+        eng = ServingEngine(model, megastep_k=k,
+                            **{**ENGINE, **self.SHARED, **kw})
+        r0 = eng.add_request([3, 17, 101], max_new_tokens=12,
+                             sampling=sampling)
+        eng.step()                            # r0 decoding
+        rids = [eng.add_request(p, max_new_tokens=6, sampling=sampling)
+                for p in (self.WAITING if waiting is None else waiting)]
+        return eng, r0, rids
+
+    @pytest.mark.parametrize("sampling", [None, SAMPLED],
+                             ids=["greedy", "seeded"])
+    def test_one_launch_advances_every_waiting_prompt(self, model, sampling):
+        """ONE mixed launch feeds all three waiting prompts a chunk an
+        iteration (the old booking gave the whole budget to the first),
+        and the tokens are those of per-token stepping and, greedy, of
+        the model's own forward."""
+        eng, r0, rids = self._decoding_then_waiting(model, 2,
+                                                    sampling=sampling)
+        before = eng.prefill_chunks
+        eng.step()                            # K=2: two chunks a row
+        assert eng.megasteps_mixed == 1
+        assert [eng._active[r].prefill_pos for r in rids] == [16, 16, 16]
+        assert eng.prefill_chunks - before == 6
+        on = eng.run()
+        ref, r0k1, rk1 = self._decoding_then_waiting(model, 1,
+                                                     sampling=sampling)
+        off = ref.run()
+        assert [on[r0]] + [on[r] for r in rids] == \
+            [off[r0k1]] + [off[r] for r in rk1]
+        if sampling is None:
+            assert on[r0] == ref_greedy(model, [3, 17, 101], 12)
+            for r, p in zip(rids, self.WAITING):
+                assert on[r] == ref_greedy(model, p, 6)
+
+    def test_a_prompt_that_does_not_fit_waits_its_turn(self, model):
+        """Room for two chunks beside the decode row: the third prompt
+        waits, nobody behind it overtakes it (a 2-token prompt would
+        fit the 3 tokens left), and both enter, in admission order, as
+        soon as rows ahead of them finish their prompts."""
+        waiting = self.WAITING + ([7, 9],)
+        eng, r0, rids = self._decoding_then_waiting(
+            model, 2, waiting=waiting, max_batch_size=5, token_budget=20)
+        first_fed, reqs = {}, None
+        for launch in range(1, 10):
+            eng.step()                        # the first admits all four
+            reqs = reqs or [eng._active[r] for r in rids]
+            for i, r in enumerate(reqs):
+                if r.chunks_fed:
+                    first_fed.setdefault(i, launch)
+            if not any(r.in_prefill for r in reqs):
+                break
+        else:
+            pytest.fail("a waiting prompt was starved")
+        assert first_fed[0] == first_fed[1] == 1      # 1 + 8 + 8 of 20
+        assert first_fed[2] > 1                       # 8 more do not fit
+        assert first_fed[3] >= first_fed[2]           # and nobody overtakes
+        # launch 2 still holds both tails (4 + 8 of 19); launch 3 has
+        # them decoding, and the two that waited go in together
+        assert (first_fed[2], first_fed[3]) == (3, 3)
+        out = eng.run()
+        for r, p in zip(rids, waiting):
+            assert out[r] == ref_greedy(model, p, 6)
+
+    def test_prefix_hit_shares_a_launch_with_a_fresh_prompt(self, model):
+        """A fresh prompt longer than the whole budget and, behind it, one
+        that a prefix hit dropped in mid-chunk (``prefill_pos`` 16, chunks
+        of 6) prefill in the same launch; cache on and off give the same
+        tokens, the model's own."""
+        shared = list(range(30, 46))          # 16 tokens = 2 full blocks
+        hit, fresh = shared + [5, 6, 8, 10, 11, 12, 13], list(range(100, 140))
+        outs = {}
+        for cache in (False, "auto"):
+            eng = ServingEngine(model, prefix_cache=cache, megastep_k=2,
+                                prefill_chunk_tokens=6,
+                                **{**ENGINE, **self.SHARED})
+            eng.add_request(shared + [7, 9], max_new_tokens=4)
+            eng.run()                         # seeds the cache
+            rd = eng.add_request([3, 17, 101], max_new_tokens=10)
+            eng.step()                        # rd decoding
+            r2 = eng.add_request(fresh, max_new_tokens=6)
+            r1 = eng.add_request(hit, max_new_tokens=6)
+            mixed = eng.megasteps_mixed
+            eng.step()
+            start = 16 if cache == "auto" else 0
+            assert eng.megasteps_mixed == mixed + 1
+            assert eng._active[r1].prefill_pos == min(start + 12, len(hit))
+            assert eng._active[r2].prefill_pos == 12
+            rest = eng.run()
+            outs[cache] = (rest[rd], rest[r1], rest[r2])
+        assert eng.prefix_hit_blocks >= 2     # the cache engaged
+        assert outs[False] == outs["auto"]
+        assert outs["auto"] == (ref_greedy(model, [3, 17, 101], 10),
+                                ref_greedy(model, hit, 6),
+                                ref_greedy(model, fresh, 6))
+
+    def test_mixed_launch_span_carries_prefill_rows(self, model, host_spans):
+        """``engine.launch`` of kind ``mixed`` says how many rows the
+        launch feeds chunks; no other kind carries the attribute."""
+        warm, _, _ = self._decoding_then_waiting(model, 2)
+        warm.run()                            # compiles, untraced
+        eng, _, _ = self._decoding_then_waiting(model, 2)
+        with host_spans("engine.launch") as events:
+            eng.run()
+        mixed = [e[3] for e in events if e[3]["kind"] == "mixed"]
+        assert [m["prefill_rows"] for m in mixed[:2]] == [3, 3]
+        assert all(1 <= m["prefill_rows"] <= 3 for m in mixed)
+        others = [e[3] for e in events if e[3]["kind"] != "mixed"]
+        assert others and not any("prefill_rows" in o for o in others)
+
+    def test_prefill_chunk_failpoint_fires_once_a_prompt_a_launch(self, model):
+        """``engine.prefill_chunk`` is traversed before the compiled call,
+        once for each prompt a mixed launch feeds: three here, the
+        routing no longer fires for the first a second time."""
+        from paddle_tpu.inference.faults import FaultInjector, prompt_signature
+
+        inj = FaultInjector({"engine.prefill_chunk":
+                             {"kind": "delay", "delay_s": 0.0},
+                             "engine.megastep":
+                             {"kind": "delay", "delay_s": 0.0}},
+                            sleep=lambda s: None)
+        eng, _, _ = self._decoding_then_waiting(model, 2, fault_injector=inj)
+        del inj.log[:]                        # r0's own single-step prefill
+        eng.step()
+        assert [(site, detail) for site, _, detail in inj.log] == [
+            ("engine.megastep", " ".join(
+                prompt_signature(p) for p in ([3, 17, 101],) + self.WAITING)),
+            *(("engine.prefill_chunk", prompt_signature(p))
+              for p in self.WAITING)]
 
 
 # ------------------------------------------- names, spans and phase seconds

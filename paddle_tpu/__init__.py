@@ -96,14 +96,22 @@ from .distributed.parallel import DataParallel  # noqa: F401
 
 
 class LazyGuard:
-    """parity: paddle.LazyGuard — defers parameter materialization in the
-    reference (meta tensors). Host-side numpy init is cheap here, so layers
-    initialize eagerly; the guard exists for API compatibility."""
+    """parity: paddle.LazyGuard — parameters created inside the guard are
+    ABSTRACT (a shape and a type, ``jax.ShapeDtypeStruct``): nothing is
+    drawn and nothing is placed on the device until a value is assigned
+    (``param._value = array``).  For a model whose weights arrive from
+    elsewhere and whose own copy would not fit beside them."""
 
     def __enter__(self):
+        from .nn.layer import layers
+
+        layers._lazy_parameters += 1
         return self
 
     def __exit__(self, *exc):
+        from .nn.layer import layers
+
+        layers._lazy_parameters -= 1
         return False
 
 

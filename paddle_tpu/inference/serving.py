@@ -11,8 +11,11 @@ Design:
   prefill chunks (admitted prompts are fed chunk-by-chunk). Sequences of any
   length enter and retire without recompilation — admission/eviction is pure
   host bookkeeping over the block free-list.
-- KV lives in per-layer block pools [num_blocks, KV, bs, D] indexed through
-  per-sequence block tables (ops/paged_attention.py). Sampling runs
+- KV lives in per-layer block pools indexed through per-sequence block
+  tables; the served model says which arrays a layer keeps there
+  (serving_model.py ``CacheSpec``): keys and values [num_blocks, KV, bs, D]
+  for a per-head cache (ops/paged_attention.py), one latent entry a token
+  [num_blocks, bs, W] for MLA (ops/latent_attention.py). Sampling runs
   in-graph — temperature / top-k / top-p with per-request PRNG keys and
   optional logprobs; ``temperature=0`` (the default) takes the exact
   argmax path, so greedy serving is bit-identical to the pre-sampling
@@ -97,7 +100,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..ops.paged_attention import blha_attention
 from ..profiler import RecordEvent
 from .faults import register_failpoint
 
@@ -543,6 +545,11 @@ class _Phase:
         return False
 
 
+def _sum_counts(counts):
+    """A scan's stacked per-iteration ``counts`` -> one number each."""
+    return jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), counts)
+
+
 def _np_dtype(name: str) -> np.dtype:
     """Numpy dtype for a cache dtype's string form.  ``bfloat16`` (and
     friends) only resolve once ml_dtypes' registrations are imported —
@@ -556,7 +563,8 @@ def _np_dtype(name: str) -> np.dtype:
 
 
 class ServingEngine:
-    """Continuous batching for a LlamaForCausalLM (single process).
+    """Continuous batching for a model that answers serving_model.py's
+    questions (weights, cache specification, trunk, rope): single process.
 
     >>> eng = ServingEngine(model, max_batch_size=4, max_seq_len=256)
     >>> rid = eng.add_request([1, 5, 7], max_new_tokens=16)
@@ -598,13 +606,17 @@ class ServingEngine:
         self.max_seq_len = self.P * self.bs
         nb = num_blocks if num_blocks is not None else self.B * self.P
         self.blocks = BlockManager(int(nb))
-        self.H = cfg.num_attention_heads
-        self.KV = cfg.num_key_value_heads
-        self.D = cfg.head_dim
-        self.E = cfg.hidden_size
-        self.L = cfg.num_hidden_layers
+        # the model says what a layer keeps in the pool (serving_model.py)
+        spec = model.serving_cache_spec()
+        self.cache_spec = spec
+        self.KV, self.D = spec.kv_heads, spec.head_dim   # None: no per-head cache
+        self.L = spec.layers
         if cache_quant not in ("none", "int8"):
             raise ValueError("cache_quant must be 'none' or 'int8'")
+        if cache_quant == "int8" and not spec.quantizable:
+            raise ValueError(
+                f"cache_quant='int8' cannot be used with {type(model).__name__}: "
+                + spec.why_not)
         self.cache_quant = cache_quant
         if prefix_cache not in ("auto", True, False):
             raise ValueError("prefix_cache must be 'auto', True, or False")
@@ -637,7 +649,7 @@ class ServingEngine:
         self._compute_dtype = (jnp.bfloat16 if cfg.dtype == "bfloat16"
                                else jnp.float32)
 
-        self._weights = self._extract_weights(model)
+        self._weights = model.serving_weights(self._compute_dtype)
         # rolling weight swaps / tenancy (ISSUE 18): a version label that
         # rides metric + trace attribution, and the model id tenant
         # routing keys on.  Both are plain host state — load_weights
@@ -645,11 +657,13 @@ class ServingEngine:
         # programs (model identity is NOT in _program_key).
         self.weights_version = "v0"
         self.model_id = "default"
-        self._rope = self._build_rope(cfg)
-        self.key_caches = [jnp.zeros((nb, self.KV, self.bs, self.D), cache_dtype)
-                           for _ in range(self.L)]
-        self.value_caches = [jnp.zeros_like(self.key_caches[0])
-                             for _ in range(self.L)]
+        self._rope = model.serving_rope(self.max_seq_len)
+        # one list (a layer each) for every array the model's layers keep:
+        # (keys, values) [nb, KV, bs, D] for a per-head cache, (latent,)
+        # [nb, bs, W] for a latent one
+        self.caches = tuple(
+            [jnp.zeros((nb,) + tuple(shape(self.bs)), cache_dtype)
+             for _ in range(self.L)] for _, shape in spec.arrays)
         if cache_quant == "int8":
             self.cache_scales = [
                 {k: jnp.zeros((self.B, self.KV), jnp.float32)
@@ -683,6 +697,11 @@ class ServingEngine:
         self.megastep_tokens = 0    # tokens emitted via the megastep path
         self.megasteps_mixed = 0    # of those launches, mixed-phase scans
         self.prefill_chunks = 0     # prompt chunks fed inside mixed scans
+        # what a model's trunk counts (``counts`` of serving_model.py), added
+        # up launch by launch: tokens through expert layers, and the picks
+        # among them that fell on an expert held here (monotone)
+        self.moe_tokens = 0
+        self.moe_local_picks = 0
         # prefill chunk size (ISSUE 19 satellite, first rung toward
         # Sarathi-style budget-adaptive chunking): tokens per prompt
         # chunk inside the mixed-phase scan.  Default = block_size (the
@@ -732,7 +751,7 @@ class ServingEngine:
         # an already-served geometry starts with warm compile caches.
         self._programs = _PROGRAM_CACHE.setdefault(self._program_key(), {})
         if "forward" not in self._programs:
-            fwd, trunk = self._build_forward()
+            fwd, trunk = self._build_forward(model)
             self._programs["forward"] = fwd
             self._programs["trunk"] = trunk
         self._forward = self._programs["forward"]
@@ -753,37 +772,27 @@ class ServingEngine:
         weights/caches/rope enter as arguments, so jit keys their
         shapes/dtypes (and the layer count, via pytree structure)
         itself — two models with the same architecture share programs."""
-        return (self.B, self.T, self.bs, self.H, self.KV, self.D, self.E,
-                float(self.cfg.rms_norm_eps), self.cache_quant,
+        return (self.B, self.T, self.bs, self.cache_spec.key, self.cache_quant,
                 bool(self.capture_sample_probs), self.pc, self.spec_k)
 
+    @property
+    def key_caches(self):
+        """A per-head cache's keys, a layer each (``caches[0]``)."""
+        return self.caches[0]
+
+    @key_caches.setter
+    def key_caches(self, value):
+        self.caches = (value,) + tuple(self.caches[1:])
+
+    @property
+    def value_caches(self):
+        return self.caches[1]
+
+    @value_caches.setter
+    def value_caches(self, value):
+        self.caches = (self.caches[0], value) + tuple(self.caches[2:])
+
     # ------------------------------------------------------------ weights
-    def _extract_weights(self, model):
-        def v(t):
-            return t._value.astype(self._compute_dtype)
-
-        lm = model.llama
-        w = {
-            "embed": v(model.llama.embed_tokens.weight),
-            "norm": v(lm.norm.weight),
-        }
-        if model.lm_head is None:
-            w["head"] = w["embed"].T
-        else:
-            w["head"] = v(model.lm_head.weight)
-        w["layers"] = []
-        for layer in lm.layers:
-            a, m = layer.self_attn, layer.mlp
-            w["layers"].append({
-                "ln1": v(layer.input_layernorm.weight),
-                "ln2": v(layer.post_attention_layernorm.weight),
-                "wq": v(a.q_proj.weight), "wk": v(a.k_proj.weight),
-                "wv": v(a.v_proj.weight), "wo": v(a.o_proj.weight),
-                "wg": v(m.gate_proj.weight), "wu": v(m.up_proj.weight),
-                "wd": v(m.down_proj.weight),
-            })
-        return w
-
     def load_weights(self, model, version: Optional[str] = None,
                      model_id: Optional[str] = None) -> str:
         """Swap in ``model``'s weights WITHOUT recompiling: weights enter
@@ -802,18 +811,14 @@ class ServingEngine:
         if self._faults is not None:
             self._faults.fire(WEIGHTS_SWAP,
                               detail=str(version or model_id or ""))
-        cfg = model.config
-        if (cfg.num_attention_heads != self.H
-                or cfg.num_key_value_heads != self.KV
-                or cfg.head_dim != self.D
-                or cfg.hidden_size != self.E
-                or cfg.num_hidden_layers != self.L):
+        spec = model.serving_cache_spec()
+        if (spec.key, spec.layers) != (self.cache_spec.key, self.L):
             raise ValueError(
-                "load_weights: new model's geometry (heads/kv/head_dim/"
-                "hidden/layers) must match the engine's — the compiled "
-                "step programs bake the attention geometry; boot a fresh "
-                "engine for a different architecture")
-        new = self._extract_weights(model)   # raises before any mutation
+                "load_weights: new model's geometry (its cache "
+                "specification's key and layers) must match the engine's — "
+                "the compiled step programs bake the attention geometry; "
+                "boot a fresh engine for a different architecture")
+        new = model.serving_weights(self._compute_dtype)   # raises before any mutation
         self._weights = new
         self.blocks.drop_cached()
         if model_id is not None:
@@ -827,88 +832,24 @@ class ServingEngine:
             self.weights_version = str(model_id)
         return self.weights_version
 
-    def _build_rope(self, cfg):
-        d = cfg.head_dim
-        inv = 1.0 / (cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
-        t = np.arange(self.max_seq_len, dtype=np.float64)
-        fr = np.outer(t, inv)
-        # blha rope layout [2, Br=1, Smax, 1, D/2]; llama uses the
-        # half-split (neox) rotation (models/llama.py apply_rotary_pos_emb)
-        return jnp.asarray(
-            np.stack([np.cos(fr), np.sin(fr)])[:, None, :, None, :],
-            jnp.float32)
-
     # ------------------------------------------------------- compiled step
-    def _build_forward(self):
-        cfg = self.cfg
-        H, KV, D, E = self.H, self.KV, self.D, self.E
-        eps = cfg.rms_norm_eps
-        T, B, bs = self.T, self.B, self.bs
+    def _build_forward(self, model):
+        """(forward, trunk): the model's own trunk over (weights, caches,
+        packed batch), and the trunk headed at each slot's LAST packed token:
+        ``forward`` heads one row a slot, the spec-verify program (ISSUE 19)
+        heads every draft position: one set of layer math, two consumers."""
+        trunk = model.serving_trunk(block_size=self.bs, cache_quant=self.cache_quant)
 
-        def rms(x, w):
-            xf = x.astype(jnp.float32)
-            nrm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-            return (nrm * w.astype(jnp.float32)).astype(x.dtype)
-
-        quant = self.cache_quant
-
-        def trunk(weights, key_caches, value_caches, rope, token_ids,
-                  enc, dec, now, cu, bt, mq, scales=None):
-            # mq (static): padded per-sequence query length for the attention
-            # compute — T for steps carrying prefill chunks, 1 for pure
-            # decode steps (avoids T× padded-query attention waste).  The
-            # trunk runs embed -> layers -> final rms and returns the FULL
-            # hidden sequence: ``forward`` heads only each slot's last
-            # packed token, the spec-verify program (ISSUE 19) heads every
-            # draft position — one set of layer math, two consumers.
-            with jax.named_scope("embed"):
-                hidden = weights["embed"][token_ids]  # [T, E]
-            new_scales = []
-            for li, lw in enumerate(weights["layers"]):
-                with jax.named_scope("norm"):
-                    h = rms(hidden, lw["ln1"])
-                with jax.named_scope("attn_proj"):
-                    q = h @ lw["wq"]
-                    k = h @ lw["wk"]
-                    v = h @ lw["wv"]
-                    qkv = jnp.concatenate([q, k, v], axis=-1)
-                sc = scales[li] if scales is not None else {}
-                out, kc, vc, kq, vq, kd, vd = blha_attention(
-                    qkv, key_caches[li], value_caches[li], enc, dec, now,
-                    cu, bt, num_heads=H, kv_num_heads=KV, head_dim=D,
-                    block_size=bs, max_q_len=mq, use_neox_style=True,
-                    compute_dtype=hidden.dtype, rope_emb=rope,
-                    cache_quant=quant if quant != "int8" else "dynamic",
-                    cache_k_quant_scales=sc.get("kq"),
-                    cache_v_quant_scales=sc.get("vq"),
-                    cache_k_dequant_scales=sc.get("kd"),
-                    cache_v_dequant_scales=sc.get("vd"))
-                key_caches[li] = kc
-                value_caches[li] = vc
-                if scales is not None:
-                    new_scales.append({"kq": kq, "vq": vq, "kd": kd, "vd": vd})
-                with jax.named_scope("attn_out"):
-                    hidden = hidden + out @ lw["wo"]
-                with jax.named_scope("norm"):
-                    h2 = rms(hidden, lw["ln2"])
-                with jax.named_scope("mlp"):
-                    g = h2 @ lw["wg"]
-                    u = h2 @ lw["wu"]
-                    hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
-            with jax.named_scope("norm"):
-                hidden = rms(hidden, weights["norm"])
-            return hidden, key_caches, value_caches, new_scales
-
-        def forward(weights, key_caches, value_caches, rope, token_ids,
-                    enc, dec, now, cu, bt, mq, scales=None):
-            hidden, kcs, vcs, new_scales = trunk(
-                weights, key_caches, value_caches, rope, token_ids, enc,
-                dec, now, cu, bt, mq, scales)
+        def forward(weights, caches, rope, token_ids, enc, dec, now, cu, bt,
+                    mq, scales=None):
+            hidden, caches, new_scales, counts = trunk(
+                weights, caches, rope, token_ids, enc, dec, now, cu, bt, mq,
+                scales)
             # one logits row per batch slot: its LAST packed token
             with jax.named_scope("head"):
                 rows = jnp.clip(cu[1:] - 1, 0, token_ids.shape[0] - 1)
                 logits = hidden[rows] @ weights["head"]  # [B, V]
-            return logits, kcs, vcs, new_scales
+            return logits, caches, new_scales, counts
 
         return forward, trunk
 
@@ -916,8 +857,8 @@ class ServingEngine:
                   enc, dec, now, cu, bt, mq, scales=None):
         """Undonated greedy step body (in-graph benching/scans keep the
         historical (nxt, kcs, vcs, scales) contract)."""
-        logits, kcs, vcs, ns = self._forward(
-            weights, key_caches, value_caches, rope, token_ids, enc, dec,
+        logits, (kcs, vcs), ns, _ = self._forward(
+            weights, (key_caches, value_caches), rope, token_ids, enc, dec,
             now, cu, bt, mq, scales)
         nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
         return nxt, kcs, vcs, ns
@@ -926,18 +867,18 @@ class ServingEngine:
         fwd = self._forward
         with_probs = self.capture_sample_probs
 
-        def step(weights, key_caches, value_caches, rope, token_ids,
+        def step(weights, caches, rope, token_ids,
                  enc, dec, now, cu, bt, temps, top_ks, top_ps, seeds,
                  sample_pos, mq, scales=None):
-            logits, kcs, vcs, new_scales = fwd(
-                weights, key_caches, value_caches, rope, token_ids, enc,
+            logits, caches, new_scales, counts = fwd(
+                weights, caches, rope, token_ids, enc,
                 dec, now, cu, bt, mq, scales)
             nxt, logprob, probs = _sample_tokens(
                 logits, temps, top_ks, top_ps, seeds, sample_pos,
                 return_probs=with_probs)
-            return nxt, logprob, probs, kcs, vcs, new_scales
+            return nxt, logprob, probs, caches, new_scales, counts
 
-        return jax.jit(step, donate_argnums=(1, 2), static_argnames=("mq",))
+        return jax.jit(step, donate_argnums=(1,), static_argnames=("mq",))
 
     def _build_megastep(self):
         """K decode iterations inside one compiled ``lax.scan``: the
@@ -960,18 +901,19 @@ class ServingEngine:
         B = self.B
         with_probs = self.capture_sample_probs
 
-        def mega(weights, key_caches, value_caches, rope, toks, dec, now,
+        def mega(weights, caches, rope, toks, dec, now,
                  cu, occ_idx, bt, active, remaining, dl, eos, temps,
                  top_ks, top_ps, seeds, sample_pos, scales, K):
             enc = jnp.zeros((B,), jnp.int32)
 
             def body(carry, _):
-                (toks, kcs, vcs, dec, active, remaining, sample_pos, dl,
+                (toks, caches, dec, active, remaining, sample_pos, dl,
                  scales) = carry
                 with jax.named_scope("scan_carry"):
                     packed = toks[occ_idx]    # slot-order -> packed layout
-                logits, kcs, vcs, ns = fwd(weights, kcs, vcs, rope, packed,
-                                           enc, dec, now, cu, bt, 1, scales)
+                logits, caches, ns, counts = fwd(
+                    weights, caches, rope, packed, enc, dec, now, cu, bt, 1,
+                    scales)
                 scales = ns if scales is not None else None
                 nxt, lps, probs = _sample_tokens(
                     logits, temps, top_ks, top_ps, seeds, sample_pos,
@@ -993,17 +935,18 @@ class ServingEngine:
                     sample_pos = sample_pos + alive.astype(jnp.int32)
                     dl = dl - alive.astype(jnp.int32)
                     active = active & jnp.logical_not(fin)
-                return ((toks, kcs, vcs, dec, active, remaining,
-                         sample_pos, dl, scales), (nxt, valid, lps, probs))
+                return ((toks, caches, dec, active, remaining,
+                         sample_pos, dl, scales),
+                        (nxt, valid, lps, probs, counts))
 
-            carry0 = (toks, key_caches, value_caches, dec, active,
+            carry0 = (toks, caches, dec, active,
                       remaining, sample_pos, dl, scales)
-            carry, (toks_o, valid_o, lps_o, probs_o) = jax.lax.scan(
+            carry, (toks_o, valid_o, lps_o, probs_o, counts_o) = jax.lax.scan(
                 body, carry0, None, length=K)
-            return (carry[1], carry[2], carry[8], toks_o, valid_o, lps_o,
-                    probs_o)
+            return (carry[1], carry[7], toks_o, valid_o, lps_o, probs_o,
+                    _sum_counts(counts_o))
 
-        return jax.jit(mega, static_argnames=("K",), donate_argnums=(1, 2))
+        return jax.jit(mega, static_argnames=("K",), donate_argnums=(1,))
 
     def _build_mixed_megastep(self):
         """K MIXED-PHASE iterations inside one compiled ``lax.scan``:
@@ -1037,7 +980,7 @@ class ServingEngine:
         B, T, C = self.B, self.T, self.pc
         with_probs = self.capture_sample_probs
 
-        def mixed(weights, key_caches, value_caches, rope, toks, cached,
+        def mixed(weights, caches, rope, toks, cached,
                   pp, pp0, plen, prompt_buf, bt, active, remaining, dl,
                   eos, temps, top_ks, top_ps, seeds, sample_pos, K):
             enc = jnp.zeros((B,), jnp.int32)
@@ -1046,7 +989,7 @@ class ServingEngine:
                 return jax.lax.dynamic_slice(row, (start,), (C,))
 
             def body(carry, _):
-                (toks, kcs, vcs, cached, pp, active, remaining,
+                (toks, caches, cached, pp, active, remaining,
                  sample_pos, dl) = carry
                 with jax.named_scope("scan_carry"):
                     alive = active & (dl > 0)
@@ -1072,9 +1015,9 @@ class ServingEngine:
                                      T)
                     buf = jnp.zeros((T,), jnp.int32).at[flat.reshape(-1)].set(
                         row_toks.reshape(-1), mode="drop")
-                logits, kcs, vcs, _ = fwd(weights, kcs, vcs, rope, buf,
-                                          enc, cached, now_t, cu, bt, C,
-                                          None)
+                logits, caches, _, counts = fwd(
+                    weights, caches, rope, buf, enc, cached, now_t, cu, bt, C,
+                    None)
                 nxt, lps, probs = _sample_tokens(
                     logits, temps, top_ks, top_ps, seeds, sample_pos,
                     return_probs=with_probs)
@@ -1093,18 +1036,18 @@ class ServingEngine:
                     sample_pos = sample_pos + emits.astype(jnp.int32)
                     dl = dl - alive.astype(jnp.int32)
                     active = active & jnp.logical_not(fin)
-                return ((toks, kcs, vcs, cached, pp, active, remaining,
-                         sample_pos, dl), (nxt, emits, lps, probs))
+                return ((toks, caches, cached, pp, active, remaining,
+                         sample_pos, dl), (nxt, emits, lps, probs, counts))
 
-            carry0 = (toks, key_caches, value_caches, cached, pp, active,
+            carry0 = (toks, caches, cached, pp, active,
                       remaining, sample_pos, dl)
-            carry, (toks_o, emits_o, lps_o, probs_o) = jax.lax.scan(
+            carry, (toks_o, emits_o, lps_o, probs_o, counts_o) = jax.lax.scan(
                 body, carry0, None, length=K)
-            return (carry[1], carry[2], carry[4], toks_o, emits_o, lps_o,
-                    probs_o)
+            return (carry[1], carry[3], toks_o, emits_o, lps_o, probs_o,
+                    _sum_counts(counts_o))
 
         return jax.jit(mixed, static_argnames=("K",),
-                       donate_argnums=(1, 2))
+                       donate_argnums=(1,))
 
     def _build_spec_verify(self):
         """Score all ``spec_k + 1`` positions of every row's
@@ -1140,12 +1083,12 @@ class ServingEngine:
         Kp1 = sk + 1
         with_probs = self.capture_sample_probs
 
-        def spec_verify(weights, key_caches, value_caches, rope,
+        def spec_verify(weights, caches, rope,
                         token_ids, dec, now, cu, bt, dlen, draft, temps,
                         top_ks, top_ps, seeds, spos):
             enc = jnp.zeros((B,), jnp.int32)
-            hidden, kcs, vcs, _ = trunk(
-                weights, key_caches, value_caches, rope, token_ids, enc,
+            hidden, caches, _, counts = trunk(
+                weights, caches, rope, token_ids, enc,
                 dec, now, cu, bt, Kp1, None)
             # per-slot per-position logits rows: position j of slot b is
             # packed token cu[b] + j; rows whose draft is shorter than
@@ -1180,9 +1123,9 @@ class ServingEngine:
                 match = (nxt[:, :sk] == draft) & (jk < dlen[:, None])
                 acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
                               axis=1).astype(jnp.int32)
-            return kcs, vcs, nxt, lps, probs, acc
+            return caches, nxt, lps, probs, acc, counts
 
-        return jax.jit(spec_verify, donate_argnums=(1, 2))
+        return jax.jit(spec_verify, donate_argnums=(1,))
 
     # ------------------------------------------------------------- serving
     def add_request(self, prompt_ids, max_new_tokens: int = 32,
@@ -1250,21 +1193,19 @@ class ServingEngine:
         return matched
 
     def _copy_block(self, src: int, dst: int):
-        """Device-side copy of one pool block across every layer's K and V
-        cache (the copy-on-write fork: the writer gets a private copy, the
+        """Device-side copy of one pool block across every array of every
+        layer's cache (the copy-on-write fork: the writer gets a private copy, the
         shared original stays read-only for its other owners)."""
         if self._cow_fn is None:
             if "cow" not in self._programs:
-                def cow(kcs, vcs, s, d):
-                    kcs = [kc.at[d].set(kc[s]) for kc in kcs]
-                    vcs = [vc.at[d].set(vc[s]) for vc in vcs]
-                    return kcs, vcs
+                def cow(caches, s, d):
+                    return tuple([c.at[d].set(c[s]) for c in cs]
+                                 for cs in caches)
                 # s/d are data, not static: one compiled copy program total
-                self._programs["cow"] = jax.jit(cow, donate_argnums=(0, 1))
+                self._programs["cow"] = jax.jit(cow, donate_argnums=(0,))
             self._cow_fn = self._programs["cow"]
-        self.key_caches, self.value_caches = self._cow_fn(
-            self.key_caches, self.value_caches,
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+        self.caches = self._cow_fn(
+            self.caches, jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
 
     def _try_admit(self):
         while self._queue and self._free_slots:
@@ -1417,6 +1358,11 @@ class ServingEngine:
                 "mixed": self.megasteps_mixed,
                 "prefill_chunks": self.prefill_chunks,
             },
+            # expert layers of a sparse model (monotone; zero for a dense one)
+            "moe": {
+                "tokens": self.moe_tokens,
+                "local_picks": self.moe_local_picks,
+            },
             # speculative-decode counters (ISSUE 19; same monotone
             # delta-fold contract as the megastep block above)
             "spec": {
@@ -1510,6 +1456,15 @@ class ServingEngine:
         """``with self._phase("schedule"):`` — the one place a step's
         phases are measured (span and ``phase_seconds`` together)."""
         return _Phase(self, name, attrs)
+
+    def _add_counts(self, counts) -> Dict[str, int]:
+        """What the model's trunk counted in one launch, added to the
+        engine's counters of the same names; returned for the launch's
+        ``engine.harvest`` span."""
+        got = {name: int(np.asarray(v)) for name, v in counts.items()}
+        for name, n in got.items():
+            setattr(self, name, getattr(self, name) + n)
+        return got
 
     def _launch_phase(self, kind: str, k: int, **attrs) -> _Phase:
         """``engine.launch`` of one compiled program: ``k`` iterations of
@@ -1714,9 +1669,9 @@ class ServingEngine:
 
         with self._launch_phase("step", 1):
             had_cache = self._step_fn._cache_size() if hasattr(self._step_fn, "_cache_size") else None
-            nxt, lps, probs, self.key_caches, self.value_caches, new_scales = \
+            nxt, lps, probs, self.caches, new_scales, counts = \
                 self._step_fn(
-                    self._weights, self.key_caches, self.value_caches,
+                    self._weights, self.caches,
                     self._rope, jnp.asarray(tokens), jnp.asarray(enc),
                     jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu),
                     jnp.asarray(self.block_tables), jnp.asarray(temps),
@@ -1731,7 +1686,8 @@ class ServingEngine:
             nxt = np.asarray(nxt)
             lps = np.asarray(lps)
             probs = np.asarray(probs) if probs is not None else None
-        with self._phase("harvest"):
+            counted = self._add_counts(counts)
+        with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
             for req, n, finishes in sched:
                 if req.in_prefill:
@@ -1900,14 +1856,13 @@ class ServingEngine:
                 self._spec_fn = self._programs["spec"]
             had = (self._spec_fn._cache_size()
                    if hasattr(self._spec_fn, "_cache_size") else None)
-            kcs, vcs, nxt, lps, probs, acc = self._spec_fn(
-                self._weights, self.key_caches, self.value_caches, self._rope,
+            self.caches, nxt, lps, probs, acc, counts = self._spec_fn(
+                self._weights, self.caches, self._rope,
                 jnp.asarray(tokens), jnp.asarray(dec), jnp.asarray(now),
                 jnp.asarray(cu), jnp.asarray(self.block_tables),
                 jnp.asarray(dlen), jnp.asarray(draft_a), jnp.asarray(temps),
                 jnp.asarray(top_ks), jnp.asarray(top_ps), jnp.asarray(seeds),
                 jnp.asarray(spos))
-            self.key_caches, self.value_caches = kcs, vcs
             if had is not None:
                 self.compile_count += self._spec_fn._cache_size() - had
         with self._phase("wait"):
@@ -1915,8 +1870,9 @@ class ServingEngine:
             lps = np.asarray(lps)
             probs = np.asarray(probs) if probs is not None else None
             acc = np.asarray(acc)       # [B] accepted draft-prefix lengths
+            counted = self._add_counts(counts)
 
-        with self._phase("harvest"):
+        with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
             for req in reqs:
                 s = req.slot
@@ -2013,9 +1969,9 @@ class ServingEngine:
                 self._mega_fn = self._programs["mega"]
             had = (self._mega_fn._cache_size()
                    if hasattr(self._mega_fn, "_cache_size") else None)
-            kcs, vcs, new_scales, toks_o, valid_o, lps_o, probs_o = \
+            self.caches, new_scales, toks_o, valid_o, lps_o, probs_o, counts = \
                 self._mega_fn(
-                    self._weights, self.key_caches, self.value_caches,
+                    self._weights, self.caches,
                     self._rope, jnp.asarray(toks), jnp.asarray(dec),
                     jnp.asarray(now), jnp.asarray(cu), jnp.asarray(occ_idx),
                     jnp.asarray(self.block_tables), jnp.asarray(active),
@@ -2023,7 +1979,6 @@ class ServingEngine:
                     jnp.asarray(temps), jnp.asarray(top_ks),
                     jnp.asarray(top_ps), jnp.asarray(seeds),
                     jnp.asarray(spos), self.cache_scales, K=K)
-            self.key_caches, self.value_caches = kcs, vcs
             if self.cache_scales is not None:
                 self.cache_scales = new_scales
             compiled = False
@@ -2036,10 +1991,11 @@ class ServingEngine:
             valid_o = np.asarray(valid_o)
             lps_o = np.asarray(lps_o)
             probs_o = np.asarray(probs_o) if probs_o is not None else None
+            counted = self._add_counts(counts)
         self.megasteps += 1
         self._update_tau(launch.seconds + wait.seconds, K, compiled)
 
-        with self._phase("harvest"):
+        with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
             for req in reqs:
                 s = req.slot
@@ -2147,15 +2103,14 @@ class ServingEngine:
                 self._mixed_fn = self._programs["mixed"]
             had = (self._mixed_fn._cache_size()
                    if hasattr(self._mixed_fn, "_cache_size") else None)
-            kcs, vcs, pp_f, toks_o, emits_o, lps_o, probs_o = self._mixed_fn(
-                self._weights, self.key_caches, self.value_caches, self._rope,
+            self.caches, pp_f, toks_o, emits_o, lps_o, probs_o, counts = self._mixed_fn(
+                self._weights, self.caches, self._rope,
                 jnp.asarray(toks), jnp.asarray(cached), jnp.asarray(pp),
                 jnp.asarray(pp0), jnp.asarray(plen), jnp.asarray(prompt_buf),
                 jnp.asarray(self.block_tables), jnp.asarray(active),
                 jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
                 jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
                 jnp.asarray(seeds), jnp.asarray(spos), K=K)
-            self.key_caches, self.value_caches = kcs, vcs
             compiled = False
             if had is not None:
                 grew = self._mixed_fn._cache_size() - had
@@ -2167,11 +2122,12 @@ class ServingEngine:
             emits_o = np.asarray(emits_o)
             lps_o = np.asarray(lps_o)
             probs_o = np.asarray(probs_o) if probs_o is not None else None
+            counted = self._add_counts(counts)
         self.megasteps += 1
         self.megasteps_mixed += 1
         self._update_tau(launch.seconds + wait.seconds, K, compiled)
 
-        with self._phase("harvest"):
+        with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
             for req in sorted(reqs, key=lambda r: r.slot):
                 s = req.slot
@@ -2283,6 +2239,10 @@ class ServingEngine:
     # engines as bit-exact payloads keyed by chain hash)
 
     def _check_transferable(self, op: str):
+        if not self.cache_spec.transferable:
+            raise ValueError(
+                f"{op} cannot be used with {type(self).__name__} over this "
+                "model: " + self.cache_spec.why_not)
         if self.cache_quant == "int8":
             raise ValueError(
                 f"{op} cannot be used with cache_quant='int8': the int8 "

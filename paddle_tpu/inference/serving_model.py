@@ -1,0 +1,44 @@
+"""What ``ServingEngine`` asks of a model it serves.  The engine knows no
+architecture: it owns slots, the block pool, the token budget, the scans and
+sampling, and asks the model three questions:
+
+``serving_weights(dtype)``
+    the weight pytree the compiled programs take as an argument.  It has a
+    ``"head"`` leaf ``[hidden, vocab]``: the engine heads the rows it samples.
+``serving_cache_spec()``
+    a :class:`CacheSpec`: which arrays a layer keeps in the block pool and
+    the shape of one block of each, so the engine can allocate the pool,
+    copy a block (COW) and key its compiled programs.
+``serving_trunk(block_size=, cache_quant=)``
+    a pure function ``trunk(weights, caches, rope, token_ids, enc, dec, now,
+    cu, bt, mq, scales) -> (hidden, caches, new_scales, counts)``: packed
+    tokens through every layer against the paged cache, final norm applied.
+    ``caches`` is a tuple with one list (a layer each) for every array of the
+    spec; ``counts`` a dict of int32 scalars the engine adds to its counters
+    of the same names (``{}``: none).
+
+and ``serving_rope(max_seq_len)`` for the table the trunk reads positions
+from.  ``LlamaForCausalLM`` (models/llama.py) and ``PanguUltraMoEForCausalLM``
+(models/pangu_moe.py) answer them."""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Callable, Optional, Tuple
+
+__all__ = ["CacheSpec"]
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    # ((name, block_shape(block_size) -> tuple), ...): one pool array
+    # [num_blocks, *block_shape] a layer for each
+    arrays: Tuple[Tuple[str, Callable[[int], tuple]], ...]
+    layers: int
+    # everything of the model that shapes the trunk's trace
+    key: tuple
+    # kv heads and head size of a per-head K/V cache (the block wire header)
+    kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    quantizable: bool = True      # cache_quant="int8"
+    transferable: bool = True     # export_blocks* / import_blocks* / blockwire
+    why_not: str = ""             # said by the typed refusals

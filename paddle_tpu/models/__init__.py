@@ -19,3 +19,11 @@ from .gpt import (  # noqa: F401,E402
     gpt_pipeline_descs,
     gpt_tiny,
 )
+from .pangu_moe import (  # noqa: F401,E402
+    PanguMLAttention,
+    PanguSparseMoE,
+    PanguUltraMoEConfig,
+    PanguUltraMoEForCausalLM,
+    PanguUltraMoEModel,
+    pangu_ultra_moe_tiny,
+)

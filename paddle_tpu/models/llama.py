@@ -474,6 +474,126 @@ class LlamaForCausalLM(nn.Layer):
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())
 
+    # ---------------------------------------------- what a serving engine asks
+    # (inference/serving_model.py: weights, cache specification, trunk, rope)
+    def serving_weights(self, dtype):
+        def v(t):
+            return t._value.astype(dtype)
+
+        lm = self.llama
+        w = {
+            "embed": v(self.llama.embed_tokens.weight),
+            "norm": v(lm.norm.weight),
+        }
+        if self.lm_head is None:
+            w["head"] = w["embed"].T
+        else:
+            w["head"] = v(self.lm_head.weight)
+        w["layers"] = []
+        for layer in lm.layers:
+            a, m = layer.self_attn, layer.mlp
+            w["layers"].append({
+                "ln1": v(layer.input_layernorm.weight),
+                "ln2": v(layer.post_attention_layernorm.weight),
+                "wq": v(a.q_proj.weight), "wk": v(a.k_proj.weight),
+                "wv": v(a.v_proj.weight), "wo": v(a.o_proj.weight),
+                "wg": v(m.gate_proj.weight), "wu": v(m.up_proj.weight),
+                "wd": v(m.down_proj.weight),
+            })
+        return w
+
+    def serving_cache_spec(self):
+        """Keys and values a kv-head: two ``[nb, KV, bs, D]`` arrays a layer."""
+        from ..inference.serving_model import CacheSpec
+
+        cfg = self.config
+        KV, D = cfg.num_key_value_heads, cfg.head_dim
+
+        def block(bs):
+            return (KV, bs, D)
+
+        return CacheSpec(
+            arrays=(("k", block), ("v", block)), layers=cfg.num_hidden_layers,
+            key=("llama", cfg.num_attention_heads, KV, D, cfg.hidden_size,
+                 float(cfg.rms_norm_eps)),
+            kv_heads=KV, head_dim=D)
+
+    def serving_rope(self, max_seq_len):
+        cfg = self.config
+        d = cfg.head_dim
+        inv = 1.0 / (cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+        t = np.arange(max_seq_len, dtype=np.float64)
+        fr = np.outer(t, inv)
+        # blha rope layout [2, Br=1, Smax, 1, D/2]; llama uses the
+        # half-split (neox) rotation (apply_rotary_pos_emb above)
+        return jnp.asarray(
+            np.stack([np.cos(fr), np.sin(fr)])[:, None, :, None, :],
+            jnp.float32)
+
+    def serving_trunk(self, *, block_size, cache_quant="none"):
+        from ..ops.paged_attention import blha_attention
+
+        cfg = self.config
+        H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        eps = cfg.rms_norm_eps
+        bs = block_size
+
+        def rms(x, w):
+            xf = x.astype(jnp.float32)
+            nrm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+            return (nrm * w.astype(jnp.float32)).astype(x.dtype)
+
+        quant = cache_quant
+
+        def trunk(weights, caches, rope, token_ids,
+                  enc, dec, now, cu, bt, mq, scales=None):
+            # mq (static): padded per-sequence query length for the attention
+            # compute — T for steps carrying prefill chunks, 1 for pure
+            # decode steps (avoids T× padded-query attention waste).  The
+            # trunk runs embed -> layers -> final rms and returns the FULL
+            # hidden sequence: the engine heads each slot's last packed
+            # token, or every draft position (spec verify).
+            key_caches, value_caches = caches
+            with jax.named_scope("embed"):
+                hidden = weights["embed"][token_ids]  # [T, E]
+            new_scales = []
+            for li, lw in enumerate(weights["layers"]):
+                with jax.named_scope("norm"):
+                    h = rms(hidden, lw["ln1"])
+                with jax.named_scope("attn_proj"):
+                    q = h @ lw["wq"]
+                    k = h @ lw["wk"]
+                    v = h @ lw["wv"]
+                    qkv = jnp.concatenate([q, k, v], axis=-1)
+                sc = scales[li] if scales is not None else {}
+                out, kc, vc, kq, vq, kd, vd = blha_attention(
+                    qkv, key_caches[li], value_caches[li], enc, dec, now,
+                    cu, bt, num_heads=H, kv_num_heads=KV, head_dim=D,
+                    block_size=bs, max_q_len=mq, use_neox_style=True,
+                    compute_dtype=hidden.dtype, rope_emb=rope,
+                    cache_quant=quant if quant != "int8" else "dynamic",
+                    cache_k_quant_scales=sc.get("kq"),
+                    cache_v_quant_scales=sc.get("vq"),
+                    cache_k_dequant_scales=sc.get("kd"),
+                    cache_v_dequant_scales=sc.get("vd"))
+                key_caches[li] = kc
+                value_caches[li] = vc
+                if scales is not None:
+                    new_scales.append({"kq": kq, "vq": vq, "kd": kd, "vd": vd})
+                with jax.named_scope("attn_out"):
+                    hidden = hidden + out @ lw["wo"]
+                with jax.named_scope("norm"):
+                    h2 = rms(hidden, lw["ln2"])
+                with jax.named_scope("mlp"):
+                    g = h2 @ lw["wg"]
+                    u = h2 @ lw["wu"]
+                    hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
+            with jax.named_scope("norm"):
+                hidden = rms(hidden, weights["norm"])
+            return hidden, (key_caches, value_caches), new_scales, {}
+
+        return trunk
+
 
 # ------------------------------------------------- pipeline-parallel mapping
 class _PipeEmbed(nn.Layer):

@@ -15,10 +15,14 @@ import itertools
 from collections import OrderedDict
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ...framework import dtype as dtype_mod
 from ...tensor.tensor import Tensor
+
+# depth of paddle.LazyGuard: parameters created inside it are abstract
+_lazy_parameters = 0
 
 __all__ = ["Layer"]
 
@@ -127,7 +131,10 @@ class Layer:
             init = default_initializer
         if init is None:
             init = Constant(0.0) if is_bias else XavierUniform()
-        value = init(tuple(int(s) for s in shape), dt.np_dtype)
+        if _lazy_parameters:       # inside paddle.LazyGuard: a shape and a type
+            value = jax.ShapeDtypeStruct(tuple(int(s) for s in shape), dt.np_dtype)
+        else:
+            value = init(tuple(int(s) for s in shape), dt.np_dtype)
         t = Tensor(value, stop_gradient=not trainable, name=name)
         t.is_parameter = True
         t.trainable = trainable

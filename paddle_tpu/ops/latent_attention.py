@@ -1,0 +1,175 @@
+"""Attention over a paged LATENT cache (multi-head latent attention, MLA:
+DeepSeek-V2, arXiv 2405.04434 section 2.1): one cache entry a token and
+layer, ``[kv_lora_rank + qk_rope_head_dim]`` values shared by every head, in
+a pool ``[num_blocks, block_size, C + R]`` read through per-sequence block
+tables like ``ops/paged_attention.py``'s.
+
+The ABSORBED form: a head's query is carried into the latent space before it
+meets the cache (``q_lat = q_nope W_uk^T``, done by the caller), so a score is
+one dot of ``[q_lat | q_rope]`` with the entry ``[c | rope(k_r)]``, and the
+weighted sum of the ``c`` parts goes back through ``W_uv`` afterwards (the
+caller again).  Per head and cache byte that is 2 FLOPs for every one of the
+H heads, so with 128 heads the decode side sits near the ridge of a v5e
+instead of far below it.
+
+Nothing here grows with ``B x H x max_q_len x L``.  Two passes, both blocked
+over the context with an online softmax (float32 running max, sum and
+accumulator) and a trip count that is DATA, the longest live context:
+
+* rows that feed ONE token (decode rows, and a prompt's one-token tail): all
+  ``B`` at once, ``[B, H, C + R]`` queries against a ``[B, ctx_block, C + R]``
+  gather a pass;
+* rows that feed a CHUNK (``now > 1``: prompt chunks, speculative drafts):
+  one row at a time in a loop over the rows that carry one, its
+  ``[max_q_len x H, C + R]`` queries against ``[ctx_block, C + R]`` of its own
+  context, causal inside the chunk.  Rows without a chunk cost nothing.
+
+Scopes (children of ``latent_attention``): ``kv_write``, ``kv_gather``,
+``scores`` (QK^T, mask, the online softmax's bookkeeping), ``values`` (PV)."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["latent_attention", "rope_half"]
+
+_NEG = -1e30
+
+
+def rope_half(x, cos, sin):
+    """Rotate-half rope over the last axis. x: [T, ..., R]; cos/sin [T, R/2]
+    (already taken at each token's position)."""
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (cos.shape[-1],)
+    c, s = cos.reshape(shape), sin.reshape(shape)
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+
+
+def _online(carry, s, visible, v, pv):
+    """One block of the online softmax. carry: (m, l, acc) with m, l [...],
+    acc [..., C]; s [..., Lc] float32 scores; visible [..., Lc]; v the block's
+    values, which ``pv(p, v)`` contracts with the probabilities."""
+    m, l, acc = carry
+    with jax.named_scope("scores"):
+        s = jnp.where(visible, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(visible, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1)
+    with jax.named_scope("values"):
+        acc = acc * corr[..., None] + pv(p.astype(v.dtype), v)
+    return m_new, l, acc
+
+
+@jax.named_scope("latent_attention")
+def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
+                     cu_seqlens_q, block_tables, *, rank: int, max_q_len: int,
+                     scale: float, ctx_block: int = 512):
+    """One serving attention step over the latent cache.
+
+    q        [T, H, C + R]: ``[q_lat | rope(q_rope)]`` of the packed tokens
+    entries  [T, C + R]:    ``[c | rope(k_r)]`` of the same tokens
+    cache    [NB, bs, C + R]
+    seq_lens_decoder / seq_lens_this_time / cu_seqlens_q / block_tables: as
+    ``blha_attention`` takes them (tokens already cached, tokens this step,
+    packed offsets, [B, P] block ids with -1 unassigned).
+    ``rank`` = C; ``max_q_len`` (static) bounds a row's tokens this step.
+
+    Returns (o_lat [T, H, C] in q's dtype, cache')."""
+    T, H, W = q.shape
+    C = int(rank)
+    nb, bs, _ = cache.shape
+    B, P = block_tables.shape
+    dec, now, cu = seq_lens_decoder, seq_lens_this_time, cu_seqlens_q
+
+    # ---- token coordinates (as blha_attention) ---------------------------
+    tok = jnp.arange(T, dtype=jnp.int32)
+    b_idx = jnp.clip(
+        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1, 0, B - 1)
+    local = tok - cu[b_idx]
+    abs_pos = dec[b_idx] + local
+    valid = (tok < cu[-1]) & (local < now[b_idx])
+
+    with jax.named_scope("kv_write"):
+        blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, P - 1)]
+        blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)   # OOB -> drop
+        cache = cache.at[blk, abs_pos % bs].set(entries.astype(cache.dtype),
+                                                mode="drop")
+
+    # context is read ``per`` table columns (= ctx_block positions) a pass
+    per = max(1, min(P, int(ctx_block) // bs))
+    Lc = per * bs
+    pad = (-P) % per
+    bt = jnp.pad(block_tables, ((0, 0), (0, pad)), constant_values=-1)
+    bt = jnp.where((bt < 0) | (bt >= nb), nb, bt)                   # -> zeros
+    kpos = jnp.arange(Lc, dtype=jnp.int32)
+    cdt = cache.dtype
+
+    def gather(ids):
+        with jax.named_scope("kv_gather"):
+            g = cache.at[ids].get(mode="fill", fill_value=0)
+            return g.reshape(ids.shape[:-1] + (Lc, W)).astype(cdt)
+
+    # ---- rows that feed one token: all B at once --------------------------
+    one = now == 1
+    n_ctx1 = jnp.where(one, dec + 1, 0)
+    q1 = q[jnp.clip(cu[:-1], 0, T - 1)].astype(cdt)                 # [B, H, W]
+
+    def one_block(j, carry):
+        kv = gather(jax.lax.dynamic_slice_in_dim(bt, j * per, per, axis=1))
+        with jax.named_scope("scores"):
+            s = jnp.einsum("bhw,blw->bhl", q1, kv,
+                           preferred_element_type=jnp.float32) * scale
+        vis = ((j * Lc + kpos)[None, :] < n_ctx1[:, None])[:, None, :]
+        return _online(carry, s, vis, kv[..., :C], pv_one)
+
+    def pv_one(p, v):
+        return jnp.einsum("bhl,blc->bhc", p, v, preferred_element_type=jnp.float32)
+
+    _, l1, acc1 = jax.lax.fori_loop(
+        0, (jnp.max(n_ctx1) + Lc - 1) // Lc, one_block,
+        (jnp.full((B, H), _NEG, jnp.float32), jnp.zeros((B, H), jnp.float32),
+         jnp.zeros((B, H, C), jnp.float32)))
+    o1 = (acc1 / jnp.maximum(l1, 1e-30)[..., None]).astype(q.dtype)  # [B, H, C]
+
+    S = int(max_q_len)
+    if S == 1:
+        at = jnp.where(one, jnp.clip(cu[:-1], 0, T - 1), T)
+        return jnp.zeros((T, H, C), q.dtype).at[at].set(o1, mode="drop"), cache
+
+    # ---- rows that feed a chunk: one at a time -----------------------------
+    rows = jnp.nonzero(now > 1, size=B, fill_value=0)[0].astype(jnp.int32)
+    q_pad = jnp.pad(q, ((0, S), (0, 0), (0, 0))).astype(cdt)
+    qi = jnp.arange(S, dtype=jnp.int32)
+
+    def pv_chunk(p, v):
+        return jnp.einsum("qhl,lc->qhc", p, v, preferred_element_type=jnp.float32)
+
+    def chunk_row(i, out):
+        r = rows[i]
+        start, base, nq = cu[r], dec[r], now[r]
+        qt = jax.lax.dynamic_slice_in_dim(q_pad, start, S, axis=0)   # [S, H, W]
+        ids = bt[r]
+
+        def block(j, carry):
+            kv = gather(jax.lax.dynamic_slice_in_dim(ids, j * per, per))
+            with jax.named_scope("scores"):
+                s = jnp.einsum("qhw,lw->qhl", qt, kv,
+                               preferred_element_type=jnp.float32) * scale
+            vis = ((j * Lc + kpos)[None, :] <= (base + qi)[:, None])[:, None, :]
+            return _online(carry, s, vis, kv[:, :C], pv_chunk)
+
+        _, l, acc = jax.lax.fori_loop(
+            0, (base + nq + Lc - 1) // Lc, block,
+            (jnp.full((S, H), _NEG, jnp.float32), jnp.zeros((S, H), jnp.float32),
+             jnp.zeros((S, H, C), jnp.float32)))
+        o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(out.dtype)
+        old = jax.lax.dynamic_slice_in_dim(out, start, S, axis=0)
+        o = jnp.where((qi < nq)[:, None, None], o, old)
+        return jax.lax.dynamic_update_slice_in_dim(out, o, start, axis=0)
+
+    at = jnp.where(one, jnp.clip(cu[:-1], 0, T - 1), T + S)
+    out = jnp.zeros((T + S, H, C), q.dtype).at[at].set(o1, mode="drop")
+    out = jax.lax.fori_loop(0, jnp.sum(now > 1).astype(jnp.int32), chunk_row, out)
+    return out[:T], cache

@@ -1,0 +1,534 @@
+"""openPangu-Ultra-MoE at a tiny size on the CPU, seeded random weights: the
+model's own ``forward``, the serving engine's trunk over the paged latent
+cache (prefill, decode, a request resumed after preemption, the prefix cache
+and its COW fork, n-gram speculation), the expert layer's share of a
+deployment, the typed refusals, the names in the compiled programs, the
+counters; all held to the plain float32 reference
+(benchmark/references/mla_moe.py), which shares nothing with the program."""
+import hashlib
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as P
+from paddle_tpu.distributed.topology import set_hybrid_communicate_group
+from paddle_tpu.inference import ServingEngine, ServingFrontend
+from paddle_tpu.models import (LlamaForCausalLM, PanguUltraMoEConfig,
+                               PanguUltraMoEForCausalLM, llama_tiny)
+from paddle_tpu.models import pangu_moe
+from paddle_tpu.ops.latent_attention import latent_attention, rope_half
+
+from benchmark.harness import loader
+
+ROOT = loader.ROOT
+FAMILY = loader.load_module("families", "mla_moe")
+REFERENCE = loader.load_module("references", "mla_moe")
+
+# a share of a deployment: 16 routed experts a layer, this chip holds [4, 8)
+TINY = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
+    num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=4,
+    num_key_value_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=4, router_outputs=16,
+    experts_held=[4, 8], n_shared_experts=1, num_experts_per_tok=4, norm_topk_prob=True,
+    routed_scaling_factor=2.5, sandwich_norm=True, num_nextn_predict_layers=0,
+    max_position_embeddings=256, rms_norm_eps=1e-5, rope_theta=10000.0,
+    tie_word_embeddings=False, attention_bias=False, hidden_act="silu",
+    torch_dtype="float32")
+ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
+
+# A float32 engine and the float32 reference differ by the order of their
+# sums alone: the absorbed scores against the expanded ones, a blocked
+# softmax, experts added tile by tile.  1e-4 nats is twenty times what that
+# gives here and a fiftieth of what bf16 arithmetic gives (0.005-0.05: its 8
+# mantissa bits against 24), so bf16 in a float32 configuration fails it.
+LOGPROB_TOL = 1e-4
+
+
+@pytest.fixture(autouse=True)
+def _no_fleet_group():
+    set_hybrid_communicate_group(None)
+
+
+def _build(cfg=TINY, seed=7):
+    weights = FAMILY.make_weights(cfg, seed)
+    model = FAMILY.build_model(cfg)
+    FAMILY.assign(model, weights)
+    model.eval()
+    return model, weights
+
+
+@pytest.fixture(scope="module")
+def built():
+    set_hybrid_communicate_group(None)
+    return _build()
+
+
+def _prompts(lens, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, TINY["vocab_size"], n).tolist() for n in lens]
+
+
+def _ref_logprobs(weights, cfg, prompt, new):
+    """log-softmax of the reference's logits at each new token."""
+    full = np.asarray(prompt + new, np.int32)
+    rows = np.arange(len(prompt) - 1, len(full) - 1)
+    lg = np.asarray(REFERENCE.logits_at(weights, cfg, full, rows), np.float64)
+    lp = lg - np.log(np.exp(lg - lg.max(-1, keepdims=True)).sum(-1, keepdims=True)) \
+        - lg.max(-1, keepdims=True)
+    return lp, lp[np.arange(len(new)), new]
+
+
+def _serve(model, prompts, new=12, **engine):
+    eng = ServingEngine(model, **{**ENGINE, **engine})
+    rids = [eng.add_request(p, max_new_tokens=new, sampling={"logprobs": True})
+            for p in prompts]
+    out = eng.run()
+    lps = eng.pop_token_logprobs()
+    return eng, [(out[r], np.asarray(lps[r])) for r in rids]
+
+
+# ------------------------------------------------------------- the model
+def test_config_keeps_the_published_names_and_refuses_another_model():
+    cfg = PanguUltraMoEConfig()
+    assert (cfg.hidden_size, cfg.num_hidden_layers, cfg.n_routed_experts) == (7680, 61, 256)
+    assert cfg.experts_held == (0, 256) and cfg.latent_width == 576
+    assert cfg.latent_cache_width == 640      # whole 128-lane tiles
+    assert pangu_moe.pangu_ultra_moe_tiny().latent_cache_width == 24
+    with pytest.raises(ValueError, match="no range"):
+        PanguUltraMoEConfig(experts_held=(250, 260))
+    with pytest.raises(ValueError, match="sandwich"):
+        PanguUltraMoEConfig(sandwich_norm=False)
+
+
+def test_forward_agrees_with_the_reference(built):
+    model, weights = built
+    ids = np.asarray(_prompts([40])[0], np.int32)
+    want = np.asarray(REFERENCE.logits_at(weights, TINY, ids, np.arange(40)))
+    got = np.asarray(model(P.to_tensor(ids[None]))._value)[0]
+    assert np.abs(got - want).max() < 2e-5
+    low = np.asarray(REFERENCE.logits_at(weights, TINY, ids, np.arange(40), quant="int8"))
+    assert 1e-3 < np.abs(low - want).max()          # the control moves them
+    with pytest.raises(ValueError):
+        REFERENCE.logits_at(weights, TINY, ids, np.arange(40), quant="int3")
+
+
+def test_the_next_token_module_agrees_with_the_reference():
+    cfg = dict(TINY, num_nextn_predict_layers=1)
+    model, weights = _build(cfg, seed=3)
+    assert "mtp" in weights and model.mtp is not None
+    ids = np.asarray(_prompts([24], seed=5)[0], np.int32)
+    logits, mtp = model(P.to_tensor(ids[None]), mtp=True)
+    want = np.asarray(REFERENCE.mtp_logits_at(weights, cfg, ids, np.arange(23)))
+    assert np.asarray(mtp._value).shape == (1, 23, cfg["vocab_size"])
+    assert np.abs(np.asarray(mtp._value)[0] - want).max() < 2e-5
+    main = np.asarray(REFERENCE.logits_at(weights, cfg, ids, np.arange(24)))
+    assert np.abs(np.asarray(logits._value)[0] - main).max() < 2e-5
+    with pytest.raises(ValueError, match="num_nextn_predict_layers"):
+        _build()[0](P.to_tensor(ids[None]), mtp=True)
+
+
+def test_lazy_guard_makes_abstract_parameters():
+    with P.LazyGuard():
+        model = PanguUltraMoEForCausalLM(FAMILY.model_config(TINY))
+    p = model.lm_head.weight
+    assert isinstance(p._value, jax.ShapeDtypeStruct) and tuple(p.shape) == (64, 256)
+    eager = PanguUltraMoEForCausalLM(FAMILY.model_config(TINY))
+    assert isinstance(eager.lm_head.weight._value, jax.Array)
+    with pytest.raises(ValueError, match="weight"):
+        FAMILY.assign(model, FAMILY.make_weights(dict(TINY, hidden_size=32,
+                                                      num_attention_heads=2), 1))
+
+
+# -------------------------------------------------- engine against reference
+def test_prefill_then_decode_through_the_latent_cache(built):
+    """Prompts shorter and longer than a launch's budget, so the single-step
+    prefill, the mixed scan and the decode scan all serve them."""
+    model, weights = built
+    prompts = _prompts([5, 23, 40, 9, 17, 61])
+    eng, served = _serve(model, prompts)
+    assert eng.megasteps > eng.megasteps_mixed >= 1
+    for prompt, (new, lps) in zip(prompts, served):
+        table, want = _ref_logprobs(weights, TINY, prompt, new)
+        assert np.abs(lps - want).max() < LOGPROB_TOL
+        assert (table.argmax(-1) == np.asarray(new)).all()
+
+
+def test_bf16_arithmetic_fails_the_float32_tolerance(built):
+    _, weights = built
+    low = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), weights)
+    cfg = dict(TINY, torch_dtype="bfloat16")
+    model = FAMILY.build_model(cfg)
+    FAMILY.assign(model, low)
+    prompts = _prompts([23, 40])
+    _, served = _serve(model.eval(), prompts)
+    gaps = [np.abs(lps - _ref_logprobs(weights, TINY, p, new)[1]).max()
+            for p, (new, lps) in zip(prompts, served)]
+    assert max(gaps) > 10 * LOGPROB_TOL
+
+
+def test_a_request_resumed_after_preemption(built):
+    model, weights = built
+    (prompt,) = _prompts([37], seed=2)
+    whole = _serve(model, [prompt], new=20)[1][0][0]
+    eng = ServingEngine(model, **ENGINE)
+    rid = eng.add_request(prompt, max_new_tokens=20)
+    while len(eng._active[rid].generated) < 6 if rid in eng._active else True:
+        eng.step()
+    req = eng.evict(rid)
+    done = list(req.generated)
+    assert 6 <= len(done) < 20 and eng.state_summary()["free_slots"] == eng.B
+    rid2 = eng.add_request(prompt + done, max_new_tokens=20 - len(done),
+                           sampling={"logprobs": True}, sample_offset=len(done))
+    rest = eng.run()[rid2]
+    assert done + rest == whole
+    assert eng.prefix_hit_blocks > 0          # its own blocks, published at eviction
+    lps = np.asarray(eng.pop_token_logprobs()[rid2])
+    _, want = _ref_logprobs(weights, TINY, prompt + done, rest)
+    assert np.abs(lps - want).max() < LOGPROB_TOL
+
+
+def test_prefix_cache_and_cow_fork_leave_the_tokens_as_they_were(built):
+    model, _ = built
+    (base,) = _prompts([32], seed=4)                 # four whole blocks
+    prompts = [base, base + [9, 8, 7], base]          # a hit, and a full match (COW)
+    cold = [_serve(model, [p], prefix_cache=False)[1][0][0] for p in prompts]
+    eng = ServingEngine(model, **ENGINE)
+    warm = []
+    for p in prompts:
+        rid = eng.add_request(p, max_new_tokens=12)
+        warm.append(eng.run()[rid])
+    assert warm == cold
+    assert eng.prefix_hit_blocks >= 4 + 4 and eng._cow_fn is not None
+
+
+def test_ngram_speculation_commits_the_same_tokens(built):
+    model, _ = built
+    prompt = [1, 2, 3, 1, 2, 3, 1, 2]
+    plain = _serve(model, [prompt], new=40)[1][0][0]
+    eng, served = _serve(model, [prompt], new=40, spec_k=2)
+    assert served[0][0] == plain and eng.spec_verify_forwards > 0
+
+
+def test_served_behind_the_frontend(built):
+    model, _ = built
+    prompts = _prompts([12, 30, 7])
+    plain = [toks for toks, _ in _serve(model, prompts, new=10)[1]]
+    fe = ServingFrontend([ServingEngine(model, **ENGINE)])
+    rids = [fe.submit(p, max_new_tokens=10) for p in prompts]
+    while fe.pending:
+        fe.step()
+    assert [fe.result(r).tokens for r in rids] == plain
+
+
+# ------------------------------------------------------------- attention
+def test_absorbed_attention_equals_the_expanded_form():
+    """``latent_attention`` (queries carried into the latent space, scores
+    against the paged entries, blocked online softmax) against keys and
+    values expanded a head over each row's whole context: one decode row, a
+    row feeding a chunk behind a cached prefix, a one-token chunk, and an
+    empty slot."""
+    rng = np.random.default_rng(0)
+    H, N, R, V, C, bs, P_, B = 4, 8, 8, 8, 16, 4, 6, 4
+    dec = np.asarray([13, 5, 0, 0], np.int32)
+    now = np.asarray([1, 7, 1, 0], np.int32)
+    cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+    T = 12                                              # 9 packed, 3 of padding
+    wkv_b = rng.normal(size=(C, H, N + V)).astype(np.float32) * C ** -0.5
+    ctx = [rng.normal(size=(int(d + n), C + R)).astype(np.float32) for d, n in zip(dec, now)]
+    bt = np.full((B, P_), -1, np.int32)
+    cache = np.zeros((B * P_ + 1, bs, C + R), np.float32)
+    order = rng.permutation(B * P_)
+    for b in range(B):
+        bt[b] = order[b * P_:(b + 1) * P_]
+        for pos in range(int(dec[b])):                 # what earlier steps cached
+            cache[bt[b, pos // bs], pos % bs] = ctx[b][pos]
+    q_n = rng.normal(size=(T, H, N)).astype(np.float32)
+    q_r = rng.normal(size=(T, H, R)).astype(np.float32)
+    entries = np.zeros((T, C + R), np.float32)
+    for b in range(B):
+        entries[cu[b]:cu[b + 1]] = ctx[b][dec[b]:]
+    q_lat = np.einsum("thn,chn->thc", q_n, wkv_b[..., :N])
+    scale = (N + R) ** -0.5
+    for mq in (8, 12):
+        o_lat, new_cache = latent_attention(
+            jnp.asarray(np.concatenate([q_lat, q_r], -1)), jnp.asarray(entries),
+            jnp.asarray(cache), jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu),
+            jnp.asarray(bt), rank=C, max_q_len=mq, scale=scale, ctx_block=8)
+        got = np.einsum("thc,chv->thv", np.asarray(o_lat), wkv_b[..., N:])
+        for b in range(B):
+            for j in range(int(now[b])):
+                t, n = cu[b] + j, int(dec[b]) + j + 1
+                k = np.einsum("lc,chn->lhn", ctx[b][:n, :C], wkv_b[..., :N])
+                v = np.einsum("lc,chv->lhv", ctx[b][:n, :C], wkv_b[..., N:])
+                s = (np.einsum("hn,lhn->hl", q_n[t], k)
+                     + np.einsum("hr,lr->hl", q_r[t], ctx[b][:n, C:])) * scale
+                p = np.exp(s - s.max(-1, keepdims=True))
+                want = np.einsum("hl,lhv->hv", p / p.sum(-1, keepdims=True), v)
+                assert np.abs(got[t] - want).max() < 1e-5, (mq, b, j)
+            pos = int(dec[b]) + int(now[b]) - 1
+            if now[b]:
+                assert (np.asarray(new_cache)[bt[b, pos // bs], pos % bs] == ctx[b][pos]).all()
+        assert (np.asarray(o_lat)[cu[-1]:] == 0).all()      # padding stays empty
+    one_only = latent_attention(
+        jnp.asarray(np.concatenate([q_lat, q_r], -1))[:4], jnp.asarray(entries)[:4],
+        jnp.asarray(cache), jnp.asarray(dec), jnp.asarray(np.asarray([1, 0, 1, 0], np.int32)),
+        jnp.asarray(np.asarray([0, 1, 1, 2, 2], np.int32)), jnp.asarray(bt),
+        rank=C, max_q_len=1, scale=scale, ctx_block=8)[0]
+    assert np.abs(np.asarray(one_only)[0] - np.asarray(o_lat)[0]).max() < 1e-6
+
+
+def test_rope_half_rotates_the_two_halves():
+    x = np.arange(8, dtype=np.float32).reshape(1, 1, 8)
+    ang = np.asarray([[0.1, 0.2, 0.3, 0.4]], np.float32)
+    out = np.asarray(rope_half(jnp.asarray(x), jnp.cos(ang), jnp.sin(ang)))[0, 0]
+    x1, x2 = x[0, 0, :4], x[0, 0, 4:]
+    want = np.concatenate([x1 * np.cos(ang[0]) - x2 * np.sin(ang[0]),
+                           x2 * np.cos(ang[0]) + x1 * np.sin(ang[0])])
+    assert np.abs(out - want).max() < 1e-6
+
+
+# ------------------------------------------------------- the expert layer
+def test_the_share_adds_up():
+    """The routed parts that the four shares of a 16-expert layer give, with
+    the shared expert counted once, equal the uncut reference's expert
+    layer: in the program (``held_experts``) and in the reference alike."""
+    uncut = dict(TINY, n_routed_experts=16, experts_held=[0, 16])
+    weights = FAMILY.make_weights(uncut, 13)
+    p = weights["layers"][1]
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(50, 64)), jnp.float32)
+    idx, w = REFERENCE.route(x, p["router"], 4, 2.5)
+    shared = REFERENCE.swiglu(x, p["sg"], p["su"], p["sd"])
+    whole = shared + sum(
+        REFERENCE.weight_of(idx, w, e)[:, None] * REFERENCE.swiglu(
+            x, p["eg"][e], p["eu"][e], p["ed"][e]) for e in range(16))
+    parts, picks = [], 0
+    for lo in (0, 4, 8, 12):
+        pidx, pw = pangu_moe.route(x, p["router"], 4, 2.5)
+        y, n = pangu_moe.held_experts(x, pidx, pw, p["eg"][lo:lo + 4], p["eu"][lo:lo + 4],
+                                      p["ed"][lo:lo + 4], lo, tile=8)
+        parts.append(np.asarray(y))
+        picks += int(n)
+    assert picks == 50 * 4                      # every pick falls on exactly one share
+    assert np.abs(np.asarray(shared) + sum(parts) - np.asarray(whole)).max() < 2e-5
+    assert np.abs(parts[0]).max() > 0.01        # and a share is not nothing
+
+
+def test_no_pick_is_dropped_when_every_token_picks_one_expert():
+    """No capacity: 40 tokens that all pick the same held expert all get it."""
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(40, 16)), jnp.float32)
+    eg, eu = (jnp.asarray(rng.normal(size=(2, 16, 8)), jnp.float32) for _ in range(2))
+    ed = jnp.asarray(rng.normal(size=(2, 8, 16)), jnp.float32)
+    idx = jnp.full((40, 1), 5, jnp.int32)
+    w = jnp.full((40, 1), 0.5, jnp.float32)
+    y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 4, tile=16)
+    want = 0.5 * np.asarray(pangu_moe._swiglu(x, eg[1], eu[1], ed[1]))
+    assert int(picks) == 40 and np.abs(np.asarray(y) - want).max() < 1e-5
+    none, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 8, tile=16)
+    assert int(picks) == 0 and not np.asarray(none).any()
+
+
+# ------------------------------------------------------------ the refusals
+def test_int8_cache_and_block_transfer_refuse_a_latent_cache(built):
+    model, _ = built
+    with pytest.raises(ValueError, match="latent"):
+        ServingEngine(model, cache_quant="int8", **ENGINE)
+    eng = ServingEngine(model, **ENGINE)
+    for call in (lambda: eng.export_blocks(["h"]), lambda: eng.export_blocks_packed(["h"]),
+                 lambda: eng.import_blocks({}), lambda: eng.import_blocks_packed({}, b"")):
+        with pytest.raises(ValueError, match="latent"):
+            call()
+
+
+def test_load_weights_refuses_another_geometry(built):
+    model, weights = built
+    eng = ServingEngine(model, **ENGINE)
+    other, _ = _build(dict(TINY, experts_held=[0, 4]))
+    with pytest.raises(ValueError, match="geometry"):
+        eng.load_weights(other)
+    again, _ = _build(seed=8)
+    assert eng.load_weights(again, version="v1") == "v1"
+    set_hybrid_communicate_group(None)
+    with pytest.raises(ValueError, match="geometry"):
+        eng.load_weights(LlamaForCausalLM(llama_tiny()))
+
+
+# ------------------------------------------------- names, spans and counters
+# (regular expressions: the three scopes inside the loops over the context
+# lie under ``latent_attention/while/body/``, once for each loop around them)
+IN_LOOP = "latent_attention/(?:while/body/)+"
+SCOPES = ("embed", "norm", "latent_proj", "latent_attention", "latent_attention/kv_write",
+          IN_LOOP + "kv_gather", IN_LOOP + "scores", IN_LOOP + "values", "attn_out",
+          "post_norm", "router", "experts", "experts/while/body", "shared_expert", "mlp",
+          "head", "sample")
+
+
+def _lowered(eng, debug_info):
+    B, T, C, K = eng.B, eng.T, eng.pc, eng.megastep_k
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)          # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)        # noqa: E731
+    flag = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
+    bt = i32(B, eng.P)
+    head = (eng._weights, eng.caches, eng._rope)
+    low = {
+        "step": eng._step_fn.lower(*head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt,
+                                   *samp, mq=T, scales=None),
+        "mega": eng._build_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
+            i32(B), *samp, None, K=K),
+        "mixed": eng._build_mixed_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
+            i32(B), i32(B), *samp, K=K),
+        "spec": eng._build_spec_verify().lower(
+            *head, i32(B * (eng.spec_k + 1)), i32(B), i32(B), i32(B + 1), bt, i32(B),
+            i32(B, eng.spec_k), *samp),
+    }
+    return {k: v.as_text(debug_info=debug_info) for k, v in low.items()}
+
+
+@pytest.fixture(scope="module")
+def pangu_texts(built):
+    return _lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
+
+
+@pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
+def test_lowered_program_names_the_new_scopes(pangu_texts, kind):
+    text = pangu_texts[kind]
+    want = SCOPES + (() if kind == "step" else ("scan_carry",))
+    missing = [s for s in want if not re.search(rf'["/(]{s}[/)"]', text)]
+    assert not missing, f"{kind}: no operation under {missing}"
+    assert f"jit_{'spec_verify' if kind == 'spec' else kind}" in text
+
+
+# sha256 of each program's lowered text for LlamaForCausalLM(llama_tiny())
+# under the engine geometry below, with the ``jax.result_info`` labels (the
+# names of the outputs' places in the result pytree) taken out, as the tree
+# BEFORE the engine asked the model for its trunk lowered them (commit
+# 14eb942, jax 0.9.0): the Mistral cells' programs are what they were.
+LLAMA_GEOMETRY = dict(max_batch_size=4, max_seq_len=64, block_size=8, token_budget=16,
+                      megastep_k=4, spec_k=2)
+LLAMA_BEFORE = {"step": "6ec6d65a4e771850", "mega": "29085cb94899a7eb", "mixed": "eecd25314b184b8a",
+                "spec": "d92a2ead6dd39eb6"}
+
+
+def _digest(text):
+    text = re.sub(r' \{jax\.result_info = "[^"]*"\}', "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.fixture(scope="module")
+def llama_texts():
+    set_hybrid_communicate_group(None)
+    P.seed(0)
+    model = LlamaForCausalLM(llama_tiny())
+    model.eval()
+    return _lowered(ServingEngine(model, **LLAMA_GEOMETRY), debug_info=False)
+
+
+@pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
+def test_llama_lowers_to_the_text_it_had_before_the_interface(llama_texts, kind):
+    assert _digest(llama_texts[kind]) == LLAMA_BEFORE[kind]
+
+
+def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
+    model, _ = built
+    eng = ServingEngine(model, **ENGINE)
+    seen = []
+    real = eng._phase
+
+    def phase(name, **attrs):
+        if name == "harvest":
+            seen.append(attrs)
+        return real(name, **attrs)
+
+    eng._phase = phase
+    before = (eng.moe_tokens, eng.moe_local_picks)
+    assert before == (0, 0)
+    for p in _prompts([20, 9]):
+        eng.add_request(p, max_new_tokens=6)
+    last = before
+    while eng._queue or eng._active:
+        eng.step()
+        now = (eng.moe_tokens, eng.moe_local_picks)
+        assert now[0] >= last[0] and now[1] >= last[1]
+        last = now
+    # two expert layers see every token fed: 29 prompt tokens and 5 + 5 fed back
+    assert eng.moe_tokens == 2 * (29 + 10)
+    assert 0 < eng.moe_local_picks < eng.moe_tokens * TINY["num_experts_per_tok"]
+    assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
+                                          "local_picks": eng.moe_local_picks}
+    assert seen and all(set(a) == {"moe_tokens", "moe_local_picks"} for a in seen)
+    assert sum(a["moe_tokens"] for a in seen) == eng.moe_tokens
+    assert sum(a["moe_local_picks"] for a in seen) == eng.moe_local_picks
+
+
+def test_a_dense_model_counts_no_experts():
+    set_hybrid_communicate_group(None)
+    model = LlamaForCausalLM(llama_tiny())
+    eng = ServingEngine(model.eval(), **LLAMA_GEOMETRY)
+    eng.add_request([5, 6, 7], max_new_tokens=6)
+    eng.run()
+    assert eng.state_summary()["moe"] == {"tokens": 0, "local_picks": 0}
+    assert [name for name, _ in eng.cache_spec.arrays] == ["k", "v"]
+    assert len(eng.caches) == 2 and eng.key_caches is eng.caches[0]
+
+
+# ------------------------------------------------ the configuration's file
+# openPangu-Ultra-MoE-718B's row of the model-configs catalog (``config`` of
+# /opt/skills/guides/model-configs/architectures.jsonl), as published at
+# huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B config.json
+CATALOG_CONFIG = {
+    "attention_bias": False, "first_k_dense_replace": 3, "hidden_act": "silu",
+    "hidden_size": 7680, "intermediate_size": 18432, "kv_lora_rank": 512,
+    "max_position_embeddings": 131072, "model_type": "pangu_ultra_moe",
+    "moe_intermediate_size": 2048, "n_routed_experts": 256, "n_shared_experts": 1,
+    "norm_topk_prob": True, "num_attention_heads": 128, "num_experts_per_tok": 8,
+    "num_hidden_layers": 61, "num_key_value_heads": 128, "num_nextn_predict_layers": 1,
+    "q_lora_rank": 1536, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-05, "rope_theta": 25600000, "routed_scaling_factor": 2.5,
+    "sandwich_norm": True, "tie_word_embeddings": False, "v_head_dim": 128,
+    "vocab_size": 153600}
+REDUCED = {"num_hidden_layers": 5, "first_k_dense_replace": 1, "n_routed_experts": 16,
+           "vocab_size": 19200, "num_nextn_predict_layers": 0}
+
+
+def _config_file():
+    with open(os.path.join(ROOT, "benchmark/configs/openpangu-ultra-moe-718b.serve1.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("key", sorted(CATALOG_CONFIG))
+def test_the_configuration_file_holds_the_catalog_row(key):
+    cfg = _config_file()
+    assert cfg["published"][key] == CATALOG_CONFIG[key]
+    if key in REDUCED:
+        assert cfg[key] == REDUCED[key] and key in cfg["reduced"]
+    else:
+        assert cfg[key] == CATALOG_CONFIG[key] and key not in cfg["reduced"]
+
+
+def test_the_configuration_file_states_its_share_and_builds():
+    cfg = _config_file()
+    assert set(cfg["published"]) == set(CATALOG_CONFIG) and set(cfg["reduced"]) == set(REDUCED)
+    assert cfg["router_outputs"] == 256 and cfg["experts_held"] == [0, 16]
+    assert "16 chips" in cfg["stands_for"] and cfg["family"] == "mla_moe"
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):                      # the guide's own copy, where present
+        rows = [json.loads(line) for line in open(catalog)]
+        (row,) = [r for r in rows if r["name"] == "openPangu-Ultra-MoE-718B"]
+        assert row["config"] == cfg["published"] and cfg["source"] == row["source_url"]
+    mc = FAMILY.model_config(cfg)
+    assert (mc.n_routed_experts, mc.experts_held, mc.num_hidden_layers) == (256, (0, 16), 5)
+    dense, sparse, outer = FAMILY.leaf_shapes(cfg)
+    count = lambda shapes: sum(int(np.prod(s)) for s in shapes.values())   # noqa: E731
+    total = count(dense) + 4 * count(sparse) + count(outer)
+    assert abs(total / 4.919e9 - 1) < 0.001          # the issue's arithmetic
+    assert sparse["eg"] == (16, 7680, 2048) and sparse["router"] == (7680, 256)

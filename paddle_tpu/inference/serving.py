@@ -27,7 +27,13 @@ Design:
   ARMING RULE: the scan arms whenever any scheduled row is DECODING
   (``megastep_k > 1``).  A pure-decode batch runs the tight [B]-token
   scan (mq=1); a batch mixing decode rows with prefilling rows runs the
-  MIXED scan (mq=block_size): each iteration processes, per row, either
+  MIXED scan (mq=the chunk width, block_size by default).  ``mq`` bounds
+  what ONE row may feed; it is not a padding every row pays: the dense
+  attention (ops/paged_attention.py) runs the rows that feed one token in
+  tiles of like context length and the rows that feed a chunk one at a
+  time, each against the live part of its own context, so a mixed
+  iteration costs a decode iteration plus its chunks.  Each iteration
+  processes, per row, either
   one decode token or one block-size prompt chunk — prompt chunks are fed
   as data through a ``prefill_pos`` carry against a host-staged prompt
   window, so chunked prefill adds no shape axis and no recompile.  A
@@ -698,10 +704,16 @@ class ServingEngine:
         self.megasteps_mixed = 0    # of those launches, mixed-phase scans
         self.prefill_chunks = 0     # prompt chunks fed inside mixed scans
         # what a model's trunk counts (``counts`` of serving_model.py), added
-        # up launch by launch: tokens through expert layers, and the picks
-        # among them that fell on an expert held here (monotone)
+        # up launch by launch (monotone): tokens through expert layers, and
+        # the picks among them that fell on an expert held here; the context
+        # positions the iterations' rows had to attend, and the positions
+        # the dense paged attention read for them (ops/paged_attention.py
+        # ``attention_positions``: read / live is what its tiles and context
+        # blocks round up)
         self.moe_tokens = 0
         self.moe_local_picks = 0
+        self.attn_positions_live = 0
+        self.attn_positions_read = 0
         # prefill chunk size (ISSUE 19 satellite, first rung toward
         # Sarathi-style budget-adaptive chunking): tokens per prompt
         # chunk inside the mixed-phase scan.  Default = block_size (the
@@ -962,7 +974,8 @@ class ServingEngine:
         per-row token counts are EXACT-packed into the [token_budget]
         buffer with an in-graph cumsum + scatter, so the forward's
         last-packed-token logits extraction (``cu[1:] - 1``) works
-        unchanged; the attention runs with ``mq=block_size``.  No shape
+        unchanged; the attention runs with ``mq=block_size``, which only
+        the rows that feed a chunk use (one at a time).  No shape
         depends on which rows are prefilling — no recompile axes beyond
         the existing static K.
 
@@ -1075,7 +1088,9 @@ class ServingEngine:
 
         The packed buffer is its OWN shape, [B * (spec_k+1)] — the trunk
         does not bake a packed length, and ``mq = spec_k + 1`` is the
-        multi-token decode-extend case the mixed scan already exercises.
+        multi-token decode-extend case the mixed scan already exercises
+        (every drafting row is a chunk row of the dense attention: one
+        at a time, PERF.md section 6, PR 27).
         int8 KV-quant is excluded by the scheduler (same dynamic-scale
         one-shot contract that excludes it from chunked prefill)."""
         trunk = self._trunk
@@ -1363,6 +1378,11 @@ class ServingEngine:
                 "tokens": self.moe_tokens,
                 "local_picks": self.moe_local_picks,
             },
+            # dense paged attention (monotone; zero for a latent cache)
+            "attention": {
+                "positions_live": self.attn_positions_live,
+                "positions_read": self.attn_positions_read,
+            },
             # speculative-decode counters (ISSUE 19; same monotone
             # delta-fold contract as the megastep block above)
             "spec": {
@@ -1461,7 +1481,7 @@ class ServingEngine:
         """What the model's trunk counted in one launch, added to the
         engine's counters of the same names; returned for the launch's
         ``engine.harvest`` span."""
-        got = {name: int(np.asarray(v)) for name, v in counts.items()}
+        got = {name: int(v) for name, v in jax.device_get(counts).items()}
         for name, n in got.items():
             setattr(self, name, getattr(self, name) + n)
         return got

@@ -531,7 +531,7 @@ class LlamaForCausalLM(nn.Layer):
             jnp.float32)
 
     def serving_trunk(self, *, block_size, cache_quant="none"):
-        from ..ops.paged_attention import blha_attention
+        from ..ops.paged_attention import attention_positions, blha_attention
 
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -547,9 +547,12 @@ class LlamaForCausalLM(nn.Layer):
 
         def trunk(weights, caches, rope, token_ids,
                   enc, dec, now, cu, bt, mq, scales=None):
-            # mq (static): padded per-sequence query length for the attention
-            # compute — T for steps carrying prefill chunks, 1 for pure
-            # decode steps (avoids T× padded-query attention waste).  The
+            # mq (static): the most tokens one row may feed this step — T for
+            # the prefill step, the chunk width in the mixed scan, spec_k + 1
+            # for verification, 1 for pure decode steps.  It bounds a CHUNK
+            # row's queries only: attention runs rows that feed one token in
+            # tiles and rows that feed a chunk one at a time, so a row costs
+            # what it holds and no row is padded to mq (blha_attention).  The
             # trunk runs embed -> layers -> final rms and returns the FULL
             # hidden sequence: the engine heads each slot's last packed
             # token, or every draft position (spec verify).
@@ -590,7 +593,12 @@ class LlamaForCausalLM(nn.Layer):
                     hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
             with jax.named_scope("norm"):
                 hidden = rms(hidden, weights["norm"])
-            return hidden, (key_caches, value_caches), new_scales, {}
+            # what every layer's attention had to attend and what it read
+            # for that, once an iteration (the layers read alike)
+            live, read = attention_positions(
+                dec, now, block_size=bs, blocks_per_seq=bt.shape[1])
+            return hidden, (key_caches, value_caches), new_scales, {
+                "attn_positions_live": live, "attn_positions_read": read}
 
         return trunk
 

@@ -8,16 +8,43 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   a per-sequence ``block_tables [B, blocks_per_seq]`` maps logical positions
   to pool blocks — admission/eviction is host-side free-list bookkeeping, so
   sequences of different lengths share one compiled program.
-- One step = (scatter this step's K/V into the pool) + (gather each
-  sequence's blocks back) + (padded-batch masked attention). Scatter/gather
-  are XLA dynamic-(update-)slice/gather ops that tile fine on TPU; attention
-  is one fp32-softmax einsum chain the MXU eats. A hand-written Pallas paged
-  kernel was deliberately NOT used: r4 measured XLA's einsum decode path at
-  610-688 GB/s vs 299-366 for the Pallas small-M-dot kernel.
+- One step = (scatter this step's K/V into the pool) + (attention blocked
+  over the context). Nothing grows with ``B x max_q_len x blocks_per_seq x
+  bs``: the cost follows what the batch holds, not the table's shape.
+  The cache is read ``_CTX_BLOCK`` positions a pass (whole table columns),
+  contracted in the type it is stored in with float32 accumulation, through
+  an online softmax (float32 running max, sum and accumulator; the
+  bookkeeping is ``latent_attention._online``) whose trip count is DATA:
+  * rows that feed ONE token (decode rows, a prompt's one-token tail) are
+    ordered by context length and run in tiles of ``_ROW_TILE`` rows, each
+    tile as many passes as its longest row needs, so a short row does not
+    pay for the longest one and a (tile, context block) pair without a live
+    position is never gathered;
+  * rows that feed a CHUNK (``now > 1``: prompt chunks, the prefill step,
+    speculative drafts) run one at a time in a loop over the rows that
+    carry one, ``[max_q_len, H, D]`` queries against the row's own context;
+    rows without a chunk cost nothing;
+  * this step's own tokens are attended from registers as one trailing
+    block (causal inside a chunk), so the cache is read for ``[0, dec)``
+    only and an int8 cache's fresh tokens stay unquantised, as in the
+    reference kernel. An int8 block is gathered as its integers and the
+    scales are applied to the products, which is exact.
+  ``attention_positions`` counts what a call had to attend and what it read
+  for that; ``ServingEngine`` adds them up (``attn_positions_live`` /
+  ``_read``).
+- Layouts: the pool is written by (block, kv head, slot) with a window of
+  one head's ``D`` values, and gathered as rows of ``[num_blocks x KV, bs,
+  D]``, so both sides keep the pool's own row-major layout. Written by
+  (block, slot) with a ``[KV, D]`` window, the TPU compiler kept a second,
+  transposed copy of every layer's pool in each program (3.2 GB at the
+  benchmark's size; PERF.md section 6, PR 27).
+- A hand-written Pallas paged kernel with no gathered copy is the next step
+  (ROADMAP S3(c)); r4 had measured XLA's einsum decode path at 610-688 GB/s
+  against 299-366 for a Pallas small-M-dot kernel over a ring cache.
 - Everything is static-shape: the query side is a packed token buffer
-  ``[T, ...]`` (mixed prefill+decode chunks), the key side is
-  ``blocks_per_seq * block_size`` — both fixed by the serving engine, so
-  admitting/retiring sequences never recompiles.
+  ``[T, ...]`` (mixed prefill+decode chunks), the table ``blocks_per_seq``
+  columns — both fixed by the serving engine, so admitting/retiring
+  sequences never recompiles.
 
 Supports the reference kernel's full surface: MHA/GQA, in-kernel rope
 (neox + interleaved), per-sequence encoder/decoder lengths, mixed batches,
@@ -28,13 +55,16 @@ output quantization, additive encoder/decoder masks.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
-__all__ = ["blha_attention", "paged_gather_kv", "build_padding_metadata",
+from .latent_attention import _NEG, _online
+
+__all__ = ["blha_attention", "attention_positions", "build_padding_metadata",
            "rope_rotate"]
+
+_CTX_BLOCK = 512    # cache positions a pass over the context reads
+_ROW_TILE = 8       # one-token rows that share a trip count
 
 
 def rope_rotate(x, cos, sin, neox: bool):
@@ -71,14 +101,236 @@ def _quantize_u8(x, scale, round_ties_away: bool, max_bound: float,
     return (v + 128.0).astype(jnp.uint8)
 
 
-def paged_gather_kv(cache, block_tables):
-    """cache [NB, KV, bs, D] + block_tables [B, P] -> [B, KV, P*bs, D].
-    Out-of-range block ids (free slots marked -1) gather zeros."""
-    nb = cache.shape[0]
-    bt = jnp.where((block_tables < 0) | (block_tables >= nb), nb, block_tables)
-    g = cache.at[bt].get(mode="fill", fill_value=0)  # [B, P, KV, bs, D]
-    B, P, KV, bs, D = g.shape
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, KV, P * bs, D)
+def _context_block(block_size: int, blocks_per_seq: int):
+    """(table columns, cache positions) that one pass over the context reads."""
+    per = max(1, min(blocks_per_seq, _CTX_BLOCK // block_size))
+    return per, per * block_size
+
+
+def _trips(positions, per_pass: int):
+    return (positions + per_pass - 1) // per_pass
+
+
+def _one_token_tiles(dec, now):
+    """The rows that feed ONE token, ordered by context length and cut into
+    tiles of ``_ROW_TILE`` rows, so that a short row rides with short rows.
+    Returns (rows, live, cached), each [tiles, _ROW_TILE]: a row's index,
+    whether it is such a row (the tail of the order is not), and the cache
+    positions it reads, 0 where not live."""
+    B = dec.shape[0]
+    n = jnp.where(now == 1, dec, -1)
+    order = jnp.argsort(-n).astype(jnp.int32)
+    pad = (-B) % _ROW_TILE
+    rows = jnp.pad(order, (0, pad)).reshape(-1, _ROW_TILE)
+    cached = jnp.pad(n[order], (0, pad), constant_values=-1).reshape(rows.shape)
+    return rows, cached >= 0, jnp.maximum(cached, 0)
+
+
+def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
+                        block_size: int, blocks_per_seq: int):
+    """What one ``blha_attention`` call with these lengths attends and what
+    it reads for that, as int32 scalars (live, read): ``live`` the context
+    of every row fed, ``dec + now``; ``read`` the cache positions gathered
+    (a tile of one-token rows reads its longest row's passes for all of its
+    ``_ROW_TILE`` rows, a chunk row its own) plus this step's own tokens.
+    The arithmetic is the loops': the same tiles, the same trip counts."""
+    dec, now = seq_lens_decoder, seq_lens_this_time
+    _, Lc = _context_block(block_size, blocks_per_seq)
+    live = jnp.sum(jnp.where(now > 0, dec + now, 0))
+    _, _, cached = _one_token_tiles(dec, now)
+    read = (jnp.sum(_trips(jnp.max(cached, axis=1), Lc)) * _ROW_TILE
+            + jnp.sum(jnp.where(now > 1, _trips(dec, Lc), 0))) * Lc
+    return live.astype(jnp.int32), (read + jnp.sum(now)).astype(jnp.int32)
+
+
+def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
+    """``mask`` (rows in prefill) and ``tgt_mask`` (decoder rows), each
+    [B, 1|H, Sq, Lm] additive with the key axis aligned at column 0 (a
+    pre-cache prefix takes the first columns, as in the reference's
+    create_attn_mask), as ONE float32 bias [B, 1|H, S, width], zero where a
+    mask does not reach or does not apply. None if neither was given."""
+    def fit(m, rows):
+        m = m.astype(jnp.float32)[:, :, :S, :width]
+        m = jnp.pad(m, ((0, 0), (0, 0), (0, S - m.shape[2]),
+                        (0, width - m.shape[3])))
+        return jnp.where(rows[:, None, None, None], m, 0.0)
+
+    parts = []
+    if mask is not None:
+        parts.append(fit(mask, enc > 0))
+    if tgt_mask is not None:
+        parts.append(fit(tgt_mask, (enc <= 0) & (now > 0)))
+    return sum(parts) if parts else None
+
+
+def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
+                       block_tables, *, max_q_len: int, quant: bool,
+                       k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask):
+    """Steps 6-8 of ``blha_attention``: q [T, H, D] and this step's k, v
+    [T, KV, D] against the pool, which already holds them. Returns
+    [T, H, D] float32, zeros for tokens of no live row.
+
+    A row attends, in this order and through one online softmax: its
+    pre-cache, the cache positions [0, dec) a context block at a time, and
+    this step's own tokens (causal among themselves) from k and v."""
+    T, H, D = q.shape
+    KV = k.shape[1]
+    g = H // KV
+    nb, _, bs, _ = key_cache.shape
+    B, P = block_tables.shape
+    S = int(max_q_len)
+    scale = 1.0 / (D ** 0.5)
+    per, Lc = _context_block(bs, P)
+    bt = jnp.pad(block_tables, ((0, 0), (0, (-P) % per)), constant_values=-1)
+    bt = jnp.where((bt < 0) | (bt >= nb), nb, bt)                  # -> nothing
+    pools = (key_cache.reshape(nb * KV, bs, D),
+             value_cache.reshape(nb * KV, bs, D))
+    heads = jnp.arange(KV, dtype=jnp.int32)
+    kpos = jnp.arange(Lc, dtype=jnp.int32)
+    pre_len = 0 if pre_k is None else pre_k.shape[2]
+    bias = _additive_bias(mask, tgt_mask, enc, now, S,
+                          pre_len + bt.shape[1] * bs + S)
+
+    def gather(ids):
+        """Table columns ids [..., per] -> keys, values [..., KV, Lc, D]: a
+        (block, kv head) is one row of the pool seen as [nb x KV, bs, D], so
+        the gather lands in the order the products want. An int8 cache comes
+        back as its integers less 128 in q's dtype (exact); ``scales`` turn
+        the products into what the dequantised cache would give."""
+        with jax.named_scope("kv_gather"):
+            idx = ids[..., None, :] * KV + heads[:, None]          # [..., KV, per]
+
+            def take(pool):
+                blk = pool.at[idx].get(mode="fill",
+                                       fill_value=128 if quant else 0)
+                blk = blk.reshape(idx.shape[:-1] + (Lc, D))
+                if quant:
+                    blk = (blk.astype(jnp.float32) - 128.0).astype(q.dtype)
+                return blk
+            return take(pools[0]), take(pools[1])
+
+    def scales(rows):
+        if not quant:
+            return None, None
+        return tuple((sc if sc.ndim == 1 else sc[rows])[..., None, None]
+                     for sc in (k_dequant, v_dequant))
+
+    def attend(carry, qt, kb, vb, visible, bias_blk=None, kd=None, vd=None):
+        """One block of the online softmax: queries qt [..., Q, D] against
+        keys and values kb, vb [..., L, D]."""
+        with jax.named_scope("scores"):
+            s = jnp.einsum("...qd,...ld->...ql", qt, kb,
+                           preferred_element_type=jnp.float32) * scale
+            if kd is not None:
+                s = s * kd
+            if bias_blk is not None:
+                s = s + bias_blk
+
+        def pv(p, vv):
+            o = jnp.einsum("...ql,...ld->...qd", p, vv,
+                           preferred_element_type=jnp.float32)
+            return o if vd is None else o * vd
+        return _online(carry, s, visible, vb.astype(jnp.float32), pv)
+
+    def cols(b, at, width):
+        """Columns [at, at + width) of a row's bias b [..., W]; None if none."""
+        return None if b is None else jax.lax.dynamic_slice_in_dim(
+            b, at, width, axis=-1)
+
+    def start(*lead):
+        return (jnp.full(lead, _NEG, jnp.float32), jnp.zeros(lead, jnp.float32),
+                jnp.zeros(lead + (D,), jnp.float32))
+
+    def finish(carry):
+        _, l, acc = carry
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+    # ---- rows that feed one token: tiles of rows of like length ------------
+    first = jnp.clip(cu[:-1], 0, T - 1)
+    q1 = q[first].reshape(B, KV, g, D)
+    k1, v1 = k[first][:, :, None], v[first][:, :, None]            # [B, KV, 1, D]
+
+    rows_t, live_t, cached_t = _one_token_tiles(dec, now)
+
+    def tile(t, out):
+        rows, live, cached = rows_t[t], live_t[t], cached_t[t]
+        qt, ids = q1[rows], bt[rows]
+        kd, vd = scales(rows)
+        on = live[:, None, None, None]
+        b = None
+        if bias is not None:
+            b = bias[rows, :, 0]                                   # [R, 1|H, W]
+            b = b.reshape((_ROW_TILE,) + ((KV, g) if b.shape[1] == H else (1, 1))
+                          + b.shape[-1:])
+        carry = start(_ROW_TILE, KV, g)
+        if pre_k is not None:
+            carry = attend(carry, qt, pre_k[rows].astype(k.dtype),
+                           pre_v[rows].astype(v.dtype), on, cols(b, 0, pre_len))
+
+        def block(j, carry):
+            kb, vb = gather(jax.lax.dynamic_slice_in_dim(ids, j * per, per, axis=1))
+            vis = ((j * Lc + kpos)[None, :] < cached[:, None])[:, None, None, :]
+            return attend(carry, qt, kb, vb, vis, cols(b, pre_len + j * Lc, Lc),
+                          kd, vd)
+
+        carry = jax.lax.fori_loop(0, _trips(jnp.max(cached), Lc), block, carry)
+        bb = None if b is None else jnp.take_along_axis(
+            b, (pre_len + cached)[:, None, None, None], axis=-1)
+        o = finish(attend(carry, qt, k1[rows], v1[rows], on, bb))
+        return out.at[jnp.where(live, cu[rows], T + S)].set(
+            o.reshape(_ROW_TILE, H, D), mode="drop")
+
+    # the order puts these rows first, so the tiles past them hold none
+    out = jax.lax.fori_loop(
+        0, _trips(jnp.sum(now == 1).astype(jnp.int32), _ROW_TILE), tile,
+        jnp.zeros((T + S, H, D), jnp.float32))
+    if S == 1:
+        return out[:T]
+
+    # ---- rows that feed a chunk: one at a time ------------------------------
+    chunk_rows = jnp.nonzero(now > 1, size=B, fill_value=0)[0].astype(jnp.int32)
+    tail = ((0, S), (0, 0), (0, 0))
+    q_pad, k_pad, v_pad = jnp.pad(q, tail), jnp.pad(k, tail), jnp.pad(v, tail)
+    qi = jnp.arange(S, dtype=jnp.int32)
+
+    def chunk_row(i, out):
+        r = chunk_rows[i]
+        at, base, nq = cu[r], dec[r], now[r]
+
+        def own(x):     # [T + S, heads, D] -> this row's [heads, S, D]
+            return jnp.swapaxes(jax.lax.dynamic_slice_in_dim(x, at, S, axis=0), 0, 1)
+
+        qt = own(q_pad).reshape(KV, g * S, D)          # a kv head's g x S queries
+        ids = bt[r]
+        kd, vd = scales(r)
+        b = None
+        if bias is not None:
+            b = bias[r]                                            # [1|H, S, W]
+            b = (b.reshape(KV, g * S, -1) if b.shape[0] == H
+                 else jnp.tile(b, (1, g, 1)))
+        carry = start(KV, g * S)
+        if pre_k is not None:
+            carry = attend(carry, qt, pre_k[r].astype(k.dtype),
+                           pre_v[r].astype(v.dtype), True, cols(b, 0, pre_len))
+
+        def block(j, carry):
+            kb, vb = gather(jax.lax.dynamic_slice_in_dim(ids, j * per, per))
+            return attend(carry, qt, kb, vb, j * Lc + kpos < base,
+                          cols(b, pre_len + j * Lc, Lc), kd, vd)
+
+        carry = jax.lax.fori_loop(0, _trips(base, Lc), block, carry)
+        # this step's tokens: causal inside the chunk
+        sq = jnp.tile(qi, g)[:, None]
+        carry = attend(carry, qt, own(k_pad), own(v_pad),
+                       (qi[None, :] <= sq) & (qi[None, :] < nq),
+                       cols(b, pre_len + base, S))
+        o = jnp.swapaxes(finish(carry).reshape(H, S, D), 0, 1)     # [S, H, D]
+        old = jax.lax.dynamic_slice_in_dim(out, at, S, axis=0)
+        o = jnp.where((qi < nq)[:, None, None], o, old)
+        return jax.lax.dynamic_update_slice_in_dim(out, o, at, axis=0)
+
+    out = jax.lax.fori_loop(0, jnp.sum(now > 1).astype(jnp.int32), chunk_row, out)
+    return out[:T]
 
 
 def build_padding_metadata(seq_lens_this_time):
@@ -150,15 +402,16 @@ def blha_attention(
              v_dequant_scales') — scale arrays pass through unchanged except
     in dynamic quant mode, where prefill rows refresh them.
 
-    Scopes (children of ``paged_attention``): ``rope``, ``kv_write``,
-    ``kv_gather`` (the gather, its dequant and the pre-cache concat),
-    ``scores`` (QK^T, masks, softmax), ``values`` (PV and the return to the
-    packed buffer); what is under none is unpacking and token coordinates.
+    Scopes (children of ``paged_attention``): ``rope``, ``kv_write``, and
+    inside the loops over row tiles, chunk rows and context blocks
+    (``while/body/``) ``kv_gather`` (a block's gather, an int8 block's
+    integers), ``scores`` (QK^T, masks, the online softmax's bookkeeping),
+    ``values`` (PV); what is under none is unpacking, token coordinates and
+    the return to the packed buffer.
     """
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]
     B = block_tables.shape[0]
-    L = block_tables.shape[1] * bs
 
     # ---- 1. unpack + dequant + bias ------------------------------------
     if qkv_out_scale is not None:
@@ -238,93 +491,29 @@ def blha_attention(
         else:
             k_store = k.astype(key_cache.dtype)
             v_store = v.astype(value_cache.dtype)
-        key_cache = key_cache.at[blk, :, slot, :].set(k_store, mode="drop")
-        value_cache = value_cache.at[blk, :, slot, :].set(v_store, mode="drop")
+        # by (block, kv head, slot), a head's D values an update: the layout
+        # the gather reads, so the pool keeps one layout (module docstring)
+        hd = jnp.arange(KV, dtype=jnp.int32)[None, :]
+        key_cache = key_cache.at[blk[:, None], hd, slot[:, None]].set(
+            k_store, mode="drop")
+        value_cache = value_cache.at[blk[:, None], hd, slot[:, None]].set(
+            v_store, mode="drop")
 
-    with jax.named_scope("kv_gather"):
-        # ---- 6. gather each sequence's context back ------------------------
-        k_all = paged_gather_kv(key_cache, block_tables)   # [B, KV, L, D]
-        v_all = paged_gather_kv(value_cache, block_tables)
-        if cache_quant != "none":
-            if cache_quant == "static":
-                kd = cache_k_dequant_scales[None, :, None, None]
-                vd = cache_v_dequant_scales[None, :, None, None]
-            else:
-                kd = cache_k_dequant_scales[:, :, None, None]
-                vd = cache_v_dequant_scales[:, :, None, None]
-            k_all = (k_all.astype(jnp.float32) - 128.0) * kd
-            v_all = (v_all.astype(jnp.float32) - 128.0) * vd
-            # overlay this step's K/V at full precision: the reference kernel
-            # attends the fresh tokens unquantized (only the stored cache is
-            # int8), which keeps prefill outputs exact
-            ov_b = jnp.where(valid, b_idx, B)
-            ov_p = jnp.where(valid, abs_pos, L)
-            k_all = k_all.at[ov_b, :, ov_p].set(k.astype(k_all.dtype), mode="drop")
-            v_all = v_all.at[ov_b, :, ov_p].set(v.astype(v_all.dtype), mode="drop")
-        pre_len = 0
-        if pre_key_cache is not None:
-            pre_len = pre_key_cache.shape[2]
-            k_all = jnp.concatenate([pre_key_cache.astype(k_all.dtype), k_all], axis=2)
-            v_all = jnp.concatenate([pre_value_cache.astype(v_all.dtype), v_all], axis=2)
-        Lf = pre_len + L
-
-    with jax.named_scope("scores"):
-        # ---- 7. padded-batch attention -------------------------------------
-        S = max_q_len
-        bs_idx = jnp.where(valid, b_idx, B)
-        lc_idx = jnp.where(valid & (local < S), local, S)
-        q_pad = jnp.zeros((B, S, H, D), q.dtype).at[bs_idx, lc_idx].set(
-            q, mode="drop")
-        group = H // KV
-        qg = q_pad.reshape(B, S, KV, group, D).astype(jnp.float32)
-        kf = k_all.astype(jnp.float32)
-        logits = jnp.einsum("bskgd,bkld->bkgsl", qg, kf) / (D ** 0.5)
-
-        # causal visibility: query at absolute position p sees keys [0, p] of
-        # its own context plus the whole pre-cache prefix
-        qpos = (seq_lens_decoder[:, None]
-                + jnp.arange(S, dtype=jnp.int32)[None, :])  # [B, S] (rows past the real length are masked on output)
-        kpos = jnp.arange(Lf, dtype=jnp.int32)[None, None, :] - pre_len  # [1,1,Lf]
-        vis = kpos <= qpos[:, :, None]                                   # [B, S, Lf]
-        neg = jnp.asarray(-1e30, jnp.float32)
-        logits = jnp.where(vis[:, None, None, :, :], logits, neg)
-
-        def _add_mask(lg, m):
-            # m: [B, 1|H, Sq, Lm] additive; key axis aligned at column 0 (the
-            # pre-cache prefix occupies the first ``pre_len`` columns, matching
-            # the reference's create_attn_mask layout)
-            m = m.astype(jnp.float32)
-            if m.shape[1] == 1:
-                m = jnp.broadcast_to(m, (B, H, m.shape[2], m.shape[3]))
-            mh = m.reshape(B, KV, group, m.shape[2], m.shape[3])
-            Lm, Sq = m.shape[3], m.shape[2]
-            if Lm < Lf:
-                mh = jnp.pad(mh, ((0, 0),) * 4 + ((0, Lf - Lm),))
-            elif Lm > Lf:
-                mh = mh[..., :Lf]
-            if Sq < S:
-                mh = jnp.pad(mh, ((0, 0),) * 3 + ((0, S - Sq), (0, 0)))
-            elif Sq > S:
-                mh = mh[..., :S, :]
-            return lg + mh
-
-        if mask is not None:
-            # encoder-phase custom mask applies to prefill rows only
-            enc_rows = (seq_lens_encoder > 0)[:, None, None, None, None]
-            logits = jnp.where(enc_rows, _add_mask(logits, mask), logits)
-        if tgt_mask is not None:
-            dec_rows = ((seq_lens_encoder <= 0) &
-                        (seq_lens_this_time > 0))[:, None, None, None, None]
-            logits = jnp.where(dec_rows, _add_mask(logits, tgt_mask), logits)
-
-        p = jax.nn.softmax(logits, axis=-1)
-    with jax.named_scope("values"):
-        out_pad = jnp.einsum("bkgsl,bkld->bskgd", p, v_all.astype(jnp.float32))
-        out_pad = out_pad.reshape(B, S, H, D)
-
-        # ---- 8. gather back to the packed token buffer -----------------
-        out = out_pad.at[bs_idx, lc_idx].get(mode="fill", fill_value=0)  # [T, H, D]
-        out = out.reshape(T, H * D)
+    # ---- 6-8. attention, blocked over the context -----------------------
+    # this step's own keys and values are attended from registers, as one
+    # trailing block: unquantised whatever the cache stores (the reference
+    # kernel keeps prefill outputs exact that way), and rounded to the
+    # cache's dtype where it is not quantised, which is what a read of the
+    # cache would return; so the cache is read for positions [0, dec) only
+    quant = cache_quant != "none"
+    fresh_dt = q.dtype if quant else key_cache.dtype
+    out = _blocked_attention(
+        q, k.astype(fresh_dt), v.astype(fresh_dt), key_cache, value_cache,
+        seq_lens_encoder, seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
+        block_tables, max_q_len=max_q_len, quant=quant,
+        k_dequant=cache_k_dequant_scales, v_dequant=cache_v_dequant_scales,
+        pre_k=pre_key_cache, pre_v=pre_value_cache, mask=mask,
+        tgt_mask=tgt_mask).reshape(T, H * D)
     # smooth-quant epilogue: (x + shift) * smooth — the reference kernel's
     # order (shift first, then the per-channel smoothing scale)
     if out_shift is not None:

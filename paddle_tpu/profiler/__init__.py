@@ -16,14 +16,18 @@ Host spans, every one a ``RecordEvent`` on the profiler's clock:
 ``engine.step`` > ``engine.admit``, ``engine.schedule``, ``engine.launch``
 (stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``, and
 on a mixed launch ``prefill_rows``, the rows it feeds prompt chunks),
-``engine.wait``, ``engine.harvest``; ``train_step.call`` (stats ``step``,
-``steps``).
+``engine.wait``, ``engine.harvest`` (stats: what the model's trunk counted
+in the launch, ``attn_positions_live`` / ``attn_positions_read`` for a dense
+paged cache, ``moe_tokens`` / ``moe_local_picks`` for expert layers);
+``train_step.call`` (stats ``step``, ``steps``).
 
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
 at run time), one vocabulary for every model family:
-``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write``
-``kv_gather`` ``scores`` ``values``, ``attn_out``, ``mlp``, ``norm``, ``head``,
-``sample``, ``scan_carry`` (the serving programs); ``attention`` >
+``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write`` and,
+under ``while/body/`` once for each loop around them (row tiles or chunk
+rows, then context blocks), ``kv_gather`` ``scores`` ``values``; ``attn_out``,
+``mlp``, ``norm``, ``head``, ``sample``, ``scan_carry`` (the serving
+programs); ``attention`` >
 ``flash_attention``, ``loss``, ``optimizer``, ``grad_unscale`` (the train
 step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 ``head``).

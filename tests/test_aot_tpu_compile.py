@@ -7,6 +7,7 @@ says nothing about results or speed, and a pass here is not a chip run.
 Named to sort first: tier-1 is cut by its clock, and a file late in the
 alphabet guards nothing.
 """
+import math
 import os
 import re
 
@@ -128,3 +129,48 @@ def test_decode_attention(chip):
     pos = ((), jnp.int32)
     _compile(decode_attention, chip, row, buf, buf, pos)
     _compile(kv_ring_write, chip, buf, row, pos)
+
+
+@pytest.mark.parametrize("mq", [1, 64, 256], ids=["decode", "chunk64", "prefill256"])
+def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq):
+    """The dense paged attention at the benchmark's serving geometry
+    (mistral-7b-v0.3.serve1: 32 rows, tables of 40 blocks of 64, 8 kv heads
+    of 128, a pool of 1024 blocks), two iterations in a scan with the pool
+    in the carry as the engine's scans hold it. XLA, no kernel; what the
+    chip's compiler must not do is what it did before ISSUE 27: keep a
+    second copy of the pool in another layout (the write and the gather
+    must agree on one), or build anything as large as every row's whole
+    table (168 MB in bf16)."""
+    from paddle_tpu.ops.paged_attention import blha_attention
+
+    B, P, bs, H, KV, D, nb = 32, 40, 64, 32, 8, 128, 1024
+    T = B if mq == 1 else 256
+
+    def two_iterations(qkv, kc, vc, dec, now, cu, bt, rope):
+        def body(carry, _):
+            kc, vc = carry
+            out = blha_attention(
+                qkv, kc, vc, jnp.zeros_like(dec), dec, now, cu, bt, num_heads=H,
+                kv_num_heads=KV, head_dim=D, block_size=bs, max_q_len=mq,
+                use_neox_style=True, compute_dtype=BF16, rope_emb=rope)
+            return (out[1], out[2]), out[0]
+        return jax.lax.scan(body, (kc, vc), None, length=2)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = sds((nb, KV, bs, D), BF16)
+    i32 = jnp.int32
+    compiled = jax.jit(two_iterations, donate_argnums=(1, 2)).lower(
+        sds((T, (H + 2 * KV) * D), BF16), pool, pool, sds((B,), i32), sds((B,), i32),
+        sds((B + 1,), i32), sds((B, P), i32),
+        sds((2, 1, P * bs, 1, D // 2), jnp.float32)).compile()
+    pool_bytes = nb * KV * bs * D * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"
+    whole = B * KV * P * bs * D
+    views_of_the_pool = {(nb, KV, bs, D), (nb * KV, bs, D), (nb * KV * bs, D)}
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", compiled.as_text())}
+    big = [s for s in shapes - views_of_the_pool if math.prod(s) >= whole]
+    assert not big, big

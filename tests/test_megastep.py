@@ -752,10 +752,14 @@ PROGRAMS = {"step": ("_step_fn", "_build_step", 0),
             "spec": ("_spec_fn", "_build_spec_verify", 2)}
 # what each program's lowered text has to name (PERF.md section 3: a
 # per-layer metric that matches a scope reads nothing once it is gone)
+# (regular expressions: attention is blocked over the context, so its three
+# inner scopes lie under ``paged_attention/while/body/``, once for each loop
+# around them: the row tiles or the chunk rows, then the context blocks)
+IN_LOOP = "paged_attention/(?:while/body/)+"
 FORWARD = ("embed", "norm", "attn_proj", "paged_attention", "attn_out", "mlp",
            "head", "sample", "paged_attention/rope", "paged_attention/kv_write",
-           "paged_attention/kv_gather", "paged_attention/scores",
-           "paged_attention/values")
+           IN_LOOP + "kv_gather", IN_LOOP + "scores", IN_LOOP + "values",
+           "paged_attention/while/body/while/body/kv_gather")
 SCOPES = {"step": FORWARD, "mega": FORWARD + ("scan_carry",),
           "mixed": FORWARD + ("scan_carry",), "spec": FORWARD + ("scan_carry",)}
 

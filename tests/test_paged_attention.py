@@ -393,3 +393,200 @@ class TestTracedSeqLens:
 
         with pytest.raises(ValueError, match="eagerly|ServingEngine"):
             jax.jit(f)(jnp.ones((B,), jnp.int32))
+
+
+# ------------------------------------------------ the blocked pass's geometry
+# ``blha_attention`` reads the context ``_CTX_BLOCK`` positions a pass, one-
+# token rows in tiles of ``_ROW_TILE`` ordered by length, chunk rows one at a
+# time. The reference below is the mathematics of the form it replaced: the
+# WHOLE table gathered, every row padded to ``max_q_len`` queries, one
+# softmax over the table's length, all in float32.
+import jax                      # noqa: E402
+import jax.numpy as jnp         # noqa: E402
+
+from paddle_tpu.ops import paged_attention as pa   # noqa: E402
+
+CTX, TILE = pa._CTX_BLOCK, pa._ROW_TILE
+G_BS, G_P, G_H, G_D = 8, 80, 4, 16       # a table of 640 positions, 64 columns a pass
+G_L = G_BS * G_P
+
+
+def padded_reference(q, k, v, kc, vc, enc, dec, now, cu, bt, *, S, quant="none",
+                     kd=None, vd=None, pre_k=None, pre_v=None, mask=None,
+                     tgt_mask=None):
+    """q [T, H, D], k / v [T, KV, D] (this step's, float32) against the pool
+    AFTER the step's write -> [T, H, D] float32."""
+    f32 = jnp.float32
+    T, H, D = q.shape
+    KV = k.shape[1]
+    nb, B = kc.shape[0], bt.shape[0]
+    L = bt.shape[1] * kc.shape[2]
+    tok = jnp.arange(T)
+    b_idx = jnp.clip(jnp.searchsorted(cu, tok, side="right") - 1, 0, B - 1)
+    local = tok - cu[b_idx]
+    abs_pos = dec[b_idx] + local
+    valid = (tok < cu[-1]) & (local < now[b_idx])
+
+    def gather(cache):
+        ids = jnp.where((bt < 0) | (bt >= nb), nb, bt)
+        g = cache.at[ids].get(mode="fill", fill_value=0)      # [B, P, KV, bs, D]
+        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, KV, L, D)
+
+    k_all, v_all = gather(kc), gather(vc)
+    if quant != "none":
+        sk = kd[None, :, None, None] if quant == "static" else kd[:, :, None, None]
+        sv = vd[None, :, None, None] if quant == "static" else vd[:, :, None, None]
+        k_all = (k_all.astype(f32) - 128.0) * sk
+        v_all = (v_all.astype(f32) - 128.0) * sv
+        ob, op = jnp.where(valid, b_idx, B), jnp.where(valid, abs_pos, L)
+        k_all = k_all.at[ob, :, op].set(k, mode="drop")
+        v_all = v_all.at[ob, :, op].set(v, mode="drop")
+    k_all, v_all = k_all.astype(f32), v_all.astype(f32)
+    pre_len = 0
+    if pre_k is not None:
+        pre_len = pre_k.shape[2]
+        k_all = jnp.concatenate([pre_k.astype(f32), k_all], axis=2)
+        v_all = jnp.concatenate([pre_v.astype(f32), v_all], axis=2)
+    Lf = pre_len + L
+    bs_idx = jnp.where(valid, b_idx, B)
+    lc_idx = jnp.where(valid & (local < S), local, S)
+    q_pad = jnp.zeros((B, S, H, D), f32).at[bs_idx, lc_idx].set(q, mode="drop")
+    g = H // KV
+    logits = jnp.einsum("bskgd,bkld->bkgsl", q_pad.reshape(B, S, KV, g, D), k_all,
+                        precision="highest") / (D ** 0.5)
+    qpos = dec[:, None] + jnp.arange(S)[None, :]
+    kpos = jnp.arange(Lf)[None, None, :] - pre_len
+    logits = jnp.where((kpos <= qpos[:, :, None])[:, None, None], logits, -1e30)
+
+    def add_mask(lg, m):
+        m = jnp.broadcast_to(m.astype(f32), (B, H) + m.shape[2:])
+        m = m[:, :, :S, :Lf]
+        m = jnp.pad(m, ((0, 0), (0, 0), (0, S - m.shape[2]), (0, Lf - m.shape[3])))
+        return lg + m.reshape(B, KV, g, S, Lf)
+
+    if mask is not None:
+        logits = jnp.where((enc > 0)[:, None, None, None, None],
+                           add_mask(logits, mask), logits)
+    if tgt_mask is not None:
+        logits = jnp.where(((enc <= 0) & (now > 0))[:, None, None, None, None],
+                           add_mask(logits, tgt_mask), logits)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgsl,bkld->bskgd", p, v_all, precision="highest")
+    return out.reshape(B, S, H, D).at[bs_idx, lc_idx].get(mode="fill", fill_value=0)
+
+
+# rows as (tokens already cached, tokens this step); (0, 0) is an empty slot
+LAYOUTS = {
+    # contexts of 1, bs - 1, bs, bs + 1, ctx_block - 1, ctx_block, ctx_block + 1
+    # and the whole table; three tiles whose longest rows read the whole
+    # table, one position past a ctx_block and one position; lengths out of
+    # order, empty slots between
+    "decode_edges": (1, [(CTX - 1, 1), (0, 1), (0, 0), (G_BS - 2, 1), (G_L - 1, 1),
+                         (G_BS - 1, 1), (0, 0), (CTX - 2, 1), (G_BS, 1), (CTX, 1),
+                         (300, 1), (1, 1), (CTX + 1, 1), (600, 1), (0, 0), (590, 1),
+                         (580, 1), (570, 1), (560, 1), (550, 1), (540, 1)]),
+    # a prompt from nothing; chunks that end on a block, on a ctx_block and on
+    # the table's end; that start on a ctx_block, one position past it and at
+    # one position; a one-token chunk tail
+    "chunk_edges": (8, [(0, 8), (16, 8), (CTX - 8, 8), (0, 0), (CTX, 5), (CTX - 3, 6),
+                        (G_L - 8, 8), (40, 1), (CTX + 1, 4), (1, 3)]),
+    # what a mixed scan feeds: decode rows and chunk rows in one call
+    "mixed": (8, [(200, 1), (CTX + 60, 1), (0, 8), (0, 0), (33, 1), (CTX - 4, 8),
+                  (7, 1), (0, 0), (130, 3), (CTX, 1), (64, 1)]),
+    # every row a chunk of the same width, as speculative verification feeds
+    "drafts": (4, [(20, 4), (CTX + 1, 4), (0, 0), (CTX - 2, 4), (3, 2)]),
+}
+
+
+def _case(layout, group, dtype, quant, holes, pre, masks, seed=0):
+    S, rows = LAYOUTS[layout]
+    rng = np.random.RandomState(seed + len(layout))
+    B, H, D, bs, P = len(rows), G_H, G_D, G_BS, G_P
+    KV = H // group
+    dec = np.array([r[0] for r in rows], np.int32)
+    now = np.array([r[1] for r in rows], np.int32)
+    enc = np.where(now > 1, now, 0).astype(np.int32)
+    cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+    T = int(cu[-1]) + 3                      # the packed buffer has a tail
+    nb = B * P
+    bt = rng.permutation(nb).reshape(B, P).astype(np.int32)
+    need = -(-(dec + now) // bs)
+    for b in range(B):
+        bt[b, need[b]:] = -1                 # what a row does not hold
+    if holes:                                # ... and holes inside what it does
+        bt[0, 1] = -1
+        bt[4, 0] = nb + 7
+    qkv = rng.uniform(-1, 1, (T, (H + 2 * KV) * D)).astype(np.float32)
+    kw = {}
+    if quant == "none":
+        kc = rng.uniform(-1, 1, (nb, KV, bs, D)).astype(np.float32)
+        vc = rng.uniform(-1, 1, (nb, KV, bs, D)).astype(np.float32)
+    else:
+        kc = rng.randint(0, 256, (nb, KV, bs, D)).astype(np.uint8)
+        vc = rng.randint(0, 256, (nb, KV, bs, D)).astype(np.uint8)
+        shape = (KV,) if quant == "static" else (B, KV)
+        for name in ("k", "v"):
+            d = rng.uniform(0.5, 1.5, shape).astype(np.float32) / 127.0
+            kw[f"cache_{name}_dequant_scales"] = jnp.asarray(d)
+            kw[f"cache_{name}_quant_scales"] = jnp.asarray(1.0 / d)
+    if pre:
+        kw["pre_key_cache"] = rng.uniform(-1, 1, (B, KV, 5, D)).astype(np.float32)
+        kw["pre_value_cache"] = rng.uniform(-1, 1, (B, KV, 5, D)).astype(np.float32)
+    if masks:
+        # an encoder mask over one head axis and a decoder mask over all
+        # heads, neither as long as the table
+        kw["mask"] = rng.uniform(-2, 0, (B, 1, S, CTX + 40)).astype(np.float32)
+        kw["tgt_mask"] = rng.uniform(-2, 0, (B, H, 1, G_L - 17)).astype(np.float32)
+    cast = (lambda a: jnp.asarray(a, dtype)) if quant == "none" else jnp.asarray
+    args = (jnp.asarray(qkv, dtype), cast(kc), cast(vc), jnp.asarray(enc),
+            jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu), jnp.asarray(bt))
+    kw = {n: (jnp.asarray(a, dtype) if n.startswith("pre_") else jnp.asarray(a))
+          for n, a in kw.items()}
+    return args, kw, dict(H=H, KV=KV, D=D, bs=bs, S=S, T=T, total=int(cu[-1]))
+
+
+def _p(layout, group=4, dtype="float32", quant="none", holes=False, pre=False,
+       masks=False):
+    name = "-".join([layout, f"g{group}", dtype, quant]
+                    + [n for n, on in (("holes", holes), ("pre", pre), ("masks", masks))
+                       if on])
+    return pytest.param(layout, group, dtype, quant, holes, pre, masks, id=name)
+
+
+GEOMETRY = (
+    [_p(lay, group=g, dtype=dt) for lay in LAYOUTS for g in (1, 4)
+     for dt in ("float32", "bfloat16")]
+    + [_p(lay, quant=qm, dtype=dt) for lay in ("decode_edges", "mixed")
+       for qm in ("static", "dynamic") for dt in ("float32", "bfloat16")]
+    + [_p("mixed", holes=True), _p("decode_edges", holes=True, group=1),
+       _p("chunk_edges", pre=True), _p("mixed", masks=True),
+       _p("mixed", pre=True, masks=True, group=1),
+       _p("decode_edges", pre=True, masks=True, dtype="bfloat16"),
+       _p("drafts", masks=True, quant="dynamic"),
+       _p("chunk_edges", pre=True, quant="static", dtype="bfloat16")])
+
+
+@pytest.mark.parametrize("layout,group,dtype,quant,holes,pre,masks", GEOMETRY)
+def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes, pre,
+                                              masks):
+    args, kw, g = _case(layout, group, dtype, quant, holes, pre, masks)
+    qkv, _, _, enc, dec, now, cu, bt = args
+    outs = pa.blha_attention(
+        *args, num_heads=g["H"], kv_num_heads=g["KV"], head_dim=g["D"],
+        block_size=g["bs"], max_q_len=g["S"], cache_quant=quant,
+        compute_dtype=jnp.dtype(dtype), **kw)
+    out, kc, vc = outs[0], outs[1], outs[2]
+    assert out.dtype == jnp.dtype(dtype) and out.shape == (g["T"], g["H"] * g["D"])
+    H, KV, D = g["H"], g["KV"], g["D"]
+    f = qkv.astype(jnp.float32)
+    want = padded_reference(
+        f[:, :H * D].reshape(-1, H, D), f[:, H * D:(H + KV) * D].reshape(-1, KV, D),
+        f[:, (H + KV) * D:].reshape(-1, KV, D), kc, vc, enc, dec, now, cu, bt,
+        S=g["S"], quant=quant, kd=outs[5], vd=outs[6], pre_k=kw.get("pre_key_cache"),
+        pre_v=kw.get("pre_value_cache"), mask=kw.get("mask"),
+        tgt_mask=kw.get("tgt_mask"))
+    tol = 2e-4 if dtype == "float32" else 5e-3
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want).reshape(g["T"], H * D),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(out[g["total"]:], np.float32).any()

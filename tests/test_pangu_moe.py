@@ -5,7 +5,6 @@ and its COW fork, n-gram speculation), the expert layer's share of a
 deployment, the typed refusals, the names in the compiled programs, the
 counters; all held to the plain float32 reference
 (benchmark/references/mla_moe.py), which shares nothing with the program."""
-import hashlib
 import json
 import os
 import re
@@ -19,6 +18,7 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine, ServingFrontend
+from paddle_tpu.inference.serving import SamplingParams
 from paddle_tpu.models import (LlamaForCausalLM, PanguUltraMoEConfig,
                                PanguUltraMoEForCausalLM, llama_tiny)
 from paddle_tpu.models import pangu_moe
@@ -408,20 +408,15 @@ def test_lowered_program_names_the_new_scopes(pangu_texts, kind):
     assert f"jit_{'spec_verify' if kind == 'spec' else kind}" in text
 
 
-# sha256 of each program's lowered text for LlamaForCausalLM(llama_tiny())
-# under the engine geometry below, with the ``jax.result_info`` labels (the
-# names of the outputs' places in the result pytree) taken out, as the tree
-# BEFORE the engine asked the model for its trunk lowered them (commit
-# 14eb942, jax 0.9.0): the Mistral cells' programs are what they were.
+# The Llama programs read the cache a context block at a time (ISSUE 27): no
+# value in a program's lowered text has the shape of a WHOLE gathered table
+# (``B x KV x P*bs x D``, or its blocks before they are merged) or of scores
+# padded to it (``... x mq x P*bs``), in any type. The guard's geometry gives
+# a table of 128 blocks of 8, twice what one pass over the context reads, and
+# a batch of 3, which no other axis of the model has.
 LLAMA_GEOMETRY = dict(max_batch_size=4, max_seq_len=64, block_size=8, token_budget=16,
                       megastep_k=4, spec_k=2)
-LLAMA_BEFORE = {"step": "6ec6d65a4e771850", "mega": "29085cb94899a7eb", "mixed": "eecd25314b184b8a",
-                "spec": "d92a2ead6dd39eb6"}
-
-
-def _digest(text):
-    text = re.sub(r' \{jax\.result_info = "[^"]*"\}', "", text)
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+GUARD_GEOMETRY = dict(LLAMA_GEOMETRY, max_batch_size=3, max_seq_len=1024)
 
 
 @pytest.fixture(scope="module")
@@ -430,26 +425,65 @@ def llama_texts():
     P.seed(0)
     model = LlamaForCausalLM(llama_tiny())
     model.eval()
-    return _lowered(ServingEngine(model, **LLAMA_GEOMETRY), debug_info=False)
+    eng = ServingEngine(model, **GUARD_GEOMETRY)
+    return eng, _lowered(eng, debug_info=False)
+
+
+def _whole_table_shapes(text, B, P, bs, KV, D):
+    """The tensor types of ``text`` that hold every position of every row's
+    table: ``[B, ..., P*bs, D]`` / ``[B, P, KV, bs, D]`` in any order of the
+    middle axes, or ``[B, ..., P*bs]`` with heads or queries between."""
+    L = P * bs
+    found = set()
+    for dims in set(re.findall(r"tensor<((?:\d+x)+)[a-z]+\d*>", text)):
+        shape = [int(d) for d in dims.split("x") if d]
+        n = int(np.prod(shape))
+        if len(shape) < 3 or shape[0] != B:
+            continue
+        if n % (B * L * D) == 0 and shape[-1] == D and (L in shape or (P in shape and bs in shape)):
+            found.add(dims)             # gathered keys or values
+        if shape[-1] == L and n >= B * KV * L:
+            found.add(dims)             # scores, probabilities or a mask over the table
+    return found
 
 
 @pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
-def test_llama_lowers_to_the_text_it_had_before_the_interface(llama_texts, kind):
-    assert _digest(llama_texts[kind]) == LLAMA_BEFORE[kind]
+def test_llama_programs_hold_no_whole_table(llama_texts, kind):
+    eng, texts = llama_texts
+    cfg = llama_tiny()
+    KV, D = cfg.num_key_value_heads, cfg.head_dim
+    assert eng.P * eng.bs == 1024 and eng.B not in (KV, cfg.num_attention_heads)
+    # the guard sees the form it guards against: the plain gather of a table
+    gathered = jax.jit(lambda c, t: c[t]).lower(
+        jax.ShapeDtypeStruct(eng.key_caches[0].shape, jnp.float32),
+        jax.ShapeDtypeStruct((eng.B, eng.P), jnp.int32)).as_text()
+    assert _whole_table_shapes(gathered, eng.B, eng.P, eng.bs, KV, D)
+    assert not _whole_table_shapes(texts[kind], eng.B, eng.P, eng.bs, KV, D)
+
+
+def _harvests(eng):
+    """[(kind of the launch, the attributes of its ``engine.harvest`` span)],
+    filled as the engine runs."""
+    seen, kinds = [], []
+    launch, phase = eng._launch_phase, eng._phase
+
+    def launched(kind, *a, **kw):
+        kinds.append(kind)
+        return launch(kind, *a, **kw)
+
+    def entered(name, **attrs):
+        if name == "harvest":
+            seen.append((kinds[-1], attrs))
+        return phase(name, **attrs)
+
+    eng._launch_phase, eng._phase = launched, entered
+    return seen
 
 
 def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     model, _ = built
     eng = ServingEngine(model, **ENGINE)
-    seen = []
-    real = eng._phase
-
-    def phase(name, **attrs):
-        if name == "harvest":
-            seen.append(attrs)
-        return real(name, **attrs)
-
-    eng._phase = phase
+    harvests = _harvests(eng)
     before = (eng.moe_tokens, eng.moe_local_picks)
     assert before == (0, 0)
     for p in _prompts([20, 9]):
@@ -465,9 +499,87 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     assert 0 < eng.moe_local_picks < eng.moe_tokens * TINY["num_experts_per_tok"]
     assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
                                           "local_picks": eng.moe_local_picks}
+    # ... and nothing of the dense attention's, which this trunk does not run
+    seen = [a for _, a in harvests]
     assert seen and all(set(a) == {"moe_tokens", "moe_local_picks"} for a in seen)
+    assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0}
     assert sum(a["moe_tokens"] for a in seen) == eng.moe_tokens
     assert sum(a["moe_local_picks"] for a in seen) == eng.moe_local_picks
+
+
+def test_attention_positions_of_a_two_row_example():
+    """Blocks of 8 in a table of 80: a pass reads 512 positions, a tile 8 rows."""
+    from paddle_tpu.ops.paged_attention import attention_positions
+
+    def count(rows):
+        dec, now = (jnp.asarray(x, jnp.int32) for x in zip(*rows))
+        return tuple(int(n) for n in attention_positions(dec, now, block_size=8,
+                                                         blocks_per_seq=80))
+
+    # two decoding rows in one tile: the longer one's two passes for all 8 rows
+    assert count([(600, 1), (10, 1)]) == (601 + 11, 2 * 512 * 8 + 2)
+    # a chunk row reads its own two passes; the tile of the other row one pass
+    assert count([(520, 4), (3, 1)]) == (524 + 4, 2 * 512 + 512 * 8 + 5)
+    # an empty slot and a prompt's first chunk read nothing of the cache
+    assert count([(0, 0), (0, 7)]) == (7, 7)
+
+
+def test_attention_counters_are_monotone_and_ride_the_harvest_span():
+    set_hybrid_communicate_group(None)
+    model = LlamaForCausalLM(llama_tiny())
+    eng = ServingEngine(model.eval(), **dict(LLAMA_GEOMETRY, megastep_k=1, spec_k=0))
+    harvests = _harvests(eng)
+    assert (eng.attn_positions_live, eng.attn_positions_read) == (0, 0)
+    eng.add_request([5, 6, 7], max_new_tokens=2)
+    eng.add_request([8, 9, 10, 11, 12], max_new_tokens=2)
+    last = (0, 0)
+    while eng._queue or eng._active:
+        eng.step()
+        now = (eng.attn_positions_live, eng.attn_positions_read)
+        assert now[0] >= last[0] and now[1] >= last[1]
+        last = now
+    # both prompts in one step (3 + 5 positions, nothing cached yet), then both
+    # rows decode at contexts 3 and 5: one pass (the whole table of 64) for the
+    # tile's 8 rows, and the two tokens fed
+    assert eng.attn_positions_live == (3 + 5) + (4 + 6)
+    assert eng.attn_positions_read == (3 + 5) + (64 * 8 + 2)
+    assert eng.attn_positions_read >= eng.attn_positions_live > 0
+    assert eng.state_summary()["attention"] == {
+        "positions_live": eng.attn_positions_live,
+        "positions_read": eng.attn_positions_read}
+    seen = [a for _, a in harvests]
+    assert len(seen) == 2
+    assert all(set(a) == {"attn_positions_live", "attn_positions_read"} for a in seen)
+    assert sum(a["attn_positions_live"] for a in seen) == eng.attn_positions_live
+    assert sum(a["attn_positions_read"] for a in seen) == eng.attn_positions_read
+
+
+@pytest.fixture(scope="module")
+def llama_harvests():
+    """The harvest spans of a Llama engine driven through its four programs:
+    a prefill step, a prompt arriving beside a decoding row (mixed scan), the
+    decode scan, and a repetitive prompt that drafts (verification)."""
+    set_hybrid_communicate_group(None)
+    model = LlamaForCausalLM(llama_tiny())
+    eng = ServingEngine(model.eval(), **LLAMA_GEOMETRY)
+    harvests = _harvests(eng)
+    eng.add_request([3, 17, 101], max_new_tokens=12, sampling=SamplingParams(spec=False))
+    eng.step()
+    eng.add_request(list(range(40, 50)), max_new_tokens=6, sampling=SamplingParams(spec=False))
+    eng.run()
+    eng.add_request([1, 2, 3, 1, 2, 3, 1, 2], max_new_tokens=48)
+    eng.run()
+    return harvests
+
+
+@pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
+def test_every_llama_program_counts_attention_positions(llama_harvests, kind):
+    """Each of the four programs adds to both counters (a scan sums its
+    iterations), and reads at least what is live."""
+    seen = [a for k, a in llama_harvests if k == kind]
+    assert seen, f"no {kind} launch among {sorted({k for k, _ in llama_harvests})}"
+    for a in seen:
+        assert a["attn_positions_read"] >= a["attn_positions_live"] > 0
 
 
 def test_a_dense_model_counts_no_experts():

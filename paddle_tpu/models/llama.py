@@ -86,7 +86,10 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_offset=0):
     Default path is the jnp rotation — measured on v5e, XLA fuses it into the
     surrounding projections as fast as the Pallas rope kernel and without the
     custom-call layout copies (0.4354 vs 0.4325 MFU on the 1B bench).
-    Set PADDLE_TPU_FUSED_LLAMA=1 to route through ops/pallas/fused_ops.py."""
+    Set PADDLE_TPU_FUSED_LLAMA=1 to route through ops/pallas/fused_ops.py.
+    At Mistral-7B widths under recompute the switch (rope and SwiGLU kernels
+    together) WON its pair: 27,713 against 25,935 tokens/s on
+    mistral7b.train.pretrain-2k (PR 28; ROADMAP S7 makes it the path)."""
     import os
 
     if isinstance(position_offset, Tensor):
@@ -165,6 +168,9 @@ class LlamaAttention(nn.Layer):
 
         b, s = hidden.shape[0], hidden.shape[1]
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        # PADDLE_TPU_FUSED_QKV=1 (here and in LlamaMLP) won its pair on
+        # mistral7b.train.pretrain-2k, 26,545 against 25,935 tokens/s (PR 28;
+        # ROADMAP S7 makes it the path and deletes the variable)
         fuse_train = os.environ.get("PADDLE_TPU_FUSED_QKV", "0") == "1"
         with jax.named_scope("attn_proj"):
             if ((s == 1 and cache is not None) or fuse_train) and not self._mp_active():
@@ -237,55 +243,38 @@ class LlamaAttention(nn.Layer):
     def _static_cache_attn(self, q, k, v, cos, sin, cache, b, s):
         """Fixed-size KV ring (serving decode): cache = (k_buf [B,L,KVH,D],
         v_buf, pos ()) — every decode step has identical shapes, so the whole
-        loop runs from ONE compiled program. The single-token step runs the
-        fused Pallas decode path (ops/pallas/decode_attention.py): aliased
-        in-place ring writes + native-layout online-softmax attention — the
-        reference's masked_multihead_attention decode kernel analog."""
-        import os
-
+        loop runs from ONE compiled program (the reference's
+        masked_multihead_attention decode analog). One token takes the
+        native-layout einsum below, a longer step the masked sdpa path."""
         kbuf, vbuf, pos = cache
         q, k = apply_rotary_pos_emb(q, k, cos, sin, position_offset=pos)
-        mode = os.environ.get("PADDLE_TPU_DECODE_KERNEL", "einsum")
-        if s == 1 and mode != "0" and self.num_heads % kbuf.shape[2] == 0:
-            if mode == "pallas":
-                # kept for study: measured SLOWER than the einsum path on
-                # v5e (299-366 vs 610-688 GB/s — per-head M=1 MXU dots don't
-                # pipeline; r4)
-                from ..ops.pallas.decode_attention import decode_attention, kv_ring_write
+        if s == 1 and self.num_heads % kbuf.shape[2] == 0:
+            # native-layout decode attention: NO head-major transposes of
+            # the ring (the sdpa path's swapaxes cost a full extra KV
+            # pass); fp32 softmax; GQA via grouped reshape, K/V never
+            # repeated. Ring writes stay XLA dynamic_update_slice — in a
+            # scan carry they are in-place (measured free). A Pallas kernel
+            # for this step read 299-366 GB/s against this path's 610-688
+            # (per-head M=1 MXU dots don't pipeline; r4) and was removed.
+            scale = 1.0 / math.sqrt(self.head_dim)
 
-                def fused(qv, kv_, vv, kb, vb, p):
-                    p32 = p.astype(jnp.int32)
-                    kb = kv_ring_write(kb, kv_, p32)
-                    vb = kv_ring_write(vb, vv, p32)
-                    o = decode_attention(qv, kb, vb, p32)
-                    return o, kb, vb
-            else:
-                # native-layout decode attention: NO head-major transposes of
-                # the ring (the sdpa path's swapaxes cost a full extra KV
-                # pass); fp32 softmax; GQA via grouped reshape, K/V never
-                # repeated. Ring writes stay XLA dynamic_update_slice — in a
-                # scan carry they are in-place (measured free).
-                import math as _math
-
-                scale = 1.0 / _math.sqrt(self.head_dim)
-
-                def fused(qv, kv_, vv, kb, vb, p):
-                    p32 = p.astype(jnp.int32)
-                    kb = jax.lax.dynamic_update_slice(
-                        kb, kv_.astype(kb.dtype), (0, p32, 0, 0))
-                    vb = jax.lax.dynamic_update_slice(
-                        vb, vv.astype(vb.dtype), (0, p32, 0, 0))
-                    bq, _, nh, hd = qv.shape
-                    kvh = kb.shape[2]
-                    rep = nh // kvh
-                    L = kb.shape[1]
-                    qg = qv.reshape(bq, 1, kvh, rep, hd)
-                    sc = jnp.einsum("bqgrd,blgd->bgrql", qg, kb).astype(jnp.float32) * scale
-                    cols = jnp.arange(L)
-                    sc = jnp.where(cols[None, None, None, None, :] <= p32, sc, -1e30)
-                    pr = jax.nn.softmax(sc, axis=-1).astype(qv.dtype)
-                    o = jnp.einsum("bgrql,blgd->bqgrd", pr, vb)
-                    return o.reshape(bq, 1, nh, hd), kb, vb
+            def fused(qv, kv_, vv, kb, vb, p):
+                p32 = p.astype(jnp.int32)
+                kb = jax.lax.dynamic_update_slice(
+                    kb, kv_.astype(kb.dtype), (0, p32, 0, 0))
+                vb = jax.lax.dynamic_update_slice(
+                    vb, vv.astype(vb.dtype), (0, p32, 0, 0))
+                bq, _, nh, hd = qv.shape
+                kvh = kb.shape[2]
+                rep = nh // kvh
+                L = kb.shape[1]
+                qg = qv.reshape(bq, 1, kvh, rep, hd)
+                sc = jnp.einsum("bqgrd,blgd->bgrql", qg, kb).astype(jnp.float32) * scale
+                cols = jnp.arange(L)
+                sc = jnp.where(cols[None, None, None, None, :] <= p32, sc, -1e30)
+                pr = jax.nn.softmax(sc, axis=-1).astype(qv.dtype)
+                o = jnp.einsum("bgrql,blgd->bqgrd", pr, vb)
+                return o.reshape(bq, 1, nh, hd), kb, vb
 
             out, kbuf, vbuf = apply(fused, q, k, v, kbuf, vbuf, pos,
                                     op_name="decode_attention", n_outs=3)

@@ -25,13 +25,6 @@ from ...device import on_tpu
 __all__ = ["rope_fused", "swiglu_fused"]
 
 
-def _enabled(name: str) -> bool:
-    import os
-
-    dis = os.environ.get("PADDLE_TPU_DISABLE_FUSED", "")
-    return name not in [s.strip() for s in dis.split(",") if s.strip()]
-
-
 def _on_tpu(interpret: bool) -> bool:
     return interpret or on_tpu()
 
@@ -109,7 +102,7 @@ def _dims_ok(q, k) -> bool:
 
 
 def _rope_fwd(q, k, cos, sin, interpret):
-    if _on_tpu(interpret) and _dims_ok(q, k) and _enabled("rope"):
+    if _on_tpu(interpret) and _dims_ok(q, k):
         out = tuple(_rope_pallas(q, k, cos, sin, interpret))
     else:
         out = _rope_ref(q, k, cos, sin)
@@ -120,7 +113,7 @@ def _rope_bwd(interpret, res, g):
     cos, sin = res
     gq, gk = g
     # d/dx of a rotation by theta is a rotation of the cotangent by -theta
-    if _on_tpu(interpret) and _dims_ok(gq, gk) and _enabled("rope"):
+    if _on_tpu(interpret) and _dims_ok(gq, gk):
         dq, dk = _rope_pallas(gq, gk, cos, -sin, interpret)
     else:
         dq, dk = _rope_ref(gq, gk, cos, -sin)
@@ -197,7 +190,7 @@ def swiglu_fused(a, b, interpret: bool = False):
 
 
 def _swiglu_fwd(a, b, interpret):
-    if _on_tpu(interpret) and _enabled("swiglu"):
+    if _on_tpu(interpret):
         shape = a.shape
         out = _swiglu_pallas(a.reshape(-1, shape[-1]), b.reshape(-1, shape[-1]),
                              interpret).reshape(shape)
@@ -209,7 +202,7 @@ def _swiglu_fwd(a, b, interpret):
 
 def _swiglu_bwd(interpret, res, g):
     a, b = res
-    if _on_tpu(interpret) and _enabled("swiglu"):
+    if _on_tpu(interpret):
         shape = a.shape
         da, db = _swiglu_bwd_pallas(a.reshape(-1, shape[-1]), b.reshape(-1, shape[-1]),
                                     g.reshape(-1, shape[-1]), interpret)

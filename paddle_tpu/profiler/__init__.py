@@ -33,9 +33,9 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 ``head``).
 
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
-``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``decode_attention``,
-``kv_ring_write``, ``fused_adamw``, ``rms_norm``, ``rms_norm_residual``,
-``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``, ``int8_matmul``.
+``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
+``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
+``int8_matmul``.
 """
 from __future__ import annotations
 

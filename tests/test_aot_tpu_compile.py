@@ -117,20 +117,6 @@ def test_int8_matmul(chip, m, monkeypatch):
              ((11008,), jnp.float32))
 
 
-def test_decode_attention(chip):
-    from paddle_tpu.ops.pallas.decode_attention import (
-        decode_attention,
-        kv_ring_write,
-    )
-
-    b, ring, h, d = 8, 2048, 32, 128
-    buf = ((b, ring, h, d), BF16)
-    row = ((b, 1, h, d), BF16)
-    pos = ((), jnp.int32)
-    _compile(decode_attention, chip, row, buf, buf, pos)
-    _compile(kv_ring_write, chip, buf, row, pos)
-
-
 @pytest.mark.parametrize("mq", [1, 64, 256], ids=["decode", "chunk64", "prefill256"])
 def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq):
     """The dense paged attention at the benchmark's serving geometry

@@ -132,47 +132,55 @@ def test_static_cache_rejects_beyond_rope_table():
         generate(m, ids, max_new_tokens=6, use_static_cache=True)
 
 
-class TestDecodeAttentionPaths:
-    """The fused decode path (native-layout einsum + fused qkv/gate-up) must
-    be numerically equivalent to the sdpa reference path (numerics
-    matched vs the current path)."""
+class TestGrowingCacheStep:
+    """``forward(ids, caches=[(k, v), ...])`` is the step ``generate`` runs and
+    what the serving tests hold the engine to: its logits are the full
+    forward's at the same positions, whatever the cached length (255 is
+    ``llama_tiny``'s last rope row)."""
 
-    def _greedy(self, monkeypatch, mode):
-        import paddle_tpu as P
-        from paddle_tpu.models import LlamaForCausalLM, greedy_decode, llama_tiny
+    @staticmethod
+    def _model(**kw):
+        from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 
-        monkeypatch.setenv("PADDLE_TPU_DECODE_KERNEL", mode)
-        P.seed(7)
-        cfg = llama_tiny()
-        model = LlamaForCausalLM(cfg)
+        P.seed(3)
+        model = LlamaForCausalLM(llama_tiny(**kw))
         model.eval()
-        ids = P.to_tensor(np.random.RandomState(1).randint(
-            0, cfg.vocab_size, (2, 12)).astype(np.int32))
-        out = greedy_decode(model, ids, max_new_tokens=10, max_length=40)
-        return np.asarray(out.numpy())
+        return model
 
-    def test_einsum_path_matches_sdpa_path(self, monkeypatch):
-        a = self._greedy(monkeypatch, "0")
-        b = self._greedy(monkeypatch, "einsum")
-        np.testing.assert_array_equal(a, b)
-
-    def test_pallas_ref_matches_sdpa_path(self, monkeypatch):
-        # the pallas kernel's jnp reference (used on CPU) must agree too
+    @staticmethod
+    def _empty(model, batch):
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.decode_attention import ref_decode_attention
+        cfg = model.config
+        shape = (batch, 0, cfg.num_key_value_heads, cfg.head_dim)
+        return [(P.to_tensor(jnp.zeros(shape, jnp.float32)),
+                 P.to_tensor(jnp.zeros(shape, jnp.float32)))
+                for _ in range(cfg.num_hidden_layers)]
 
-        rng = np.random.RandomState(3)
-        q = jnp.asarray(rng.randn(2, 1, 4, 32), jnp.float32)
-        kb = jnp.asarray(rng.randn(2, 16, 4, 32), jnp.float32)
-        vb = jnp.asarray(rng.randn(2, 16, 4, 32), jnp.float32)
-        import paddle_tpu as P
-        from paddle_tpu.nn import functional as F
+    def _check(self, model, cached, new, fwd=None):
+        fwd = fwd or model
+        ids = np.random.RandomState(cached).randint(
+            0, model.config.vocab_size, (2, cached + new)).astype(np.int32)
+        with P.no_grad():
+            full = model(P.to_tensor(ids)).numpy()
+            caches = self._empty(model, 2)
+            if cached:
+                prefill, caches = fwd(P.to_tensor(ids[:, :cached]), caches=caches)
+                np.testing.assert_allclose(prefill.numpy(), full[:, :cached], atol=2e-4)
+            step, caches = fwd(P.to_tensor(ids[:, cached:]), caches=caches)
+        np.testing.assert_allclose(step.numpy(), full[:, cached:], atol=2e-4)
+        assert [tuple(c.shape) for c in caches[0]] == [
+            (2, cached + new, model.config.num_key_value_heads, model.config.head_dim)] * 2
 
-        pos = 9
-        out = np.asarray(ref_decode_attention(q, kb, vb, jnp.int32(pos)))
-        mask = jnp.where(jnp.arange(16)[None, None, None, :] <= pos, 0.0, -1e30)
-        ref = F.scaled_dot_product_attention(
-            P.to_tensor(q), P.to_tensor(kb), P.to_tensor(vb),
-            attn_mask=P.to_tensor(mask))
-        np.testing.assert_allclose(out, np.asarray(ref.numpy()), rtol=1e-4, atol=1e-5)
+    @pytest.mark.parametrize("cached", [0, 5, 130, 255])
+    def test_one_token_step_matches_full_forward(self, cached):
+        self._check(self._model(), cached, 1)
+
+    def test_grouped_kv_heads_and_a_step_of_three(self):
+        self._check(self._model(num_key_value_heads=2), 5, 3)
+
+    def test_under_to_static(self):
+        from paddle_tpu.jit import to_static
+
+        model = self._model()
+        self._check(model, 5, 1, fwd=to_static(model))

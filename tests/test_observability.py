@@ -1,6 +1,7 @@
 """HLO dump + device memory stats (reference: paddle/fluid/memory/stats.h,
 paddle/cinn/hlir/framework/pir_compiler.h — the "see what got compiled"
 capability)."""
+import functools
 import glob
 import os
 
@@ -159,12 +160,25 @@ def test_lowered_train_step_names_its_scopes(scaled):
 
 KERNEL_NAMES = {
     "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
-    "decode_attention.py": ["kv_ring_write", "decode_attention"],
-    "fused_adamw.py": ["fused_adamw"],
     "fused_norm.py": ["rms_norm", "rms_norm_residual"],
     "fused_ops.py": ["fused_rope", "swiglu_fwd", "swiglu_bwd"],
     "int8_matmul.py": ["int8_matmul"],
 }
+
+
+def _pallas_dir():
+    import paddle_tpu.ops.pallas as pallas
+
+    return os.path.dirname(pallas.__file__)
+
+
+def _pallas_calls(tree):
+    """The file's ``pallas_call``s in line order."""
+    import ast
+
+    calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+             and isinstance(n.func, ast.Attribute) and n.func.attr == "pallas_call"]
+    return sorted(calls, key=lambda c: c.lineno)
 
 
 @pytest.mark.parametrize("filename", sorted(KERNEL_NAMES))
@@ -174,23 +188,78 @@ def test_every_pallas_call_has_its_own_name(filename):
     kernels' names are what the benchmark's kernel metrics match."""
     import ast
 
-    import paddle_tpu.ops.pallas as pallas
-
     def names(path):
-        tree = ast.parse(open(path).read())
-        calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call)
-                 and isinstance(n.func, ast.Attribute) and n.func.attr == "pallas_call"]
         out = []
-        for c in sorted(calls, key=lambda c: c.lineno):
+        for c in _pallas_calls(ast.parse(open(path).read())):
             kw = {k.arg: k.value for k in c.keywords}
             assert isinstance(kw.get("name"), ast.Constant), f"{path}:{c.lineno} has no name"
             out.append(kw["name"].value)
         return out
 
-    here = os.path.dirname(pallas.__file__)
+    here = _pallas_dir()
     assert names(os.path.join(here, filename)) == KERNEL_NAMES[filename]
     every = [n for f in glob.glob(os.path.join(here, "*.py")) for n in names(f)]
     assert len(every) == len(set(every)) == sum(map(len, KERNEL_NAMES.values()))
+
+
+@functools.lru_cache(maxsize=None)
+def _imported_from_kernel_tier():
+    """kernel module -> the names that modules of ``paddle_tpu/`` outside
+    ``ops/pallas/`` import from it (one walk of the package for all cases)."""
+    import ast
+
+    here = _pallas_dir()
+    out = {}
+    for path in glob.glob(os.path.join(os.path.dirname(os.path.dirname(here)), "**", "*.py"),
+                          recursive=True):
+        if os.path.dirname(path) == here:
+            continue
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.ImportFrom) and ".pallas." in "." + (node.module or ""):
+                out.setdefault(node.module.rsplit(".", 1)[1], set()).update(
+                    a.name for a in node.names)
+    return out
+
+
+@pytest.mark.parametrize("filename", sorted(KERNEL_NAMES))
+def test_every_kernel_is_reached_from_outside_the_kernel_tier(filename):
+    """A kernel nothing calls is a kernel nobody runs (ROADMAP D5): every
+    ``pallas_call`` of a kernel file lies under a top-level function of that
+    file which a module of ``paddle_tpu/`` outside ``ops/pallas/`` imports by
+    name (``tests/test_environment_reads.py`` keeps that caller from hiding
+    behind an environment switch)."""
+    import ast
+
+    here = _pallas_dir()
+    stem = filename[:-3]
+    tree = ast.parse(open(os.path.join(here, filename)).read())
+    # what each top-level function names; ``f.defvjp(fwd, bwd)`` makes f name both
+    uses = {}
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef):
+            uses.setdefault(node.name, set()).update(
+                n.id for n in ast.walk(node) if isinstance(n, ast.Name))
+        elif (isinstance(node, ast.Expr) and isinstance(node.value, ast.Call)
+              and isinstance(node.value.func, ast.Attribute)
+              and node.value.func.attr == "defvjp"):
+            uses.setdefault(node.value.func.value.id, set()).update(
+                a.id for a in node.value.args)
+
+    def reaches(name, target, seen):
+        if name == target:
+            return True
+        seen.add(name)
+        return any(reaches(u, target, seen) for u in uses.get(name, set()) - seen if u in uses)
+
+    imported = _imported_from_kernel_tier().get(stem, set())
+    assert imported, f"nothing outside ops/pallas/ imports {filename}"
+    for call in _pallas_calls(tree):
+        kernel = {k.arg: k.value for k in call.keywords}["name"].value
+        home = next(n.name for n in tree.body if isinstance(n, ast.FunctionDef)
+                    and n.lineno <= call.lineno <= n.end_lineno)
+        entries = sorted(f for f in imported if reaches(f, home, set()))
+        assert entries, (f"{kernel} ({filename}:{call.lineno}, in {home}) is reached from "
+                         f"nothing that is imported outside ops/pallas/: {sorted(imported)}")
 
 
 def test_train_step_call_is_one_span_with_its_step_and_steps(host_spans):

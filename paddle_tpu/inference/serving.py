@@ -709,11 +709,13 @@ class ServingEngine:
         # positions the iterations' rows had to attend, and the positions
         # the dense paged attention read for them (ops/paged_attention.py
         # ``attention_positions``: read / live is what its tiles and context
-        # blocks round up)
+        # blocks round up), and the one-token rows among them that went
+        # through the ``paged_decode`` kernel (0 where the XLA pass ran)
         self.moe_tokens = 0
         self.moe_local_picks = 0
         self.attn_positions_live = 0
         self.attn_positions_read = 0
+        self.attn_rows_kernel = 0
         # prefill chunk size (ISSUE 19 satellite, first rung toward
         # Sarathi-style budget-adaptive chunking): tokens per prompt
         # chunk inside the mixed-phase scan.  Default = block_size (the
@@ -1382,6 +1384,7 @@ class ServingEngine:
             "attention": {
                 "positions_live": self.attn_positions_live,
                 "positions_read": self.attn_positions_read,
+                "rows_kernel": self.attn_rows_kernel,
             },
             # speculative-decode counters (ISSUE 19; same monotone
             # delta-fold contract as the megastep block above)

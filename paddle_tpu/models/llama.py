@@ -520,7 +520,8 @@ class LlamaForCausalLM(nn.Layer):
             jnp.float32)
 
     def serving_trunk(self, *, block_size, cache_quant="none"):
-        from ..ops.paged_attention import attention_positions, blha_attention
+        from ..ops.paged_attention import (attention_positions, blha_attention,
+                                           decodes_in_kernel)
 
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -582,12 +583,18 @@ class LlamaForCausalLM(nn.Layer):
                     hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
             with jax.named_scope("norm"):
                 hidden = rms(hidden, weights["norm"])
-            # what every layer's attention had to attend and what it read
-            # for that, once an iteration (the layers read alike)
-            live, read = attention_positions(
-                dec, now, block_size=bs, blocks_per_seq=bt.shape[1])
+            # what every layer's attention had to attend, what it read for
+            # that and how many one-token rows the kernel took, once an
+            # iteration (the layers read alike)
+            live, read, in_kernel = attention_positions(
+                dec, now, block_size=bs, blocks_per_seq=bt.shape[1],
+                kernel=decodes_in_kernel(
+                    hidden.dtype, key_caches[0].dtype, head_dim=D, block_size=bs,
+                    rows=bt.shape[0], blocks_per_seq=bt.shape[1],
+                    plain=quant == "none"))
             return hidden, (key_caches, value_caches), new_scales, {
-                "attn_positions_live": live, "attn_positions_read": read}
+                "attn_positions_live": live, "attn_positions_read": read,
+                "attn_rows_kernel": in_kernel}
 
         return trunk
 

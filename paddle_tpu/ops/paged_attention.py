@@ -11,36 +11,54 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
 - One step = (scatter this step's K/V into the pool) + (attention blocked
   over the context). Nothing grows with ``B x max_q_len x blocks_per_seq x
   bs``: the cost follows what the batch holds, not the table's shape.
-  The cache is read ``_CTX_BLOCK`` positions a pass (whole table columns),
-  contracted in the type it is stored in with float32 accumulation, through
-  an online softmax (float32 running max, sum and accumulator; the
-  bookkeeping is ``latent_attention._online``) whose trip count is DATA:
-  * rows that feed ONE token (decode rows, a prompt's one-token tail) are
+  Which rows take which path:
+  * rows that feed ONE token (decode rows, a prompt's one-token tail), where
+    the call is one ``decodes_in_kernel`` admits (the TPU, an unquantised
+    bfloat16 pool and queries of its type, no mask or pre-cache, heads of
+    whole lane tiles, blocks of whole sublane tiles): the Pallas kernel
+    ``ops/pallas/paged_decode.py``. It takes the pools where they lie in
+    HBM, brings a row's own context blocks to VMEM by the block table (one
+    asynchronous copy a block: the layout below makes a block's KV heads one
+    contiguous piece), double buffered, through one online softmax in
+    float32. No copy is gathered into HBM, a row makes the passes ITS length
+    needs and fetches no block past its end, and the step's own token is
+    read from the pool, where the write has just put it in the very value
+    the XLA pass attends from registers;
+  * the same rows of any other call (the CPU, a float32 or int8 cache,
+    masks, pre-caches): the blocked XLA pass. The cache is read
+    ``_CTX_BLOCK`` positions a pass (whole table columns), contracted in the
+    type it is stored in with float32 accumulation, through an online
+    softmax (float32 running max, sum and accumulator; the bookkeeping is
+    ``latent_attention._online``) whose trip count is DATA; the rows are
     ordered by context length and run in tiles of ``_ROW_TILE`` rows, each
     tile as many passes as its longest row needs, so a short row does not
     pay for the longest one and a (tile, context block) pair without a live
     position is never gathered;
   * rows that feed a CHUNK (``now > 1``: prompt chunks, the prefill step,
-    speculative drafts) run one at a time in a loop over the rows that
-    carry one, ``[max_q_len, H, D]`` queries against the row's own context;
-    rows without a chunk cost nothing;
-  * this step's own tokens are attended from registers as one trailing
-    block (causal inside a chunk), so the cache is read for ``[0, dec)``
-    only and an int8 cache's fresh tokens stay unquantised, as in the
-    reference kernel. An int8 block is gathered as its integers and the
-    scales are applied to the products, which is exact.
-  ``attention_positions`` counts what a call had to attend and what it read
-  for that; ``ServingEngine`` adds them up (``attn_positions_live`` /
-  ``_read``).
+    speculative drafts), in every call: the same XLA pass one row at a time
+    in a loop over the rows that carry one, ``[max_q_len, H, D]`` queries
+    against the row's own context; rows without a chunk cost nothing.
+  On the XLA pass this step's own tokens are attended from registers as one
+  trailing block (causal inside a chunk), so the cache is read for
+  ``[0, dec)`` only and an int8 cache's fresh tokens stay unquantised, as in
+  the reference kernel. An int8 block is gathered as its integers and the
+  scales are applied to the products, which is exact.
+  ``attention_positions`` counts what a call had to attend, what it read
+  for that and the rows the kernel took; ``ServingEngine`` adds them up
+  (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``).
 - Layouts: the pool is written by (block, kv head, slot) with a window of
   one head's ``D`` values, and gathered as rows of ``[num_blocks x KV, bs,
   D]``, so both sides keep the pool's own row-major layout. Written by
   (block, slot) with a ``[KV, D]`` window, the TPU compiler kept a second,
   transposed copy of every layer's pool in each program (3.2 GB at the
   benchmark's size; PERF.md section 6, PR 27).
-- A hand-written Pallas paged kernel with no gathered copy is the next step
-  (ROADMAP S3(c)); r4 had measured XLA's einsum decode path at 610-688 GB/s
-  against 299-366 for a Pallas small-M-dot kernel over a ring cache.
+- Why a kernel after all: r4 had measured a Pallas decode kernel at 299-366
+  GB/s against 610-688 for XLA's einsum, but over a static ring cache with
+  every position live and one query head a dot. Over the paged pool the XLA
+  pass must gather each context block into a copy that is written to HBM and
+  read back, and a tile rides its longest row: 120-190 GB/s of the bytes that
+  are live. The kernel measured 4 times faster than that pass (twelve layers
+  3.05 ms against 12.41 at serve1's ragged contexts; PERF.md section 6, PR 29).
 - Everything is static-shape: the query side is a packed token buffer
   ``[T, ...]`` (mixed prefill+decode chunks), the table ``blocks_per_seq``
   columns — both fixed by the serving engine, so admitting/retiring
@@ -58,13 +76,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..device import on_tpu
 from .latent_attention import _NEG, _online
+from .pallas.paged_decode import paged_decode
 
-__all__ = ["blha_attention", "attention_positions", "build_padding_metadata",
-           "rope_rotate"]
+__all__ = ["blha_attention", "attention_positions", "decodes_in_kernel",
+           "build_padding_metadata", "rope_rotate"]
 
 _CTX_BLOCK = 512    # cache positions a pass over the context reads
 _ROW_TILE = 8       # one-token rows that share a trip count
+_TABLE_WORDS = 1 << 17   # block-table entries the kernel holds in SMEM (half of it)
 
 
 def rope_rotate(x, cos, sin, neox: bool):
@@ -126,21 +147,49 @@ def _one_token_tiles(dec, now):
     return rows, cached >= 0, jnp.maximum(cached, 0)
 
 
+def decodes_in_kernel(q_dtype, cache_dtype, *, head_dim: int, block_size: int,
+                      rows: int, blocks_per_seq: int, plain: bool = True) -> bool:
+    """Whether a call's one-token rows attend through the Pallas kernel
+    (``ops/pallas/paged_decode.py``), decided from what the call shows and
+    nothing else: the platform is the TPU; the cache is an unquantised
+    bfloat16 pool (the 16-bit float Mosaic takes: it refuses float16) and the
+    queries are of its type; ``plain``: no mask, ``tgt_mask`` or pre-cache;
+    ``head_dim`` is whole 128-lane tiles and ``block_size`` whole sublane
+    tiles; the block table fits the kernel's scalar memory. Anything else
+    takes the blocked XLA pass."""
+    return (on_tpu() and plain
+            and jnp.dtype(cache_dtype) == jnp.bfloat16
+            and jnp.dtype(q_dtype) == jnp.bfloat16
+            and head_dim % 128 == 0 and block_size % 16 == 0
+            and rows * blocks_per_seq <= _TABLE_WORDS)
+
+
 def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
-                        block_size: int, blocks_per_seq: int):
+                        block_size: int, blocks_per_seq: int,
+                        kernel: bool = False):
     """What one ``blha_attention`` call with these lengths attends and what
-    it reads for that, as int32 scalars (live, read): ``live`` the context
-    of every row fed, ``dec + now``; ``read`` the cache positions gathered
-    (a tile of one-token rows reads its longest row's passes for all of its
-    ``_ROW_TILE`` rows, a chunk row its own) plus this step's own tokens.
-    The arithmetic is the loops': the same tiles, the same trip counts."""
+    it reads for that, as int32 scalars (live, read, rows_kernel): ``live``
+    the context of every row fed, ``dec + now``; ``read`` the cache positions
+    brought to the products plus this step's own tokens where they come from
+    registers; ``rows_kernel`` the one-token rows that went through the
+    kernel. The arithmetic is the loops'. On the XLA pass a tile of one-token
+    rows reads its longest row's passes for all of its ``_ROW_TILE`` rows and
+    a chunk row its own. With ``kernel`` (``decodes_in_kernel`` of the call) a
+    one-token row reads its own context, this step's token with it, rounded
+    up to a block: the kernel fetches no block past the row's end."""
     dec, now = seq_lens_decoder, seq_lens_this_time
     _, Lc = _context_block(block_size, blocks_per_seq)
     live = jnp.sum(jnp.where(now > 0, dec + now, 0))
-    _, _, cached = _one_token_tiles(dec, now)
-    read = (jnp.sum(_trips(jnp.max(cached, axis=1), Lc)) * _ROW_TILE
-            + jnp.sum(jnp.where(now > 1, _trips(dec, Lc), 0))) * Lc
-    return live.astype(jnp.int32), (read + jnp.sum(now)).astype(jnp.int32)
+    chunks = jnp.sum(jnp.where(now > 1, _trips(dec, Lc) * Lc + now, 0))
+    one = now == 1
+    if kernel:
+        ones = jnp.sum(jnp.where(one, _trips(dec + 1, block_size), 0)) * block_size
+    else:
+        _, _, cached = _one_token_tiles(dec, now)
+        ones = (jnp.sum(_trips(jnp.max(cached, axis=1), Lc)) * _ROW_TILE * Lc
+                + jnp.sum(one))
+    return (live.astype(jnp.int32), (ones + chunks).astype(jnp.int32),
+            jnp.sum(one & kernel).astype(jnp.int32))
 
 
 def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
@@ -245,45 +294,63 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
         _, l, acc = carry
         return acc / jnp.maximum(l, 1e-30)[..., None]
 
-    # ---- rows that feed one token: tiles of rows of like length ------------
+    # ---- rows that feed one token ------------------------------------------
     first = jnp.clip(cu[:-1], 0, T - 1)
     q1 = q[first].reshape(B, KV, g, D)
-    k1, v1 = k[first][:, :, None], v[first][:, :, None]            # [B, KV, 1, D]
 
-    rows_t, live_t, cached_t = _one_token_tiles(dec, now)
+    def one_token_tiles():
+        """Tiles of ``_ROW_TILE`` rows of like length, each as many passes as
+        its longest row needs; this step's token from registers."""
+        k1, v1 = k[first][:, :, None], v[first][:, :, None]        # [B, KV, 1, D]
+        rows_t, live_t, cached_t = _one_token_tiles(dec, now)
 
-    def tile(t, out):
-        rows, live, cached = rows_t[t], live_t[t], cached_t[t]
-        qt, ids = q1[rows], bt[rows]
-        kd, vd = scales(rows)
-        on = live[:, None, None, None]
-        b = None
-        if bias is not None:
-            b = bias[rows, :, 0]                                   # [R, 1|H, W]
-            b = b.reshape((_ROW_TILE,) + ((KV, g) if b.shape[1] == H else (1, 1))
-                          + b.shape[-1:])
-        carry = start(_ROW_TILE, KV, g)
-        if pre_k is not None:
-            carry = attend(carry, qt, pre_k[rows].astype(k.dtype),
-                           pre_v[rows].astype(v.dtype), on, cols(b, 0, pre_len))
+        def tile(t, out):
+            rows, live, cached = rows_t[t], live_t[t], cached_t[t]
+            qt, ids = q1[rows], bt[rows]
+            kd, vd = scales(rows)
+            on = live[:, None, None, None]
+            b = None
+            if bias is not None:
+                b = bias[rows, :, 0]                               # [R, 1|H, W]
+                b = b.reshape((_ROW_TILE,)
+                              + ((KV, g) if b.shape[1] == H else (1, 1))
+                              + b.shape[-1:])
+            carry = start(_ROW_TILE, KV, g)
+            if pre_k is not None:
+                carry = attend(carry, qt, pre_k[rows].astype(k.dtype),
+                               pre_v[rows].astype(v.dtype), on, cols(b, 0, pre_len))
 
-        def block(j, carry):
-            kb, vb = gather(jax.lax.dynamic_slice_in_dim(ids, j * per, per, axis=1))
-            vis = ((j * Lc + kpos)[None, :] < cached[:, None])[:, None, None, :]
-            return attend(carry, qt, kb, vb, vis, cols(b, pre_len + j * Lc, Lc),
-                          kd, vd)
+            def block(j, carry):
+                kb, vb = gather(
+                    jax.lax.dynamic_slice_in_dim(ids, j * per, per, axis=1))
+                vis = ((j * Lc + kpos)[None, :] < cached[:, None])[:, None, None, :]
+                return attend(carry, qt, kb, vb, vis,
+                              cols(b, pre_len + j * Lc, Lc), kd, vd)
 
-        carry = jax.lax.fori_loop(0, _trips(jnp.max(cached), Lc), block, carry)
-        bb = None if b is None else jnp.take_along_axis(
-            b, (pre_len + cached)[:, None, None, None], axis=-1)
-        o = finish(attend(carry, qt, k1[rows], v1[rows], on, bb))
-        return out.at[jnp.where(live, cu[rows], T + S)].set(
-            o.reshape(_ROW_TILE, H, D), mode="drop")
+            carry = jax.lax.fori_loop(0, _trips(jnp.max(cached), Lc), block, carry)
+            bb = None if b is None else jnp.take_along_axis(
+                b, (pre_len + cached)[:, None, None, None], axis=-1)
+            o = finish(attend(carry, qt, k1[rows], v1[rows], on, bb))
+            return out.at[jnp.where(live, cu[rows], T + S)].set(
+                o.reshape(_ROW_TILE, H, D), mode="drop")
 
-    # the order puts these rows first, so the tiles past them hold none
-    out = jax.lax.fori_loop(
-        0, _trips(jnp.sum(now == 1).astype(jnp.int32), _ROW_TILE), tile,
-        jnp.zeros((T + S, H, D), jnp.float32))
+        # the order puts these rows first, so the tiles past them hold none
+        return jax.lax.fori_loop(
+            0, _trips(jnp.sum(now == 1).astype(jnp.int32), _ROW_TILE), tile,
+            jnp.zeros((T + S, H, D), jnp.float32))
+
+    if decodes_in_kernel(q.dtype, key_cache.dtype, head_dim=D, block_size=bs,
+                         rows=B, blocks_per_seq=P,
+                         plain=not quant and bias is None and pre_k is None):
+        # straight from the pool, which the write has already given this
+        # step's token in the value the XLA pass attends from registers
+        # (``fresh_dt``): positions [0, dec], no gathered copy, a row's own trips
+        o = paged_decode(q1, key_cache, value_cache,
+                         jnp.where(now == 1, dec + 1, 0), block_tables, scale=scale)
+        out = jnp.zeros((T + S, H, D), jnp.float32).at[
+            jnp.where(now == 1, cu[:-1], T + S)].set(o.reshape(B, H, D), mode="drop")
+    else:
+        out = one_token_tiles()
     if S == 1:
         return out[:T]
 
@@ -407,7 +474,9 @@ def blha_attention(
     (``while/body/``) ``kv_gather`` (a block's gather, an int8 block's
     integers), ``scores`` (QK^T, masks, the online softmax's bookkeeping),
     ``values`` (PV); what is under none is unpacking, token coordinates and
-    the return to the packed buffer.
+    the return to the packed buffer. Where the one-token rows go through
+    the kernel they are in none of the three: a device trace shows the
+    custom call by its name, ``paged_decode``.
     """
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]

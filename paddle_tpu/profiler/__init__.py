@@ -17,15 +17,18 @@ Host spans, every one a ``RecordEvent`` on the profiler's clock:
 (stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``, and
 on a mixed launch ``prefill_rows``, the rows it feeds prompt chunks),
 ``engine.wait``, ``engine.harvest`` (stats: what the model's trunk counted
-in the launch, ``attn_positions_live`` / ``attn_positions_read`` for a dense
-paged cache, ``moe_tokens`` / ``moe_local_picks`` for expert layers);
+in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
+``attn_rows_kernel`` for a dense paged cache, ``moe_tokens`` /
+``moe_local_picks`` for expert layers);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
 at run time), one vocabulary for every model family:
 ``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write`` and,
 under ``while/body/`` once for each loop around them (row tiles or chunk
-rows, then context blocks), ``kv_gather`` ``scores`` ``values``; ``attn_out``,
+rows, then context blocks), ``kv_gather`` ``scores`` ``values`` (on the
+chip a one-token row is in none of the three: it attends inside the
+``paged_decode`` kernel); ``attn_out``,
 ``mlp``, ``norm``, ``head``, ``sample``, ``scan_carry`` (the serving
 programs); ``attention`` >
 ``flash_attention``, ``loss``, ``optimizer``, ``grad_unscale`` (the train
@@ -35,7 +38,7 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
 ``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
-``int8_matmul``.
+``int8_matmul``, ``paged_decode``.
 """
 from __future__ import annotations
 

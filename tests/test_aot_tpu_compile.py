@@ -118,17 +118,23 @@ def test_int8_matmul(chip, m, monkeypatch):
 
 
 @pytest.mark.parametrize("mq", [1, 64, 256], ids=["decode", "chunk64", "prefill256"])
-def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq):
+def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch):
     """The dense paged attention at the benchmark's serving geometry
     (mistral-7b-v0.3.serve1: 32 rows, tables of 40 blocks of 64, 8 kv heads
     of 128, a pool of 1024 blocks), two iterations in a scan with the pool
-    in the carry as the engine's scans hold it. XLA, no kernel; what the
-    chip's compiler must not do is what it did before ISSUE 27: keep a
-    second copy of the pool in another layout (the write and the gather
-    must agree on one), or build anything as large as every row's whole
+    in the carry as the engine's scans hold it, compiled as the chip will
+    run it: whether a row feeds one token is data, so every one of the three
+    holds the one-token rows' ``paged_decode`` kernel (the chunk rows' pass
+    is XLA). What the chip's compiler must not do is what it did before
+    ISSUE 27: keep a second copy of the pool in another layout (the write,
+    the gather and the kernel's operand must agree on one, or 268 MB a layer
+    are copied in and out), or build anything as large as every row's whole
     table (168 MB in bf16)."""
-    from paddle_tpu.ops.paged_attention import blha_attention
+    from paddle_tpu.ops import paged_attention as pa
 
+    # a described-device compile still sees the CPU as the default backend
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    blha_attention = pa.blha_attention.__wrapped__     # no trace made for the CPU
     B, P, bs, H, KV, D, nb = 32, 40, 64, 32, 8, 128, 1024
     T = B if mq == 1 else 256
 
@@ -151,12 +157,19 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq):
         sds((T, (H + 2 * KV) * D), BF16), pool, pool, sds((B,), i32), sds((B,), i32),
         sds((B + 1,), i32), sds((B, P), i32),
         sds((2, 1, P * bs, 1, D // 2), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%paged_decode(\.\d+)? = [^\n]* custom-call\(", text)
     pool_bytes = nb * KV * bs * D * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"
+    # one layout of the pool, the argument's row-major order, on the
+    # parameter, the write, the kernel's operand and the gather alike
+    orders = set(re.findall(r"bf16\[1024,8,64,128\]\{([0-9,]+)", text))
+    assert orders == {"3,2,1,0"}, orders
+    assert not re.search(r"= bf16\[1024,8,64,128\][^\n]* copy\(", text)
     whole = B * KV * P * bs * D
     views_of_the_pool = {(nb, KV, bs, D), (nb * KV, bs, D), (nb * KV * bs, D)}
     shapes = {tuple(int(d) for d in dims.split(","))
-              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", compiled.as_text())}
+              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text)}
     big = [s for s in shapes - views_of_the_pool if math.prod(s) >= whole]
     assert not big, big

@@ -900,3 +900,110 @@ class TestNamesSpansAndPhases:
             range(launches[0]["launch"], launches[0]["launch"] + len(launches)))
         assert all(l["k"] == (1 if l["kind"] == "step" else 4) for l in launches)
         assert all(l["t_mono"] == 5.0 for l in launches)
+
+
+def _harvest_counts(eng):
+    """[(kind of the launch, what its ``engine.harvest`` span carries)], filled
+    as the engine runs."""
+    seen, kinds = [], []
+    launch, phase = eng._launch_phase, eng._phase
+
+    def launched(kind, *a, **kw):
+        kinds.append(kind)
+        return launch(kind, *a, **kw)
+
+    def entered(name, **attrs):
+        if name == "harvest":
+            seen.append((kinds[-1], attrs, eng.attn_rows_kernel))
+        return phase(name, **attrs)
+
+    eng._launch_phase, eng._phase = launched, entered
+    return seen
+
+
+@pytest.fixture(scope="module")
+def cpu_harvests(model):
+    eng = ServingEngine(model, megastep_k=4, spec_k=2, **ENGINE)
+    seen = _harvest_counts(eng)
+    _drive_all_programs(eng)
+    return eng, seen
+
+
+class TestRowsThroughTheKernel:
+    """``attn_rows_kernel``: the one-token rows an iteration sent through the
+    ``paged_decode`` kernel, counted by the trunk beside the two position
+    counts, added up by the engine, on the harvest span and in the summary."""
+
+    @pytest.mark.parametrize("kind", sorted(PROGRAMS))
+    def test_every_program_carries_it_and_it_is_zero_on_the_cpu(self, cpu_harvests, kind):
+        eng, seen = cpu_harvests
+        mine = [(a, total) for k, a, total in seen if k == kind]
+        assert mine, f"no {kind} launch"
+        for attrs, _ in mine:
+            assert set(attrs) == {"attn_positions_live", "attn_positions_read",
+                                  "attn_rows_kernel"}
+            assert attrs["attn_rows_kernel"] == 0 < attrs["attn_positions_live"]
+        assert eng.attn_rows_kernel == 0
+        assert eng.state_summary()["attention"] == {
+            "positions_live": eng.attn_positions_live,
+            "positions_read": eng.attn_positions_read, "rows_kernel": 0}
+
+    def test_an_engine_steered_onto_the_chip_counts_its_decoding_rows(self, monkeypatch):
+        """A bf16 model with heads of 128 and blocks of 16 is a call the
+        kernel admits; with ``on_tpu`` answering yes (and the kernel in
+        interpret mode) every decoding row of every scan iteration goes
+        through it: the counter is monotone, equals the rows decoded, the
+        kernel path reads less than the XLA pass's tiles, and the tokens are
+        the XLA pass's."""
+        import functools
+
+        import jax
+
+        from paddle_tpu.distributed.topology import set_hybrid_communicate_group
+        from paddle_tpu.inference import serving
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.ops import paged_attention as pa
+        from paddle_tpu.ops.pallas import paged_decode as pd
+
+        set_hybrid_communicate_group(None)
+        P.seed(5)
+        net = LlamaForCausalLM(LlamaConfig(
+            vocab_size=128, hidden_size=256, intermediate_size=256,
+            num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=1,
+            max_position_embeddings=128, dtype="bfloat16"))
+        net.bfloat16()
+        net.eval()
+        geometry = dict(max_batch_size=2, max_seq_len=96, block_size=16,
+                        token_budget=32, megastep_k=4, spec_k=0)
+        prompts = [[3, 17, 101, 7, 9], list(range(40, 62))]
+
+        def run():
+            eng = ServingEngine(net, **geometry)
+            seen = _harvest_counts(eng)
+            rids = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+            out = eng.run()
+            return eng, seen, [out[r] for r in rids]
+
+        plain, _, want = run()
+        assert plain.attn_rows_kernel == 0
+        # the platform is asked when a program is traced: drop the traces
+        # made for the CPU, and those made here once the test is over
+        monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
+        monkeypatch.setattr(pa, "on_tpu", lambda: True)
+        monkeypatch.setattr(pa, "paged_decode",
+                            functools.partial(pd.paged_decode, interpret=True))
+        jax.clear_caches()
+        try:
+            eng, seen, got = run()
+        finally:
+            jax.clear_caches()
+        assert got == want
+        totals = [t for _, _, t in seen]
+        assert totals == sorted(totals) and eng.attn_rows_kernel > 0
+        assert eng.attn_rows_kernel == sum(a["attn_rows_kernel"] for _, a, _ in seen)
+        # both prompts in one prefill step (chunk rows: the XLA pass), then
+        # each row decodes its other 8 tokens a row a scan iteration
+        assert eng.attn_rows_kernel == 2 * 8
+        assert eng.attn_positions_live == plain.attn_positions_live
+        assert eng.attn_positions_read < plain.attn_positions_read
+        assert eng.state_summary()["attention"]["rows_kernel"] == 16

@@ -590,3 +590,262 @@ def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes
                                np.asarray(want).reshape(g["T"], H * D),
                                rtol=tol, atol=tol)
     assert not np.asarray(out[g["total"]:], np.float32).any()
+
+
+# ------------------------------------------------ the one-token rows' kernel
+# On the chip the rows that feed ONE token attend through
+# ``ops/pallas/paged_decode.py`` when the call is one the kernel's conditions
+# admit (``pa.decodes_in_kernel``). Here the platform is the CPU, so the tests
+# steer ``on_tpu`` and run the kernel in interpret mode; the reference is the
+# padded form above. Blocks of 16 in a table of 24, heads of 128: a pass is
+# 8 blocks, 128 positions.
+import functools                 # noqa: E402
+
+from paddle_tpu.ops.pallas import paged_decode as pd   # noqa: E402
+
+K_BS, K_P, K_D, K_KV = 16, 24, 128, 2
+K_PASS = pd.BLOCKS_PER_PASS * K_BS
+K_L = K_BS * K_P
+
+KERNEL_LAYOUTS = {
+    # lengths (dec + 1) of 1, a block less one, a block, a block and one, a
+    # pass, a pass and one, two passes and the whole table; a context that
+    # ends inside a block; empty slots first, between and last; out of order
+    "decode_edges": (1, [(0, 0), (K_PASS - 1, 1), (0, 1), (K_BS - 2, 1), (0, 0),
+                         (K_L - 1, 1), (K_BS - 1, 1), (K_BS, 1), (K_PASS, 1),
+                         (2 * K_PASS - 1, 1), (37, 1), (0, 0)]),
+    # what a mixed scan feeds: only some rows feed one token; the chunk rows
+    # (and a chunk's one-token tail, which is a one-token row) ride beside
+    "mixed": (8, [(200, 1), (K_PASS + 60, 1), (0, 8), (0, 0), (33, 1),
+                  (K_PASS - 4, 8), (7, 1), (130, 3), (K_PASS, 1), (40, 1)]),
+    # no row feeds one token: the kernel makes no pass at all
+    "chunks_only": (4, [(20, 4), (K_PASS + 1, 4), (0, 0), (3, 2)]),
+}
+
+
+def _kernel_case(layout, group, holes, seed=0):
+    S, rows = KERNEL_LAYOUTS[layout]
+    rng = np.random.RandomState(seed + len(layout))
+    B, KV, D, bs, P = len(rows), K_KV, K_D, K_BS, K_P
+    H = KV * group
+    dec = np.array([r[0] for r in rows], np.int32)
+    now = np.array([r[1] for r in rows], np.int32)
+    enc = np.where(now > 1, now, 0).astype(np.int32)
+    cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+    T = int(cu[-1]) + 3
+    nb = B * P
+    bt = rng.permutation(nb).reshape(B, P).astype(np.int32)
+    need = -(-(dec + now) // bs)
+    for b in range(B):
+        bt[b, need[b]:] = -1
+    if holes:        # inside what a one-token row holds, but not its own token's block
+        one = [b for b in range(B) if now[b] == 1 and need[b] >= 3]
+        bt[one[0], 1] = -1
+        bt[one[1], 0] = nb + 7
+    bf16 = jnp.bfloat16
+    qkv = jnp.asarray(rng.uniform(-1, 1, (T, (H + 2 * KV) * D)), bf16)
+    kc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), bf16)
+    vc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), bf16)
+    args = (qkv, kc, vc, jnp.asarray(enc), jnp.asarray(dec), jnp.asarray(now),
+            jnp.asarray(cu), jnp.asarray(bt))
+    return args, dict(H=H, KV=KV, D=D, bs=bs, S=S, T=T, total=int(cu[-1]))
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """``blha_attention`` as the chip traces it: ``on_tpu`` answers yes, and
+    the kernel it then calls runs in interpret mode. Returns a fresh jit of
+    the undecorated function, so that no trace of another platform is met."""
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "paged_decode",
+                        functools.partial(pd.paged_decode, interpret=True))
+
+    def call(*args, **kw):
+        statics = {n: kw.pop(n) for n in list(kw) if not hasattr(kw[n], "shape")}
+        return jax.jit(functools.partial(pa.blha_attention.__wrapped__, **statics))(
+            *args, **kw)
+    return call
+
+
+def _kp(layout, group=4, holes=False):
+    return pytest.param(layout, group, holes,
+                        id=f"{layout}-g{group}" + ("-holes" if holes else ""))
+
+
+@pytest.mark.parametrize("layout,group,holes", (
+    [_kp(lay, g) for lay in KERNEL_LAYOUTS for g in (1, 4, 8)]
+    + [_kp("decode_edges", holes=True), _kp("mixed", 8, holes=True)]))
+def test_kernel_rows_match_the_padded_form(on_chip, layout, group, holes):
+    args, g = _kernel_case(layout, group, holes)
+    qkv, _, _, enc, dec, now, cu, bt = args
+    seen = []
+    inner = pa.paged_decode
+    pa.paged_decode = lambda *a, **k: (jax.debug.callback(seen.append, a[3]),
+                                       inner(*a, **k))[1]        # on_chip restores
+    outs = on_chip(*args, num_heads=g["H"], kv_num_heads=g["KV"], head_dim=g["D"],
+                   block_size=g["bs"], max_q_len=g["S"], compute_dtype=jnp.bfloat16)
+    # the kernel was given the one-token rows' lengths and nothing of the others
+    jax.effects_barrier()
+    assert len(seen) == 1
+    np.testing.assert_array_equal(np.asarray(seen[0]),
+                                  np.where(np.asarray(now) == 1, np.asarray(dec) + 1, 0))
+    out, kc, vc = outs[0], outs[1], outs[2]
+    H, KV, D = g["H"], g["KV"], g["D"]
+    f = qkv.astype(jnp.float32)
+    want = padded_reference(
+        f[:, :H * D].reshape(-1, H, D), f[:, H * D:(H + KV) * D].reshape(-1, KV, D),
+        f[:, (H + KV) * D:].reshape(-1, KV, D), kc, vc, enc, dec, now, cu, bt, S=g["S"])
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want).reshape(g["T"], H * D),
+                               rtol=5e-3, atol=5e-3)
+    assert not np.asarray(out[g["total"]:], np.float32).any()
+
+
+@pytest.mark.parametrize("per", [1, 3, 8], ids=lambda n: f"pass{n}")
+def test_kernel_alone_holds_the_probabilities_to_two_terms(per):
+    """The kernel's own float32 output against float64 arithmetic on the same
+    bf16 values: scores are exact products, and ``p_hi + p_lo`` keeps 16 bits
+    of a probability (a single bf16 term would err by 2e-3 here). A pass of
+    one block, of three (the table is not whole passes) and of eight."""
+    rng = np.random.RandomState(per)
+    B, KV, g, D, bs, P, nb = 5, 2, 4, K_D, K_BS, 7, 40
+    q = jnp.asarray(rng.uniform(-1, 1, (B, KV, g, D)), jnp.bfloat16)
+    kc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), jnp.bfloat16)
+    vc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), jnp.bfloat16)
+    lens = np.array([0, 70, 1, P * bs, 33], np.int32)
+    bt = rng.permutation(nb)[:B * P].reshape(B, P).astype(np.int32)
+    bt[1, 2] = -1
+    out = np.asarray(pd.paged_decode(q, kc, vc, jnp.asarray(lens), jnp.asarray(bt),
+                                     scale=D ** -0.5, blocks_per_pass=per,
+                                     interpret=True))
+    assert out.shape == (B, KV, g, D) and out.dtype == np.float32
+    for b in range(B):
+        n = lens[b]
+        if n == 0:
+            assert not out[b].any()
+            continue
+        def dense(c):
+            blocks = [np.asarray(c[i], np.float64) if i >= 0 else np.zeros((KV, bs, D))
+                      for i in bt[b]]
+            return np.stack(blocks, 1).reshape(KV, P * bs, D)[:, :n]
+        s = np.einsum("kgd,kld->kgl", np.asarray(q[b], np.float64), dense(kc)) * D ** -0.5
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("kgl,kld->kgd", p / p.sum(-1, keepdims=True), dense(vc))
+        np.testing.assert_allclose(out[b], want, rtol=0, atol=2e-5)
+
+
+def _lowered(args, g, scopes=False, **kw):
+    f = functools.partial(pa.blha_attention.__wrapped__, num_heads=g["H"],
+                          kv_num_heads=g["KV"], head_dim=g["D"], block_size=g["bs"],
+                          max_q_len=g["S"], **{n: v for n, v in kw.items()
+                                               if not hasattr(v, "shape")})
+    arrays = {n: v for n, v in kw.items() if hasattr(v, "shape")}
+    return jax.jit(f).lower(*args, **arrays).as_text(debug_info=scopes)
+
+
+def _outside_cases():
+    def admitted():
+        return _kernel_case("mixed", 4, False)
+
+    def float32_cache():
+        (qkv, kc, vc, *rest), g = admitted()
+        return (qkv, kc.astype(jnp.float32), vc.astype(jnp.float32), *rest), g, {}
+
+    def float32_queries():
+        args, g = admitted()
+        return args, g, {"compute_dtype": jnp.float32}
+
+    def int8_cache():
+        (qkv, kc, vc, *rest), g = admitted()
+        scales = {f"cache_{n}_{kind}_scales": jnp.ones((g["KV"],), jnp.float32)
+                  for n in "kv" for kind in ("quant", "dequant")}
+        return ((qkv, kc.astype(jnp.uint8), vc.astype(jnp.uint8), *rest), g,
+                dict(scales, cache_quant="static"))
+
+    def with_mask():
+        args, g = admitted()
+        return args, g, {"tgt_mask": jnp.zeros((len(args[4]), 1, 1, K_L), jnp.float32)}
+
+    def encoder_mask():
+        args, g = admitted()
+        return args, g, {"mask": jnp.zeros((len(args[4]), 1, g["S"], K_L), jnp.float32)}
+
+    def pre_cache():
+        args, g = admitted()
+        pre = jnp.zeros((len(args[4]), g["KV"], 5, g["D"]), jnp.bfloat16)
+        return args, g, {"pre_key_cache": pre, "pre_value_cache": pre}
+
+    def narrow_heads():      # head_dim 64: half a lane tile
+        args, kw, g = _case("mixed", 4, "bfloat16", "none", False, False, False)
+        return args, g, {}
+
+    return [float32_cache, float32_queries, int8_cache, with_mask, encoder_mask,
+            pre_cache, narrow_heads]
+
+
+@pytest.mark.parametrize("make", _outside_cases(), ids=lambda f: f.__name__)
+def test_a_call_outside_the_kernels_conditions_takes_the_xla_pass(monkeypatch, make):
+    """Steered onto the chip or not, such a call lowers to one and the same
+    text, the blocked XLA pass's, with no custom call in it (at PR 29 that
+    text was the parent commit's byte for byte, compared by hand)."""
+    args, g, kw = make()
+    kw.setdefault("compute_dtype", jnp.bfloat16)
+    here = _lowered(args, g, **kw)
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    assert _lowered(args, g, **kw) == here
+    assert "custom_call" not in here
+    assert "kv_gather" in _lowered(args, g, scopes=True, **kw)
+
+
+def test_the_platform_chooses_between_the_kernel_and_the_xla_pass(monkeypatch):
+    """The same admitted call: on the CPU the XLA pass; where ``on_tpu``
+    answers yes, lowered for the TPU, the ``paged_decode`` custom call, and
+    at ``max_q_len`` 1 no gather at all."""
+    args, g = _kernel_case("decode_edges", 4, False)
+    kw = dict(compute_dtype=jnp.bfloat16)
+    here = _lowered(args, g, scopes=True, **kw)
+    assert "tpu_custom_call" not in here and "kv_gather" in here
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    f = functools.partial(pa.blha_attention.__wrapped__, num_heads=g["H"],
+                          kv_num_heads=g["KV"], head_dim=g["D"], block_size=g["bs"],
+                          max_q_len=g["S"], **kw)
+    there = jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
+    assert "tpu_custom_call" in there and "paged_decode" in there
+    assert "kv_gather" not in there
+
+
+def test_attention_positions_by_hand_on_both_paths():
+    """Blocks of 16 in a table of 64: the XLA pass reads 512 positions a pass
+    for the 8 rows of a tile and this step's token from registers; the kernel
+    reads a one-token row's own context, its token with it, to a block."""
+    def count(rows, kernel):
+        dec, now = (jnp.asarray(x, jnp.int32) for x in zip(*rows))
+        return tuple(int(n) for n in pa.attention_positions(
+            dec, now, block_size=16, blocks_per_seq=64, kernel=kernel))
+
+    rows = [(600, 1), (10, 1), (0, 0), (15, 1), (16, 1), (520, 4)]
+    live = 601 + 11 + 16 + 17 + 524
+    # one tile of the four one-token rows, the longest of 600: two passes for
+    # eight rows, and four tokens from registers; the chunk row its own two
+    assert count(rows, False) == (live, 2 * 512 * 8 + 4 + 2 * 512 + 4, 0)
+    # lengths 601, 11, 16, 17 to blocks of 16: 608 + 16 + 16 + 32; the chunk
+    # row is the XLA pass's on both paths
+    assert count(rows, True) == (live, 608 + 16 + 16 + 32 + 2 * 512 + 4, 4)
+    assert count([(0, 0), (0, 7)], True) == (7, 7, 0)
+
+
+def test_decodes_in_kernel_asks_the_call_and_the_platform_only(monkeypatch):
+    ask = functools.partial(pa.decodes_in_kernel, head_dim=128, block_size=64,
+                            rows=32, blocks_per_seq=40)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert not ask(bf16, bf16)                       # the CPU
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    assert ask(bf16, bf16)
+    assert not ask(bf16, bf16, plain=False)
+    assert not ask(f32, bf16) and not ask(bf16, f32) and not ask(bf16, jnp.uint8)
+    assert not ask(jnp.float16, jnp.float16)         # Mosaic refuses float16 tiles
+    for odd in (dict(head_dim=64), dict(head_dim=192), dict(block_size=8),
+                dict(rows=4096, blocks_per_seq=64)):
+        assert not pa.decodes_in_kernel(bf16, bf16, **{
+            **dict(head_dim=128, block_size=64, rows=32, blocks_per_seq=40), **odd})

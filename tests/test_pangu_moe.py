@@ -502,7 +502,8 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     # ... and nothing of the dense attention's, which this trunk does not run
     seen = [a for _, a in harvests]
     assert seen and all(set(a) == {"moe_tokens", "moe_local_picks"} for a in seen)
-    assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0}
+    assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0,
+                                                "rows_kernel": 0}
     assert sum(a["moe_tokens"] for a in seen) == eng.moe_tokens
     assert sum(a["moe_local_picks"] for a in seen) == eng.moe_local_picks
 
@@ -513,8 +514,10 @@ def test_attention_positions_of_a_two_row_example():
 
     def count(rows):
         dec, now = (jnp.asarray(x, jnp.int32) for x in zip(*rows))
-        return tuple(int(n) for n in attention_positions(dec, now, block_size=8,
-                                                         blocks_per_seq=80))
+        live, read, in_kernel = (int(n) for n in attention_positions(
+            dec, now, block_size=8, blocks_per_seq=80))
+        assert in_kernel == 0              # the XLA pass (the kernel's count:
+        return live, read                  # tests/test_paged_attention.py)
 
     # two decoding rows in one tile: the longer one's two passes for all 8 rows
     assert count([(600, 1), (10, 1)]) == (601 + 11, 2 * 512 * 8 + 2)
@@ -546,10 +549,13 @@ def test_attention_counters_are_monotone_and_ride_the_harvest_span():
     assert eng.attn_positions_read >= eng.attn_positions_live > 0
     assert eng.state_summary()["attention"] == {
         "positions_live": eng.attn_positions_live,
-        "positions_read": eng.attn_positions_read}
+        "positions_read": eng.attn_positions_read,
+        "rows_kernel": 0}                  # the CPU: every row took the XLA pass
     seen = [a for _, a in harvests]
     assert len(seen) == 2
-    assert all(set(a) == {"attn_positions_live", "attn_positions_read"} for a in seen)
+    assert all(set(a) == {"attn_positions_live", "attn_positions_read",
+                          "attn_rows_kernel"} for a in seen)
+    assert eng.attn_rows_kernel == sum(a["attn_rows_kernel"] for a in seen) == 0
     assert sum(a["attn_positions_live"] for a in seen) == eng.attn_positions_live
     assert sum(a["attn_positions_read"] for a in seen) == eng.attn_positions_read
 

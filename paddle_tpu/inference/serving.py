@@ -616,7 +616,7 @@ class ServingEngine:
         spec = model.serving_cache_spec()
         self.cache_spec = spec
         self.KV, self.D = spec.kv_heads, spec.head_dim   # None: no per-head cache
-        self.L = spec.layers
+        self.L = spec.layers      # CACHE layers: a looped model's are not its weights'
         if cache_quant not in ("none", "int8"):
             raise ValueError("cache_quant must be 'none' or 'int8'")
         if cache_quant == "int8" and not spec.quantizable:
@@ -664,12 +664,20 @@ class ServingEngine:
         self.weights_version = "v0"
         self.model_id = "default"
         self._rope = model.serving_rope(self.max_seq_len)
-        # one list (a layer each) for every array the model's layers keep:
-        # (keys, values) [nb, KV, bs, D] for a per-head cache, (latent,)
-        # [nb, bs, W] for a latent one
-        self.caches = tuple(
-            [jnp.zeros((nb,) + tuple(shape(self.bs)), cache_dtype)
-             for _ in range(self.L)] for _, shape in spec.arrays)
+        # for every array the model's cache layers keep, a list (a layer
+        # each), or ONE array with a leading layer axis where the model's
+        # layers are a loop in its program (``spec.stacked``): (keys, values)
+        # [nb, KV, bs, D] for a per-head cache, (latent,) [nb, bs, W] for a
+        # latent one
+        self._cache_dtype = str(jnp.dtype(cache_dtype))
+
+        def pool(shape):
+            block = (nb,) + tuple(shape(self.bs))
+            if spec.stacked:
+                return jnp.zeros((self.L,) + block, cache_dtype)
+            return [jnp.zeros(block, cache_dtype) for _ in range(self.L)]
+
+        self.caches = tuple(pool(shape) for _, shape in spec.arrays)
         if cache_quant == "int8":
             self.cache_scales = [
                 {k: jnp.zeros((self.B, self.KV), jnp.float32)
@@ -716,6 +724,11 @@ class ServingEngine:
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
+        # a model that runs its layers in several passes over the same
+        # weights: tokens fed to its trunk, and tokens x passes run (``passes``
+        # times the first until a token is ever let out of a pass)
+        self.loop_tokens = 0
+        self.loop_token_passes = 0
         # prefill chunk size (ISSUE 19 satellite, first rung toward
         # Sarathi-style budget-adaptive chunking): tokens per prompt
         # chunk inside the mixed-phase scan.  Default = block_size (the
@@ -791,7 +804,8 @@ class ServingEngine:
 
     @property
     def key_caches(self):
-        """A per-head cache's keys, a layer each (``caches[0]``)."""
+        """A per-head cache's keys (``caches[0]``): a layer each, or
+        ``[layers, ...]`` where the pool is stacked."""
         return self.caches[0]
 
     @key_caches.setter
@@ -1215,14 +1229,21 @@ class ServingEngine:
         shared original stays read-only for its other owners)."""
         if self._cow_fn is None:
             if "cow" not in self._programs:
+                at = self._block_index
+
                 def cow(caches, s, d):
-                    return tuple([c.at[d].set(c[s]) for c in cs]
-                                 for cs in caches)
+                    return jax.tree_util.tree_map(
+                        lambda c: c.at[at(d)].set(c[at(s)]), caches)
                 # s/d are data, not static: one compiled copy program total
                 self._programs["cow"] = jax.jit(cow, donate_argnums=(0,))
             self._cow_fn = self._programs["cow"]
         self.caches = self._cow_fn(
             self.caches, jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+
+    def _block_index(self, b):
+        """Where block(s) ``b`` lie in a pool array: behind the layer axis
+        of a stacked pool."""
+        return (slice(None), b) if self.cache_spec.stacked else b
 
     def _try_admit(self):
         while self._queue and self._free_slots:
@@ -1386,6 +1407,12 @@ class ServingEngine:
                 "positions_read": self.attn_positions_read,
                 "rows_kernel": self.attn_rows_kernel,
             },
+            # a looped model (monotone; zero for a model of one pass)
+            "loop": {
+                "passes": self.cache_spec.passes,
+                "tokens": self.loop_tokens,
+                "token_passes": self.loop_token_passes,
+            },
             # speculative-decode counters (ISSUE 19; same monotone
             # delta-fold contract as the megastep block above)
             "spec": {
@@ -1494,10 +1521,13 @@ class ServingEngine:
         ``kind``; ``launch`` counts launches (the ``FlightRecorder``'s
         ``megastep`` events carry it too) and ``t_mono`` is this engine's
         clock, so that a recorder event's ``t`` can be placed on the trace.
-        A mixed launch adds ``prefill_rows``, the rows it feeds chunks."""
+        ``passes``: how often an iteration runs the model's layers (1 but for
+        a looped model).  A mixed launch adds ``prefill_rows``, the rows it
+        feeds chunks."""
         self.launches += 1
         return self._phase("launch", kind=kind, k=k, launch=self.launches,
-                           t_mono=self._clock(), **attrs)
+                           t_mono=self._clock(), passes=self.cache_spec.passes,
+                           **attrs)
 
     def step(self) -> Dict[int, List[int]]:
         """One engine iteration: schedule -> compiled step(s) -> retire.
@@ -2301,14 +2331,16 @@ class ServingEngine:
             ids.append(int(b))
         header = {"block_size": self.bs, "layers": self.L,
                   "kv_heads": self.KV, "head_dim": self.D,
-                  "dtype": str(self.key_caches[0].dtype), "hashes": held,
+                  "dtype": self._cache_dtype, "hashes": held,
                   "shape": [2, self.L, len(held), self.KV, self.bs, self.D]}
         if not held:
             return header, b""
         if "gather" not in self._programs:
+            stacked = self.cache_spec.stacked
+
             def gather(kcs, vcs, bids):
-                k = jnp.stack([kc[bids] for kc in kcs])
-                v = jnp.stack([vc[bids] for vc in vcs])
+                k = kcs[:, bids] if stacked else jnp.stack([kc[bids] for kc in kcs])
+                v = vcs[:, bids] if stacked else jnp.stack([vc[bids] for vc in vcs])
                 return jnp.stack([k, v])   # [2, L, n, KV, bs, D]
             self._programs["gather"] = jax.jit(gather)
         packed = self._programs["gather"](self.key_caches,
@@ -2351,7 +2383,7 @@ class ServingEngine:
                 payload.get("kv_heads"), payload.get("head_dim"),
                 payload.get("dtype"))
         want = (self.bs, self.L, self.KV, self.D,
-                str(self.key_caches[0].dtype))
+                self._cache_dtype)
         if geom != want:
             raise ValueError(
                 f"import_blocks: payload geometry {geom} does not match "
@@ -2383,7 +2415,7 @@ class ServingEngine:
                 header.get("kv_heads"), header.get("head_dim"),
                 header.get("dtype"))
         want = (self.bs, self.L, self.KV, self.D,
-                str(self.key_caches[0].dtype))
+                self._cache_dtype)
         if geom != want:
             raise ValueError(
                 f"import_blocks_packed: payload geometry {geom} does not "
@@ -2445,7 +2477,12 @@ class ServingEngine:
         id is data, so one compiled write program serves every import)."""
         if self._put_fn is None:
             if "put" not in self._programs:
+                stacked = self.cache_spec.stacked
+
                 def put(kcs, vcs, d, ks, vs):
+                    if stacked:
+                        return (kcs.at[:, d].set(jnp.stack(ks)),
+                                vcs.at[:, d].set(jnp.stack(vs)))
                     kcs = [kc.at[d].set(k) for kc, k in zip(kcs, ks)]
                     vcs = [vc.at[d].set(v) for vc, v in zip(vcs, vs)]
                     return kcs, vcs

@@ -6,20 +6,30 @@ sampling, and asks the model three questions:
     the weight pytree the compiled programs take as an argument.  It has a
     ``"head"`` leaf ``[hidden, vocab]``: the engine heads the rows it samples.
 ``serving_cache_spec()``
-    a :class:`CacheSpec`: which arrays a layer keeps in the block pool and
-    the shape of one block of each, so the engine can allocate the pool,
-    copy a block (COW) and key its compiled programs.
+    a :class:`CacheSpec`: which arrays a CACHE layer keeps in the block pool
+    and the shape of one block of each, so the engine can allocate the pool,
+    copy a block (COW), export it and key its compiled programs.  ``layers``
+    counts cache layers, which need not be the weights' layers: a model that
+    runs its stack of layers several times over the same weights keeps a
+    cache a (pass, layer), ``passes x depth`` of them.
 ``serving_trunk(block_size=, cache_quant=)``
     a pure function ``trunk(weights, caches, rope, token_ids, enc, dec, now,
     cu, bt, mq, scales) -> (hidden, caches, new_scales, counts)``: packed
     tokens through every layer against the paged cache, final norm applied.
-    ``caches`` is a tuple with one list (a layer each) for every array of the
-    spec; ``counts`` a dict of int32 scalars the engine adds to its counters
-    of the same names (``{}``: none).
+    ``caches`` is a tuple with one entry for every array of the spec: a list
+    of ``[num_blocks, *block]`` arrays, a cache layer each, which a trunk
+    that the compiler sees unrolled indexes from Python; or, where the spec
+    says ``stacked``, ONE array ``[layers, num_blocks, *block]``, which a
+    trunk whose layers are a loop in the compiled program writes and reads
+    at a traced layer index (ops/paged_attention.py ``layer=``).  ``counts``
+    is a dict of int32 scalars the engine adds to its counters of the same
+    names (``{}``: none).
 
 and ``serving_rope(max_seq_len)`` for the table the trunk reads positions
-from.  ``LlamaForCausalLM`` (models/llama.py) and ``PanguUltraMoEForCausalLM``
-(models/pangu_moe.py) answer them."""
+from.  ``LlamaForCausalLM`` (models/llama.py: a list of per-head pools),
+``PanguUltraMoEForCausalLM`` (models/pangu_moe.py: a list of latent pools) and
+``OuroForCausalLM`` (models/ouro.py: one stacked per-head pool of
+``total_ut_steps x num_hidden_layers`` cache layers) answer them."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -33,7 +43,7 @@ class CacheSpec:
     # ((name, block_shape(block_size) -> tuple), ...): one pool array
     # [num_blocks, *block_shape] a layer for each
     arrays: Tuple[Tuple[str, Callable[[int], tuple]], ...]
-    layers: int
+    layers: int                   # CACHE layers (module docstring)
     # everything of the model that shapes the trunk's trace
     key: tuple
     # kv heads and head size of a per-head K/V cache (the block wire header)
@@ -42,3 +52,5 @@ class CacheSpec:
     quantizable: bool = True      # cache_quant="int8"
     transferable: bool = True     # export_blocks* / import_blocks* / blockwire
     why_not: str = ""             # said by the typed refusals
+    stacked: bool = False         # the pool is one array with a leading layer axis
+    passes: int = 1               # times an iteration runs the weights' layers

@@ -27,3 +27,8 @@ from .pangu_moe import (  # noqa: F401,E402
     PanguUltraMoEModel,
     pangu_ultra_moe_tiny,
 )
+from .ouro import (  # noqa: F401,E402
+    OuroConfig,
+    OuroForCausalLM,
+    OuroModel,
+)

@@ -59,6 +59,10 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   read back, and a tile rides its longest row: 120-190 GB/s of the bytes that
   are live. The kernel measured 4 times faster than that pass (twelve layers
   3.05 ms against 12.41 at serve1's ragged contexts; PERF.md section 6, PR 29).
+- A model whose layers are a loop in its program keeps ONE pool with a
+  leading layer axis and names the layer by the loop's counter
+  (``blha_attention(layer=)``): the layers' blocks are one run of block
+  numbers, so nothing below the table knows of layers.
 - Everything is static-shape: the query side is a packed token buffer
   ``[T, ...]`` (mixed prefill+decode chunks), the table ``blocks_per_seq``
   columns — both fixed by the serving engine, so admitting/retiring
@@ -461,8 +465,16 @@ def blha_attention(
     out_scale: float = -1.0,
     quant_max_bound: float = 127.0,
     quant_min_bound: float = -127.0,
+    layer=None,                # int32 scalar: which layer of a stacked pool
 ):
     """One serving attention step over the paged cache.
+
+    The pools may carry a leading layer axis, ``[layers, NB, KV, bs, D]``,
+    with ``layer`` (data: a loop's counter) naming the one this call writes
+    and reads.  The stacked pool is then seen as ``layers x NB`` blocks in
+    its own row-major order, which costs nothing, and the table's entries
+    are moved to the layer's blocks: the write, the gather and the
+    ``paged_decode`` kernel go by block number and touch no other layer's.
 
     Returns (out [T, H*D], key_cache', value_cache',
              k_quant_scales', v_quant_scales', k_dequant_scales',
@@ -481,6 +493,20 @@ def blha_attention(
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]
     B = block_tables.shape[0]
+    stacked = None
+    if layer is not None:
+        if key_cache.ndim != 5:
+            raise ValueError(
+                f"blha_attention(layer=) names a layer of a stacked pool [layers, NB, KV, bs, D];"
+                f" this pool is {tuple(key_cache.shape)}")
+        stacked = layers, per_layer = key_cache.shape[:2]
+        key_cache = key_cache.reshape((layers * per_layer,) + key_cache.shape[2:])
+        value_cache = value_cache.reshape(key_cache.shape)
+        block_tables = jnp.where(
+            (block_tables >= 0) & (block_tables < per_layer),
+            block_tables + jnp.asarray(layer, jnp.int32) * per_layer, -1)
+    elif key_cache.ndim == 5:
+        raise ValueError("a stacked pool [layers, NB, KV, bs, D] needs layer= to say which is meant")
 
     # ---- 1. unpack + dequant + bias ------------------------------------
     if qkv_out_scale is not None:
@@ -598,6 +624,9 @@ def blha_attention(
         out = jnp.clip(vq, quant_min_bound, quant_max_bound).astype(jnp.int8)
     else:
         out = out.astype(compute_dtype)
+    if stacked is not None:
+        key_cache = key_cache.reshape(stacked + key_cache.shape[1:])
+        value_cache = value_cache.reshape(key_cache.shape)
     return (out, key_cache, value_cache,
             cache_k_quant_scales, cache_v_quant_scales,
             cache_k_dequant_scales, cache_v_dequant_scales)

@@ -14,12 +14,15 @@ the program's words (the table with each name's reader is PERF.md section 3):
 Host spans, every one a ``RecordEvent`` on the profiler's clock:
 ``frontend.step`` > ``frontend.dispatch``, ``engine.step``, ``frontend.deliver``;
 ``engine.step`` > ``engine.admit``, ``engine.schedule``, ``engine.launch``
-(stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``, and
-on a mixed launch ``prefill_rows``, the rows it feeds prompt chunks),
+(stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``,
+``passes`` (how often an iteration runs the model's layers: 1 but for a
+looped model), and on a mixed launch ``prefill_rows``, the rows it feeds
+prompt chunks),
 ``engine.wait``, ``engine.harvest`` (stats: what the model's trunk counted
 in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
 ``attn_rows_kernel`` for a dense paged cache, ``moe_tokens`` /
-``moe_local_picks`` for expert layers);
+``moe_local_picks`` for expert layers, ``loop_tokens`` /
+``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
@@ -30,7 +33,10 @@ rows, then context blocks), ``kv_gather`` ``scores`` ``values`` (on the
 chip a one-token row is in none of the three: it attends inside the
 ``paged_decode`` kernel); ``attn_out``,
 ``mlp``, ``norm``, ``head``, ``sample``, ``scan_carry`` (the serving
-programs); ``attention`` >
+programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
+``loop_pass`` > ``while/body/`` the layers' scopes, ``norm``, ``exit_gate``
+(one pass of a looped trunk, itself the body of the loop over the passes);
+``attention`` >
 ``flash_attention``, ``loss``, ``optimizer``, ``grad_unscale`` (the train
 step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 ``head``).

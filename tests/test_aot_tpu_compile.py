@@ -173,3 +173,90 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
               for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text)}
     big = [s for s in shapes - views_of_the_pool if math.prod(s) >= whole]
     assert not big, big
+
+
+# ------------------------------------------- a looped model's three programs
+OURO = "benchmark/configs/ouro-2.6b.serve1.json"
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def ouro_engine():
+    """``ServingEngine`` over Ouro-2.6B at ouro-2.6b.serve1's geometry, its
+    weights zeros and its pool two blocks: the programs take both as
+    arguments, and are lowered below for the cell's own shapes."""
+    import json
+
+    from benchmark.harness import loader
+    from paddle_tpu.distributed.topology import set_hybrid_communicate_group
+    from paddle_tpu.inference import ServingEngine
+
+    set_hybrid_communicate_group(None)
+    cfg = json.load(open(os.path.join(loader.ROOT, OURO)))
+    family = loader.load_module("families", cfg["family"])
+    model = family.build_model(cfg)
+    for p in jax.tree_util.tree_leaves(family.params_of(model),
+                                       is_leaf=lambda x: hasattr(x, "_value")):
+        p._value = jnp.zeros(tuple(p.shape), BF16)
+    return cfg, ServingEngine(model, **dict(cfg["engine"], num_blocks=2))
+
+
+@pytest.mark.parametrize("kind", ["step_prefill_T256", "mixed_K8", "mega_K8"])
+def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind, monkeypatch):
+    """The prefill step, the mixed scan and the decode scan of Ouro-2.6B
+    whole (48 layers' weights stacked, four passes, a pool of 192 cache
+    layers x 6,144 tokens: 9.66 GB) compiled as the chip will run them. Both
+    loops are loops of the program: ONE ``paged_decode`` call in its text.
+    The pool is written and read in place at a traced layer index: one
+    layout of it (its stacked form and the same bytes seen as layers x blocks),
+    no copy, no temporary the size of one cache layer; the stacked weights
+    are not copied either (held as three matrices, q, k and v were: 1.21 GB).
+    And the figures that sized the pool: a GB and more free under each."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    cfg, eng = ouro_engine
+    B, T, P, K, C = eng.B, eng.T, eng.P, eng.megastep_k, eng.pc
+    nb = cfg["engine"]["num_blocks"]
+    assert (T, K) == (256, 8)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+
+    i32 = lambda *s: sds(s, jnp.int32)                            # noqa: E731
+    f32 = lambda *s: sds(s, jnp.float32)                          # noqa: E731
+    weights = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), eng._weights)
+    caches = tuple(sds((a.shape[0], nb) + a.shape[2:], a.dtype) for a in eng.caches)
+    head = (weights, caches, sds(eng._rope.shape, eng._rope.dtype))
+    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
+    flag, bt = sds((B,), jnp.bool_), i32(B, P)
+    lowered = {
+        "step_prefill_T256": lambda: eng._build_step().lower(
+            *head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt, *samp, mq=T),
+        "mixed_K8": lambda: eng._build_mixed_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
+            i32(B), i32(B), *samp, K=K),
+        "mega_K8": lambda: eng._build_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
+            i32(B), *samp, None, K=K),
+    }[kind]()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%paged_decode(\.\d+)? = [^\n]* custom-call\(", text)) == 1
+    pool = caches[0].shape
+    assert pool == (192, nb, 16, eng.bs, 128) and nb * eng.bs == 6144
+    stacked = ",".join(map(str, pool))
+    flat = ",".join(map(str, (pool[0] * pool[1],) + pool[2:]))
+    orders = set(re.findall(rf"bf16\[(?:{stacked}|{flat})\]\{{([0-9,]+)", text))
+    assert orders == {"4,3,2,1,0", "3,2,1,0"}, orders
+    assert not re.search(rf"= bf16\[(?:{stacked}|{flat})\][^\n]* copy\(", text)
+    assert not re.search(r"= bf16\[48,[0-9,]+\][^\n]* copy\(", text)
+    mem = compiled.memory_analysis()
+    one_cache_layer = math.prod(pool[1:]) * 2
+    assert mem.temp_size_in_bytes < one_cache_layer, mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert live < V5E_BYTES_LIMIT - 10 ** 9
+    said = cfg["memory"]["compiled_for_v5e"][kind]
+    assert said["arguments"] == mem.argument_size_in_bytes
+    assert abs(said["live"] / live - 1) < 0.01 and said["temporaries"] < one_cache_layer

@@ -718,12 +718,18 @@ class ServingEngine:
         # the dense paged attention read for them (ops/paged_attention.py
         # ``attention_positions``: read / live is what its tiles and context
         # blocks round up), and the one-token rows among them that went
-        # through the ``paged_decode`` kernel (0 where the XLA pass ran)
+        # through the ``paged_decode`` kernel (0 where the XLA pass ran); the
+        # live tokens whose keys and values ONE cache layer's write put into
+        # the pool, and the block pieces the row-wise ``paged_write`` kernel
+        # brought and put back for them (0 where the scatter ran, which
+        # walks the packed buffer whatever is live)
         self.moe_tokens = 0
         self.moe_local_picks = 0
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
+        self.kv_write_tokens = 0
+        self.kv_write_blocks = 0
         # a model that runs its layers in several passes over the same
         # weights: tokens fed to its trunk, and tokens x passes run (``passes``
         # times the first until a token is ever let out of a pass)
@@ -1406,6 +1412,8 @@ class ServingEngine:
                 "positions_live": self.attn_positions_live,
                 "positions_read": self.attn_positions_read,
                 "rows_kernel": self.attn_rows_kernel,
+                "kv_write_tokens": self.kv_write_tokens,
+                "kv_write_blocks": self.kv_write_blocks,
             },
             # a looped model (monotone; zero for a model of one pass)
             "loop": {

@@ -521,7 +521,8 @@ class LlamaForCausalLM(nn.Layer):
 
     def serving_trunk(self, *, block_size, cache_quant="none"):
         from ..ops.paged_attention import (attention_positions, blha_attention,
-                                           decodes_in_kernel)
+                                           cache_write_counts, decodes_in_kernel,
+                                           writes_in_kernel)
 
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -592,9 +593,17 @@ class LlamaForCausalLM(nn.Layer):
                     hidden.dtype, key_caches[0].dtype, head_dim=D, block_size=bs,
                     rows=bt.shape[0], blocks_per_seq=bt.shape[1],
                     plain=quant == "none"))
+            # and what a layer's cache write put into the pool: the live
+            # tokens, and the block pieces the row-wise write moved for them
+            written, pieces = cache_write_counts(
+                dec, now, cu, kernel=writes_in_kernel(
+                    key_caches[0].dtype, head_dim=D, block_size=bs,
+                    rows=bt.shape[0], blocks_per_seq=bt.shape[1],
+                    tokens=token_ids.shape[0], kv_heads=KV))
             return hidden, (key_caches, value_caches), new_scales, {
                 "attn_positions_live": live, "attn_positions_read": read,
-                "attn_rows_kernel": in_kernel}
+                "attn_rows_kernel": in_kernel,
+                "kv_write_tokens": written, "kv_write_blocks": pieces}
 
         return trunk
 

@@ -8,9 +8,9 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   a per-sequence ``block_tables [B, blocks_per_seq]`` maps logical positions
   to pool blocks — admission/eviction is host-side free-list bookkeeping, so
   sequences of different lengths share one compiled program.
-- One step = (scatter this step's K/V into the pool) + (attention blocked
-  over the context). Nothing grows with ``B x max_q_len x blocks_per_seq x
-  bs``: the cost follows what the batch holds, not the table's shape.
+- One step = (this step's K/V into the pool) + (attention blocked over the
+  context). Nothing grows with ``B x max_q_len x blocks_per_seq x bs``: the
+  cost follows what the batch holds, not the table's shape.
   Which rows take which path:
   * rows that feed ONE token (decode rows, a prompt's one-token tail), where
     the call is one ``decodes_in_kernel`` admits (the TPU, an unquantised
@@ -46,12 +46,31 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   ``attention_positions`` counts what a call had to attend, what it read
   for that and the rows the kernel took; ``ServingEngine`` adds them up
   (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``).
-- Layouts: the pool is written by (block, kv head, slot) with a window of
-  one head's ``D`` values, and gathered as rows of ``[num_blocks x KV, bs,
-  D]``, so both sides keep the pool's own row-major layout. Written by
-  (block, slot) with a ``[KV, D]`` window, the TPU compiler kept a second,
-  transposed copy of every layer's pool in each program (3.2 GB at the
-  benchmark's size; PERF.md section 6, PR 27).
+- The write, and why ONE layout still stands. Where ``writes_in_kernel``
+  admits the call (the TPU, an unquantised bfloat16 pool, heads of whole lane
+  tiles, blocks of whole 16-slot pieces; masks and pre-caches do not matter)
+  the keys and values go in through the Pallas kernel
+  ``ops/pallas/paged_write.py``: it takes both pools where they lie and
+  returns them (aliased, in place), lists the PIECES (16 consecutive
+  positions of a row, every KV head: one sublane tile of the pool) that hold
+  one of this step's tokens, and for each brings the piece to VMEM by the
+  block table, selects the run's values in and puts it back, several pieces
+  in flight. A token's ``D`` values are half of a packed sublane pair, so a
+  one-row store is a read-modify-write of the tile around it whoever does
+  it; the kernel does it once a piece and only for what is live: a row that
+  feeds nothing, a token past ``cu[-1]`` and a block the table does not name
+  cost nothing, and a run longer than a block is just more pieces. Every
+  other call (the CPU, a float32 pool, the int8 caches) keeps the scatter
+  over the packed buffer by (block, kv head, slot) with a window of one
+  head's ``D`` values, which walks every window of the buffer, live or not
+  (63 ns each on a v5e: 516 us a layer at 256 tokens x 16 heads x 2, where
+  the kernel takes 8-17 us; PERF.md section 6, PR 30 and PR 31). Both write the pool in its own row-major
+  layout, the one the gather reads as rows of ``[num_blocks x KV, bs, D]``
+  and ``paged_decode`` copies from: written by (block, slot) with a ``[KV,
+  D]`` window, the TPU compiler kept a second, transposed copy of every
+  layer's pool in each program (3.2 GB at the benchmark's size; PERF.md
+  section 6, PR 27). ``cache_write_counts`` counts the tokens written and
+  the pieces moved (``kv_write_tokens`` / ``kv_write_blocks``).
 - Why a kernel after all: r4 had measured a Pallas decode kernel at 299-366
   GB/s against 610-688 for XLA's einsum, but over a static ring cache with
   every position live and one query head a dot. Over the paged pool the XLA
@@ -83,13 +102,16 @@ import jax.numpy as jnp
 from ..device import on_tpu
 from .latent_attention import _NEG, _online
 from .pallas.paged_decode import paged_decode
+from .pallas.paged_write import PIECE, paged_write
 
 __all__ = ["blha_attention", "attention_positions", "decodes_in_kernel",
+           "cache_write_counts", "writes_in_kernel",
            "build_padding_metadata", "rope_rotate"]
 
 _CTX_BLOCK = 512    # cache positions a pass over the context reads
 _ROW_TILE = 8       # one-token rows that share a trip count
 _TABLE_WORDS = 1 << 17   # block-table entries the kernel holds in SMEM (half of it)
+_WRITE_VALUES = 1 << 21  # new keys (and as many values) ``paged_write`` holds whole in VMEM
 
 
 def rope_rotate(x, cos, sin, neox: bool):
@@ -166,6 +188,39 @@ def decodes_in_kernel(q_dtype, cache_dtype, *, head_dim: int, block_size: int,
             and jnp.dtype(q_dtype) == jnp.bfloat16
             and head_dim % 128 == 0 and block_size % 16 == 0
             and rows * blocks_per_seq <= _TABLE_WORDS)
+
+
+def writes_in_kernel(cache_dtype, *, head_dim: int, block_size: int, rows: int,
+                     blocks_per_seq: int, tokens: int, kv_heads: int) -> bool:
+    """Whether a call's keys and values go into the pool through the Pallas
+    kernel (``ops/pallas/paged_write.py``), decided as ``decodes_in_kernel``
+    decides: the platform is the TPU; the cache is an unquantised bfloat16
+    pool; ``head_dim`` is whole 128-lane tiles and ``block_size`` whole
+    sublane tiles; the block table fits the kernel's scalar memory and the
+    packed buffer's ``tokens`` x ``kv_heads`` new keys and values its VMEM.
+    Masks and pre-caches are the attention's and do not matter here. Anything
+    else (the CPU, a float32 pool, the int8 caches) takes the scatter."""
+    return (on_tpu() and jnp.dtype(cache_dtype) == jnp.bfloat16
+            and head_dim % 128 == 0 and block_size % PIECE == 0
+            and rows * blocks_per_seq <= _TABLE_WORDS
+            and tokens * kv_heads * head_dim <= _WRITE_VALUES)
+
+
+def cache_write_counts(seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, *,
+                       kernel: bool = False):
+    """What one ``blha_attention`` call with these lengths writes into ONE
+    cache layer, as int32 scalars (tokens, blocks): ``tokens`` the live tokens
+    whose keys and values go into the pool (a row's ``now``, cut at
+    ``cu[-1]``); ``blocks`` the block pieces (``PIECE`` consecutive positions
+    of a row, every KV head) that the row-wise write brings and puts back for
+    them where the table names their blocks, as an engine's rows' do: the
+    arithmetic is the kernel's. 0 without ``kernel`` (``writes_in_kernel`` of
+    the call): the scatter walks the packed buffer and brings no piece."""
+    dec, cu = seq_lens_decoder, cu_seqlens_q
+    w = jnp.clip(jnp.minimum(seq_lens_this_time, cu[-1] - cu[:-1]), 0)
+    pieces = jnp.where(w > 0, (dec + w - 1) // PIECE - dec // PIECE + 1, 0)
+    return (jnp.sum(w).astype(jnp.int32),
+            jnp.sum(pieces * kernel).astype(jnp.int32))
 
 
 def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
@@ -481,7 +536,8 @@ def blha_attention(
              v_dequant_scales') — scale arrays pass through unchanged except
     in dynamic quant mode, where prefill rows refresh them.
 
-    Scopes (children of ``paged_attention``): ``rope``, ``kv_write``, and
+    Scopes (children of ``paged_attention``): ``rope``, ``kv_write`` (on the
+    chip it holds the ``paged_write`` custom call), and
     inside the loops over row tiles, chunk rows and context blocks
     (``while/body/``) ``kv_gather`` (a block's gather, an int8 block's
     integers), ``scores`` (QK^T, masks, the online softmax's bookkeeping),
@@ -567,11 +623,15 @@ def blha_attention(
             cache_k_quant_scales, cache_v_quant_scales = new_kq, new_vq
             cache_k_dequant_scales, cache_v_dequant_scales = new_kd, new_vd
 
-        # ---- 5. scatter K/V into the block pool ----------------------------
-        nb = key_cache.shape[0]
-        blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
-        blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)  # OOB -> drop
-        slot = abs_pos % bs
+        # ---- 5. K/V into the block pool -------------------------------------
+        by_row = cache_quant == "none" and writes_in_kernel(
+            key_cache.dtype, head_dim=D, block_size=bs, rows=B,
+            blocks_per_seq=block_tables.shape[1], tokens=T, kv_heads=KV)
+        if not by_row:      # the scatter's coordinates, where the parent had them
+            nb = key_cache.shape[0]
+            blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
+            blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)  # OOB -> drop
+            slot = abs_pos % bs
         if cache_quant != "none":
             if cache_quant == "static":
                 ksc = cache_k_quant_scales[None, :, None]          # [1, KV, 1]
@@ -586,13 +646,22 @@ def blha_attention(
         else:
             k_store = k.astype(key_cache.dtype)
             v_store = v.astype(value_cache.dtype)
-        # by (block, kv head, slot), a head's D values an update: the layout
-        # the gather reads, so the pool keeps one layout (module docstring)
-        hd = jnp.arange(KV, dtype=jnp.int32)[None, :]
-        key_cache = key_cache.at[blk[:, None], hd, slot[:, None]].set(
-            k_store, mode="drop")
-        value_cache = value_cache.at[blk[:, None], hd, slot[:, None]].set(
-            v_store, mode="drop")
+        if by_row:
+            # row by row into the pieces of the blocks a row holds, in place:
+            # what is moved follows what is live (module docstring)
+            key_cache, value_cache = paged_write(
+                k_store, v_store, key_cache, value_cache, seq_lens_decoder,
+                seq_lens_this_time, cu_seqlens_q, block_tables)
+        else:
+            # a scatter over the packed buffer by (block, kv head, slot), a
+            # head's D values an update: the layout the gather reads, so the
+            # pool keeps one layout; a dead token's window is walked, sent
+            # to block nb and dropped
+            hd = jnp.arange(KV, dtype=jnp.int32)[None, :]
+            key_cache = key_cache.at[blk[:, None], hd, slot[:, None]].set(
+                k_store, mode="drop")
+            value_cache = value_cache.at[blk[:, None], hd, slot[:, None]].set(
+                v_store, mode="drop")
 
     # ---- 6-8. attention, blocked over the context -----------------------
     # this step's own keys and values are attended from registers, as one

@@ -20,14 +20,16 @@ looped model), and on a mixed launch ``prefill_rows``, the rows it feeds
 prompt chunks),
 ``engine.wait``, ``engine.harvest`` (stats: what the model's trunk counted
 in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
-``attn_rows_kernel`` for a dense paged cache, ``moe_tokens`` /
+``attn_rows_kernel`` and the cache write's ``kv_write_tokens`` /
+``kv_write_blocks`` for a dense paged cache, ``moe_tokens`` /
 ``moe_local_picks`` for expert layers, ``loop_tokens`` /
 ``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
 at run time), one vocabulary for every model family:
-``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write`` and,
+``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write`` (on the
+chip the write is the ``paged_write`` kernel inside it) and,
 under ``while/body/`` once for each loop around them (row tiles or chunk
 rows, then context blocks), ``kv_gather`` ``scores`` ``values`` (on the
 chip a one-token row is in none of the three: it attends inside the
@@ -44,7 +46,7 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
 ``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
-``int8_matmul``, ``paged_decode``.
+``int8_matmul``, ``paged_decode``, ``paged_write``.
 """
 from __future__ import annotations
 

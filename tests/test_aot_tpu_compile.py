@@ -125,7 +125,9 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
     in the carry as the engine's scans hold it, compiled as the chip will
     run it: whether a row feeds one token is data, so every one of the three
     holds the one-token rows' ``paged_decode`` kernel (the chunk rows' pass
-    is XLA). What the chip's compiler must not do is what it did before
+    is XLA) and, since ISSUE 31, the cache write's ``paged_write`` kernel,
+    which takes the pool where it lies and returns it. What the chip's
+    compiler must not do is what it did before
     ISSUE 27: keep a second copy of the pool in another layout (the write,
     the gather and the kernel's operand must agree on one, or 268 MB a layer
     are copied in and out), or build anything as large as every row's whole
@@ -159,6 +161,8 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
         sds((2, 1, P * bs, 1, D // 2), jnp.float32)).compile()
     text = compiled.as_text()
     assert re.search(r"%paged_decode(\.\d+)? = [^\n]* custom-call\(", text)
+    assert re.search(r"%paged_write(\.\d+)? = [^\n]* custom-call\(", text)
+    assert "kv_write/scatter" not in text
     pool_bytes = nb * KV * bs * D * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"
@@ -206,7 +210,8 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     """The prefill step, the mixed scan and the decode scan of Ouro-2.6B
     whole (48 layers' weights stacked, four passes, a pool of 192 cache
     layers x 6,144 tokens: 9.66 GB) compiled as the chip will run them. Both
-    loops are loops of the program: ONE ``paged_decode`` call in its text.
+    loops are loops of the program: ONE ``paged_decode`` call and ONE
+    ``paged_write`` call in its text, and no scatter under ``kv_write``.
     The pool is written and read in place at a traced layer index: one
     layout of it (its stacked form and the same bytes seen as layers x blocks),
     no copy, no temporary the size of one cache layer; the stacked weights
@@ -243,6 +248,8 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     compiled = lowered.compile()
     text = compiled.as_text()
     assert len(re.findall(r"%paged_decode(\.\d+)? = [^\n]* custom-call\(", text)) == 1
+    assert len(re.findall(r"%paged_write(\.\d+)? = [^\n]* custom-call\(", text)) == 1
+    assert "kv_write/scatter" not in text
     pool = caches[0].shape
     assert pool == (192, nb, 16, eng.bs, 128) and nb * eng.bs == 6144
     stacked = ",".join(map(str, pool))

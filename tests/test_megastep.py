@@ -932,7 +932,11 @@ def cpu_harvests(model):
 class TestRowsThroughTheKernel:
     """``attn_rows_kernel``: the one-token rows an iteration sent through the
     ``paged_decode`` kernel, counted by the trunk beside the two position
-    counts, added up by the engine, on the harvest span and in the summary."""
+    counts, added up by the engine, on the harvest span and in the summary.
+    And the cache write's two: ``kv_write_tokens``, the live tokens ONE cache
+    layer's write put into the pool, and ``kv_write_blocks``, the block
+    pieces the row-wise ``paged_write`` kernel moved for them (0 where the
+    scatter ran)."""
 
     @pytest.mark.parametrize("kind", sorted(PROGRAMS))
     def test_every_program_carries_it_and_it_is_zero_on_the_cpu(self, cpu_harvests, kind):
@@ -941,12 +945,17 @@ class TestRowsThroughTheKernel:
         assert mine, f"no {kind} launch"
         for attrs, _ in mine:
             assert set(attrs) == {"attn_positions_live", "attn_positions_read",
-                                  "attn_rows_kernel"}
+                                  "attn_rows_kernel", "kv_write_tokens",
+                                  "kv_write_blocks"}
             assert attrs["attn_rows_kernel"] == 0 < attrs["attn_positions_live"]
-        assert eng.attn_rows_kernel == 0
+            # the CPU's path is the scatter: tokens written, no piece moved
+            assert attrs["kv_write_blocks"] == 0 < attrs["kv_write_tokens"]
+        assert eng.attn_rows_kernel == 0 == eng.kv_write_blocks
+        assert eng.kv_write_tokens == sum(a["kv_write_tokens"] for _, a, _ in seen)
         assert eng.state_summary()["attention"] == {
             "positions_live": eng.attn_positions_live,
-            "positions_read": eng.attn_positions_read, "rows_kernel": 0}
+            "positions_read": eng.attn_positions_read, "rows_kernel": 0,
+            "kv_write_tokens": eng.kv_write_tokens, "kv_write_blocks": 0}
 
     def test_an_engine_steered_onto_the_chip_counts_its_decoding_rows(self, monkeypatch):
         """A bf16 model with heads of 128 and blocks of 16 is a call the
@@ -964,6 +973,7 @@ class TestRowsThroughTheKernel:
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         from paddle_tpu.ops import paged_attention as pa
         from paddle_tpu.ops.pallas import paged_decode as pd
+        from paddle_tpu.ops.pallas import paged_write as pw
 
         set_hybrid_communicate_group(None)
         P.seed(5)
@@ -985,13 +995,15 @@ class TestRowsThroughTheKernel:
             return eng, seen, [out[r] for r in rids]
 
         plain, _, want = run()
-        assert plain.attn_rows_kernel == 0
+        assert plain.attn_rows_kernel == 0 == plain.kv_write_blocks
         # the platform is asked when a program is traced: drop the traces
         # made for the CPU, and those made here once the test is over
         monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
         monkeypatch.setattr(pa, "on_tpu", lambda: True)
         monkeypatch.setattr(pa, "paged_decode",
                             functools.partial(pd.paged_decode, interpret=True))
+        monkeypatch.setattr(pa, "paged_write",
+                            functools.partial(pw.paged_write, interpret=True))
         jax.clear_caches()
         try:
             eng, seen, got = run()
@@ -1007,3 +1019,10 @@ class TestRowsThroughTheKernel:
         assert eng.attn_positions_live == plain.attn_positions_live
         assert eng.attn_positions_read < plain.attn_positions_read
         assert eng.state_summary()["attention"]["rows_kernel"] == 16
+        # the write: the same tokens as the scatter wrote, 5 + 22 prompt
+        # tokens and 8 fed back a row; the prompts lie in one piece of 16
+        # positions and in two, a token fed back in one
+        assert eng.kv_write_tokens == plain.kv_write_tokens == 27 + 16
+        assert eng.kv_write_blocks == 1 + 2 + 16
+        assert eng.kv_write_blocks == sum(a["kv_write_blocks"] for _, a, _ in seen)
+        assert eng.state_summary()["attention"]["kv_write_blocks"] == 19

@@ -164,6 +164,7 @@ KERNEL_NAMES = {
     "fused_ops.py": ["fused_rope", "swiglu_fwd", "swiglu_bwd"],
     "int8_matmul.py": ["int8_matmul"],
     "paged_decode.py": ["paged_decode"],
+    "paged_write.py": ["paged_write"],
 }
 
 

@@ -420,7 +420,8 @@ def test_loop_counters_are_monotone_and_ride_the_spans(built):
     assert eng.loop_tokens == 70 + 15 and eng.loop_token_passes == 4 * eng.loop_tokens
     assert eng.state_summary()["loop"] == {"passes": 4, "tokens": 85, "token_passes": 340}
     names = {"loop_tokens", "loop_token_passes", "attn_positions_live",
-             "attn_positions_read", "attn_rows_kernel"}
+             "attn_positions_read", "attn_rows_kernel", "kv_write_tokens",
+             "kv_write_blocks"}
     assert {k for k, _, _ in harvests} >= {"step", "mega", "mixed"}
     assert all(set(h) == names for _, _, h in harvests)
     assert all(l["passes"] == 4 and l["kind"] == k for k, l, _ in harvests)
@@ -429,6 +430,11 @@ def test_loop_counters_are_monotone_and_ride_the_spans(built):
     # the attention's three count ONE cache layer, as they do for a model of one pass
     assert eng.attn_positions_live == sum(h["attn_positions_live"] for _, _, h in harvests)
     assert 0 < eng.attn_positions_live <= eng.attn_positions_read
+    # and the cache write's two: every token fed is written into a cache
+    # layer once, by the scatter here (no piece moved)
+    assert eng.kv_write_tokens == sum(h["kv_write_tokens"] for _, _, h in harvests) == 85
+    assert eng.kv_write_blocks == 0
+    assert eng.state_summary()["attention"]["kv_write_tokens"] == 85
     assert eng.state_summary()["moe"] == {"tokens": 0, "local_picks": 0}
 
 
@@ -447,9 +453,15 @@ def test_a_model_of_one_pass_says_so():
 # commit (364d68d), tiny geometry, jax 0.9.0: a shared function that this PR
 # touched (``blha_attention``'s ``layer=``, the engine's pool, its COW copy)
 # leaves the other two families' programs byte for byte what they were.
+# The ``llama`` row was pinned anew at PR 31: the dense trunk's ``counts``
+# gained ``kv_write_tokens`` and ``kv_write_blocks`` (two more results of each
+# program and the few integer operations that make them). With those two
+# taken out again the four texts were PR 30's, a61c1bc0 / f6aa624f / 490e19ac
+# / d29cf24a, byte for byte: on the CPU the write is still the scatter, in
+# the parent's order of operations.
 PARENT_TEXTS = {
-    "llama": {"step": "a61c1bc00576da57", "mega": "f6aa624faa965b2e",
-              "mixed": "490e19aca90faaec", "spec": "d29cf24aea950a2f"},
+    "llama": {"step": "ee99d68488cd7fb1", "mega": "659c864c2723c567",
+              "mixed": "5931fbbd204472a5", "spec": "0fbd7142f153243d"},
     "pangu": {"step": "12f7caac479ecbd3", "mega": "46dd4ae2b6422070",
               "mixed": "f0e344e3fab3c3ff", "spec": "f585eb675ec177f6"}}
 
@@ -474,3 +486,49 @@ def test_the_other_families_programs_lower_to_the_parents_text(family):
     texts = _lowered(ServingEngine(model, spec_k=2, **ENGINE), debug_info=False)
     got = {k: hashlib.sha256(t.encode()).hexdigest()[:16] for k, t in texts.items()}
     assert got == PARENT_TEXTS[family]
+
+
+def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypatch):
+    """A bf16 looped model with heads of 128 and blocks of 16 is a call both
+    kernels admit. With ``on_tpu`` answering yes (the kernels in interpret
+    mode) the stacked pool is written piece by piece at the loop's layer:
+    the tokens are the scatter's, ``kv_write_tokens`` the same, and
+    ``kv_write_blocks`` what the rows' runs lie in."""
+    import functools
+
+    from paddle_tpu.inference import serving
+    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops.pallas import paged_decode as pd
+    from paddle_tpu.ops.pallas import paged_write as pw
+
+    cfg = dict(TINY, hidden_size=128, intermediate_size=128, num_hidden_layers=2,
+               num_attention_heads=1, num_key_value_heads=1, head_dim=128,
+               total_ut_steps=2, torch_dtype="bfloat16")
+    model, _ = _build(cfg)
+    geometry = dict(max_batch_size=2, max_seq_len=64, block_size=16, token_budget=32,
+                    megastep_k=4)
+    prompts = _prompts([5, 22])
+
+    def run():
+        eng = ServingEngine(model, **geometry)
+        rids = [eng.add_request(p, max_new_tokens=7) for p in prompts]
+        out = eng.run()
+        return eng, [out[r] for r in rids]
+
+    plain, want = run()
+    assert plain.kv_write_blocks == 0 and plain.kv_write_tokens == 27 + 12
+    monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "paged_decode", functools.partial(pd.paged_decode, interpret=True))
+    monkeypatch.setattr(pa, "paged_write", functools.partial(pw.paged_write, interpret=True))
+    jax.clear_caches()
+    try:
+        eng, got = run()
+    finally:
+        jax.clear_caches()
+    assert got == want
+    assert eng.kv_write_tokens == plain.kv_write_tokens
+    # the prompts lie in one piece of 16 positions and in two; a token fed
+    # back in one
+    assert eng.kv_write_blocks == 1 + 2 + 12
+    assert eng.attn_rows_kernel == 12

@@ -602,6 +602,7 @@ def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes
 import functools                 # noqa: E402
 
 from paddle_tpu.ops.pallas import paged_decode as pd   # noqa: E402
+from paddle_tpu.ops.pallas import paged_write as pw    # noqa: E402
 
 K_BS, K_P, K_D, K_KV = 16, 24, 128, 2
 K_PASS = pd.BLOCKS_PER_PASS * K_BS
@@ -651,20 +652,38 @@ def _kernel_case(layout, group, holes, seed=0):
     return args, dict(H=H, KV=KV, D=D, bs=bs, S=S, T=T, total=int(cu[-1]))
 
 
-@pytest.fixture
-def on_chip(monkeypatch):
-    """``blha_attention`` as the chip traces it: ``on_tpu`` answers yes, and
-    the kernel it then calls runs in interpret mode. Returns a fresh jit of
-    the undecorated function, so that no trace of another platform is met."""
+def _fresh_call(args, kw):
+    """A fresh jit of the undecorated function: the platform is asked as it
+    answers now, and no other test's trace is met or left behind."""
+    statics = {n: v for n, v in kw.items() if not hasattr(v, "shape")}
+    arrays = {n: v for n, v in kw.items() if hasattr(v, "shape")}
+    return jax.jit(functools.partial(pa.blha_attention.__wrapped__, **statics))(
+        *args, **arrays)
+
+
+def _steer_onto_the_chip(monkeypatch):
+    """``on_tpu`` answers yes and both kernels run in interpret mode; returns
+    the list that grows by one with every ``paged_write`` call traced."""
+    calls = []
+
+    def write(*a, **k):
+        calls.append(a[0].shape)
+        return pw.paged_write(*a, interpret=True, **k)
+
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     monkeypatch.setattr(pa, "paged_decode",
                         functools.partial(pd.paged_decode, interpret=True))
+    monkeypatch.setattr(pa, "paged_write", write)
+    return calls
 
-    def call(*args, **kw):
-        statics = {n: kw.pop(n) for n in list(kw) if not hasattr(kw[n], "shape")}
-        return jax.jit(functools.partial(pa.blha_attention.__wrapped__, **statics))(
-            *args, **kw)
-    return call
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """``blha_attention`` as the chip traces it: ``on_tpu`` answers yes, and
+    the kernels it then calls (the one-token rows' attention, the cache
+    write) run in interpret mode."""
+    _steer_onto_the_chip(monkeypatch)
+    return lambda *args, **kw: _fresh_call(args, kw)
 
 
 def _kp(layout, group=4, holes=False):
@@ -783,18 +802,36 @@ def _outside_cases():
             pre_cache, narrow_heads]
 
 
+# of those, the calls whose WRITE still goes through ``paged_write``: masks,
+# pre-caches and the queries' type are the attention's, the pool is bf16
+_WRITTEN_BY_ROW = {"float32_queries", "with_mask", "encoder_mask", "pre_cache"}
+
+
 @pytest.mark.parametrize("make", _outside_cases(), ids=lambda f: f.__name__)
 def test_a_call_outside_the_kernels_conditions_takes_the_xla_pass(monkeypatch, make):
-    """Steered onto the chip or not, such a call lowers to one and the same
-    text, the blocked XLA pass's, with no custom call in it (at PR 29 that
-    text was the parent commit's byte for byte, compared by hand)."""
+    """Steered onto the chip or not, the attention of such a call is the
+    blocked XLA pass, with no ``paged_decode`` in it. Where the pool is not
+    one the write's kernel admits either (float32, int8, half a lane tile)
+    both lower to one and the same text with no custom call at all (at PR 29
+    that text was the parent commit's byte for byte, compared by hand); the
+    others differ by the ``paged_write`` call alone."""
     args, g, kw = make()
     kw.setdefault("compute_dtype", jnp.bfloat16)
     here = _lowered(args, g, **kw)
-    monkeypatch.setattr(pa, "on_tpu", lambda: True)
-    assert _lowered(args, g, **kw) == here
     assert "custom_call" not in here
     assert "kv_gather" in _lowered(args, g, scopes=True, **kw)
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    if make.__name__ not in _WRITTEN_BY_ROW:
+        assert _lowered(args, g, **kw) == here
+        return
+    f = functools.partial(pa.blha_attention.__wrapped__, num_heads=g["H"],
+                          kv_num_heads=g["KV"], head_dim=g["D"], block_size=g["bs"],
+                          max_q_len=g["S"], **{n: v for n, v in kw.items()
+                                               if not hasattr(v, "shape")})
+    there = jax.jit(f).trace(*args, **{n: v for n, v in kw.items() if hasattr(v, "shape")}
+                             ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "paged_write" in there and "paged_decode" not in there
+    assert "kv_gather" in there and "kv_write/scatter" not in there
 
 
 def test_the_platform_chooses_between_the_kernel_and_the_xla_pass(monkeypatch):
@@ -813,6 +850,9 @@ def test_the_platform_chooses_between_the_kernel_and_the_xla_pass(monkeypatch):
         debug_info=True)
     assert "tpu_custom_call" in there and "paged_decode" in there
     assert "kv_gather" not in there
+    # and the cache write: the scatter here, the ``paged_write`` call there
+    assert "kv_write/scatter" in here and "paged_write" not in here
+    assert "paged_write" in there and "kv_write/scatter" not in there
 
 
 def test_attention_positions_by_hand_on_both_paths():
@@ -849,3 +889,177 @@ def test_decodes_in_kernel_asks_the_call_and_the_platform_only(monkeypatch):
                 dict(rows=4096, blocks_per_seq=64)):
         assert not pa.decodes_in_kernel(bf16, bf16, **{
             **dict(head_dim=128, block_size=64, rows=32, blocks_per_seq=40), **odd})
+
+
+# ------------------------------------------------- the row-wise cache write
+# On the chip a call with an unquantised bf16 pool writes this step's keys and
+# values through ``ops/pallas/paged_write.py`` (``pa.writes_in_kernel``): the
+# pieces of the blocks a row holds, brought, selected into and put back. The
+# reference is the scatter, which every other call keeps: the POOLS must come
+# out bit for bit the same. Blocks of 64 in a table of 4, so a piece is a
+# quarter of a block; heads of 128.
+W_BS, W_P, W_KV, W_D = 64, 4, 2, 128
+
+WRITE_LAYOUTS = {
+    # (max_q_len, [(dec, now)]); T is the rows' tokens and a dead tail
+    "decode_only": (1, [(0, 1), (15, 1), (16, 1), (63, 1), (64, 1), (200, 1), (255, 1)]),
+    "chunk_inside_a_block": (64, [(64, 64), (130, 1), (0, 40)]),
+    "chunk_across_a_blocks_end": (64, [(40, 64), (7, 1), (100, 64), (63, 2)]),
+    "a_prompts_tail": (64, [(128, 5), (64, 1), (70, 17), (191, 1)]),
+    "idle_rows_between": (64, [(0, 0), (33, 1), (0, 0), (0, 0), (64, 30), (9, 0), (77, 1),
+                               (0, 0)]),
+    "a_run_longer_than_a_block": (200, [(3, 200), (50, 1), (0, 130)]),
+    "nothing_live": (64, [(0, 0), (12, 0)]),
+}
+
+
+def _write_case(layout, *, holes=False, stacked=None, dtype=jnp.bfloat16, seed=0):
+    S, rows = WRITE_LAYOUTS[layout]
+    rng = np.random.RandomState(seed + len(layout))
+    B, KV, D, bs, P, H = len(rows), W_KV, W_D, W_BS, W_P, 2 * W_KV
+    dec = np.array([r[0] for r in rows], np.int32)
+    now = np.array([r[1] for r in rows], np.int32)
+    enc = np.where(now > 1, now, 0).astype(np.int32)
+    cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+    T = int(cu[-1]) + 5
+    nb = B * P + 3
+    bt = rng.permutation(nb)[:B * P].reshape(B, P).astype(np.int32)
+    if holes:       # under the first two live rows' own tokens
+        live = [b for b in range(B) if now[b] > 0]
+        bt[live[0], dec[live[0]] // bs] = -1
+        bt[live[1], dec[live[1]] // bs] = nb + 7
+    shape = (nb, KV, bs, D) if stacked is None else (3, nb, KV, bs, D)
+    qkv = jnp.asarray(rng.uniform(-1, 1, (T, (H + 2 * KV) * D)), jnp.bfloat16)
+    kc = jnp.asarray(rng.uniform(-1, 1, shape), dtype)
+    vc = jnp.asarray(rng.uniform(-1, 1, shape), dtype)
+    args = (qkv, kc, vc, jnp.asarray(enc), jnp.asarray(dec), jnp.asarray(now),
+            jnp.asarray(cu), jnp.asarray(bt))
+    kw = dict(num_heads=H, kv_num_heads=KV, head_dim=D, block_size=bs, max_q_len=S,
+              compute_dtype=jnp.bfloat16)
+    if stacked is not None:
+        kw["layer"] = jnp.int32(stacked)
+    return args, kw
+
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(
+        x, {2: jnp.uint16, 4: jnp.uint32, 1: jnp.uint8}[x.dtype.itemsize]))
+
+
+def _wp(layout, **kw):
+    return pytest.param(layout, kw, id="-".join(
+        [layout] + [f"{k}{'' if v is True else v}" for k, v in kw.items()]))
+
+
+@pytest.mark.parametrize("layout,how", (
+    [_wp(lay) for lay in WRITE_LAYOUTS]
+    + [_wp("decode_only", holes=True), _wp("chunk_across_a_blocks_end", holes=True)]
+    + [_wp("chunk_across_a_blocks_end", stacked=l) for l in (0, 1, 2)]
+    + [_wp("decode_only", stacked=2), _wp("idle_rows_between", stacked=1, holes=True)]))
+def test_the_row_wise_write_leaves_the_pools_the_scatter_leaves(monkeypatch, layout, how):
+    """The same call as the CPU runs it (the scatter, the XLA pass) and as the
+    chip traces it (``paged_write`` and ``paged_decode``, in interpret mode):
+    the two pools bit for bit, a stacked pool's other layers untouched, a
+    block the table does not name not written and no other touched, and the
+    attention's output, which on the chip reads a one-token row's own token
+    back out of the pool."""
+    args, kw = _write_case(layout, **how)
+    want = _fresh_call(args, kw)
+    calls = _steer_onto_the_chip(monkeypatch)
+    got = _fresh_call(args, kw)
+    assert len(calls) == 1
+    dec, now, cu = (np.asarray(a) for a in (args[4], args[5], args[6]))
+    for pool, mine, theirs, before in zip("kv", got[1:3], want[1:3], args[1:3]):
+        assert mine.shape == before.shape and mine.dtype == before.dtype
+        np.testing.assert_array_equal(_bits(mine), _bits(theirs), err_msg=pool)
+        if "stacked" in how:
+            others = [l for l in range(3) if l != how["stacked"]]
+            np.testing.assert_array_equal(_bits(mine)[others], _bits(before)[others])
+        assert (_bits(mine) != _bits(before)).any() == bool(now.sum())
+    # a row whose own token found no block reads zeros for it from the pool
+    # on the chip and the fresh token from registers here: leave those out
+    sound = np.ones(args[0].shape[0], bool)
+    if how.get("holes"):
+        for b in [b for b in range(len(now)) if now[b] > 0][:2]:
+            sound[cu[b]:cu[b] + now[b]] = False
+    np.testing.assert_allclose(np.asarray(got[0], np.float32)[sound],
+                               np.asarray(want[0], np.float32)[sound],
+                               rtol=1e-2, atol=1e-2)
+
+
+def _refused_cases():
+    def the_cpu():
+        return _write_case("chunk_across_a_blocks_end"), False
+
+    def float32_pool():
+        return _write_case("chunk_across_a_blocks_end", dtype=jnp.float32), True
+
+    def int8_pool():
+        args, kw = _write_case("chunk_across_a_blocks_end", dtype=jnp.uint8)
+        scales = {f"cache_{n}_{kind}_scales": jnp.ones((W_KV,), jnp.float32)
+                  for n in "kv" for kind in ("quant", "dequant")}
+        return (args, dict(kw, cache_quant="static", **scales)), True
+
+    def blocks_of_half_a_piece():
+        args, kw = _write_case("decode_only")
+        qkv, kc, vc, *rest = args
+        cut = lambda c: c.reshape(-1, W_KV, 8, W_D)               # noqa: E731
+        return ((qkv, cut(kc), cut(vc), *rest), dict(kw, block_size=8)), True
+
+    return [the_cpu, float32_pool, int8_pool, blocks_of_half_a_piece]
+
+
+@pytest.mark.parametrize("make", _refused_cases(), ids=lambda f: f.__name__)
+def test_a_call_the_writes_conditions_refuse_takes_the_scatter(monkeypatch, make):
+    """The CPU, a float32 pool, an int8 cache, blocks that are no whole
+    pieces: the scatter, whatever the platform says, and the pools it leaves
+    are the unsteered call's."""
+    (args, kw), steered = make()
+    want = _fresh_call(args, kw)
+    calls = _steer_onto_the_chip(monkeypatch) if steered else []
+    got = _fresh_call(args, kw)
+    assert not calls
+    for mine, theirs in zip(got[1:3], want[1:3]):
+        np.testing.assert_array_equal(_bits(mine), _bits(theirs))
+    statics = {n: v for n, v in kw.items() if not hasattr(v, "shape")}
+    text = jax.jit(functools.partial(pa.blha_attention.__wrapped__, **statics)).lower(
+        *args, **{n: v for n, v in kw.items() if hasattr(v, "shape")}).as_text(
+        debug_info=True)
+    assert "kv_write/scatter" in text and "paged_write" not in text
+
+
+def test_writes_in_kernel_asks_the_pool_and_the_platform_only(monkeypatch):
+    usual = dict(head_dim=128, block_size=64, rows=32, blocks_per_seq=40, tokens=256,
+                 kv_heads=8)
+    ask = functools.partial(pa.writes_in_kernel, **usual)
+    assert not ask(jnp.bfloat16)                     # the CPU
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    assert ask(jnp.bfloat16)
+    assert not ask(jnp.float32) and not ask(jnp.uint8) and not ask(jnp.float16)
+    # 8,192 tokens x 8 heads of keys and of values are 34 MB: no VMEM holds them
+    for odd in (dict(head_dim=64), dict(head_dim=192), dict(block_size=8),
+                dict(block_size=24), dict(rows=4096, blocks_per_seq=64),
+                dict(tokens=8192)):
+        assert not pa.writes_in_kernel(jnp.bfloat16, **{**usual, **odd})
+    assert ask(jnp.bfloat16, tokens=1024)
+
+
+def test_cache_write_counts_by_hand():
+    """Tokens: a row's ``now``, cut at ``cu[-1]``. Pieces of 16 positions: a
+    token one; 64 tokens from a block's start four, from position 40 five
+    (40-47, 48-63, ..., 96-103); 5 tokens from 14 two; nothing for a row that
+    feeds nothing, and nothing at all where the scatter runs."""
+    def count(rows, kernel, total=None):
+        dec, now = (np.asarray(x, np.int32) for x in zip(*rows))
+        cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+        if total is not None:
+            cu[-1] = total
+        return tuple(int(n) for n in pa.cache_write_counts(
+            jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu), kernel=kernel))
+
+    rows = [(600, 1), (0, 0), (64, 64), (40, 64), (14, 5), (15, 1), (9, 0)]
+    assert count(rows, True) == (135, 1 + 4 + 5 + 2 + 1)
+    assert count(rows, False) == (135, 0)
+    assert count([(0, 0), (5, 0)], True) == (0, 0)
+    # the buffer ends inside the last row's run: 3 of its 5 tokens are live
+    assert count([(0, 16), (14, 5)], True, total=19) == (19, 1 + 2)

@@ -503,7 +503,8 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     seen = [a for _, a in harvests]
     assert seen and all(set(a) == {"moe_tokens", "moe_local_picks"} for a in seen)
     assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0,
-                                                "rows_kernel": 0}
+                                                "rows_kernel": 0, "kv_write_tokens": 0,
+                                                "kv_write_blocks": 0}
     assert sum(a["moe_tokens"] for a in seen) == eng.moe_tokens
     assert sum(a["moe_local_picks"] for a in seen) == eng.moe_local_picks
 
@@ -550,11 +551,14 @@ def test_attention_counters_are_monotone_and_ride_the_harvest_span():
     assert eng.state_summary()["attention"] == {
         "positions_live": eng.attn_positions_live,
         "positions_read": eng.attn_positions_read,
-        "rows_kernel": 0}                  # the CPU: every row took the XLA pass
+        "rows_kernel": 0,                  # the CPU: every row took the XLA pass
+        "kv_write_tokens": 3 + 5 + 2,      # ... and the scatter wrote every token fed
+        "kv_write_blocks": 0}
     seen = [a for _, a in harvests]
     assert len(seen) == 2
     assert all(set(a) == {"attn_positions_live", "attn_positions_read",
-                          "attn_rows_kernel"} for a in seen)
+                          "attn_rows_kernel", "kv_write_tokens", "kv_write_blocks"}
+               for a in seen)
     assert eng.attn_rows_kernel == sum(a["attn_rows_kernel"] for a in seen) == 0
     assert sum(a["attn_positions_live"] for a in seen) == eng.attn_positions_live
     assert sum(a["attn_positions_read"] for a in seen) == eng.attn_positions_read

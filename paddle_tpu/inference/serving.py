@@ -730,6 +730,15 @@ class ServingEngine:
         self.attn_rows_kernel = 0
         self.kv_write_tokens = 0
         self.kv_write_blocks = 0
+        # learned sparse attention (ops/sparse_index.py), ONE layer's count an
+        # iteration over the live queries whose context exceeds the model's
+        # ``index_topk``: such queries, the context positions the indexer
+        # scored for them, the positions it selected, and the latent entries
+        # the attention pass brought for them
+        self.dsa_queries = 0
+        self.dsa_positions_scored = 0
+        self.dsa_positions_selected = 0
+        self.dsa_positions_read = 0
         # a model that runs its layers in several passes over the same
         # weights: tokens fed to its trunk, and tokens x passes run (``passes``
         # times the first until a token is ever let out of a pass)
@@ -1407,13 +1416,22 @@ class ServingEngine:
                 "tokens": self.moe_tokens,
                 "local_picks": self.moe_local_picks,
             },
-            # dense paged attention (monotone; zero for a latent cache)
+            # paged attention (monotone; a latent cache under a learned
+            # selection counts ``positions_live`` alone, another latent cache none)
             "attention": {
                 "positions_live": self.attn_positions_live,
                 "positions_read": self.attn_positions_read,
                 "rows_kernel": self.attn_rows_kernel,
                 "kv_write_tokens": self.kv_write_tokens,
                 "kv_write_blocks": self.kv_write_blocks,
+            },
+            # learned sparse attention (monotone; zero for a model without
+            # an indexer): ONE layer's counts, see ``dsa_queries`` above
+            "sparse_attention": {
+                "queries": self.dsa_queries,
+                "positions_scored": self.dsa_positions_scored,
+                "positions_selected": self.dsa_positions_selected,
+                "positions_read": self.dsa_positions_read,
             },
             # a looped model (monotone; zero for a model of one pass)
             "loop": {

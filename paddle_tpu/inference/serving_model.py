@@ -27,9 +27,14 @@ sampling, and asks the model three questions:
 
 and ``serving_rope(max_seq_len)`` for the table the trunk reads positions
 from.  ``LlamaForCausalLM`` (models/llama.py: a list of per-head pools),
-``PanguUltraMoEForCausalLM`` (models/pangu_moe.py: a list of latent pools) and
+``PanguUltraMoEForCausalLM`` (models/pangu_moe.py: a list of latent pools),
 ``OuroForCausalLM`` (models/ouro.py: one stacked per-head pool of
-``total_ut_steps x num_hidden_layers`` cache layers) answer them."""
+``total_ut_steps x num_hidden_layers`` cache layers) and
+``DeepseekV32ForCausalLM`` (models/deepseek_v32.py: TWO arrays a layer of
+different width and meaning, a latent entry and a selector's key, under one
+block table) answer them.  The arrays of a spec are positional: whatever the
+engine does to a block (allocate, copy on write, keep for a shared prefix,
+free) it does to that block of every array of every layer."""
 from __future__ import annotations
 
 from dataclasses import dataclass

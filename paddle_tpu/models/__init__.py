@@ -32,3 +32,9 @@ from .ouro import (  # noqa: F401,E402
     OuroForCausalLM,
     OuroModel,
 )
+from .deepseek_v32 import (  # noqa: F401,E402
+    DeepseekV32Config,
+    DeepseekV32ForCausalLM,
+    DeepseekV32Model,
+    deepseek_v32_tiny,
+)

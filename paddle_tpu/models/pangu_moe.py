@@ -47,8 +47,44 @@ F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+class LatentMoEGeometry:
+    """What a config of latent attention and held experts derives from its
+    published keys (shared with models/deepseek_v32.py)."""
+
+    def _check_experts_held(self):
+        if self.experts_held is None:
+            self.experts_held = (0, self.n_routed_experts)
+        lo, hi = (int(v) for v in self.experts_held)
+        if not 0 <= lo < hi <= self.n_routed_experts:
+            raise ValueError(f"experts_held={self.experts_held} is no range of "
+                             f"{self.n_routed_experts} routed experts")
+        self.experts_held = (lo, hi)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values of one cache entry: the latent and the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_cache_width(self) -> int:
+        """Width an entry is STORED at: whole 128-lane tiles once it is wider
+        than one.  At 576 the TPU compiler gives the pool a transposed device
+        layout and copies all of it into and out of every program (a layer's
+        pool as temporaries; 0.5 MB at 640: compile, PR 26); the padding is
+        zeros, which a score's dot ignores."""
+        w = self.latent_width
+        return -(-w // 128) * 128 if w > 128 else w
+
+    def is_sparse(self, layer: int) -> bool:
+        return layer >= self.first_k_dense_replace
+
+
 @dataclass
-class PanguUltraMoEConfig:
+class PanguUltraMoEConfig(LatentMoEGeometry):
     vocab_size: int = 153600
     hidden_size: int = 7680
     intermediate_size: int = 18432
@@ -80,41 +116,13 @@ class PanguUltraMoEConfig:
     experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
-        if self.experts_held is None:
-            self.experts_held = (0, self.n_routed_experts)
-        lo, hi = (int(v) for v in self.experts_held)
-        if not 0 <= lo < hi <= self.n_routed_experts:
-            raise ValueError(f"experts_held={self.experts_held} is no range of "
-                             f"{self.n_routed_experts} routed experts")
-        self.experts_held = (lo, hi)
+        self._check_experts_held()
         if (not self.sandwich_norm or not self.norm_topk_prob or self.attention_bias
                 or self.hidden_act != "silu" or self.n_shared_experts != 1
                 or self.tie_word_embeddings):
             raise ValueError("pangu_ultra_moe as published: sandwich norms, top-k "
                              "weights normalised, one shared expert, SwiGLU, no "
                              "biases, untied head")
-
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-    @property
-    def latent_width(self) -> int:
-        """Values of one cache entry: the latent and the shared rope key."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def latent_cache_width(self) -> int:
-        """Width an entry is STORED at: whole 128-lane tiles once it is wider
-        than one.  At 576 the TPU compiler gives the pool a transposed device
-        layout and copies all of it into and out of every program (a layer's
-        pool as temporaries; 0.5 MB at 640: compile, PR 26); the padding is
-        zeros, which a score's dot ignores."""
-        w = self.latent_width
-        return -(-w // 128) * 128 if w > 128 else w
-
-    def is_sparse(self, layer: int) -> bool:
-        return layer >= self.first_k_dense_replace
 
 
 def pangu_ultra_moe_tiny(**kw) -> PanguUltraMoEConfig:
@@ -148,12 +156,18 @@ def rope_table(cfg, length):
     return jnp.asarray(np.stack([np.cos(fr), np.sin(fr)]), F32)
 
 
-def _latent_proj(cfg, p, x, cos, sin):
+def _q_latent(cfg, p, x):
+    """x [T, E] -> c_q [T, q_lora_rank]: the queries' normed latent."""
+    return _rms(x @ p["wq_a"], p["q_norm"], cfg.rms_norm_eps)
+
+
+def _latent_proj(cfg, p, x, cos, sin, c_q=None):
     """x [T, E] -> q_nope [T, H, N], rope(q_rope) [T, H, R], c [T, C],
-    rope(k_r) [T, R]; cos/sin [T, R/2] at each token's position."""
+    rope(k_r) [T, R]; cos/sin [T, R/2] at each token's position.  ``c_q``:
+    ``_q_latent`` of the same ``x``, where the caller needs it too."""
     H, N, R = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps = cfg.rms_norm_eps
-    q = (_rms(x @ p["wq_a"], p["q_norm"], eps) @ p["wq_b"]).reshape(-1, H, N + R)
+    q = ((_q_latent(cfg, p, x) if c_q is None else c_q) @ p["wq_b"]).reshape(-1, H, N + R)
     kv = x @ p["wkv_a"]
     c = _rms(kv[:, :cfg.kv_lora_rank], p["kv_norm"], eps)
     k_r = rope_half(kv[:, cfg.kv_lora_rank:], cos, sin)
@@ -225,9 +239,10 @@ def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128):
     return y, jnp.sum(hit).astype(jnp.int32)
 
 
-def _moe_ffn(cfg, p, x, valid=None):
-    """Shared expert + the held experts' part. -> (y in x's dtype, picks)."""
-    idx, w = route(x, p["router"], cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+def _moe_ffn(cfg, p, x, valid=None, router=route):
+    """Shared expert + the held experts' part. -> (y in x's dtype, picks).
+    ``router(x, w_router, top_k, scale)`` -> (idx, w): the model's routing."""
+    idx, w = router(x, p["router"], cfg.num_experts_per_tok, cfg.routed_scaling_factor)
     routed, picks = held_experts(x, idx, w, p["eg"], p["eu"], p["ed"],
                                  cfg.experts_held[0], valid)
     with jax.named_scope("shared_expert"):
@@ -388,26 +403,29 @@ class PanguDecoderLayer(nn.Layer):
 class PanguMTPModule(nn.Layer):
     """The next-token module: h' = W_p [n_a(h_t) ; n_b(Emb(tok_{t+1}))], one
     more layer of the expert kind, a norm, the model's own output head."""
+    layer_class = PanguDecoderLayer
 
-    def __init__(self, cfg: PanguUltraMoEConfig):
+    def __init__(self, cfg):
         super().__init__()
         e, dt = cfg.hidden_size, cfg.dtype
         self.hnorm = _Gain(e, dt)
         self.enorm = _Gain(e, dt)
         self.eh_proj = _Dense(2 * e, e, dt)
-        self.block = PanguDecoderLayer(cfg, sparse=True)
+        self.block = self.layer_class(cfg, sparse=True)
         self.norm = _Gain(e, dt)
 
 
 class PanguUltraMoEModel(nn.Layer):
-    def __init__(self, cfg: PanguUltraMoEConfig):
+    layer_class = PanguDecoderLayer
+
+    def __init__(self, cfg):
         super().__init__()
         self.config = cfg
         self.embed_tokens = nn.Layer()
         self.embed_tokens.weight = self.embed_tokens.create_parameter(
             [cfg.vocab_size, cfg.hidden_size], dtype=cfg.dtype,
             default_initializer=Normal(0.0, 1.0))
-        self.layers = nn.LayerList([PanguDecoderLayer(cfg, cfg.is_sparse(i))
+        self.layers = nn.LayerList([self.layer_class(cfg, cfg.is_sparse(i))
                                     for i in range(cfg.num_hidden_layers)])
         self.norm = _Gain(cfg.hidden_size, cfg.dtype)
 
@@ -421,12 +439,23 @@ class PanguUltraMoEModel(nn.Layer):
 
 
 class PanguUltraMoEForCausalLM(nn.Layer):
-    def __init__(self, cfg: PanguUltraMoEConfig):
+    # what a family of the same shape (models/deepseek_v32.py) replaces: the
+    # attribute the model under the head is kept under, and the two classes
+    backbone_name = "pangu"
+    model_class = PanguUltraMoEModel
+    mtp_class = PanguMTPModule
+
+    def __init__(self, cfg):
         super().__init__()
         self.config = cfg
-        self.pangu = PanguUltraMoEModel(cfg)
+        setattr(self, self.backbone_name, self.model_class(cfg))
         self.lm_head = _Dense(cfg.hidden_size, cfg.vocab_size, cfg.dtype)
-        self.mtp = (PanguMTPModule(cfg) if cfg.num_nextn_predict_layers > 0 else None)
+        self.mtp = (self.mtp_class(cfg) if cfg.num_nextn_predict_layers > 0 else None)
+
+    @property
+    def backbone(self):
+        """The model under the head: embedding, layers, final norm."""
+        return getattr(self, self.backbone_name)
 
     def _head(self, h, gain):
         eps = self.config.rms_norm_eps
@@ -441,8 +470,8 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         next-token module): -> (logits, mtp_logits [B, S - 1, V]), where row t
         of ``mtp_logits``, from the trunk's h_t and the embedding of token
         t + 1, predicts token t + 2."""
-        h = self.pangu(input_ids)
-        logits = self._head(h, self.pangu.norm)
+        h = self.backbone(input_ids)
+        logits = self._head(h, self.backbone.norm)
         if not mtp:
             return logits
         if self.mtp is None:
@@ -455,7 +484,7 @@ class PanguUltraMoEForCausalLM(nn.Layer):
                                    _rms(emb, p["enorm"], eps)], axis=-1)
             return cat @ p["proj"]
 
-        merged = _apply(mtp_merge, {"embed": self.pangu.embed_tokens.weight,
+        merged = _apply(mtp_merge, {"embed": self.backbone.embed_tokens.weight,
                                     "hnorm": m.hnorm.weight, "enorm": m.enorm.weight,
                                     "proj": m.eh_proj.weight}, h, input_ids)
         return logits, self._head(m.block(merged), m.norm)
@@ -475,9 +504,10 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         def v(t):
             return t._value.astype(dtype)
 
-        w = {"embed": v(self.pangu.embed_tokens.weight), "norm": v(self.pangu.norm.weight),
+        net = self.backbone
+        w = {"embed": v(net.embed_tokens.weight), "norm": v(net.norm.weight),
              "head": v(self.lm_head.weight), "layers": []}
-        for layer in self.pangu.layers:
+        for layer in net.layers:
             lw = {k: v(t) for k, t in layer.leaves().items()}
             kvb = lw.pop("wkv_b").reshape(C, H, N + V)
             lw["wuk"] = jnp.transpose(kvb[..., :N], (1, 2, 0))

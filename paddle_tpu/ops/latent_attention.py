@@ -24,14 +24,30 @@ accumulator) and a trip count that is DATA, the longest live context:
   ``[max_q_len x H, C + R]`` queries against ``[ctx_block, C + R]`` of its own
   context, causal inside the chunk.  Rows without a chunk cost nothing.
 
+``selection`` (a ``Selection``, made by ops/sparse_index.py) makes the
+visible set a query's OWN, in one form for each kind of row, the faster on a
+v5e (PERF.md section 6, PR 32).  A row's ONE token attends the K positions
+``idx`` names (those with ``ok``): ONE gather of the row's K entries through
+the table and a plain softmax over them in place of the blocked pass, so what
+it reads is K a row whatever the context (24 rows at 4-16k, K = 2,048: 1.8 ms
+against 3.0 for the blocked pass under a mask).  A chunk row keeps its blocked
+pass and attends ``position <= its own`` AND ``mask`` (row t: the packed token
+t's): it still brings every live entry, what is not selected is masked after
+the score, and costs what the dense pass costs (gathered it loses by three).
+``selection_reads`` counts what the two bring.
+
 Scopes (children of ``latent_attention``): ``kv_write``, ``kv_gather``,
-``scores`` (QK^T, mask, the online softmax's bookkeeping), ``values`` (PV)."""
+``scores`` (QK^T, mask, the online softmax's bookkeeping), ``values`` (PV),
+and ``select_gather`` (the selected entries of the one-token rows)."""
 from __future__ import annotations
+
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["latent_attention", "rope_half"]
+__all__ = ["latent_attention", "rope_half", "token_coords", "write_entries", "Selection",
+           "selection_reads"]
 
 _NEG = -1e30
 
@@ -62,10 +78,72 @@ def _online(carry, s, visible, v, pv):
     return m_new, l, acc
 
 
+def token_coords(T, seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, rows):
+    """Of each of ``T`` packed tokens: (row [T], position in its sequence
+    [T], whether it is a live token [T])."""
+    dec, now, cu = seq_lens_decoder, seq_lens_this_time, cu_seqlens_q
+    tok = jnp.arange(T, dtype=jnp.int32)
+    b_idx = jnp.clip(
+        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1, 0, rows - 1)
+    local = tok - cu[b_idx]
+    abs_pos = dec[b_idx] + local
+    valid = (tok < cu[-1]) & (local < now[b_idx])
+    return b_idx, abs_pos, valid
+
+
+def write_entries(cache, entries, block_tables, b_idx, abs_pos, valid):
+    """``entries`` [T, W] of the live tokens into ``cache`` [NB, bs, W] at
+    (block of the token's position by its row's table, slot); a token
+    without a block is dropped."""
+    nb, bs, _ = cache.shape
+    P = block_tables.shape[1]
+    blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, P - 1)]
+    blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)   # OOB -> drop
+    return cache.at[blk, abs_pos % bs].set(entries.astype(cache.dtype), mode="drop")
+
+
+class Selection(NamedTuple):
+    """What each query may attend (module docstring).  ``idx`` [B, K] int32,
+    ``ok`` [B, K] bool: the context positions of a row's ONE token, and which
+    of the K count.  ``mask`` [T + max_q_len, >= P x bs] bool: row t, a chunk
+    row's packed token t over its row's context positions; None where
+    ``max_q_len`` is 1 (no row feeds a chunk)."""
+    idx: jax.Array
+    ok: jax.Array
+    mask: Optional[jax.Array]
+
+
+def _tiling(blocks_per_seq: int, block_size: int, ctx_block: int):
+    """(table columns, positions) of the context a blocked pass brings a trip."""
+    per = max(1, min(blocks_per_seq, int(ctx_block) // block_size))
+    return per, per * block_size
+
+
+def _trips(n, Lc: int):
+    return (n + Lc - 1) // Lc
+
+
+def selection_reads(seq_lens_decoder, seq_lens_this_time, *, topk: int, gathered: int,
+                    block_size: int, blocks_per_seq: int, ctx_block: int = 512):
+    """Latent entries one ``latent_attention(selection=)`` call with these
+    lengths brings for the live queries whose context exceeds ``topk``,
+    summed a QUERY (int32 scalar; the arithmetic is the passes'): the token of
+    a one-token row its row's ``gathered`` (= K) entries; a chunk row's query
+    all of its row's trips, ``ceil((dec + now) / Lc) x Lc``, which the row's
+    queries share."""
+    dec, now = seq_lens_decoder, seq_lens_this_time
+    _, Lc = _tiling(blocks_per_seq, block_size, ctx_block)
+    ones = gathered * jnp.sum((now == 1) & (dec + 1 > topk))
+    sparse = jnp.clip(dec + now - topk, 0, now)         # queries at positions >= topk
+    chunks = jnp.sum(jnp.where(now > 1, sparse * _trips(dec + now, Lc) * Lc, 0))
+    return (ones + chunks).astype(jnp.int32)
+
+
 @jax.named_scope("latent_attention")
 def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
                      cu_seqlens_q, block_tables, *, rank: int, max_q_len: int,
-                     scale: float, ctx_block: int = 512):
+                     scale: float, ctx_block: int = 512,
+                     selection: Optional[Selection] = None):
     """One serving attention step over the latent cache.
 
     q        [T, H, C + R]: ``[q_lat | rope(q_rope)]`` of the packed tokens
@@ -75,6 +153,8 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
     ``blha_attention`` takes them (tokens already cached, tokens this step,
     packed offsets, [B, P] block ids with -1 unassigned).
     ``rank`` = C; ``max_q_len`` (static) bounds a row's tokens this step.
+    ``selection``: what each query may attend (``Selection``), or None: every
+    position up to the query's own.
 
     Returns (o_lat [T, H, C] in q's dtype, cache')."""
     T, H, W = q.shape
@@ -84,22 +164,13 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
     dec, now, cu = seq_lens_decoder, seq_lens_this_time, cu_seqlens_q
 
     # ---- token coordinates (as blha_attention) ---------------------------
-    tok = jnp.arange(T, dtype=jnp.int32)
-    b_idx = jnp.clip(
-        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1, 0, B - 1)
-    local = tok - cu[b_idx]
-    abs_pos = dec[b_idx] + local
-    valid = (tok < cu[-1]) & (local < now[b_idx])
+    b_idx, abs_pos, valid = token_coords(T, dec, now, cu, B)
 
     with jax.named_scope("kv_write"):
-        blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, P - 1)]
-        blk = jnp.where(valid & (blk >= 0) & (blk < nb), blk, nb)   # OOB -> drop
-        cache = cache.at[blk, abs_pos % bs].set(entries.astype(cache.dtype),
-                                                mode="drop")
+        cache = write_entries(cache, entries, block_tables, b_idx, abs_pos, valid)
 
     # context is read ``per`` table columns (= ctx_block positions) a pass
-    per = max(1, min(P, int(ctx_block) // bs))
-    Lc = per * bs
+    per, Lc = _tiling(P, bs, ctx_block)
     pad = (-P) % per
     bt = jnp.pad(block_tables, ((0, 0), (0, pad)), constant_values=-1)
     bt = jnp.where((bt < 0) | (bt >= nb), nb, bt)                   # -> zeros
@@ -127,10 +198,22 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
     def pv_one(p, v):
         return jnp.einsum("bhl,blc->bhc", p, v, preferred_element_type=jnp.float32)
 
-    _, l1, acc1 = jax.lax.fori_loop(
-        0, (jnp.max(n_ctx1) + Lc - 1) // Lc, one_block,
-        (jnp.full((B, H), _NEG, jnp.float32), jnp.zeros((B, H), jnp.float32),
-         jnp.zeros((B, H, C), jnp.float32)))
+    # (the operations of a call without a selection stay in the order they had:
+    # tests hold such a program's lowered text to the parent commit's)
+    def carry1():
+        return (jnp.full((B, H), _NEG, jnp.float32), jnp.zeros((B, H), jnp.float32),
+                jnp.zeros((B, H, C), jnp.float32))
+
+    if selection is None:
+        _, l1, acc1 = jax.lax.fori_loop(0, _trips(jnp.max(n_ctx1), Lc), one_block, carry1())
+    else:
+        idx, ok = selection.idx, selection.ok
+        with jax.named_scope("select_gather"):
+            blk = jnp.take_along_axis(bt, idx // bs, axis=1)
+            kv = cache.at[blk, idx % bs].get(mode="fill", fill_value=0).astype(cdt)
+        with jax.named_scope("scores"):
+            s = jnp.einsum("bhw,bkw->bhk", q1, kv, preferred_element_type=jnp.float32) * scale
+        _, l1, acc1 = _online(carry1(), s, (ok & one[:, None])[:, None, :], kv[..., :C], pv_one)
     o1 = (acc1 / jnp.maximum(l1, 1e-30)[..., None]).astype(q.dtype)  # [B, H, C]
 
     S = int(max_q_len)
@@ -142,6 +225,9 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
     rows = jnp.nonzero(now > 1, size=B, fill_value=0)[0].astype(jnp.int32)
     q_pad = jnp.pad(q, ((0, S), (0, 0), (0, 0))).astype(cdt)
     qi = jnp.arange(S, dtype=jnp.int32)
+    if selection is not None:
+        mask = jnp.pad(selection.mask,
+                       ((0, 0), (0, bt.shape[1] * bs - selection.mask.shape[1])))
 
     def pv_chunk(p, v):
         return jnp.einsum("qhl,lc->qhc", p, v, preferred_element_type=jnp.float32)
@@ -158,10 +244,12 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
                 s = jnp.einsum("qhw,lw->qhl", qt, kv,
                                preferred_element_type=jnp.float32) * scale
             vis = ((j * Lc + kpos)[None, :] <= (base + qi)[:, None])[:, None, :]
+            if selection is not None:
+                vis = vis & jax.lax.dynamic_slice(mask, (start, j * Lc), (S, Lc))[:, None, :]
             return _online(carry, s, vis, kv[:, :C], pv_chunk)
 
         _, l, acc = jax.lax.fori_loop(
-            0, (base + nq + Lc - 1) // Lc, block,
+            0, _trips(base + nq, Lc), block,
             (jnp.full((S, H), _NEG, jnp.float32), jnp.zeros((S, H), jnp.float32),
              jnp.zeros((S, H, C), jnp.float32)))
         o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(out.dtype)

@@ -23,7 +23,11 @@ in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
 ``attn_rows_kernel`` and the cache write's ``kv_write_tokens`` /
 ``kv_write_blocks`` for a dense paged cache, ``moe_tokens`` /
 ``moe_local_picks`` for expert layers, ``loop_tokens`` /
-``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run);
+``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run,
+``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected`` /
+``dsa_positions_read`` for learned sparse attention: ONE layer's, over the
+live queries whose context exceeds the model's ``index_topk``, beside
+``attn_positions_live``, the context of every row fed);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
@@ -38,6 +42,11 @@ chip a one-token row is in none of the three: it attends inside the
 programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
 ``loop_pass`` > ``while/body/`` the layers' scopes, ``norm``, ``exit_gate``
 (one pass of a looped trunk, itself the body of the loop over the passes);
+``latent_proj``, ``latent_attention`` > ``kv_write`` and the three under
+``while/body/`` (a latent cache), ``router``, ``experts``, ``shared_expert``
+(expert layers); ``indexer`` > ``index_proj``, ``index_write``,
+``while/body/`` {``index_gather``, ``index_scores``}, ``index_topk`` (the
+selector of learned sparse attention, ops/sparse_index.py);
 ``attention`` >
 ``flash_attention``, ``loss``, ``optimizer``, ``grad_unscale`` (the train
 step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``

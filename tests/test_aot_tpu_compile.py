@@ -267,3 +267,84 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     said = cfg["memory"]["compiled_for_v5e"][kind]
     assert said["arguments"] == mem.argument_size_in_bytes
     assert abs(said["live"] / live - 1) < 0.01 and said["temporaries"] < one_cache_layer
+
+
+# ------------------- latent attention under a learned selection, three programs
+DSA = "benchmark/configs/deepseek-v3.2-exp.serve1.json"
+
+
+@pytest.fixture(scope="module")
+def dsa_engine():
+    """``ServingEngine`` over DeepSeek-V3.2-Exp at deepseek-v3.2-exp.serve1's
+    geometry, its weights zeros and its pool two blocks: the programs take
+    both as arguments, and are lowered below for the cell's own shapes."""
+    import json
+
+    from benchmark.harness import loader
+    from paddle_tpu.distributed.topology import set_hybrid_communicate_group
+    from paddle_tpu.inference import ServingEngine
+
+    set_hybrid_communicate_group(None)
+    cfg = json.load(open(os.path.join(loader.ROOT, DSA)))
+    family = loader.load_module("families", cfg["family"])
+    model = family.build_model(cfg)
+    shapes = jax.eval_shape(lambda: family.make_weights(cfg, 0))
+    jax.tree_util.tree_map(
+        lambda p, s: setattr(p, "_value", jnp.zeros(s.shape, s.dtype)),
+        family.params_of(model), shapes, is_leaf=lambda x: hasattr(x, "_value"))
+    return cfg, ServingEngine(model, **dict(cfg["engine"], num_blocks=2))
+
+
+@pytest.mark.parametrize("kind", ["step_prefill_T512", "mixed_K8", "mega_K8"])
+def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind):
+    """The prefill step, the mixed scan and the decode scan of
+    deepseek-v3.2-exp.serve1 (4.6 B parameters, a pool of 5,120 blocks x two
+    arrays x five layers) compiled as the chip will run them: both pool
+    arrays keep the argument's row-major layout and neither is copied (at the
+    latent's own width 576 the compiler transposed every layer's pool: PR 26),
+    the score buffer and the selection are temporaries, and the largest
+    program leaves 1.5 GB of the chip free.  The figures are the
+    configuration file's ``memory.compiled_for_v5e``."""
+    cfg, eng = dsa_engine
+    B, T, P, K, C = eng.B, eng.T, eng.P, eng.megastep_k, eng.pc
+    nb, bs = cfg["engine"]["num_blocks"], eng.bs
+    assert (B, T, P, K, C) == (24, 512, 268, 8, 64)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+
+    i32 = lambda *s: sds(s, jnp.int32)                            # noqa: E731
+    f32 = lambda *s: sds(s, jnp.float32)                          # noqa: E731
+    weights = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), eng._weights)
+    caches = tuple([sds((nb,) + a.shape[1:], a.dtype) for a in layers]
+                   for layers in eng.caches)
+    assert [c[0].shape for c in caches] == [(nb, bs, 640), (nb, bs, 128)]
+    head = (weights, caches, sds(eng._rope.shape, eng._rope.dtype))
+    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
+    flag, bt = sds((B,), jnp.bool_), i32(B, P)
+    compiled = {
+        "step_prefill_T512": lambda: eng._build_step().lower(
+            *head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt, *samp, mq=T),
+        "mixed_K8": lambda: eng._build_mixed_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
+            i32(B), i32(B), *samp, K=K),
+        "mega_K8": lambda: eng._build_megastep().lower(
+            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
+            i32(B), *samp, None, K=K),
+    }[kind]().compile()
+    text = compiled.as_text()
+    for width in (640, 128):
+        pool = f"{nb},{bs},{width}"
+        orders = set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+)", text))
+        assert orders == {"2,1,0"}, (width, orders)
+        assert not re.search(rf"= bf16\[{pool}\][^\n]* copy\(", text)
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(kind, dict(arguments=mem.argument_size_in_bytes, temporaries=mem.temp_size_in_bytes,
+                     live=live))
+    assert live < V5E_BYTES_LIMIT - 1.5e9, live
+    said = cfg["memory"].get("compiled_for_v5e", {}).get(kind)
+    assert said is not None, "the configuration's memory.compiled_for_v5e lacks " + kind
+    assert said["arguments"] == mem.argument_size_in_bytes
+    assert abs(said["live"] / live - 1) < 0.01

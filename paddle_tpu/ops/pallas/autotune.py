@@ -44,13 +44,23 @@ def _bucket_seq(s: int) -> int:
     return b
 
 
-# Measured on TPU v5e-1 via tune() with in-graph iteration loops (bf16,
-# causal, seq 2048, head_dim 128: fwd 256x256 ≈ 9.2ms vs 512x512 10.4ms;
-# bwd within noise of each other — keep 256x256). Values are *targets* —
-# _pick_block snaps them to divisors of the actual seq.
+# Measured on TPU v5e-1 via tune() with in-graph iteration loops. head_dim 128
+# swept in PR 33 for the kernels that feed the MXU bf16 tiles, at the Mistral
+# train cell's call (tune((2048,), (128,), bh=128, kv_rep=4): q [128, 2048,
+# 128] bf16 over 32 KV heads, causal), ms a call, block_q x block_k:
+#   fwd  512x512 2.04 | 1024x512 2.20 | 1024x256 2.32 | 512x256 2.35 |
+#        512x1024 2.35 | 256x512 3.17 | 256x256 3.38 | 128x128 6.96
+#   bwd  512x512 5.17 | 1024x512 5.67 | 512x256 5.68 | 512x1024 5.77 |
+#        256x512 6.06 | 256x256 7.01 | 128x128 12.77
+# With one-pass products a tile's fixed work (the running max and sum, the
+# rescale of the accumulator, the loop turn) sets the pace, so larger tiles
+# win until the masked half of the diagonal tiles costs more (1024). The
+# float32 kernels before read fwd 256x256 9.2 against 512x512 10.4. Other head
+# sizes keep what was swept for those. Values are *targets* — _pick_block
+# snaps them to divisors of the actual seq.
 _DEFAULT_TARGETS: Dict[Tuple[str, int], Tuple[int, int]] = {
-    ("fwd", 128): (256, 256),
-    ("bwd", 128): (256, 256),
+    ("fwd", 128): (512, 512),
+    ("bwd", 128): (512, 512),
     ("fwd", 64): (256, 256),
     ("bwd", 64): (256, 256),
     # large head_dim: smaller tiles keep K/V + fp32 staging inside VMEM
@@ -127,20 +137,21 @@ def _candidates(kind: str, sq: int, sk: int):
                 yield bq, bk
 
 
-def _measure(kind: str, sq: int, sk: int, d: int, n_iter: int = 20) -> Tuple[int, int]:
-    """Time candidates with an IN-GRAPH iteration loop: each candidate runs
-    ``n_iter`` chained kernel invocations inside one jit dispatch, so
-    per-dispatch latency and async readback cannot corrupt the
-    measurement."""
+def _time_candidates(kind: str, sq: int, sk: int, d: int, n_iter: int = 20,
+                     bh: int = 8, kv_rep: int = 1) -> Dict[Tuple[int, int], float]:
+    """Seconds a call of every candidate the compiler takes, by an IN-GRAPH
+    iteration loop: each candidate runs ``n_iter`` chained kernel invocations
+    inside one jit dispatch, so per-dispatch latency and async readback cannot
+    corrupt the measurement. ``bh`` query heads over ``bh // kv_rep`` KV heads,
+    bf16, causal."""
     from jax import lax
 
     from . import flash_attention as fa
 
-    bh = 8
     rng = jax.random.key(0)
     q = jax.random.normal(rng, (bh, sq, d), jnp.bfloat16)
-    k = jax.random.normal(rng, (bh, sk, d), jnp.bfloat16)
-    v = jax.random.normal(rng, (bh, sk, d), jnp.bfloat16)
+    k = jax.random.normal(rng, (bh // kv_rep, sk, d), jnp.bfloat16)
+    v = jax.random.normal(rng, (bh // kv_rep, sk, d), jnp.bfloat16)
     scale = 1.0 / (d ** 0.5)
 
     def run_chained(body):
@@ -152,38 +163,58 @@ def _measure(kind: str, sq: int, sk: int, d: int, n_iter: int = 20) -> Tuple[int
         float(out.reshape(-1)[0])
         return (time.perf_counter() - t0) / n_iter
 
-    best, best_t = None, float("inf")
+    def fwd(x, bq, bk):
+        return fa._pallas_fwd(x, k, v, True, scale, bq, bk, False, kv_rep=kv_rep)[0]
+
     if kind != "fwd":
-        o, lse = fa._pallas_fwd(q, k, v, True, scale,
-                                _pick_block(sq, 256), _pick_block(sk, 256), False)
+        o, lse = fa._pallas_fwd(q, k, v, True, scale, _pick_block(sq, 256),
+                                _pick_block(sk, 256), False, kv_rep=kv_rep)
         g = jnp.ones_like(o)
+
+    def bwd(x, bq, bk):
+        # all three gradients reach the carry: an unused one takes its
+        # kernel out of the program
+        dq, dk, dv = fa._pallas_bwd(x, k, v, o, lse, g, True, scale, bq, bk,
+                                    False, kv_rep=kv_rep)
+        return dq + jnp.sum(dk + dv).astype(dq.dtype)
+
+    seconds = {}
     for bq, bk in _candidates(kind, sq, sk):
         try:
-            if kind == "fwd":
-                dt = run_chained(lambda x, bq=bq, bk=bk: fa._pallas_fwd(
-                    x, k, v, True, scale, bq, bk, False)[0].astype(q.dtype))
-            else:
-                dt = run_chained(lambda x, bq=bq, bk=bk: fa._pallas_bwd(
-                    x, k, v, o, lse, g, True, scale, bq, bk,
-                    False)[0].astype(q.dtype))
-            if dt < best_t:
-                best, best_t = (bq, bk), dt
-        except Exception:
+            seconds[bq, bk] = run_chained(
+                functools.partial(fwd if kind == "fwd" else bwd, bq=bq, bk=bk))
+        except Exception:  # a tiling the compiler refuses (VMEM) is no candidate
             continue
-    return best or (_pick_block(sq, 256), _pick_block(sk, 256))
+    return seconds
 
 
-def tune(seqs=(1024, 2048, 4096, 8192), head_dims=(64, 128), verbose=True):
+def _fastest(seconds, sq: int, sk: int) -> Tuple[int, int]:
+    if not seconds:
+        return _pick_block(sq, 256), _pick_block(sk, 256)
+    return min(seconds, key=seconds.get)
+
+
+def _measure(kind: str, sq: int, sk: int, d: int, **shape) -> Tuple[int, int]:
+    return _fastest(_time_candidates(kind, sq, sk, d, **shape), sq, sk)
+
+
+def tune(seqs=(1024, 2048, 4096, 8192), head_dims=(64, 128), verbose=True,
+         bh=8, kv_rep=1):
     """Offline tuner: measure all (kind, seq, head_dim) combos and return the
-    results table (also fills the in-process cache)."""
+    results table (also fills the in-process cache). ``bh`` and ``kv_rep`` give
+    the call's heads: ``tune((2048,), (128,), bh=128, kv_rep=4)`` is the
+    Mistral train cell's."""
     out = {}
     for d in head_dims:
         for s in seqs:
             for kind in ("fwd", "bwd"):
-                bq, bk = _measure(kind, s, s, d)
+                seconds = _time_candidates(kind, s, s, d, bh=bh, kv_rep=kv_rep)
+                bq, bk = _fastest(seconds, s, s)
                 _measured[(kind, _bucket_seq(s), _bucket_seq(s), d)] = (bq, bk)
                 out[(kind, s, d)] = (bq, bk)
                 if verbose:
-                    print(f"tune {kind} seq={s} d={d}: block_q={bq} block_k={bk}")
+                    print(f"tune {kind} seq={s} d={d}: block_q={bq} block_k={bk}  "
+                          + "  ".join(f"{q}x{k} {t * 1e3:.3f}ms"
+                                      for (q, k), t in sorted(seconds.items())))
     _save_cache()
     return out

@@ -61,25 +61,38 @@ def _compile(fn, chip, *shapes, names=()):
 BF16 = jnp.bfloat16
 
 
-@pytest.mark.parametrize("seq", [2048, 4096])
-@pytest.mark.parametrize("kv_rep", [1, 4], ids=["mha", "gqa4"])
+# (bh, kv_rep, seq, causal); "cell" is mistral7b.train.pretrain-2k's call
+# (4 x 32 heads over 8 KV heads), "ring" the non-causal block that ring
+# attention runs off the diagonal
+_FLASH_CALLS = {
+    "mha-2048": (32, 1, 2048, True), "gqa4-2048": (32, 4, 2048, True),
+    "mha-4096": (32, 1, 4096, True), "gqa4-4096": (32, 4, 4096, True),
+    "cell": (128, 4, 2048, True), "ring": (32, 4, 2048, False),
+}
+
+
+@pytest.mark.parametrize("call", _FLASH_CALLS)
 @pytest.mark.parametrize("kind", ["fwd", "bwd"])
-def test_flash_attention(chip, kind, kv_rep, seq):
+def test_flash_attention(chip, kind, call):
+    """bf16 operands at the table's blocks: whether Mosaic takes the kernels'
+    bf16 products (the backward's transposed scores among them) and their
+    tiles fit VMEM is learned here, on the CPU."""
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas.autotune import get_flash_blocks
 
-    bh, d = 32, 128
+    bh, kv_rep, seq, causal = _FLASH_CALLS[call]
+    d = 128
     scale = d ** -0.5
     bq, bk = get_flash_blocks(kind, seq, seq, d)
     q = ((bh, seq, d), BF16)
     kv = ((bh // kv_rep, seq, d), BF16)
     if kind == "fwd":
         _compile(lambda q, k, v: fa._pallas_fwd(
-            q, k, v, True, scale, bq, bk, False, kv_rep=kv_rep),
+            q, k, v, causal, scale, bq, bk, False, kv_rep=kv_rep),
             chip, q, kv, kv, names=("flash_fwd",))
     else:
         _compile(lambda q, k, v, o, lse, g: fa._pallas_bwd(
-            q, k, v, o, lse, g, True, scale, bq, bk, False, kv_rep=kv_rep),
+            q, k, v, o, lse, g, causal, scale, bq, bk, False, kv_rep=kv_rep),
             chip, q, kv, kv, q, ((bh, seq), jnp.float32), q,
             names=("flash_bwd_dq", "flash_bwd_dkv"))
 

@@ -69,6 +69,135 @@ class TestBackwardKernel:
         np.testing.assert_allclose(np.asarray(dv), np.asarray(rdv), rtol=2e-4, atol=2e-5)
 
 
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry (the
+    kernel's body under ``pallas_call``, the loops' under ``while``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+class TestBf16Operands:
+    """bf16 q, k, v: the tiles reach the MXU as bf16, the probabilities are
+    rounded to bf16 at their products, everything else is float32. Against the
+    float32 reference on the same values; the error is a share of the largest
+    reference value, as the chip check reads it (PR 33 on the chip at seq
+    2048: o .0025, dq .0069, dk .0055, dv .0068 at the worst of MHA and GQA;
+    these cases read up to .0045 here)."""
+
+    TOL = 0.01
+
+    @staticmethod
+    def _inputs(bh, kv_rep, sq, sk, d=32):
+        rs = np.random.RandomState(33)
+        return tuple(jnp.asarray(rs.randn(n, s, d), jnp.bfloat16) for n, s in
+                     ((bh, sq), (bh // kv_rep, sk), (bh // kv_rep, sk), (bh, sq)))
+
+    @staticmethod
+    def _reference(q, k, v, g, causal, scale, kv_rep):
+        """(o, lse, dq, dk, dv) in float32, K/V heads repeated and their
+        gradients summed over the group."""
+        q32, k32, v32, g32 = (x.astype(jnp.float32) for x in (q, k, v, g))
+
+        def fwd(q_, k_, v_):
+            return _ref_fwd_impl(q_, jnp.repeat(k_, kv_rep, axis=0),
+                                 jnp.repeat(v_, kv_rep, axis=0), causal, scale)
+
+        (o, lse), vjp = jax.vjp(fwd, q32, k32, v32)
+        return (o, lse) + vjp((g32, jnp.zeros_like(lse)))
+
+    # 64 positions in blocks of 16 and 32: tiles wholly under the diagonal,
+    # crossed by it (by two tiles of the smaller side where the blocks differ)
+    # and wholly over it; sk > sq moves the diagonal right, sq > sk leaves the
+    # first rows with no key at all
+    @pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32)],
+                             ids=lambda b: f"{b[0]}x{b[1]}")
+    @pytest.mark.parametrize("causal,sq,sk", [(True, 64, 64), (True, 32, 64),
+                                              (True, 64, 32), (False, 64, 64)])
+    @pytest.mark.parametrize("kv_rep", [1, 4], ids=["mha", "gqa4"])
+    def test_kernels_match_float32_reference(self, kv_rep, causal, sq, sk, blocks):
+        bq, bk = blocks
+        q, k, v, g = self._inputs(4, kv_rep, sq, sk)
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        o, lse = _pallas_fwd(q, k, v, causal, scale, bq, bk, interpret=True,
+                             kv_rep=kv_rep)
+        assert o.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+        dq, dk, dv = _pallas_bwd(q, k, v, o, lse, g, causal, scale, bq, bk,
+                                 interpret=True, kv_rep=kv_rep)
+        assert (dq.dtype, dk.dtype, dv.dtype) == (jnp.bfloat16,) * 3
+        want = self._reference(q, k, v, g, causal, scale, kv_rep)
+        for name, a, b in zip(("o", "lse", "dq", "dk", "dv"),
+                              (o, lse, dq, dk, dv), want):
+            a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+            seen = np.abs(b) < 1e29  # a row with no key: lse is a sentinel
+            assert np.isfinite(a).all(), name
+            err = np.abs(a - b)[seen].max() / max(np.abs(b[seen]).max(), 1.0)
+            assert err <= self.TOL, (name, err)
+            if not seen.all():
+                np.testing.assert_array_equal(np.asarray(o, np.float32)[:, :sq - sk], 0)
+                np.testing.assert_array_equal(np.asarray(dq, np.float32)[:, :sq - sk], 0)
+
+    @pytest.mark.parametrize("dtype,other", [(jnp.bfloat16, jnp.float32),
+                                             (jnp.float32, jnp.bfloat16)],
+                             ids=["bf16", "f32"])
+    def test_products_take_the_inputs_dtype(self, dtype, other):
+        """No float32 operand in a bf16 call's kernels (the package pins
+        ``highest``: a widened tile runs the several-pass product) and no bf16
+        one in a float32 call's (float32 callers keep float32 products)."""
+        q, k, v, g = (x.astype(dtype) for x in self._inputs(4, 4, 64, 64))
+        lse = jnp.zeros(q.shape[:2], jnp.float32)
+
+        def both(q, k, v, g, lse):
+            o, _ = _pallas_fwd(q, k, v, True, 0.25, 16, 16, interpret=True, kv_rep=4)
+            return _pallas_bwd(q, k, v, o, lse, g, True, 0.25, 16, 16,
+                               interpret=True, kv_rep=4)
+
+        dots = [e for e in _walk_eqns(jax.make_jaxpr(both)(q, k, v, g, lse).jaxpr)
+                if e.primitive.name == "dot_general"]
+        assert len(dots) == 2 * 2 + 3 + 4  # the forward's body twice: unmasked, masked
+        for e in dots:
+            assert all(x.aval.dtype == dtype for x in e.invars), e
+            assert e.outvars[0].aval.dtype == jnp.float32
+            if dtype == jnp.bfloat16:
+                assert e.params["precision"] == (jax.lax.Precision.DEFAULT,) * 2
+
+
+class TestTuner:
+    def test_times_every_kernel_of_a_kind(self, monkeypatch):
+        """The tuner's in-graph loop at a GQA shape, the kernels steered into
+        interpret mode: a time for the one candidate that divides 128, forward
+        and backward, at ``bh`` query heads over ``bh // kv_rep`` KV heads."""
+        import functools
+
+        from paddle_tpu.ops.pallas import autotune
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        seen = []
+
+        def steered(fn):
+            @functools.wraps(fn)
+            def call(*args, **kw):
+                args = list(args)
+                args[{"_pallas_fwd": 7, "_pallas_bwd": 10}[fn.__name__]] = True
+                out = fn(*args, **kw)
+                seen.append(fn.__name__)
+                return out
+            return call
+
+        monkeypatch.setattr(fa, "_pallas_fwd", steered(fa._pallas_fwd))
+        monkeypatch.setattr(fa, "_pallas_bwd", steered(fa._pallas_bwd))
+        for kind in ("fwd", "bwd"):
+            seconds = autotune._time_candidates(kind, 128, 128, 32, n_iter=1,
+                                                bh=4, kv_rep=2)
+            assert list(seconds) == [(128, 128)] and seconds[128, 128] > 0
+        assert "_pallas_bwd" in seen
+        assert autotune._measure("fwd", 128, 128, 32, n_iter=1) == (128, 128)
+
+
 class TestGQA:
     def test_forward_repeats_kv_heads(self):
         b, s, h, hk, d = 2, 32, 8, 2, 16

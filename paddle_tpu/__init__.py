@@ -10,6 +10,11 @@ from __future__ import annotations
 __version__ = "0.1.0"
 
 import os as _os
+import sys as _sys
+import time as _time
+
+# ``setup.import`` (profiler/__init__.py): this file, first line to last
+_import_t0, _jax_loaded = _time.perf_counter(), "jax" in _sys.modules
 
 import jax as _jax
 
@@ -155,3 +160,7 @@ def disable_static(place=None):
 
 def is_grad_enabled_():
     return is_grad_enabled()
+
+
+profiler.SETUP.record("setup.import", _import_t0, _time.perf_counter() - _import_t0,
+                      jax_loaded=_jax_loaded)

@@ -262,7 +262,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, SetupSpan
 from .ha import HANDOFF_FLUSH, FrontendLease, StaleEpoch
 from .journal import (ADMIT, EPOCH, PROGRESS, TERMINAL, JournalSuperseded,
                       RequestJournal)
@@ -479,6 +479,7 @@ class ServingFrontend:
     >>> fe.metrics.snapshot()["tokens_per_sec"]
     """
 
+    @SetupSpan("frontend.init")
     def __init__(self, engines: Union[ServingEngine, Sequence[ServingEngine]],
                  *, max_queue_requests: Optional[int] = None,
                  max_queue_tokens: Optional[int] = None,

@@ -106,7 +106,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..profiler import RecordEvent
+from ..profiler import SETUP, RecordEvent, SetupSpan
 from .faults import register_failpoint
 
 __all__ = ["BlockManager", "ServingRequest", "ServingEngine",
@@ -556,6 +556,11 @@ def _sum_counts(counts):
     return jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), counts)
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of the arrays in ``tree``."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
+
+
 def _np_dtype(name: str) -> np.dtype:
     """Numpy dtype for a cache dtype's string form.  ``bfloat16`` (and
     friends) only resolve once ml_dtypes' registrations are imported —
@@ -583,6 +588,7 @@ class ServingEngine:
     # degrade ladder skips the wire rung)
     wire_endpoint: Optional[str] = None
 
+    @SetupSpan("engine.init")
     def __init__(self, model, max_batch_size: int = 4, max_seq_len: int = 256,
                  block_size: int = 16, token_budget: int = 32,
                  num_blocks: Optional[int] = None, cache_dtype=None,
@@ -655,7 +661,15 @@ class ServingEngine:
         self._compute_dtype = (jnp.bfloat16 if cfg.dtype == "bfloat16"
                                else jnp.float32)
 
-        self._weights = model.serving_weights(self._compute_dtype)
+        # this engine's set-up, as ``state_summary()["setup"]`` gives it: the
+        # spans of its construction and the programs its launches acquired
+        self._setup_spans = [SETUP.innermost()]
+        self._acquired: List[Dict] = []
+        with SetupSpan("engine.init.weights") as span:
+            self._weights = model.serving_weights(self._compute_dtype)
+            self._rope = model.serving_rope(self.max_seq_len)
+            span.note(bytes=_tree_bytes((self._weights, self._rope)))
+        self._setup_spans.append(span)
         # rolling weight swaps / tenancy (ISSUE 18): a version label that
         # rides metric + trace attribution, and the model id tenant
         # routing keys on.  Both are plain host state — load_weights
@@ -663,7 +677,6 @@ class ServingEngine:
         # programs (model identity is NOT in _program_key).
         self.weights_version = "v0"
         self.model_id = "default"
-        self._rope = model.serving_rope(self.max_seq_len)
         # for every array the model's cache layers keep, a list (a layer
         # each), or ONE array with a leading layer axis where the model's
         # layers are a loop in its program (``spec.stacked``): (keys, values)
@@ -677,13 +690,16 @@ class ServingEngine:
                 return jnp.zeros((self.L,) + block, cache_dtype)
             return [jnp.zeros(block, cache_dtype) for _ in range(self.L)]
 
-        self.caches = tuple(pool(shape) for _, shape in spec.arrays)
-        if cache_quant == "int8":
-            self.cache_scales = [
-                {k: jnp.zeros((self.B, self.KV), jnp.float32)
-                 for k in ("kq", "vq", "kd", "vd")} for _ in range(self.L)]
-        else:
-            self.cache_scales = None
+        with SetupSpan("engine.init.pool") as span:
+            self.caches = tuple(pool(shape) for _, shape in spec.arrays)
+            if cache_quant == "int8":
+                self.cache_scales = [
+                    {k: jnp.zeros((self.B, self.KV), jnp.float32)
+                     for k in ("kq", "vq", "kd", "vd")} for _ in range(self.L)]
+            else:
+                self.cache_scales = None
+            span.note(bytes=_tree_bytes((self.caches, self.cache_scales)))
+        self._setup_spans.append(span)
         self.block_tables = np.full((self.B, self.P), -1, np.int32)
 
         # capture the renormalized post-top-k/top-p distribution each
@@ -791,22 +807,24 @@ class ServingEngine:
         # Programs are shared process-wide across engines with identical
         # trace-shaping config (see _PROGRAM_CACHE): a fresh engine over
         # an already-served geometry starts with warm compile caches.
-        self._programs = _PROGRAM_CACHE.setdefault(self._program_key(), {})
-        if "forward" not in self._programs:
-            fwd, trunk = self._build_forward(model)
-            self._programs["forward"] = fwd
-            self._programs["trunk"] = trunk
-        self._forward = self._programs["forward"]
-        self._trunk = self._programs["trunk"]
-        if "step" not in self._programs:
-            self._programs["step"] = self._build_step()
-        self._step_fn = self._programs["step"]
-        self._mega_fn = self._programs.get("mega")    # lazy: pure-decode scan
-        self._mixed_fn = self._programs.get("mixed")  # lazy: mixed-phase scan
-        self._spec_fn = self._programs.get("spec")    # lazy: spec verify
-        self._cow_fn = self._programs.get("cow")      # lazy: COW block copy
-        self._put_fn = self._programs.get("put")      # lazy: block import write
-        self.compile_count = 0
+        with SetupSpan("engine.init.programs") as span:
+            self._programs = _PROGRAM_CACHE.setdefault(self._program_key(), {})
+            span.note(shared="step" in self._programs)
+            if "forward" not in self._programs:
+                fwd, trunk = self._build_forward(model)
+                self._programs["forward"] = fwd
+                self._programs["trunk"] = trunk
+            self._forward = self._programs["forward"]
+            self._trunk = self._programs["trunk"]
+            if "step" not in self._programs:
+                self._programs["step"] = self._build_step()
+            self._step_fn = self._programs["step"]
+            self._mega_fn = self._programs.get("mega")    # lazy: pure-decode scan
+            self._mixed_fn = self._programs.get("mixed")  # lazy: mixed-phase scan
+            self._spec_fn = self._programs.get("spec")    # lazy: spec verify
+            self._cow_fn = self._programs.get("cow")      # lazy: COW block copy
+            self._put_fn = self._programs.get("put")      # lazy: block import write
+        self._setup_spans.append(span)
 
     def _program_key(self) -> tuple:
         """Everything the compiled-program closures capture that shapes
@@ -1450,6 +1468,14 @@ class ServingEngine:
             # cumulative host seconds per step phase — megastep cost
             # attribution without a profiler (ISSUE 15 satellite)
             "phase_seconds": dict(self.phase_seconds),
+            # where this engine's start-up went (profiler.setup_report() has
+            # the process's): the rows of its ``engine.init`` spans and of
+            # the ``program.acquire`` of every program its launches compiled
+            # or read; grows only while programs are new
+            "setup": {
+                "stages": [s.row for s in self._setup_spans],
+                "programs": list(self._acquired),
+            },
         }
 
     def pop_trace_events(self) -> List[Dict]:
@@ -1554,6 +1580,14 @@ class ServingEngine:
         return self._phase("launch", kind=kind, k=k, launch=self.launches,
                            t_mono=self._clock(), passes=self.cache_spec.passes,
                            **attrs)
+
+    def _acquire(self, fn, launch: _Phase):
+        """``program.acquire``, after the fact: ``launch`` has just ended and
+        found ``fn``'s cache grown, so its seconds held the trace, the
+        lowering and the compile or cache read of one more program."""
+        row = SETUP.acquired(fn.__name__, launch.seconds, kind=launch.attrs["kind"],
+                             k=launch.attrs["k"])
+        self._acquired.append(row)
 
     def step(self) -> Dict[int, List[int]]:
         """One engine iteration: schedule -> compiled step(s) -> retire.
@@ -1746,7 +1780,7 @@ class ServingEngine:
                 pos += n
                 cu[slot + 1] = pos
 
-        with self._launch_phase("step", 1):
+        with self._launch_phase("step", 1) as launch:
             had_cache = self._step_fn._cache_size() if hasattr(self._step_fn, "_cache_size") else None
             nxt, lps, probs, self.caches, new_scales, counts = \
                 self._step_fn(
@@ -1759,8 +1793,10 @@ class ServingEngine:
                     mq=1 if decode_only else self.T, scales=self.cache_scales)
             if self.cache_scales is not None:
                 self.cache_scales = new_scales
-            if had_cache is not None:
-                self.compile_count += self._step_fn._cache_size() - had_cache
+            compiled = (had_cache is not None
+                        and self._step_fn._cache_size() > had_cache)
+        if compiled:
+            self._acquire(self._step_fn, launch)
         with self._phase("wait"):
             nxt = np.asarray(nxt)
             lps = np.asarray(lps)
@@ -1928,7 +1964,7 @@ class ServingEngine:
                                     spos)
                 pos += len(row)
                 cu[slot + 1] = pos
-        with self._launch_phase("spec", Kp1):
+        with self._launch_phase("spec", Kp1) as launch:
             if self._spec_fn is None:
                 if "spec" not in self._programs:
                     self._programs["spec"] = self._build_spec_verify()
@@ -1942,8 +1978,9 @@ class ServingEngine:
                 jnp.asarray(dlen), jnp.asarray(draft_a), jnp.asarray(temps),
                 jnp.asarray(top_ks), jnp.asarray(top_ps), jnp.asarray(seeds),
                 jnp.asarray(spos))
-            if had is not None:
-                self.compile_count += self._spec_fn._cache_size() - had
+            compiled = had is not None and self._spec_fn._cache_size() > had
+        if compiled:
+            self._acquire(self._spec_fn, launch)
         with self._phase("wait"):
             nxt = np.asarray(nxt)       # [B, spec_k+1] redraws
             lps = np.asarray(lps)
@@ -2060,11 +2097,9 @@ class ServingEngine:
                     jnp.asarray(spos), self.cache_scales, K=K)
             if self.cache_scales is not None:
                 self.cache_scales = new_scales
-            compiled = False
-            if had is not None:
-                grew = self._mega_fn._cache_size() - had
-                self.compile_count += grew
-                compiled = grew > 0
+            compiled = had is not None and self._mega_fn._cache_size() > had
+        if compiled:
+            self._acquire(self._mega_fn, launch)
         with self._phase("wait") as wait:
             toks_o = np.asarray(toks_o)       # [K, B]
             valid_o = np.asarray(valid_o)
@@ -2190,11 +2225,9 @@ class ServingEngine:
                 jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
                 jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
                 jnp.asarray(seeds), jnp.asarray(spos), K=K)
-            compiled = False
-            if had is not None:
-                grew = self._mixed_fn._cache_size() - had
-                self.compile_count += grew
-                compiled = grew > 0
+            compiled = had is not None and self._mixed_fn._cache_size() > had
+        if compiled:
+            self._acquire(self._mixed_fn, launch)
         with self._phase("wait") as wait:
             pp_f = np.asarray(pp_f)           # [B] final prefill positions
             toks_o = np.asarray(toks_o)       # [K, B]

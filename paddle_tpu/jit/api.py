@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from ..autograd import tape
 from ..framework.random import default_generator
-from ..profiler import RecordEvent
+from ..profiler import SETUP, RecordEvent, SetupSpan
 from ..tensor.tensor import Tensor
 from . import trace_state
 
@@ -434,10 +434,17 @@ class TrainStep:
         self.scaler = scaler if (scaler is not None and scaler.is_enable()) else None
         self._params = list(model.parameters())
         self._buffers = [b for b in model.buffers() if b is not None]
-        optimizer._ensure_state()
-        self._pid2idx = {id(p): i for i, p in enumerate(self._params)}
-        self._commit_state_to_mesh()
+        with SetupSpan("train_step.init") as span:
+            optimizer._ensure_state()
+            self._pid2idx = {id(p): i for i, p in enumerate(self._params)}
+            self._commit_state_to_mesh()
+            span.note(bytes=sum(int(a.nbytes) for a in
+                                jax.tree_util.tree_leaves(self._get_opt_state())))
         self._compiled = None
+        # until a call has compiled nothing: a call that grows the step's
+        # cache leaves a ``program.acquire`` row (the second call may too,
+        # where the first one's outputs are placed as its inputs were not)
+        self._acquiring = True
         self._multi_cache: Dict[Any, Any] = {}
         self._step_raw = None
         self._donate = donate
@@ -634,10 +641,17 @@ class TrainStep:
                     maybe_dump("train_step", self._compiled,
                                ([p._value for p in self._params], accs, masters, buf_vals,
                                 self._scaler_state(), rng_key, batch_vals, lr))
+            if self._acquiring:
+                had, t0 = self._compiled._cache_size(), SETUP.clock()
             loss, new_params, new_accs, new_masters, buf_out, new_scaler = self._compiled(
                 [p._value for p in self._params], accs, masters, buf_vals,
                 self._scaler_state(), rng_key, batch_vals, lr,
             )
+            if self._acquiring:
+                self._acquiring = self._compiled._cache_size() > had
+                if self._acquiring:
+                    SETUP.acquired(self._compiled.__name__, SETUP.clock() - t0,
+                                   kind="train", k=1)
             for p, v in zip(self._params, new_params):
                 p._value = v
             self._put_opt_state(new_accs, new_masters)
@@ -684,7 +698,8 @@ class TrainStep:
         with RecordEvent("train_step.call", step=self.optimizer._step_count,
                          steps=K):
             multi = self._multi_cache.get(spec_sig)
-            if multi is None:
+            first_call = multi is None
+            if first_call:
                 step_raw = self._step_raw
 
                 def multi_fn(param_vals, accs, masters, buf_vals, scaler_state,
@@ -715,11 +730,14 @@ class TrainStep:
             base_key = default_generator().next_key()
             lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
             accs, masters = self._get_opt_state()
+            t0 = SETUP.clock() if first_call else None
             losses, new_params, new_accs, new_masters, buf_out, new_scaler = multi(
                 [p._value for p in self._params], accs, masters,
                 [b._value for b in self._buffers], self._scaler_state(),
                 base_key, batch_vals, lr,
             )
+            if first_call:
+                SETUP.acquired(multi.__name__, SETUP.clock() - t0, kind="train", k=K)
             for p, v in zip(self._params, new_params):
                 p._value = v
             self._put_opt_state(new_accs, new_masters)

@@ -27,6 +27,7 @@ from .. import nn
 from ..distributed.fleet.mp_layers import ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding
 from ..nn import functional as F
 from ..ops.dispatch import apply
+from ..profiler import SetupSpan
 from ..tensor import manipulation as M
 from ..tensor.tensor import Tensor
 
@@ -421,14 +422,17 @@ class LlamaForCausalLM(nn.Layer):
     supports_static_kv_cache = True  # 3-tuple (k_buf, v_buf, pos) ring decode
 
     def __init__(self, config: LlamaConfig):
-        super().__init__()
-        self.config = config
-        self.llama = LlamaModel(config)
-        if config.tie_word_embeddings:
-            self.lm_head = None
-        else:
-            self.lm_head = ColumnParallelLinear(config.hidden_size, config.vocab_size,
-                                                has_bias=False, gather_output=True)
+        with SetupSpan("model.init", family=type(self).__name__,
+                       dtype=config.dtype) as span:
+            super().__init__()
+            self.config = config
+            self.llama = LlamaModel(config)
+            if config.tie_word_embeddings:
+                self.lm_head = None
+            else:
+                self.lm_head = ColumnParallelLinear(config.hidden_size, config.vocab_size,
+                                                    has_bias=False, gather_output=True)
+            span.note(parameters=self.num_params)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
         out = self.llama(input_ids, attn_mask, caches)

@@ -50,6 +50,7 @@ from ..ops.dispatch import apply
 from ..ops.paged_attention import (attention_positions, blha_attention,
                                    cache_write_counts, decodes_in_kernel,
                                    rope_rotate, writes_in_kernel)
+from ..profiler import SetupSpan
 from .pangu_moe import _rms, _swiglu          # the sandwich block's two, as openPangu has them
 
 __all__ = ["OuroConfig", "OuroModel", "OuroForCausalLM", "exit_distribution"]
@@ -218,12 +219,14 @@ class OuroModel(nn.Layer):
 
 class OuroForCausalLM(nn.Layer):
     def __init__(self, cfg: OuroConfig):
-        super().__init__()
-        self.config = cfg
-        self.model = OuroModel(cfg)
-        self.lm_head = nn.Layer()
-        self.lm_head.weight = _matrix(self.lm_head, (cfg.hidden_size, cfg.vocab_size),
-                                      cfg.dtype)
+        with SetupSpan("model.init", family=type(self).__name__, dtype=cfg.dtype) as span:
+            super().__init__()
+            self.config = cfg
+            self.model = OuroModel(cfg)
+            self.lm_head = nn.Layer()
+            self.lm_head.weight = _matrix(self.lm_head, (cfg.hidden_size, cfg.vocab_size),
+                                          cfg.dtype)
+            span.note(parameters=self.num_params())
 
     def leaves(self):
         """Every parameter, in the structure the pure functions read."""

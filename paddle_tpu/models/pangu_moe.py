@@ -39,6 +39,7 @@ from .. import nn
 from ..nn.initializer import Constant, Normal
 from ..ops.dispatch import apply
 from ..ops.latent_attention import latent_attention, rope_half
+from ..profiler import SetupSpan
 
 __all__ = ["PanguUltraMoEConfig", "PanguUltraMoEModel", "PanguUltraMoEForCausalLM",
            "PanguSparseMoE", "PanguMLAttention", "pangu_ultra_moe_tiny"]
@@ -446,11 +447,13 @@ class PanguUltraMoEForCausalLM(nn.Layer):
     mtp_class = PanguMTPModule
 
     def __init__(self, cfg):
-        super().__init__()
-        self.config = cfg
-        setattr(self, self.backbone_name, self.model_class(cfg))
-        self.lm_head = _Dense(cfg.hidden_size, cfg.vocab_size, cfg.dtype)
-        self.mtp = (self.mtp_class(cfg) if cfg.num_nextn_predict_layers > 0 else None)
+        with SetupSpan("model.init", family=type(self).__name__, dtype=cfg.dtype) as span:
+            super().__init__()
+            self.config = cfg
+            setattr(self, self.backbone_name, self.model_class(cfg))
+            self.lm_head = _Dense(cfg.hidden_size, cfg.vocab_size, cfg.dtype)
+            self.mtp = (self.mtp_class(cfg) if cfg.num_nextn_predict_layers > 0 else None)
+            span.note(parameters=self.num_params())
 
     @property
     def backbone(self):

@@ -30,6 +30,36 @@ live queries whose context exceeds the model's ``index_topk``, beside
 ``attn_positions_live``, the context of every row fed);
 ``train_step.call`` (stats ``step``, ``steps``).
 
+Set-up spans, every one a ``SetupSpan``: a ``RecordEvent`` that also leaves a
+row ``{id, name, parent, attrs, t0, seconds}`` on ``time.perf_counter`` in the
+process-wide, bounded ``SETUP`` ledger (``parent``: the set-up span open when
+it opened; the steady-state spans above write no rows):
+``setup.import`` (``paddle_tpu/__init__.py`` first line to last, written after
+the fact; attr ``jax_loaded``: jax was imported before it);
+``model.init`` (the served causal-LM constructors; attrs ``family``,
+``dtype``, ``parameters``);
+``engine.init`` > ``engine.init.weights`` (``model.serving_weights`` and the
+rope table: cast, stack, place; attr ``bytes``), ``engine.init.pool`` (the
+cache arrays; attr ``bytes``), ``engine.init.programs`` (``_build_*`` and the
+``_PROGRAM_CACHE`` lookup; attr ``shared``: another engine's programs were
+taken); ``frontend.init``; ``train_step.init`` (optimizer state and masters;
+attr ``bytes``);
+``program.acquire`` (written after the fact, on the launch or train step that
+found its jitted program's cache grown: trace, lowering, compile or cache read
+and the first execution's enqueue; attrs ``program`` (the jitted function's
+name), ``kind`` and ``k`` as ``engine.launch`` has them (``kind`` ``train`` for
+the train step), and the compile ledger's row of it: ``trace_s``, ``lower_s``,
+``backend_s``, ``cache_hit``, ``cache_read_s``).
+
+The compile ledger (``SETUP.compiles``), fed by ONE pair of ``jax.monitoring``
+listeners that fire where jax traces, lowers or compiles and nowhere else: a
+row a program handed to the backend, ``{fun_name, trace_s, lower_s,
+backend_s, cache_hit, cache_read_s, t0, acquired}`` (``backend_s``: XLA
+compiling, or the persistent cache read back; ``acquired``: a
+``program.acquire`` claimed it; the rest, jax's own small programs and the
+eager helpers of a model's build, are what ``setup_report()`` sums under
+``other``).  ``setup_report()`` is what an operator reads.
+
 Device scopes (``jax.named_scope``: metadata in the compiled program, nothing
 at run time), one vocabulary for every model family:
 ``embed``, ``attn_proj``, ``paged_attention`` > ``rope`` ``kv_write`` (on the
@@ -60,10 +90,13 @@ Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
+import re
+import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
@@ -72,6 +105,7 @@ import jax
 __all__ = [
     "Profiler", "ProfilerState", "ProfilerTarget", "make_scheduler", "export_chrome_tracing",
     "RecordEvent", "benchmark", "SummaryView",
+    "SetupSpan", "SetupLedger", "SETUP", "setup_report",
 ]
 
 
@@ -156,6 +190,239 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+# jax.monitoring's names for what a jitted call does before it can run
+# (jax/_src/dispatch.py, compiler.py); each duration carries ``fun_name``
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_MODULE = re.compile(r"^\w+\((.*)\)$")            # "jit(step)" -> "step"
+_COMPILE_SECONDS = ("trace_s", "lower_s", "backend_s")
+
+
+def _compile_seconds(row) -> float:
+    return row["trace_s"] + row["lower_s"] + row["backend_s"]
+
+
+def _compile_row(fun_name, t0, trace_s=0.0, lower_s=0.0) -> dict:
+    return {"fun_name": fun_name, "trace_s": trace_s, "lower_s": lower_s, "backend_s": 0.0,
+            "cache_hit": False, "cache_read_s": 0.0, "t0": t0, "acquired": False}
+
+
+class _ThreadState(threading.local):
+    """A thread's open set-up spans, the traces it has seen since its last
+    lowering, and the program it has lowered and not yet compiled."""
+
+    def __init__(self):
+        self.spans, self.traces, self.flight = [], {}, None
+
+
+class SetupLedger:
+    """Where a process's seconds go before its first launch: the rows of the
+    set-up spans and the compile ledger (the module's docstring has the
+    names).  Both lists are bounded: overflow drops the oldest and counts
+    it, as ``FlightRecorder`` does."""
+
+    def __init__(self, capacity: int = 512, clock: Callable[[], float] = time.perf_counter):
+        self.clock = clock
+        self.rows: deque = deque(maxlen=int(capacity))
+        self.compiles: deque = deque(maxlen=4 * int(capacity))
+        self.dropped_rows = 0
+        self.dropped_compiles = 0
+        self._ids = itertools.count(1)
+        self._local = _ThreadState()
+
+    # ------------------------------------------------------------ span rows
+    def innermost(self) -> Optional["SetupSpan"]:
+        """The set-up span this thread has open, if any."""
+        spans = self._local.spans
+        return spans[-1] if spans else None
+
+    def _append(self, span_id, parent, name, attrs, t0, seconds) -> dict:
+        row = {"id": span_id, "name": name, "parent": parent, "attrs": attrs,
+               "t0": t0, "seconds": seconds}
+        if len(self.rows) == self.rows.maxlen:
+            self.dropped_rows += 1
+        self.rows.append(row)
+        return row
+
+    def record(self, name: str, t0: float, seconds: float, **attrs) -> dict:
+        """One row, after the fact, under the span this thread has open now."""
+        inner = self.innermost()
+        return self._append(next(self._ids), inner.id if inner is not None else None,
+                            name, attrs, t0, seconds)
+
+    def acquired(self, program: str, seconds: float, **attrs) -> dict:
+        """``program.acquire``, after the fact: the call of the jitted function
+        ``program`` that has just returned took ``seconds`` and had to trace,
+        lower and compile or read it.  The compile ledger's newest unclaimed
+        row of that name is its own, and its numbers ride the row."""
+        end = self.clock()
+        for c in reversed(self.compiles):
+            if c["fun_name"] == program and not c["acquired"]:
+                c["acquired"] = True
+                attrs.update({k: c[k] for k in
+                              _COMPILE_SECONDS + ("cache_hit", "cache_read_s")})
+                break
+        return self.record("program.acquire", end - seconds, seconds,
+                           program=program, **attrs)
+
+    # -------------------------------------------------------- compile ledger
+    def _on_duration(self, event, secs, fun_name=None, **_):
+        st = self._local
+        if event == _TRACE:
+            # every jitted function traced on the way fires one; the program's
+            # own is the one its lowering then names
+            if len(st.traces) > 256:
+                st.traces.clear()
+            st.traces[fun_name] = secs
+        elif event == _LOWER:
+            name = _MODULE.sub(r"\1", fun_name or "")
+            trace_s = st.traces.get(name, 0.0)
+            st.traces.clear()
+            st.flight = _compile_row(name, self.clock() - secs - trace_s, trace_s, secs)
+        elif event == _CACHE_READ:
+            if st.flight is not None:
+                st.flight["cache_read_s"] = secs
+        elif event == _BACKEND:
+            name = _MODULE.sub(r"\1", fun_name or "")
+            row, st.flight = st.flight, None
+            if row is None or row["fun_name"] != name:    # compiled from a lowering kept
+                row = _compile_row(name, self.clock() - secs)
+            row["backend_s"] = secs
+            if len(self.compiles) == self.compiles.maxlen:
+                self.dropped_compiles += 1
+            self.compiles.append(row)
+
+    def _on_event(self, event, **_):
+        if event == _CACHE_HIT and self._local.flight is not None:
+            self._local.flight["cache_hit"] = True
+
+    # --------------------------------------------------------------- report
+    def report(self, since: Optional[float] = None, until: Optional[float] = None) -> dict:
+        """What ``setup_report`` returns, over ``[since, until]`` on the
+        ledger's clock (by default from the first row to the end of the last)."""
+        rows, compiles = list(self.rows), list(self.compiles)
+        starts = [r["t0"] for r in rows] + [c["t0"] for c in compiles]
+        ends = ([r["t0"] + r["seconds"] for r in rows]
+                + [c["t0"] + _compile_seconds(c) for c in compiles])
+        if since is None:
+            since = min(starts, default=self.clock())
+        if until is None:
+            until = max(ends + [since])
+        rows = sorted((r for r in rows if r["t0"] < until and r["t0"] + r["seconds"] > since),
+                      key=lambda r: (r["t0"], r["id"]))
+        compiles = [c for c in compiles if since <= c["t0"] < until]
+        kept = {r["id"] for r in rows}
+        stages, named = [], 0.0
+        for r in rows:
+            end = r["t0"] + r["seconds"]
+            children = sum(c["seconds"] for c in rows if c["parent"] == r["id"])
+            stage = dict(r, self_s=r["seconds"] - children,
+                         compile_s=sum(_compile_seconds(c) for c in compiles
+                                       if r["t0"] <= c["t0"] < end))
+            stages.append(stage)
+            if r["parent"] not in kept:           # top level: no two overlap on a thread
+                named += min(end, until) - max(r["t0"], since)
+        programs = sorted((dict(r["attrs"], t0=r["t0"], seconds=r["seconds"])
+                           for r in rows if r["name"] == "program.acquire"),
+                          key=lambda p: -sum(p.get(k, 0.0) for k in _COMPILE_SECONDS))
+        rest = [c for c in compiles if not c["acquired"]]
+        by_name = defaultdict(lambda: [0, 0.0])
+        for c in rest:
+            by_name[c["fun_name"]][0] += 1
+            by_name[c["fun_name"]][1] += _compile_seconds(c)
+        other = {"count": len(rest), "cache_hits": sum(c["cache_hit"] for c in rest),
+                 "by_name": sorted(([n, k, s] for n, (k, s) in by_name.items()),
+                                   key=lambda e: -e[2])[:5]}
+        other.update({k: sum(c[k] for c in rest) for k in _COMPILE_SECONDS})
+        out = {"since": since, "until": until, "seconds": until - since,
+               "stages": stages, "programs": programs, "other": other,
+               "compiles": compiles, "unnamed_s": (until - since) - named,
+               "dropped": {"rows": self.dropped_rows, "compiles": self.dropped_compiles}}
+        out["text"] = _setup_table(out)
+        return out
+
+
+def _setup_table(rep: dict) -> str:
+    """About ten lines: the stages by name, the dearest programs, the rest."""
+    depth, agg = {}, {}
+    for s in rep["stages"]:
+        depth[s["id"]] = depth.get(s["parent"], -1) + 1
+        a = agg.setdefault(s["name"], [depth[s["id"]], 0, 0.0, 0.0])
+        a[1] += 1
+        a[2] += s["seconds"]
+        a[3] += s["self_s"]
+    lines = [f"set-up: {rep['seconds']:.2f} s, {rep['unnamed_s']:.2f} s of it under no span"
+             + (f" ({rep['dropped']['rows']} rows, {rep['dropped']['compiles']} compiles dropped)"
+                if any(rep["dropped"].values()) else ""),
+             f"{'stage':<30}{'n':>4}{'seconds':>10}{'self':>10}"]
+    lines += [f"{'  ' * d + name:<30}{n:>4}{secs:>10.2f}{self_s:>10.2f}"
+              for name, (d, n, secs, self_s) in agg.items()]
+    lines.append(f"{'program (kind, k)':<30}{'trace':>8}{'lower':>8}{'backend':>9}  cache")
+    for p in rep["programs"][:5]:
+        hit = {True: "hit", False: "miss"}.get(p.get("cache_hit"), "-")
+        label = f"{p['program']} ({p.get('kind')}, {p.get('k')})"
+        lines.append(f"{label:<30}{p.get('trace_s', 0.0):>8.2f}{p.get('lower_s', 0.0):>8.2f}"
+                     f"{p.get('backend_s', 0.0):>9.2f}  {hit}")
+    o = rep["other"]
+    lines.append(f"{'other x' + str(o['count']):<30}{o['trace_s']:>8.2f}{o['lower_s']:>8.2f}"
+                 f"{o['backend_s']:>9.2f}  {o['cache_hits']} hits; "
+                 + ", ".join(f"{n} x{k} {s:.2f}" for n, k, s in o["by_name"][:3]))
+    return "\n".join(lines)
+
+
+SETUP = SetupLedger()
+jax.monitoring.register_event_duration_secs_listener(SETUP._on_duration)
+jax.monitoring.register_event_listener(SETUP._on_event)
+
+
+def setup_report(since: Optional[float] = None, until: Optional[float] = None) -> dict:
+    """Why did this process take so long to start: ``stages`` (the set-up
+    spans' rows in order, each with its ``self_s`` and the ``compile_s`` jax
+    spent inside it), ``programs`` (the ``program.acquire`` rows, dearest
+    first by trace + lowering + backend seconds), ``other`` (the compiles no
+    acquisition claimed, summed, with the five dearest names), ``compiles``
+    (the compile ledger's rows), ``unnamed_s`` (seconds of ``[since, until]``
+    under no span) and ``text``, a table of it.  Times are
+    ``time.perf_counter``'s; the interval defaults to first row .. last end."""
+    return SETUP.report(since, until)
+
+
+class SetupSpan(RecordEvent, contextlib.ContextDecorator):
+    """A ``RecordEvent`` of the set-up path: the same span in a trace, and a
+    row in ``SETUP`` when it ends.  ``note`` adds what is known only after
+    the work (bytes, parameters) to the row.  Also a decorator, a fresh span
+    a call: ``@SetupSpan("engine.init")``."""
+
+    def __init__(self, name: str, *, ledger: Optional[SetupLedger] = None, **attrs):
+        super().__init__(name, **attrs)
+        self.ledger = SETUP if ledger is None else ledger
+        self.id = self.parent = self.row = self._start = None
+
+    def _recreate_cm(self):
+        return type(self)(self.name, ledger=self.ledger, **self._attrs)
+
+    def note(self, **attrs):
+        self._attrs.update(attrs)
+
+    def begin(self):
+        spans = self.ledger._local.spans
+        self.id = next(self.ledger._ids)
+        self.parent = spans[-1].id if spans else None
+        spans.append(self)
+        self._start = self.ledger.clock()
+        super().begin()
+
+    def end(self):
+        super().end()
+        seconds = self.ledger.clock() - self._start
+        self.ledger._local.spans.remove(self)
+        self.row = self.ledger._append(self.id, self.parent, self.name, dict(self._attrs),
+                                       self._start, seconds)
 
 
 class SummaryView(Enum):

@@ -98,7 +98,7 @@ import hashlib
 import time
 from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -108,6 +108,7 @@ import jax.numpy as jnp
 
 from ..profiler import SETUP, RecordEvent, SetupSpan
 from .faults import register_failpoint
+from .launch_block import Layout, ResultBlock
 
 __all__ = ["BlockManager", "ServingRequest", "ServingEngine",
            "SamplingParams", "prefix_block_hash", "prompt_block_hashes",
@@ -551,6 +552,38 @@ class _Phase:
         return False
 
 
+@lru_cache(maxsize=64)
+def control_layout(kind: str, B: int, P: int, n: int = 0) -> Layout:
+    """The control rows a launch of ``kind`` sends up, in block order: the
+    ``[B]`` scheduling rows, the five sampling rows, then the flattened
+    tails (the block table, the mixed scan's prompt window).  ``n``: the
+    packed token buffer's length (``step``), the prompt window's width
+    ``K x chunk`` (``mixed``), ``spec_k`` (``spec``).  The host packs by it
+    and the program slices by it: static, from numbers both already key
+    their shapes on."""
+    rows = {
+        "step": ("enc", "dec", "now"),
+        "mega": ("toks", "dec", "now", "occ_idx", "active", "remaining", "dl", "eos"),
+        "mixed": ("toks", "cached", "pp", "pp0", "plen", "active", "remaining",
+                  "dl", "eos"),
+        "spec": ("dec", "now", "dlen"),
+    }[kind]
+    tails = {
+        "step": (("cu", (B + 1,)), ("token_ids", (n,))),
+        "mega": (("cu", (B + 1,)),),
+        "mixed": (("prompt_buf", (B, n)),),
+        "spec": (("cu", (B + 1,)), ("token_ids", (B * (n + 1),)), ("draft", (B, n))),
+    }[kind]
+    return Layout.of(
+        *((name, (B,), "b" if name == "active" else "i") for name in rows),
+        ("temps", (B,), "f"), ("top_ks", (B,)), ("top_ps", (B,), "f"),
+        ("seeds", (B,)), ("sample_pos", (B,)), *tails, ("bt", (B, P)))
+
+
+_BUILDERS = {"step": "_build_step", "mega": "_build_megastep",
+             "mixed": "_build_mixed_megastep", "spec": "_build_spec_verify"}
+
+
 def _sum_counts(counts):
     """A scan's stacked per-iteration ``counts`` -> one number each."""
     return jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), counts)
@@ -804,6 +837,10 @@ class ServingEngine:
         self.phase_seconds = {"schedule": 0.0, "execute": 0.0, "harvest": 0.0,
                               "launch": 0.0}
         self.launches = 0           # compiled-program launches (monotone)
+        # crossings of the host-device boundary (monotone): control arrays a
+        # launch sent up with its dispatch, blocking reads its wait made
+        self.control_arrays_up = 0
+        self.result_reads = 0
         # Programs are shared process-wide across engines with identical
         # trace-shaping config (see _PROGRAM_CACHE): a fresh engine over
         # an already-served geometry starts with warm compile caches.
@@ -833,7 +870,7 @@ class ServingEngine:
         shapes/dtypes (and the layer count, via pytree structure)
         itself — two models with the same architecture share programs."""
         return (self.B, self.T, self.bs, self.cache_spec.key, self.cache_quant,
-                bool(self.capture_sample_probs), self.pc, self.spec_k)
+                bool(self.capture_sample_probs), self.pc, self.spec_k, self.P)
 
     @property
     def key_caches(self):
@@ -925,19 +962,28 @@ class ServingEngine:
         return nxt, kcs, vcs, ns
 
     def _build_step(self):
+        """Every program takes ONE control ``block`` (``control_layout``)
+        beside weights, caches, rope and the int8 scales, slices it first
+        thing, and returns ``(caches, scales, result, logprobs, probs)``:
+        ``result`` a :class:`ResultBlock` of everything the host always
+        reads, ``logprobs`` read only when a scheduled row asked."""
         fwd = self._forward
+        B, P = self.B, self.P
         with_probs = self.capture_sample_probs
+        fixed = control_layout("step", B, P).size
 
-        def step(weights, caches, rope, token_ids,
-                 enc, dec, now, cu, bt, temps, top_ks, top_ps, seeds,
-                 sample_pos, mq, scales=None):
+        def step(weights, caches, rope, block, scales=None, *, mq):
+            with jax.named_scope("scan_carry"):
+                c = control_layout("step", B, P, block.shape[0] - fixed).unpack(block)
             logits, caches, new_scales, counts = fwd(
-                weights, caches, rope, token_ids, enc,
-                dec, now, cu, bt, mq, scales)
+                weights, caches, rope, c["token_ids"], c["enc"], c["dec"],
+                c["now"], c["cu"], c["bt"], mq, scales)
             nxt, logprob, probs = _sample_tokens(
-                logits, temps, top_ks, top_ps, seeds, sample_pos,
-                return_probs=with_probs)
-            return nxt, logprob, probs, caches, new_scales, counts
+                logits, c["temps"], c["top_ks"], c["top_ps"], c["seeds"],
+                c["sample_pos"], return_probs=with_probs)
+            with jax.named_scope("scan_carry"):
+                res = ResultBlock.of({"toks": nxt}, counts)
+            return caches, new_scales, res, logprob, probs
 
         return jax.jit(step, donate_argnums=(1,), static_argnames=("mq",))
 
@@ -959,12 +1005,19 @@ class ServingEngine:
         dequantize reads with them, so ``cache_quant='int8'`` rides the
         same scan instead of keeping a per-token path."""
         fwd = self._forward
-        B = self.B
+        B, P = self.B, self.P
         with_probs = self.capture_sample_probs
+        layout = control_layout("mega", B, P)
 
-        def mega(weights, caches, rope, toks, dec, now,
-                 cu, occ_idx, bt, active, remaining, dl, eos, temps,
-                 top_ks, top_ps, seeds, sample_pos, scales, K):
+        def mega(weights, caches, rope, block, scales=None, *, K):
+            with jax.named_scope("scan_carry"):
+                c = layout.unpack(block)
+            toks, dec, now, cu, occ_idx, bt = (
+                c[n] for n in ("toks", "dec", "now", "cu", "occ_idx", "bt"))
+            active, remaining, dl, eos = (
+                c[n] for n in ("active", "remaining", "dl", "eos"))
+            temps, top_ks, top_ps, seeds, sample_pos = (
+                c[n] for n in ("temps", "top_ks", "top_ps", "seeds", "sample_pos"))
             enc = jnp.zeros((B,), jnp.int32)
 
             def body(carry, _):
@@ -1004,8 +1057,10 @@ class ServingEngine:
                       remaining, sample_pos, dl, scales)
             carry, (toks_o, valid_o, lps_o, probs_o, counts_o) = jax.lax.scan(
                 body, carry0, None, length=K)
-            return (carry[1], carry[7], toks_o, valid_o, lps_o, probs_o,
-                    _sum_counts(counts_o))
+            with jax.named_scope("scan_carry"):
+                res = ResultBlock.of({"toks": toks_o, "valid": valid_o},
+                                     _sum_counts(counts_o))
+            return carry[1], carry[7], res, lps_o, probs_o
 
         return jax.jit(mega, static_argnames=("K",), donate_argnums=(1,))
 
@@ -1039,12 +1094,21 @@ class ServingEngine:
         int8 is excluded here by the scheduler: dynamic quant scales
         freeze at one-shot prefill, which chunking would violate."""
         fwd = self._forward
-        B, T, C = self.B, self.T, self.pc
+        B, T, C, P = self.B, self.T, self.pc, self.P
         with_probs = self.capture_sample_probs
 
-        def mixed(weights, caches, rope, toks, cached,
-                  pp, pp0, plen, prompt_buf, bt, active, remaining, dl,
-                  eos, temps, top_ks, top_ps, seeds, sample_pos, K):
+        def mixed(weights, caches, rope, block, scales=None, *, K):
+            if scales is not None:
+                raise ValueError("the mixed scan carries no int8 scales")
+            with jax.named_scope("scan_carry"):
+                c = control_layout("mixed", B, P, K * C).unpack(block)
+            toks, cached, pp, pp0, plen, prompt_buf, bt = (
+                c[n] for n in ("toks", "cached", "pp", "pp0", "plen",
+                               "prompt_buf", "bt"))
+            active, remaining, dl, eos = (
+                c[n] for n in ("active", "remaining", "dl", "eos"))
+            temps, top_ks, top_ps, seeds, sample_pos = (
+                c[n] for n in ("temps", "top_ks", "top_ps", "seeds", "sample_pos"))
             enc = jnp.zeros((B,), jnp.int32)
 
             def chunk_at(row, start):
@@ -1105,8 +1169,11 @@ class ServingEngine:
                       remaining, sample_pos, dl)
             carry, (toks_o, emits_o, lps_o, probs_o, counts_o) = jax.lax.scan(
                 body, carry0, None, length=K)
-            return (carry[1], carry[3], toks_o, emits_o, lps_o, probs_o,
+            with jax.named_scope("scan_carry"):
+                res = ResultBlock.of(
+                    {"toks": toks_o, "valid": emits_o, "pp": carry[3]},
                     _sum_counts(counts_o))
+            return carry[1], None, res, lps_o, probs_o
 
         return jax.jit(mixed, static_argnames=("K",),
                        donate_argnums=(1,))
@@ -1146,10 +1213,18 @@ class ServingEngine:
         B, sk = self.B, self.spec_k
         Kp1 = sk + 1
         with_probs = self.capture_sample_probs
+        layout = control_layout("spec", B, self.P, sk)
 
-        def spec_verify(weights, caches, rope,
-                        token_ids, dec, now, cu, bt, dlen, draft, temps,
-                        top_ks, top_ps, seeds, spos):
+        def spec_verify(weights, caches, rope, block, scales=None):
+            if scales is not None:
+                raise ValueError("the verify program carries no int8 scales")
+            with jax.named_scope("scan_carry"):
+                c = layout.unpack(block)
+            token_ids, dec, now, cu, bt, dlen, draft = (
+                c[n] for n in ("token_ids", "dec", "now", "cu", "bt", "dlen",
+                               "draft"))
+            temps, top_ks, top_ps, seeds, spos = (
+                c[n] for n in ("temps", "top_ks", "top_ps", "seeds", "sample_pos"))
             enc = jnp.zeros((B,), jnp.int32)
             hidden, caches, _, counts = trunk(
                 weights, caches, rope, token_ids, enc,
@@ -1187,7 +1262,8 @@ class ServingEngine:
                 match = (nxt[:, :sk] == draft) & (jk < dlen[:, None])
                 acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
                               axis=1).astype(jnp.int32)
-            return caches, nxt, lps, probs, acc, counts
+                res = ResultBlock.of({"toks": nxt, "acc": acc}, counts)
+            return caches, None, res, lps, probs
 
         return jax.jit(spec_verify, donate_argnums=(1,))
 
@@ -1468,6 +1544,14 @@ class ServingEngine:
             # cumulative host seconds per step phase — megastep cost
             # attribution without a profiler (ISSUE 15 satellite)
             "phase_seconds": dict(self.phase_seconds),
+            # a launch's crossings of the host-device boundary (monotone):
+            # 1 control array up and 1 read down a launch, one more read
+            # where a scheduled row asked for log-probabilities
+            "launch": {
+                "launches": self.launches,
+                "control_arrays_up": self.control_arrays_up,
+                "result_reads": self.result_reads,
+            },
             # where this engine's start-up went (profiler.setup_report() has
             # the process's): the rows of its ``engine.init`` spans and of
             # the ``program.acquire`` of every program its launches compiled
@@ -1559,14 +1643,59 @@ class ServingEngine:
         phases are measured (span and ``phase_seconds`` together)."""
         return _Phase(self, name, attrs)
 
-    def _add_counts(self, counts) -> Dict[str, int]:
-        """What the model's trunk counted in one launch, added to the
-        engine's counters of the same names; returned for the launch's
-        ``engine.harvest`` span."""
-        got = {name: int(v) for name, v in jax.device_get(counts).items()}
+    def _add_counts(self, got: Dict[str, int]) -> Dict[str, int]:
+        """What the model's trunk counted in one launch (read out of the
+        launch's result block), added to the engine's counters of the same
+        names; returned for the launch's ``engine.harvest`` span."""
         for name, n in got.items():
             setattr(self, name, getattr(self, name) + n)
         return got
+
+    def _program(self, kind: str):
+        """The compiled program of ``kind``, built at its first launch
+        (``step`` with the engine)."""
+        attr = f"_{kind}_fn"
+        if getattr(self, attr) is None:
+            if kind not in self._programs:
+                self._programs[kind] = getattr(self, _BUILDERS[kind])()
+            setattr(self, attr, self._programs[kind])
+        return getattr(self, attr)
+
+    def _launch(self, kind: str, k: int, block: np.ndarray,
+                reqs: Sequence[ServingRequest], static: Dict, **attrs):
+        """One launch, for every kind: the control ``block`` goes up as ONE
+        array with the dispatch; the copies of what the host will read (the
+        result block; the log-probabilities only if a scheduled row asked
+        for them; the sampling distributions where captured) start right
+        behind it, so ``engine.wait`` finds them on their way and makes one
+        blocking read.  -> (rows of the result block, logprobs or None,
+        probs or None, the trunk's counts, seconds of launch + wait, whether
+        the launch compiled)."""
+        want_lps = any(r.sampling.logprobs for r in reqs)
+        with self._launch_phase(kind, k, arrays_up=1, bytes_up=block.nbytes,
+                                **attrs) as launch:
+            fn = self._program(kind)
+            had = fn._cache_size() if hasattr(fn, "_cache_size") else None
+            self.caches, new_scales, res, lps, probs = fn(
+                self._weights, self.caches, self._rope, block,
+                self.cache_scales, **static)
+            if self.cache_scales is not None:
+                self.cache_scales = new_scales
+            reads = ([res.words] + ([lps] if want_lps else [])
+                     + ([probs] if probs is not None else []))
+            for a in reads:
+                a.copy_to_host_async()
+            compiled = had is not None and fn._cache_size() > had
+        if compiled:
+            self._acquire(fn, launch)
+        self.control_arrays_up += 1
+        self.result_reads += len(reads)
+        with self._phase("wait", reads=len(reads)) as wait:
+            out, counts = res.read()
+            lps = np.asarray(lps) if want_lps else None
+            probs = np.asarray(probs) if probs is not None else None
+            counted = self._add_counts(counts)
+        return out, lps, probs, counted, launch.seconds + wait.seconds, compiled
 
     def _launch_phase(self, kind: str, k: int, **attrs) -> _Phase:
         """``engine.launch`` of one compiled program: ``k`` iterations of
@@ -1574,8 +1703,9 @@ class ServingEngine:
         ``megastep`` events carry it too) and ``t_mono`` is this engine's
         clock, so that a recorder event's ``t`` can be placed on the trace.
         ``passes``: how often an iteration runs the model's layers (1 but for
-        a looped model).  A mixed launch adds ``prefill_rows``, the rows it
-        feeds chunks."""
+        a looped model).  ``_launch`` adds ``arrays_up`` and ``bytes_up``, the
+        control arrays sent up with the dispatch; a mixed launch
+        ``prefill_rows``, the rows it feeds chunks."""
         self.launches += 1
         return self._phase("launch", kind=kind, k=k, launch=self.launches,
                            t_mono=self._clock(), passes=self.cache_spec.passes,
@@ -1607,6 +1737,14 @@ class ServingEngine:
         host — admission included — only observes the engine at megastep
         boundaries.  Prefill-only batches (plus int8 one-shot prefill
         and ``megastep_k=1``) run the single-step program.
+
+        A launch crosses the host-device boundary once each way
+        (``_launch``, launch_block.py): its control rows go up as ONE packed
+        ``int32`` block with the dispatch, and what the host reads of it
+        (tokens, masks, the trunk's counts) comes down as ONE, its copy
+        started at the dispatch; log-probabilities are a second read, made
+        only when a scheduled row asked for them
+        (``state_summary()["launch"]`` counts both).
 
         Spans (``RecordEvent``, children of ``engine.step``):
         ``engine.admit``, ``engine.schedule``, ``engine.launch``,
@@ -1779,29 +1917,15 @@ class ServingEngine:
                 tokens[pos:pos + n] = chunk
                 pos += n
                 cu[slot + 1] = pos
+            block = control_layout("step", self.B, self.P, len(tokens)).pack(dict(
+                token_ids=tokens, enc=enc, dec=dec, now=now, cu=cu,
+                bt=self.block_tables, temps=temps, top_ks=top_ks, top_ps=top_ps,
+                seeds=seeds, sample_pos=spos))
 
-        with self._launch_phase("step", 1) as launch:
-            had_cache = self._step_fn._cache_size() if hasattr(self._step_fn, "_cache_size") else None
-            nxt, lps, probs, self.caches, new_scales, counts = \
-                self._step_fn(
-                    self._weights, self.caches,
-                    self._rope, jnp.asarray(tokens), jnp.asarray(enc),
-                    jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu),
-                    jnp.asarray(self.block_tables), jnp.asarray(temps),
-                    jnp.asarray(top_ks), jnp.asarray(top_ps),
-                    jnp.asarray(seeds), jnp.asarray(spos),
-                    mq=1 if decode_only else self.T, scales=self.cache_scales)
-            if self.cache_scales is not None:
-                self.cache_scales = new_scales
-            compiled = (had_cache is not None
-                        and self._step_fn._cache_size() > had_cache)
-        if compiled:
-            self._acquire(self._step_fn, launch)
-        with self._phase("wait"):
-            nxt = np.asarray(nxt)
-            lps = np.asarray(lps)
-            probs = np.asarray(probs) if probs is not None else None
-            counted = self._add_counts(counts)
+        out, lps, probs, counted, _, _ = self._launch(
+            "step", 1, block, [s[0] for s in sched],
+            {"mq": 1 if decode_only else self.T})
+        nxt = out["toks"]
         with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
             for req, n, finishes in sched:
@@ -1964,29 +2088,13 @@ class ServingEngine:
                                     spos)
                 pos += len(row)
                 cu[slot + 1] = pos
-        with self._launch_phase("spec", Kp1) as launch:
-            if self._spec_fn is None:
-                if "spec" not in self._programs:
-                    self._programs["spec"] = self._build_spec_verify()
-                self._spec_fn = self._programs["spec"]
-            had = (self._spec_fn._cache_size()
-                   if hasattr(self._spec_fn, "_cache_size") else None)
-            self.caches, nxt, lps, probs, acc, counts = self._spec_fn(
-                self._weights, self.caches, self._rope,
-                jnp.asarray(tokens), jnp.asarray(dec), jnp.asarray(now),
-                jnp.asarray(cu), jnp.asarray(self.block_tables),
-                jnp.asarray(dlen), jnp.asarray(draft_a), jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps), jnp.asarray(seeds),
-                jnp.asarray(spos))
-            compiled = had is not None and self._spec_fn._cache_size() > had
-        if compiled:
-            self._acquire(self._spec_fn, launch)
-        with self._phase("wait"):
-            nxt = np.asarray(nxt)       # [B, spec_k+1] redraws
-            lps = np.asarray(lps)
-            probs = np.asarray(probs) if probs is not None else None
-            acc = np.asarray(acc)       # [B] accepted draft-prefix lengths
-            counted = self._add_counts(counts)
+            block = control_layout("spec", B, self.P, sk).pack(dict(
+                token_ids=tokens, dec=dec, now=now, cu=cu, bt=self.block_tables,
+                dlen=dlen, draft=draft_a, temps=temps, top_ks=top_ks,
+                top_ps=top_ps, seeds=seeds, sample_pos=spos))
+        out, lps, probs, counted, _, _ = self._launch("spec", Kp1, block, reqs, {})
+        nxt = out["toks"]       # [B, spec_k+1] redraws
+        acc = out["acc"]        # [B] accepted draft-prefix lengths
 
         with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
@@ -2078,36 +2186,16 @@ class ServingEngine:
                     pos += 1
                 cu[slot + 1] = pos
             dl = self._deadline_budgets(by_slot)
-        with self._launch_phase("mega", K) as launch:
-            if self._mega_fn is None:
-                if "mega" not in self._programs:
-                    self._programs["mega"] = self._build_megastep()
-                self._mega_fn = self._programs["mega"]
-            had = (self._mega_fn._cache_size()
-                   if hasattr(self._mega_fn, "_cache_size") else None)
-            self.caches, new_scales, toks_o, valid_o, lps_o, probs_o, counts = \
-                self._mega_fn(
-                    self._weights, self.caches,
-                    self._rope, jnp.asarray(toks), jnp.asarray(dec),
-                    jnp.asarray(now), jnp.asarray(cu), jnp.asarray(occ_idx),
-                    jnp.asarray(self.block_tables), jnp.asarray(active),
-                    jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
-                    jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(seeds),
-                    jnp.asarray(spos), self.cache_scales, K=K)
-            if self.cache_scales is not None:
-                self.cache_scales = new_scales
-            compiled = had is not None and self._mega_fn._cache_size() > had
-        if compiled:
-            self._acquire(self._mega_fn, launch)
-        with self._phase("wait") as wait:
-            toks_o = np.asarray(toks_o)       # [K, B]
-            valid_o = np.asarray(valid_o)
-            lps_o = np.asarray(lps_o)
-            probs_o = np.asarray(probs_o) if probs_o is not None else None
-            counted = self._add_counts(counts)
+            block = control_layout("mega", B, self.P).pack(dict(
+                toks=toks, dec=dec, now=now, cu=cu, occ_idx=occ_idx,
+                bt=self.block_tables, active=active, remaining=remaining, dl=dl,
+                eos=eos, temps=temps, top_ks=top_ks, top_ps=top_ps, seeds=seeds,
+                sample_pos=spos))
+        out, lps_o, probs_o, counted, execute_s, compiled = self._launch(
+            "mega", K, block, reqs, {"K": K})
+        toks_o, valid_o = out["toks"], out["valid"]       # [K, B]
         self.megasteps += 1
-        self._update_tau(launch.seconds + wait.seconds, K, compiled)
+        self._update_tau(execute_s, K, compiled)
 
         with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}
@@ -2209,35 +2297,18 @@ class ServingEngine:
                     # pp == plen marks the row as decoding from iteration 0
                     pp[slot] = pp0[slot] = plen[slot] = len(req.prompt)
             dl = self._deadline_budgets(by_slot)
-        with self._launch_phase("mixed", K,
-                                prefill_rows=len(pre_reqs)) as launch:
-            if self._mixed_fn is None:
-                if "mixed" not in self._programs:
-                    self._programs["mixed"] = self._build_mixed_megastep()
-                self._mixed_fn = self._programs["mixed"]
-            had = (self._mixed_fn._cache_size()
-                   if hasattr(self._mixed_fn, "_cache_size") else None)
-            self.caches, pp_f, toks_o, emits_o, lps_o, probs_o, counts = self._mixed_fn(
-                self._weights, self.caches, self._rope,
-                jnp.asarray(toks), jnp.asarray(cached), jnp.asarray(pp),
-                jnp.asarray(pp0), jnp.asarray(plen), jnp.asarray(prompt_buf),
-                jnp.asarray(self.block_tables), jnp.asarray(active),
-                jnp.asarray(remaining), jnp.asarray(dl), jnp.asarray(eos),
-                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-                jnp.asarray(seeds), jnp.asarray(spos), K=K)
-            compiled = had is not None and self._mixed_fn._cache_size() > had
-        if compiled:
-            self._acquire(self._mixed_fn, launch)
-        with self._phase("wait") as wait:
-            pp_f = np.asarray(pp_f)           # [B] final prefill positions
-            toks_o = np.asarray(toks_o)       # [K, B]
-            emits_o = np.asarray(emits_o)
-            lps_o = np.asarray(lps_o)
-            probs_o = np.asarray(probs_o) if probs_o is not None else None
-            counted = self._add_counts(counts)
+            block = control_layout("mixed", B, self.P, K * C).pack(dict(
+                toks=toks, cached=cached, pp=pp, pp0=pp0, plen=plen,
+                prompt_buf=prompt_buf, bt=self.block_tables, active=active,
+                remaining=remaining, dl=dl, eos=eos, temps=temps, top_ks=top_ks,
+                top_ps=top_ps, seeds=seeds, sample_pos=spos))
+        out, lps_o, probs_o, counted, execute_s, compiled = self._launch(
+            "mixed", K, block, reqs, {"K": K}, prefill_rows=len(pre_reqs))
+        toks_o, emits_o = out["toks"], out["valid"]       # [K, B]
+        pp_f = out["pp"]                                  # [B] final prefill positions
         self.megasteps += 1
         self.megasteps_mixed += 1
-        self._update_tau(launch.seconds + wait.seconds, K, compiled)
+        self._update_tau(execute_s, K, compiled)
 
         with self._phase("harvest", **counted):
             emitted: Dict[int, List[int]] = {}

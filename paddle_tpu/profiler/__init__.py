@@ -16,9 +16,13 @@ Host spans, every one a ``RecordEvent`` on the profiler's clock:
 ``engine.step`` > ``engine.admit``, ``engine.schedule``, ``engine.launch``
 (stats ``kind`` step|mega|mixed|spec, ``k``, ``launch``, ``t_mono``,
 ``passes`` (how often an iteration runs the model's layers: 1 but for a
-looped model), and on a mixed launch ``prefill_rows``, the rows it feeds
-prompt chunks),
-``engine.wait``, ``engine.harvest`` (stats: what the model's trunk counted
+looped model), ``arrays_up`` and ``bytes_up``, the control arrays the launch
+sent to the device with its dispatch and their bytes (ONE packed block:
+inference/launch_block.py), and on a mixed launch ``prefill_rows``, the rows
+it feeds prompt chunks),
+``engine.wait`` (stats ``reads``: the blocking reads it made of copies started
+at the dispatch, 1, and one more where a scheduled row asked for
+log-probabilities), ``engine.harvest`` (stats: what the model's trunk counted
 in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
 ``attn_rows_kernel`` and the cache write's ``kv_write_tokens`` /
 ``kv_write_blocks`` for a dense paged cache, ``moe_tokens`` /

@@ -218,6 +218,14 @@ def ouro_engine():
     return cfg, ServingEngine(model, **dict(cfg["engine"], num_blocks=2))
 
 
+def _one_control_block_less(said, compiled):
+    """The configuration files' ``arguments`` were compiled when a launch took
+    its control rows as eleven to sixteen arrays, each padded to a tile of its
+    own; since ISSUE 35 they are ONE block, 11-20 KB less of 11-15 GB (the
+    files are the benchmark's, which that PR could not edit)."""
+    return 0 <= said - compiled < 32 * 1024
+
+
 @pytest.mark.parametrize("kind", ["step_prefill_T256", "mixed_K8", "mega_K8"])
 def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind, monkeypatch):
     """The prefill step, the mixed scan and the decode scan of Ouro-2.6B
@@ -230,6 +238,7 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     no copy, no temporary the size of one cache layer; the stacked weights
     are not copied either (held as three matrices, q, k and v were: 1.21 GB).
     And the figures that sized the pool: a GB and more free under each."""
+    from paddle_tpu.inference.serving import control_layout
     from paddle_tpu.ops import paged_attention as pa
 
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
@@ -241,22 +250,17 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
 
-    i32 = lambda *s: sds(s, jnp.int32)                            # noqa: E731
-    f32 = lambda *s: sds(s, jnp.float32)                          # noqa: E731
+    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
+        return sds((control_layout(kind, B, P, n).size,), jnp.int32)
+
     weights = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), eng._weights)
     caches = tuple(sds((a.shape[0], nb) + a.shape[2:], a.dtype) for a in eng.caches)
     head = (weights, caches, sds(eng._rope.shape, eng._rope.dtype))
-    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
-    flag, bt = sds((B,), jnp.bool_), i32(B, P)
     lowered = {
-        "step_prefill_T256": lambda: eng._build_step().lower(
-            *head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt, *samp, mq=T),
+        "step_prefill_T256": lambda: eng._build_step().lower(*head, block("step", T), mq=T),
         "mixed_K8": lambda: eng._build_mixed_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
-            i32(B), i32(B), *samp, K=K),
-        "mega_K8": lambda: eng._build_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
-            i32(B), *samp, None, K=K),
+            *head, block("mixed", K * C), K=K),
+        "mega_K8": lambda: eng._build_megastep().lower(*head, block("mega"), K=K),
     }[kind]()
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -278,7 +282,7 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert live < V5E_BYTES_LIMIT - 10 ** 9
     said = cfg["memory"]["compiled_for_v5e"][kind]
-    assert said["arguments"] == mem.argument_size_in_bytes
+    assert _one_control_block_less(said["arguments"], mem.argument_size_in_bytes)
     assert abs(said["live"] / live - 1) < 0.01 and said["temporaries"] < one_cache_layer
 
 
@@ -318,6 +322,8 @@ def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind):
     the score buffer and the selection are temporaries, and the largest
     program leaves 1.5 GB of the chip free.  The figures are the
     configuration file's ``memory.compiled_for_v5e``."""
+    from paddle_tpu.inference.serving import control_layout
+
     cfg, eng = dsa_engine
     B, T, P, K, C = eng.B, eng.T, eng.P, eng.megastep_k, eng.pc
     nb, bs = cfg["engine"]["num_blocks"], eng.bs
@@ -326,24 +332,19 @@ def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
 
-    i32 = lambda *s: sds(s, jnp.int32)                            # noqa: E731
-    f32 = lambda *s: sds(s, jnp.float32)                          # noqa: E731
+    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
+        return sds((control_layout(kind, B, P, n).size,), jnp.int32)
+
     weights = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), eng._weights)
     caches = tuple([sds((nb,) + a.shape[1:], a.dtype) for a in layers]
                    for layers in eng.caches)
     assert [c[0].shape for c in caches] == [(nb, bs, 640), (nb, bs, 128)]
     head = (weights, caches, sds(eng._rope.shape, eng._rope.dtype))
-    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
-    flag, bt = sds((B,), jnp.bool_), i32(B, P)
     compiled = {
-        "step_prefill_T512": lambda: eng._build_step().lower(
-            *head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt, *samp, mq=T),
+        "step_prefill_T512": lambda: eng._build_step().lower(*head, block("step", T), mq=T),
         "mixed_K8": lambda: eng._build_mixed_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
-            i32(B), i32(B), *samp, K=K),
-        "mega_K8": lambda: eng._build_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
-            i32(B), *samp, None, K=K),
+            *head, block("mixed", K * C), K=K),
+        "mega_K8": lambda: eng._build_megastep().lower(*head, block("mega"), K=K),
     }[kind]().compile()
     text = compiled.as_text()
     for width in (640, 128):
@@ -359,5 +360,5 @@ def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind):
     assert live < V5E_BYTES_LIMIT - 1.5e9, live
     said = cfg["memory"].get("compiled_for_v5e", {}).get(kind)
     assert said is not None, "the configuration's memory.compiled_for_v5e lacks " + kind
-    assert said["arguments"] == mem.argument_size_in_bytes
+    assert _one_control_block_less(said["arguments"], mem.argument_size_in_bytes)
     assert abs(said["live"] / live - 1) < 0.01

@@ -626,16 +626,21 @@ def test_a_model_without_an_indexer_counts_no_selection():
 
 
 # ------------------------------------- the families that were there before
-# sha256 (first 16 hex digits) of each program's lowered text at the parent
-# commit (4f74fa7), tiny geometry, jax 0.9.0: what this PR touched of the
-# shared code (``_latent_proj``'s ``c_q=``, ``_moe_ffn``'s ``router=``, the
-# configs' shared base, ``latent_attention``'s ``selection=`` and its
-# ``token_coords`` / ``write_entries`` / ``_trips``, the causal LM's classes, the engine's
-# four new counters) leaves the other three families' programs byte for byte
-# what they were.  The ``llama`` and ``pangu`` rows are tests/test_ouro.py's.
+# sha256 (first 16 hex digits) of each program's lowered text, tiny geometry,
+# jax 0.9.0: what PR 32 touched of the shared code (``_latent_proj``'s
+# ``c_q=``, ``_moe_ffn``'s ``router=``, the configs' shared base,
+# ``latent_attention``'s ``selection=`` and its ``token_coords`` /
+# ``write_entries`` / ``_trips``, the causal LM's classes, the engine's four
+# new counters) left the other three families' programs byte for byte what
+# they were.  The twelve texts are PR 35's, not a parent's: that PR moved
+# every one on purpose (a launch's control rows cross as ONE ``int32`` block
+# that each program slices first thing, and what the host reads comes back
+# as ONE; the ``ouro`` row was 43988f27 / 9a221a04 / 63d77585 / 9cb418a3),
+# so they guard what comes AFTER it.  The ``llama`` and ``pangu`` rows are
+# tests/test_ouro.py's.
 PARENT_TEXTS = dict(test_ouro.PARENT_TEXTS, ouro={
-    "step": "43988f2732149f2c", "mega": "9a221a0470e0a771",
-    "mixed": "63d7758597660959", "spec": "9cb418a305755095"})
+    "step": "0aa019dc9c4bf168", "mega": "f386f52fe27ce8f1",
+    "mixed": "9d7b11f2acd8d537", "spec": "06ca74df7df2cf6b"})
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0", reason="the texts are jax 0.9.0's")

@@ -44,6 +44,7 @@ from paddle_tpu.inference import (
     TraceContext,
     Tracer,
 )
+from paddle_tpu.inference.launch_block import ResultBlock
 from paddle_tpu.inference.tracing import tree_complete
 
 pytestmark = pytest.mark.quick
@@ -746,10 +747,13 @@ class _Recorded:
         return self.on_call(out) if self.on_call is not None else out
 
 
-PROGRAMS = {"step": ("_step_fn", "_build_step", 0),
-            "mega": ("_mega_fn", "_build_megastep", 3),
-            "mixed": ("_mixed_fn", "_build_mixed_megastep", 2),
-            "spec": ("_spec_fn", "_build_spec_verify", 2)}
+# (attribute, builder): every program returns (caches, scales, result block,
+# logprobs, probs), and the result block is the first thing the host reads
+PROGRAMS = {"step": ("_step_fn", "_build_step"),
+            "mega": ("_mega_fn", "_build_megastep"),
+            "mixed": ("_mixed_fn", "_build_mixed_megastep"),
+            "spec": ("_spec_fn", "_build_spec_verify")}
+RESULT = 2
 # what each program's lowered text has to name (PERF.md section 3: a
 # per-layer metric that matches a scope reads nothing once it is gone)
 # (regular expressions: attention is blocked over the context, so its three
@@ -760,15 +764,18 @@ FORWARD = ("embed", "norm", "attn_proj", "paged_attention", "attn_out", "mlp",
            "head", "sample", "paged_attention/rope", "paged_attention/kv_write",
            IN_LOOP + "kv_gather", IN_LOOP + "scores", IN_LOOP + "values",
            "paged_attention/while/body/while/body/kv_gather")
-SCOPES = {"step": FORWARD, "mega": FORWARD + ("scan_carry",),
-          "mixed": FORWARD + ("scan_carry",), "spec": FORWARD + ("scan_carry",)}
+# ``scan_carry`` holds the slicing of the launch's ONE control block too
+# (ISSUE 35), so every program has it, the step included; the float rows come
+# out of the block by their bits
+CARRY = ("scan_carry", "scan_carry/slice", "scan_carry/bitcast_convert_type")
+SCOPES = {kind: FORWARD + CARRY for kind in ("step", "mega", "mixed", "spec")}
 
 
 def _recorded_engine(model, on_call=None, **kw):
     """An engine whose four programs are ``_Recorded``; ``on_call(kind,
     outputs)`` sees every launch's outputs."""
     eng = ServingEngine(model, megastep_k=4, spec_k=2, **{**ENGINE, **kw})
-    for kind, (attr, build, _) in PROGRAMS.items():
+    for kind, (attr, build) in PROGRAMS.items():
         fn = eng._programs.setdefault(kind, None) or getattr(eng, build)()
         eng._programs[kind] = fn
         setattr(eng, attr, _Recorded(
@@ -793,7 +800,7 @@ def _drive_all_programs(eng):
 def program_texts(model):
     eng = _recorded_engine(model)
     _drive_all_programs(eng)
-    return {kind: getattr(eng, attr).text for kind, (attr, _, _) in PROGRAMS.items()}
+    return {kind: getattr(eng, attr).text for kind, (attr, _) in PROGRAMS.items()}
 
 
 class TestNamesSpansAndPhases:
@@ -819,6 +826,9 @@ class TestNamesSpansAndPhases:
             def __init__(self, value):
                 self.value = value
 
+            def copy_to_host_async(self):
+                pass
+
             def __array__(self, dtype=None, copy=None):
                 clock.advance(2.0)
                 return np.asarray(self.value)
@@ -827,7 +837,7 @@ class TestNamesSpansAndPhases:
             clock.advance(4.0)
             launches.append(k)
             out = list(out)
-            out[PROGRAMS[k][2]] = SlowRead(out[PROGRAMS[k][2]])
+            out[RESULT] = ResultBlock(SlowRead(out[RESULT].words), out[RESULT].layout)
             return tuple(out)
 
         eng = _recorded_engine(model, on_call, clock=clock)

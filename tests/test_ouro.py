@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine
+from paddle_tpu.inference.serving import control_layout
 from paddle_tpu.models import LlamaForCausalLM, OuroConfig, OuroForCausalLM, llama_tiny
 from paddle_tpu.models import ouro
 from paddle_tpu.ops.paged_attention import blha_attention
@@ -326,25 +327,19 @@ SCOPES = ("embed", "loop_pass", "loop_pass/while/body", "norm", "attn_proj",
 
 
 def _lowered(eng, debug_info, kinds=("step", "mega", "mixed", "spec")):
-    B, T, C, K = eng.B, eng.T, eng.pc, eng.megastep_k
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)          # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)        # noqa: E731
-    flag = jax.ShapeDtypeStruct((B,), jnp.bool_)
-    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
-    bt = i32(B, eng.P)
+    B, T, P_, C, K = eng.B, eng.T, eng.P, eng.pc, eng.megastep_k
+
+    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
+        return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
+
     head = (eng._weights, eng.caches, eng._rope)
     low = {
-        "step": lambda: eng._step_fn.lower(
-            *head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt, *samp, mq=T, scales=None),
-        "mega": lambda: eng._build_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
-            i32(B), *samp, None, K=K),
+        "step": lambda: eng._step_fn.lower(*head, block("step", T), None, mq=T),
+        "mega": lambda: eng._build_megastep().lower(*head, block("mega"), None, K=K),
         "mixed": lambda: eng._build_mixed_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
-            i32(B), i32(B), *samp, K=K),
+            *head, block("mixed", K * C), None, K=K),
         "spec": lambda: eng._build_spec_verify().lower(
-            *head, i32(B * (eng.spec_k + 1)), i32(B), i32(B), i32(B + 1), bt, i32(B),
-            i32(B, eng.spec_k), *samp),
+            *head, block("spec", eng.spec_k), None),
     }
     return {k: low[k]().as_text(debug_info=debug_info) for k in kinds}
 
@@ -449,21 +444,23 @@ def test_a_model_of_one_pass_says_so():
 
 
 # ------------------------------------- the families that were there before
-# sha256 (first 16 hex digits) of each program's lowered text at the parent
-# commit (364d68d), tiny geometry, jax 0.9.0: a shared function that this PR
-# touched (``blha_attention``'s ``layer=``, the engine's pool, its COW copy)
-# leaves the other two families' programs byte for byte what they were.
+# sha256 (first 16 hex digits) of each program's lowered text, tiny geometry,
+# jax 0.9.0: a change to a shared function that is meant to leave the other
+# families' programs alone (PR 30: ``blha_attention``'s ``layer=``, the
+# engine's pool, its COW copy) leaves these byte for byte what they were.
 # The ``llama`` row was pinned anew at PR 31: the dense trunk's ``counts``
-# gained ``kv_write_tokens`` and ``kv_write_blocks`` (two more results of each
-# program and the few integer operations that make them). With those two
-# taken out again the four texts were PR 30's, a61c1bc0 / f6aa624f / 490e19ac
-# / d29cf24a, byte for byte: on the CPU the write is still the scatter, in
-# the parent's order of operations.
+# gained ``kv_write_tokens`` and ``kv_write_blocks``.  BOTH rows are PR 35's:
+# that PR changed every program's signature on purpose (the fifteen control
+# arrays became ONE ``int32`` block sliced first thing under ``scan_carry``,
+# the tokens, masks and counts ONE result block), so the parent's texts
+# (ee99d684 / 659c864c / 5931fbbd / 0fbd7142 and 12f7caac / 46dd4ae2 /
+# f0e344e3 / f585eb67) could not stand; that the mathematics did is
+# tests/test_launch_block.py's, against the parent's recorded answers.
 PARENT_TEXTS = {
-    "llama": {"step": "ee99d68488cd7fb1", "mega": "659c864c2723c567",
-              "mixed": "5931fbbd204472a5", "spec": "0fbd7142f153243d"},
-    "pangu": {"step": "12f7caac479ecbd3", "mega": "46dd4ae2b6422070",
-              "mixed": "f0e344e3fab3c3ff", "spec": "f585eb675ec177f6"}}
+    "llama": {"step": "779917ad04438754", "mega": "5f964e23d4c735de",
+              "mixed": "bb1f19f3e0b23bcc", "spec": "3f5eb174a4edd6ad"},
+    "pangu": {"step": "a208678cfbacc27d", "mega": "9bc0f7a2dd819e50",
+              "mixed": "edb208f416683632", "spec": "986e9feb31e5f88e"}}
 
 
 def _pangu_tiny():

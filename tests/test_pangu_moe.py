@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine, ServingFrontend
-from paddle_tpu.inference.serving import SamplingParams
+from paddle_tpu.inference.serving import SamplingParams, control_layout
 from paddle_tpu.models import (LlamaForCausalLM, PanguUltraMoEConfig,
                                PanguUltraMoEForCausalLM, llama_tiny)
 from paddle_tpu.models import pangu_moe
@@ -371,25 +371,17 @@ SCOPES = ("embed", "norm", "latent_proj", "latent_attention", "latent_attention/
 
 
 def _lowered(eng, debug_info):
-    B, T, C, K = eng.B, eng.T, eng.pc, eng.megastep_k
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)          # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)        # noqa: E731
-    flag = jax.ShapeDtypeStruct((B,), jnp.bool_)
-    samp = (f32(B), i32(B), f32(B), i32(B), i32(B))
-    bt = i32(B, eng.P)
+    B, T, P_, C, K = eng.B, eng.T, eng.P, eng.pc, eng.megastep_k
+
+    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
+        return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
+
     head = (eng._weights, eng.caches, eng._rope)
     low = {
-        "step": eng._step_fn.lower(*head, i32(T), i32(B), i32(B), i32(B), i32(B + 1), bt,
-                                   *samp, mq=T, scales=None),
-        "mega": eng._build_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B + 1), i32(B), bt, flag, i32(B), i32(B),
-            i32(B), *samp, None, K=K),
-        "mixed": eng._build_mixed_megastep().lower(
-            *head, i32(B), i32(B), i32(B), i32(B), i32(B), i32(B, K * C), bt, flag, i32(B),
-            i32(B), i32(B), *samp, K=K),
-        "spec": eng._build_spec_verify().lower(
-            *head, i32(B * (eng.spec_k + 1)), i32(B), i32(B), i32(B + 1), bt, i32(B),
-            i32(B, eng.spec_k), *samp),
+        "step": eng._step_fn.lower(*head, block("step", T), None, mq=T),
+        "mega": eng._build_megastep().lower(*head, block("mega"), None, K=K),
+        "mixed": eng._build_mixed_megastep().lower(*head, block("mixed", K * C), None, K=K),
+        "spec": eng._build_spec_verify().lower(*head, block("spec", eng.spec_k), None),
     }
     return {k: v.as_text(debug_info=debug_info) for k, v in low.items()}
 

@@ -674,8 +674,14 @@ class ServingEngine:
                 "scales — a second sequence sharing the block would "
                 "dequantize garbage. Use the unquantized cache with the "
                 "prefix cache, or pass prefix_cache=False")
-        # 'auto' = on wherever it is sound (everything but int8)
+        if spec.slot_state and prefix_cache is True:
+            raise ValueError(
+                f"prefix_cache cannot be used with {type(model).__name__}: "
+                + spec.why_not)
+        # 'auto' = on wherever it is sound (everything but int8 and a model
+        # that keeps state a slot: adopted blocks carry no such state)
         self.prefix_cache_enabled = (cache_quant != "int8"
+                                     and not spec.slot_state
                                      and prefix_cache in ("auto", True))
         self.prefix_hit_blocks = 0      # full blocks reused from the cache
         self.prefix_miss_blocks = 0     # full prompt blocks that missed
@@ -731,7 +737,13 @@ class ServingEngine:
                      for k in ("kq", "vq", "kd", "vd")} for _ in range(self.L)]
             else:
                 self.cache_scales = None
-            span.note(bytes=_tree_bytes((self.caches, self.cache_scales)))
+            # state a slot (serving_model.py): one array a kind,
+            # [layers of that kind, slots, *shape]; () for a model without
+            self.slot_state = tuple(
+                jnp.zeros((layers, self.B) + tuple(shape), cache_dtype)
+                for _, layers, shape in spec.slot_state)
+            span.note(bytes=_tree_bytes((self.caches, self.cache_scales,
+                                         self.slot_state)))
         self._setup_spans.append(span)
         self.block_tables = np.full((self.B, self.P), -1, np.int32)
 
@@ -774,6 +786,15 @@ class ServingEngine:
         # walks the packed buffer whatever is live)
         self.moe_tokens = 0
         self.moe_local_picks = 0
+        # an expert layer's tile loop (models/pangu_moe.py ``held_experts``,
+        # where the trunk counts it): held experts that got at least one row,
+        # summed over layers and iterations; the rows the tiles multiplied,
+        # and those among them that were a live pick
+        self.experts_touched = 0
+        self.expert_tile_rows = 0
+        self.expert_tile_rows_live = 0
+        # state a slot: (row, layer) pairs whose state an iteration advanced
+        self.conv_rows_fed = 0
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
@@ -810,6 +831,10 @@ class ServingEngine:
         # batched forward.  0 (default) disarms the path entirely.
         if int(spec_k) < 0:
             raise ValueError("spec_k must be >= 0")
+        if int(spec_k) > 0 and spec.slot_state:
+            raise ValueError(
+                f"spec_k > 0 cannot be used with {type(model).__name__}: "
+                + spec.why_not)
         self.spec_k = int(spec_k)
         self.spec_accepted_tokens = 0   # draft tokens committed (monotone)
         self.spec_draft_tokens = 0      # draft tokens proposed (monotone)
@@ -871,6 +896,11 @@ class ServingEngine:
         itself — two models with the same architecture share programs."""
         return (self.B, self.T, self.bs, self.cache_spec.key, self.cache_quant,
                 bool(self.capture_sample_probs), self.pc, self.spec_k, self.P)
+
+    def program_caches(self) -> tuple:
+        """The ``caches`` argument of every program: the pool arrays, then
+        the arrays of state a slot (none for a model without)."""
+        return tuple(self.caches) + self.slot_state
 
     @property
     def key_caches(self):
@@ -1008,6 +1038,8 @@ class ServingEngine:
         B, P = self.B, self.P
         with_probs = self.capture_sample_probs
         layout = control_layout("mega", B, P)
+        n_pool = len(self.cache_spec.arrays)
+        slot_state = bool(self.cache_spec.slot_state)
 
         def mega(weights, caches, rope, block, scales=None, *, K):
             with jax.named_scope("scan_carry"):
@@ -1025,10 +1057,20 @@ class ServingEngine:
                  scales) = carry
                 with jax.named_scope("scan_carry"):
                     packed = toks[occ_idx]    # slot-order -> packed layout
+                before = caches[n_pool:]
                 logits, caches, ns, counts = fwd(
                     weights, caches, rope, packed, enc, dec, now, cu, bt, 1,
                     scales)
                 scales = ns if scales is not None else None
+                if slot_state:
+                    # a frozen row is fed its token again at the same
+                    # position: the pool takes the same bits, state a slot
+                    # would advance, so it keeps what it had
+                    with jax.named_scope("scan_carry"):
+                        keep = active & (dl > 0)
+                        caches = tuple(caches[:n_pool]) + tuple(
+                            jnp.where(keep.reshape((1, B) + (1,) * (a.ndim - 2)), a, b)
+                            for a, b in zip(caches[n_pool:], before))
                 nxt, lps, probs = _sample_tokens(
                     logits, temps, top_ks, top_ps, seeds, sample_pos,
                     return_probs=with_probs)
@@ -1510,6 +1552,17 @@ class ServingEngine:
                 "tokens": self.moe_tokens,
                 "local_picks": self.moe_local_picks,
             },
+            # the expert layers' tile loop, where a trunk counts it (monotone)
+            "experts": {
+                "touched": self.experts_touched,
+                "tile_rows": self.expert_tile_rows,
+                "tile_rows_live": self.expert_tile_rows_live,
+            },
+            # state a slot (monotone ``rows_fed``; no arrays for a model without)
+            "slot_state": {
+                "arrays": [name for name, _, _ in self.cache_spec.slot_state],
+                "rows_fed": self.conv_rows_fed,
+            },
             # paged attention (monotone; a latent cache under a learned
             # selection counts ``positions_live`` alone, another latent cache none)
             "attention": {
@@ -1676,9 +1729,11 @@ class ServingEngine:
                                 **attrs) as launch:
             fn = self._program(kind)
             had = fn._cache_size() if hasattr(fn, "_cache_size") else None
-            self.caches, new_scales, res, lps, probs = fn(
-                self._weights, self.caches, self._rope, block,
+            caches, new_scales, res, lps, probs = fn(
+                self._weights, self.program_caches(), self._rope, block,
                 self.cache_scales, **static)
+            n_pool = len(self.caches)
+            self.caches, self.slot_state = tuple(caches[:n_pool]), tuple(caches[n_pool:])
             if self.cache_scales is not None:
                 self.cache_scales = new_scales
             reads = ([res.words] + ([lps] if want_lps else [])
@@ -2422,7 +2477,7 @@ class ServingEngine:
     # engines as bit-exact payloads keyed by chain hash)
 
     def _check_transferable(self, op: str):
-        if not self.cache_spec.transferable:
+        if not self.cache_spec.transferable or self.cache_spec.slot_state:
             raise ValueError(
                 f"{op} cannot be used with {type(self).__name__} over this "
                 "model: " + self.cache_spec.why_not)

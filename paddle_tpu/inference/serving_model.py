@@ -32,9 +32,30 @@ from.  ``LlamaForCausalLM`` (models/llama.py: a list of per-head pools),
 ``total_ut_steps x num_hidden_layers`` cache layers) and
 ``DeepseekV32ForCausalLM`` (models/deepseek_v32.py: TWO arrays a layer of
 different width and meaning, a latent entry and a selector's key, under one
-block table) answer them.  The arrays of a spec are positional: whatever the
+block table) and ``Lfm2MoeForCausalLM`` (models/lfm2_moe.py: per-head pools
+for its attention layers alone and STATE A SLOT for its conv layers, below)
+answer them.  The arrays of a spec are positional: whatever the
 engine does to a block (allocate, copy on write, keep for a shared prefix,
-free) it does to that block of every array of every layer."""
+free) it does to that block of every array of every layer.
+
+State WITHOUT positions is the second kind (``CacheSpec.slot_state``): a
+fixed-size state a batch SLOT a layer, whatever the context's length (a short
+convolution's last inputs; ``LFM2-MoE``, models/lfm2_moe.py).  The engine owns
+one array ``[layers_of_that_kind, max_batch_size, *shape]`` for each (zeros at
+construction, the cache's type), hands them to the trunk in ``caches`` AFTER
+the pool arrays and takes them back in the same places, donated and carried
+through the scans like the pool.  Rows of the trunk's arguments (``dec``,
+``now``, ``cu``, ``bt``) ARE slots, so a token finds its state by its row.
+The contract for such state: a row fed ``now`` tokens at ``dec`` reads what
+the positions ``dec - 1, dec - 2, ..`` left there and writes back what
+``dec + now`` will read; a position under 0 reads zero BY POSITION, so a new
+tenant (or a request recomputed from 0 after ``evict``) never sees the last
+one's state and nothing is ever reset; a row that feeds nothing keeps its
+state, and the engine keeps it for a row its scan has frozen.  What takes a
+request's state to be its blocks does not hold for it, and the engine refuses
+each with a typed error that says ``why_not``: the prefix cache (adopted
+blocks start a request at position n with no state for n - 1), speculation
+(a refused draft would have advanced the state), block export and import."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -59,3 +80,7 @@ class CacheSpec:
     why_not: str = ""             # said by the typed refusals
     stacked: bool = False         # the pool is one array with a leading layer axis
     passes: int = 1               # times an iteration runs the weights' layers
+    # state a SLOT (module docstring): ((name, layers that keep it, shape of
+    # one slot's state in one layer), ...); ``layers`` above stays the POOLED
+    # cache layers.  The model puts it into ``key`` too.
+    slot_state: Tuple[Tuple[str, int, tuple], ...] = ()

@@ -38,3 +38,9 @@ from .deepseek_v32 import (  # noqa: F401,E402
     DeepseekV32Model,
     deepseek_v32_tiny,
 )
+from .lfm2_moe import (  # noqa: F401,E402
+    Lfm2MoeConfig,
+    Lfm2MoeForCausalLM,
+    Lfm2MoeModel,
+    lfm2_moe_tiny,
+)

@@ -200,7 +200,7 @@ def route(x, w_router, top_k, scale):
 
 
 @jax.named_scope("experts")
-def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128):
+def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None):
     """The part of the routed result that the held experts give.
 
     x [T, E]; idx, w [T, k] from ``route``; eg, eu [n_held, E, F], ed
@@ -209,7 +209,11 @@ def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128):
     rows padded to whole tiles of ``tile``, and one tile at a time goes
     through its expert's SwiGLU; the loop runs over the tiles in use, so an
     expert no token picked is not read.  Nothing is dropped: there is no
-    capacity.  -> (y [T, E] float32, picks that fell on a held expert)."""
+    capacity.  -> (y [T, E] float32, picks that fell on a held expert).
+    ``counts``: a trunk's dict of int32 scalars, to which the loop adds what
+    it did: ``experts_touched`` (held experts with at least one row),
+    ``expert_tile_rows`` (rows the tiles multiplied) and
+    ``expert_tile_rows_live`` (the picks among them)."""
     T, E = x.shape
     n_held, k = eg.shape[0], idx.shape[1]
     tile = min(int(tile), T * k)
@@ -237,15 +241,24 @@ def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128):
         return out.at[t].add(y * jnp.where(ok, w_s[r], 0.0)[:, None], mode="drop")
 
     y = jax.lax.fori_loop(0, last_tile[-1], one_tile, jnp.zeros((T, E), F32))
-    return y, jnp.sum(hit).astype(jnp.int32)
+    picks = jnp.sum(hit).astype(jnp.int32)
+    if counts is not None:
+        counts["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
+        counts["expert_tile_rows"] += (last_tile[-1] * tile).astype(jnp.int32)
+        counts["expert_tile_rows_live"] += picks
+    return y, picks
 
 
-def _moe_ffn(cfg, p, x, valid=None, router=route):
-    """Shared expert + the held experts' part. -> (y in x's dtype, picks).
-    ``router(x, w_router, top_k, scale)`` -> (idx, w): the model's routing."""
+def _moe_ffn(cfg, p, x, valid=None, router=route, counts=None):
+    """Shared expert (where the layer's weights hold one: ``sg``) + the held
+    experts' part. -> (y in x's dtype, picks).
+    ``router(x, w_router, top_k, scale)`` -> (idx, w): the model's routing;
+    ``counts``: ``held_experts``'s."""
     idx, w = router(x, p["router"], cfg.num_experts_per_tok, cfg.routed_scaling_factor)
     routed, picks = held_experts(x, idx, w, p["eg"], p["eu"], p["ed"],
-                                 cfg.experts_held[0], valid)
+                                 cfg.experts_held[0], valid, counts=counts)
+    if "sg" not in p:
+        return routed.astype(x.dtype), picks
     with jax.named_scope("shared_expert"):
         shared = _swiglu(x, p["sg"], p["su"], p["sd"])
     return (shared.astype(F32) + routed).astype(x.dtype), picks

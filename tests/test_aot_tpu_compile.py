@@ -290,11 +290,11 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
 DSA = "benchmark/configs/deepseek-v3.2-exp.serve1.json"
 
 
-@pytest.fixture(scope="module")
-def dsa_engine():
-    """``ServingEngine`` over DeepSeek-V3.2-Exp at deepseek-v3.2-exp.serve1's
-    geometry, its weights zeros and its pool two blocks: the programs take
-    both as arguments, and are lowered below for the cell's own shapes."""
+def _engine_of(config_file):
+    """``ServingEngine`` over a configuration's model at its own geometry, its
+    weights zeros (in the shapes and types ``make_weights`` gives them) and its
+    pool two blocks: the programs take both as arguments, and are lowered for
+    the cell's own shapes."""
     import json
 
     from benchmark.harness import loader
@@ -302,7 +302,7 @@ def dsa_engine():
     from paddle_tpu.inference import ServingEngine
 
     set_hybrid_communicate_group(None)
-    cfg = json.load(open(os.path.join(loader.ROOT, DSA)))
+    cfg = json.load(open(os.path.join(loader.ROOT, config_file)))
     family = loader.load_module("families", cfg["family"])
     model = family.build_model(cfg)
     shapes = jax.eval_shape(lambda: family.make_weights(cfg, 0))
@@ -310,6 +310,12 @@ def dsa_engine():
         lambda p, s: setattr(p, "_value", jnp.zeros(s.shape, s.dtype)),
         family.params_of(model), shapes, is_leaf=lambda x: hasattr(x, "_value"))
     return cfg, ServingEngine(model, **dict(cfg["engine"], num_blocks=2))
+
+
+@pytest.fixture(scope="module")
+def dsa_engine():
+    """DeepSeek-V3.2-Exp at deepseek-v3.2-exp.serve1's geometry."""
+    return _engine_of(DSA)
 
 
 @pytest.mark.parametrize("kind", ["step_prefill_T512", "mixed_K8", "mega_K8"])
@@ -361,4 +367,80 @@ def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind):
     said = cfg["memory"].get("compiled_for_v5e", {}).get(kind)
     assert said is not None, "the configuration's memory.compiled_for_v5e lacks " + kind
     assert _one_control_block_less(said["arguments"], mem.argument_size_in_bytes)
+    assert abs(said["live"] / live - 1) < 0.01
+
+
+# ------------------- conv layers with state a slot, GQA at heads of 64, 64 experts
+LFM2 = "benchmark/configs/lfm2-24b-a2b.serve1.json"
+LFM2_PROGRAMS = ("step_prefill_T512", "step_decode", "mixed_K8", "mega_K2", "mega_K4",
+                 "mega_K8")
+
+
+@pytest.fixture(scope="module")
+def lfm2_engine():
+    """LFM2-24B-A2B's first ten layers at lfm2-24b-a2b.serve1's geometry."""
+    return _engine_of(LFM2)
+
+
+@pytest.mark.parametrize("kind", LFM2_PROGRAMS)
+def test_a_conv_state_models_programs_fit_the_chip(chip, lfm2_engine, kind):
+    """The six programs lfm2-24b.serve.chat-batch can reach (the step at a
+    prefill's and at a decode's ``mq``, the decode scan at K 2, 4 and 8, the
+    mixed scan) of lfm2-24b-a2b.serve1 (5.27 B parameters with every expert of
+    eight layers, a pool of 2,048 blocks x keys and values x two attention
+    layers, conv state ``[8, 128, 2, 2048]`` a slot) compiled as the chip will
+    run them: heads of 64 take the XLA attention and the scatter (no
+    ``paged_decode`` / ``paged_write`` custom call); the state a slot is
+    donated and updated in place, never copied whole but ONCE in the decode
+    scan's body (8.4 MB: what a row the scan has frozen keeps); a pool array
+    ``[2048, 8, 64, 64]``, whose last axis is half a lane tile, gets a device
+    layout of the compiler's own and is copied ONCE into the layout the
+    scatter and the gather want and once back, outside the scan's loop (8
+    copies of 134 MB a launch, 0.5 GB of temporaries: ROADMAP's speed item for
+    heads of 64; one more copy would be a copy an iteration); and the largest
+    program leaves 1.5 GB of the chip free.  The figures are the configuration
+    file's ``memory.compiled_for_v5e``."""
+    from paddle_tpu.inference.serving import control_layout
+
+    cfg, eng = lfm2_engine
+    B, T, P, K, C = eng.B, eng.T, eng.P, eng.megastep_k, eng.pc
+    nb, bs = cfg["engine"]["num_blocks"], eng.bs
+    assert (B, T, P, K, C) == (128, 512, 44, 8, 64)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+
+    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
+        return sds((control_layout(kind, B, P, n).size,), jnp.int32)
+
+    weights = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), eng._weights)
+    pools = tuple([sds((nb,) + a.shape[1:], a.dtype) for a in layers]
+                  for layers in eng.caches)
+    assert [[a.shape for a in c] for c in pools] == [[(nb, 8, bs, 64)] * 2] * 2
+    (state,) = eng.slot_state
+    assert state.shape == (8, 128, 2, 2048) and state.dtype == jnp.bfloat16
+    head = (weights, pools + (sds(state.shape, state.dtype),),
+            sds(eng._rope.shape, eng._rope.dtype))
+    scans = {f"mega_K{k}": (lambda k=k: eng._build_megastep().lower(
+        *head, block("mega"), K=k)) for k in (2, 4, 8)}
+    compiled = dict(scans, **{
+        "step_prefill_T512": lambda: eng._build_step().lower(*head, block("step", T), mq=T),
+        "step_decode": lambda: eng._build_step().lower(*head, block("step", B), mq=1),
+        "mixed_K8": lambda: eng._build_mixed_megastep().lower(
+            *head, block("mixed", K * C), K=K),
+    })[kind]().compile()
+    text = compiled.as_text()
+    assert "paged_decode" not in text and "paged_write" not in text
+    state_copies = len(re.findall(r"= bf16\[8,128,2,2048\][^\n]* copy\(", text))
+    assert state_copies == (1 if kind.startswith("mega") else 0)
+    assert len(re.findall(rf"= bf16\[{nb},8,{bs},64\][^\n]* copy\(", text)) <= 8
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(kind, dict(arguments=mem.argument_size_in_bytes, temporaries=mem.temp_size_in_bytes,
+                     live=live))
+    assert 0.25 * V5E_BYTES_LIMIT < live < V5E_BYTES_LIMIT - 1.5e9, live
+    said = cfg["memory"].get("compiled_for_v5e", {}).get(kind)
+    assert said is not None, "the configuration's memory.compiled_for_v5e lacks " + kind
+    assert said["arguments"] == mem.argument_size_in_bytes
     assert abs(said["live"] / live - 1) < 0.01

@@ -332,7 +332,7 @@ def _lowered(eng, debug_info, kinds=("step", "mega", "mixed", "spec")):
     def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
         return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
 
-    head = (eng._weights, eng.caches, eng._rope)
+    head = (eng._weights, eng.program_caches(), eng._rope)
     low = {
         "step": lambda: eng._step_fn.lower(*head, block("step", T), None, mq=T),
         "mega": lambda: eng._build_megastep().lower(*head, block("mega"), None, K=K),

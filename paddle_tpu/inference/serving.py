@@ -786,6 +786,9 @@ class ServingEngine:
         # walks the packed buffer whatever is live)
         self.moe_tokens = 0
         self.moe_local_picks = 0
+        # of those picks, the ones that went through the grouped product
+        # (``held_experts``: ops/pallas/expert_gmm.py; 0 where the tile loop ran)
+        self.expert_rows_grouped = 0
         # an expert layer's tile loop (models/pangu_moe.py ``held_experts``,
         # where the trunk counts it): held experts that got at least one row,
         # summed over layers and iterations; the rows the tiles multiplied,
@@ -1551,6 +1554,7 @@ class ServingEngine:
             "moe": {
                 "tokens": self.moe_tokens,
                 "local_picks": self.moe_local_picks,
+                "rows_grouped": self.expert_rows_grouped,
             },
             # the expert layers' tile loop, where a trunk counts it (monotone)
             "experts": {

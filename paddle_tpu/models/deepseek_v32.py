@@ -372,7 +372,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
         scales) -> (hidden [T, E] after the final norm, caches, [], counts):
         packed tokens through every layer against the paged pool, ``caches`` =
         (latent pools, index_k pools), a layer each.  ``counts``: the expert
-        layers' ``moe_tokens`` / ``moe_local_picks``, and of ONE layer's
+        layers' ``moe_tokens`` / ``moe_local_picks`` / ``expert_rows_grouped``, and of ONE
+        layer's
         indexer and attention, over the live queries whose context exceeds
         ``index_topk``: ``dsa_queries``, ``dsa_positions_scored``,
         ``dsa_positions_selected`` and ``dsa_positions_read`` (the latent
@@ -393,8 +394,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
             cos, sin = rope[0, pos], rope[1, pos]
             with jax.named_scope("embed"):
                 hidden = weights["embed"][token_ids]
-            counts = {"moe_tokens": jnp.zeros((), jnp.int32),
-                      "moe_local_picks": jnp.zeros((), jnp.int32)}
+            counts = {name: jnp.zeros((), jnp.int32) for name in (
+                "moe_tokens", "moe_local_picks", "expert_rows_grouped")}
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_in"], eps)
@@ -426,7 +427,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                 with jax.named_scope("norm"):
                     h2 = _rms(hidden, lw["ln_post"], eps)
                 if "router" in lw:
-                    ffn, picks = _moe_ffn(cfg, lw, h2, valid, router=_router_of(cfg, lw))
+                    ffn, picks = _moe_ffn(cfg, lw, h2, valid, router=_router_of(cfg, lw),
+                                          counts=counts)
                     counts["moe_tokens"] += jnp.sum(valid).astype(jnp.int32)
                     counts["moe_local_picks"] += picks
                 else:

@@ -396,7 +396,8 @@ class Lfm2MoeForCausalLM(nn.Layer):
         E]``).  ``counts``: ``conv_rows_fed`` (row-layers whose state
         advanced), ``moe_tokens`` / ``moe_local_picks`` as the other expert
         families count them, ``experts_touched`` / ``expert_tile_rows`` /
-        ``expert_tile_rows_live`` of the tile loop, and ONE attention layer's
+        ``expert_tile_rows_live`` / ``expert_rows_grouped`` of the expert
+        layer's products (``held_experts``), and ONE attention layer's
         ``attn_positions_*`` / ``kv_write_*`` as models/llama.py's trunk."""
         from ..ops.paged_attention import (attention_positions, blha_attention,
                                            cache_write_counts)
@@ -416,7 +417,7 @@ class Lfm2MoeForCausalLM(nn.Layer):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "conv_rows_fed", "moe_tokens", "moe_local_picks", "experts_touched",
-                "expert_tile_rows", "expert_tile_rows_live")}
+                "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped")}
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_op"], eps)

@@ -26,7 +26,9 @@ log-probabilities), ``engine.harvest`` (stats: what the model's trunk counted
 in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
 ``attn_rows_kernel`` and the cache write's ``kv_write_tokens`` /
 ``kv_write_blocks`` for a dense paged cache, ``moe_tokens`` /
-``moe_local_picks`` for expert layers, ``loop_tokens`` /
+``moe_local_picks`` / ``expert_rows_grouped`` (the picks that went through
+the grouped product, ops/pallas/expert_gmm.py; 0 where the tile loop ran) for
+expert layers, ``loop_tokens`` /
 ``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run,
 ``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected`` /
 ``dsa_positions_read`` for learned sparse attention: ONE layer's, over the
@@ -77,8 +79,9 @@ programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
 ``loop_pass`` > ``while/body/`` the layers' scopes, ``norm``, ``exit_gate``
 (one pass of a looped trunk, itself the body of the loop over the passes);
 ``latent_proj``, ``latent_attention`` > ``kv_write`` and the three under
-``while/body/`` (a latent cache), ``router``, ``experts``, ``shared_expert``
-(expert layers); ``indexer`` > ``index_proj``, ``index_write``,
+``while/body/`` (a latent cache), ``router``, ``experts`` (on the chip three
+``expert_gmm`` kernels; elsewhere the tile loop, ``experts/while/body/``),
+``shared_expert`` (expert layers); ``indexer`` > ``index_proj``, ``index_write``,
 ``while/body/`` {``index_gather``, ``index_scores``}, ``index_topk`` (the
 selector of learned sparse attention, ops/sparse_index.py);
 ``attention`` >
@@ -89,7 +92,8 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
 ``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
-``int8_matmul``, ``paged_decode``, ``paged_write``.
+``int8_matmul``, ``paged_decode``, ``paged_write``, ``expert_gmm`` (three a
+layer under ``experts``, on the chip: gate, up, down).
 """
 from __future__ import annotations
 

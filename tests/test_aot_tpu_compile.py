@@ -444,3 +444,83 @@ def test_a_conv_state_models_programs_fit_the_chip(chip, lfm2_engine, kind):
     assert said is not None, "the configuration's memory.compiled_for_v5e lacks " + kind
     assert said["arguments"] == mem.argument_size_in_bytes
     assert abs(said["live"] / live - 1) < 0.01
+
+
+# ------------------- the expert layer as three grouped products (ISSUE 39)
+PANGU = "benchmark/configs/openpangu-ultra-moe-718b.serve1.json"
+# (configuration, held experts, E, F, k, the mixed scan's live bytes before: PR 38's
+# 12.99 GB for LFM2, and for openPangu the largest program PR 26 compiled, 13.22 GB)
+EXPERT_LAYERS = {"lfm2": (LFM2, 64, 2048, 1536, 4, 12_986_028_032),
+                 "openpangu": (PANGU, 16, 7680, 2048, 8, 13_223_340_544)}
+
+
+@pytest.fixture(scope="module")
+def expert_engines():
+    """An engine a configuration, built once (the LFM2 one is ``lfm2_engine``'s twin:
+    the fixture above hands its own to tests that must not see the steering)."""
+    return {}
+
+
+@pytest.mark.parametrize("family", sorted(EXPERT_LAYERS))
+def test_the_expert_layer_is_three_grouped_products(chip, family, expert_engines,
+                                                    monkeypatch):
+    """With the platform answering yes, ``held_experts`` at the cell's widths
+    (512 packed tokens, bf16) compiles to ONE ``expert_gmm`` call an expert
+    matrix (gate, up, down) and no tile loop; the stacks ``eg`` / ``eu`` /
+    ``ed`` stay where they lie: no copy of one, nor of an expert's slice,
+    among the temporaries; each call's blocks fit the kernel's VMEM limit
+    (the compile refuses otherwise).  And the mixed scan, the largest program
+    of the window, is no larger than it was with the loop."""
+    from paddle_tpu.inference.serving import control_layout
+    from paddle_tpu.models import pangu_moe
+    from paddle_tpu.ops.pallas import expert_gmm
+
+    config_file, n_held, E, F, k, live_before = EXPERT_LAYERS[family]
+    monkeypatch.setattr(pangu_moe, "on_tpu", lambda: True)
+    T = 512
+
+    def layer(x, idx, w, eg, eu, ed, valid):
+        counts = {"expert_rows_grouped": jnp.zeros((), jnp.int32)}
+        y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts)
+        return y, picks, counts
+
+    compiled = _compile(layer, chip, ((T, E), BF16), ((T, k), jnp.int32),
+                        ((T, k), jnp.float32), ((n_held, E, F), BF16),
+                        ((n_held, E, F), BF16), ((n_held, F, E), BF16), ((T,), jnp.bool_),
+                        names=("expert_gmm",))
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_gmm(\.\d+)? = [^\n]* custom-call\(", text)) == 3
+    stacks = "|".join((f"{n_held},{E},{F}", f"{n_held},{F},{E}", f"{E},{F}", f"{F},{E}"))
+    made = [line.strip()[:120] for line in text.splitlines()
+            if re.search(rf"= bf16\[({stacks})\]", line)
+            and not re.search(r"\] (parameter|get-tuple-element)\(", line)
+            and " parameter(" not in line and " get-tuple-element(" not in line]
+    assert not made, made           # a loop's carry hands the stacks on; nothing makes one
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pangu_moe._CHUNK_BYTES * 4
+    assert expert_gmm.VMEM_LIMIT <= 100 << 20       # of a v5e core's 128 MiB
+
+    if family not in expert_engines:
+        expert_engines[family] = _engine_of(config_file)
+    cfg, eng = expert_engines[family]
+    B, P, K, C = eng.B, eng.P, eng.megastep_k, eng.pc
+    nb = cfg["engine"]["num_blocks"]
+
+    def sds(a, lead=None):
+        shape = a.shape if lead is None else (lead,) + a.shape[1:]
+        return jax.ShapeDtypeStruct(tuple(shape), a.dtype, sharding=chip)
+
+    weights = jax.tree_util.tree_map(sds, eng._weights)
+    caches = tuple([sds(a, nb) for a in layers] for layers in eng.caches) + tuple(
+        sds(s) for s in eng.slot_state)
+    block = jax.ShapeDtypeStruct((control_layout("mixed", B, P, K * C).size,), jnp.int32,
+                                 sharding=chip)
+    mixed = eng._build_mixed_megastep().lower(weights, caches, sds(eng._rope), block,
+                                              K=K).compile()
+    sparse = sum("router" in lw for lw in eng._weights["layers"])
+    assert len(re.findall(r"%expert_gmm(\.\d+)? = [^\n]* custom-call\(",
+                          mixed.as_text())) == 3 * sparse
+    mem = mixed.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(family, "mixed_K8 steered", dict(temporaries=mem.temp_size_in_bytes, live=live))
+    assert live <= live_before, (live, live_before)

@@ -160,6 +160,7 @@ def test_lowered_train_step_names_its_scopes(scaled):
 
 KERNEL_NAMES = {
     "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    "expert_gmm.py": ["expert_gmm"],
     "fused_norm.py": ["rms_norm", "rms_norm_residual"],
     "fused_ops.py": ["fused_rope", "swiglu_fwd", "swiglu_bwd"],
     "int8_matmul.py": ["int8_matmul"],

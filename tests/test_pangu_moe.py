@@ -489,11 +489,14 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     # two expert layers see every token fed: 29 prompt tokens and 5 + 5 fed back
     assert eng.moe_tokens == 2 * (29 + 10)
     assert 0 < eng.moe_local_picks < eng.moe_tokens * TINY["num_experts_per_tok"]
+    # on the CPU every expert layer takes the tile loop: nothing was grouped
     assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
-                                          "local_picks": eng.moe_local_picks}
+                                          "local_picks": eng.moe_local_picks,
+                                          "rows_grouped": 0}
     # ... and nothing of the dense attention's, which this trunk does not run
     seen = [a for _, a in harvests]
-    assert seen and all(set(a) == {"moe_tokens", "moe_local_picks"} for a in seen)
+    assert seen and all(set(a) == {"moe_tokens", "moe_local_picks", "expert_rows_grouped"}
+                        for a in seen)
     assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0,
                                                 "rows_kernel": 0, "kv_write_tokens": 0,
                                                 "kv_write_blocks": 0}
@@ -590,7 +593,7 @@ def test_a_dense_model_counts_no_experts():
     eng = ServingEngine(model.eval(), **LLAMA_GEOMETRY)
     eng.add_request([5, 6, 7], max_new_tokens=6)
     eng.run()
-    assert eng.state_summary()["moe"] == {"tokens": 0, "local_picks": 0}
+    assert eng.state_summary()["moe"] == {"tokens": 0, "local_picks": 0, "rows_grouped": 0}
     assert [name for name, _ in eng.cache_spec.arrays] == ["k", "v"]
     assert len(eng.caches) == 2 and eng.key_caches is eng.caches[0]
 

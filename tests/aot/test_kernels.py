@@ -1,0 +1,190 @@
+"""The Pallas kernels of the main paths, each alone at the widths its callers
+give it."""
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from described_device import (BF16, LEFT_ALONE, STEERED, compile_kernel, kernel_calls,
+                              on_the_chip)
+
+
+def test_every_module_that_asks_on_tpu_is_steered_or_left_alone_with_a_reason():
+    """A module of ``paddle_tpu`` that starts asking ``on_tpu()`` cannot be
+    forgotten: it is in ``STEERED`` or in ``LEFT_ALONE``, and nothing is
+    listed that no longer asks."""
+    import pathlib
+
+    import paddle_tpu
+
+    root = pathlib.Path(paddle_tpu.__file__).parent
+    asking = {".".join(f.relative_to(root).with_suffix("").parts).removesuffix(".__init__")
+              for f in root.rglob("*.py") if re.search(r"\bon_tpu\b", f.read_text())}
+    assert not set(STEERED) & set(LEFT_ALONE)
+    assert asking == set(STEERED) | set(LEFT_ALONE)
+
+
+# (bh, kv_rep, seq, causal); "cell" is mistral7b.train.pretrain-2k's call
+# (4 x 32 heads over 8 KV heads), "ring" the non-causal block that ring
+# attention runs off the diagonal
+_FLASH_CALLS = {
+    "mha-2048": (32, 1, 2048, True), "gqa4-2048": (32, 4, 2048, True),
+    "mha-4096": (32, 1, 4096, True), "gqa4-4096": (32, 4, 4096, True),
+    "cell": (128, 4, 2048, True), "ring": (32, 4, 2048, False),
+}
+
+
+@pytest.mark.parametrize("call", _FLASH_CALLS)
+@pytest.mark.parametrize("kind", ["fwd", "bwd"])
+def test_flash_attention(chip, kind, call):
+    """bf16 operands at the table's blocks: whether Mosaic takes the kernels'
+    bf16 products (the backward's transposed scores among them) and their
+    tiles fit VMEM is learned here, on the CPU."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas.autotune import get_flash_blocks
+
+    bh, kv_rep, seq, causal = _FLASH_CALLS[call]
+    d = 128
+    scale = d ** -0.5
+    bq, bk = get_flash_blocks(kind, seq, seq, d)
+    q = ((bh, seq, d), BF16)
+    kv = ((bh // kv_rep, seq, d), BF16)
+    if kind == "fwd":
+        compile_kernel(lambda q, k, v: fa._pallas_fwd(
+            q, k, v, causal, scale, bq, bk, False, kv_rep=kv_rep),
+            chip, q, kv, kv, names=("flash_fwd",))
+    else:
+        compile_kernel(lambda q, k, v, o, lse, g: fa._pallas_bwd(
+            q, k, v, o, lse, g, causal, scale, bq, bk, False, kv_rep=kv_rep),
+            chip, q, kv, kv, q, ((bh, seq), jnp.float32), q,
+            names=("flash_bwd_dq", "flash_bwd_dkv"))
+
+
+@pytest.mark.parametrize("rows", [16384, 2048])
+def test_rms_norm(chip, rows):
+    from paddle_tpu.ops.pallas import fused_norm as fn
+
+    x = ((rows, 4096), BF16)
+    compile_kernel(lambda x, w: fn._pallas_rms(x, w, 1e-6, False),
+                   chip, x, ((4096,), BF16))
+
+
+@pytest.mark.parametrize("hidden,dtype", [(4096, BF16), (2560, BF16),
+                                          (8192, BF16), (4096, jnp.float32)])
+def test_rms_norm_residual(chip, hidden, dtype):
+    """[16384, 4096] bf16 with the old fixed 256-row block asked for 16.01M
+    of a 16.00M scoped VMEM limit."""
+    from paddle_tpu.ops.pallas import fused_norm as fn
+
+    x = ((16384, hidden), dtype)
+    compile_kernel(lambda x, r, w: fn._pallas_rms_residual(x, r, w, 1e-6, False),
+                   chip, x, x, ((hidden,), dtype))
+
+
+@pytest.mark.parametrize("m", [8, 512])
+def test_int8_matmul(chip, m, monkeypatch):
+    from paddle_tpu.ops.pallas import int8_matmul as im
+
+    on_the_chip(monkeypatch)        # on the CPU _int8_mm_impl takes its jnp branch
+    compile_kernel(lambda x, q, s: im._int8_mm_impl(x, q, s, False), chip,
+                   ((m, 4096), BF16), ((4096, 11008), jnp.int8), ((11008,), jnp.float32))
+
+
+@pytest.mark.parametrize("mq", [1, 64, 256], ids=["decode", "chunk64", "prefill256"])
+def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch):
+    """The dense paged attention at the benchmark's serving geometry
+    (mistral-7b-v0.3.serve1: 32 rows, tables of 40 blocks of 64, 8 kv heads
+    of 128, a pool of 1024 blocks), two iterations in a scan with the pool
+    in the carry as the engine's scans hold it, compiled as the chip will
+    run it: whether a row feeds one token is data, so every one of the three
+    holds the one-token rows' ``paged_decode`` kernel (the chunk rows' pass
+    is XLA) and, since ISSUE 31, the cache write's ``paged_write`` kernel,
+    which takes the pool where it lies and returns it. What the chip's
+    compiler must not do is what it did before
+    ISSUE 27: keep a second copy of the pool in another layout (the write,
+    the gather and the kernel's operand must agree on one, or 268 MB a layer
+    are copied in and out), or build anything as large as every row's whole
+    table (168 MB in bf16)."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    on_the_chip(monkeypatch)
+    blha_attention = pa.blha_attention.__wrapped__     # no trace made for the CPU
+    B, P, bs, H, KV, D, nb = 32, 40, 64, 32, 8, 128, 1024
+    T = B if mq == 1 else 256
+
+    def two_iterations(qkv, kc, vc, dec, now, cu, bt, rope):
+        def body(carry, _):
+            kc, vc = carry
+            out = blha_attention(
+                qkv, kc, vc, jnp.zeros_like(dec), dec, now, cu, bt, num_heads=H,
+                kv_num_heads=KV, head_dim=D, block_size=bs, max_q_len=mq,
+                use_neox_style=True, compute_dtype=BF16, rope_emb=rope)
+            return (out[1], out[2]), out[0]
+        return jax.lax.scan(body, (kc, vc), None, length=2)
+
+    pool, i32 = ((nb, KV, bs, D), BF16), jnp.int32
+    compiled = compile_kernel(
+        two_iterations, chip, ((T, (H + 2 * KV) * D), BF16), pool, pool, ((B,), i32),
+        ((B,), i32), ((B + 1,), i32), ((B, P), i32),
+        ((2, 1, P * bs, 1, D // 2), jnp.float32), names=("paged_decode", "paged_write"),
+        donate=(1, 2))
+    text = compiled.as_text()
+    assert "kv_write/scatter" not in text
+    pool_bytes = nb * KV * bs * D * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"
+    # one layout of the pool, the argument's row-major order, on the
+    # parameter, the write, the kernel's operand and the gather alike
+    orders = set(re.findall(r"bf16\[1024,8,64,128\]\{([0-9,]+)", text))
+    assert orders == {"3,2,1,0"}, orders
+    assert not re.search(r"= bf16\[1024,8,64,128\][^\n]* copy\(", text)
+    whole = B * KV * P * bs * D
+    views_of_the_pool = {(nb, KV, bs, D), (nb * KV, bs, D), (nb * KV * bs, D)}
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text)}
+    big = [s for s in shapes - views_of_the_pool if math.prod(s) >= whole]
+    assert not big, big
+
+
+# (held experts, E, F, k) of lfm2-24b-a2b.serve1 and openpangu-ultra-moe-718b.serve1
+EXPERT_LAYERS = {"lfm2": (64, 2048, 1536, 4), "openpangu": (16, 7680, 2048, 8)}
+
+
+@pytest.mark.parametrize("family", sorted(EXPERT_LAYERS))
+def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
+    """With the platform answering yes, ``held_experts`` at the cell's widths
+    (512 packed tokens, bf16) compiles to ONE ``expert_gmm`` call an expert
+    matrix (gate, up, down) and no tile loop; the stacks ``eg`` / ``eu`` /
+    ``ed`` stay where they lie: no copy of one, nor of an expert's slice,
+    among the temporaries; each call's blocks fit the kernel's VMEM limit
+    (the compile refuses otherwise).  (That the mixed scan, the largest program
+    of the window, is no larger than it was with the loop is a line of each
+    family's own ``mixed_K8`` case.)"""
+    from paddle_tpu.models import pangu_moe
+    from paddle_tpu.ops.pallas import expert_gmm
+
+    n_held, E, F, k = EXPERT_LAYERS[family]
+    on_the_chip(monkeypatch)
+    T = 512
+
+    def layer(x, idx, w, eg, eu, ed, valid):
+        counts = {"expert_rows_grouped": jnp.zeros((), jnp.int32)}
+        y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts)
+        return y, picks, counts
+
+    compiled = compile_kernel(
+        layer, chip, ((T, E), BF16), ((T, k), jnp.int32), ((T, k), jnp.float32),
+        ((n_held, E, F), BF16), ((n_held, E, F), BF16), ((n_held, F, E), BF16),
+        ((T,), jnp.bool_), names=("expert_gmm",))
+    text = compiled.as_text()
+    assert kernel_calls(text, "expert_gmm") == 3
+    stacks = "|".join((f"{n_held},{E},{F}", f"{n_held},{F},{E}", f"{E},{F}", f"{F},{E}"))
+    made = [line.strip()[:120] for line in text.splitlines()
+            if re.search(rf"= bf16\[({stacks})\]", line)
+            and not re.search(r"\] (parameter|get-tuple-element)\(", line)
+            and " parameter(" not in line and " get-tuple-element(" not in line]
+    assert not made, made           # a loop's carry hands the stacks on; nothing makes one
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pangu_moe._CHUNK_BYTES * 4
+    assert expert_gmm.VMEM_LIMIT <= 100 << 20       # of a v5e core's 128 MiB

@@ -25,7 +25,7 @@ if os.environ.get("PADDLE_TPU_TEST_ON_TPU") != "1":
     # compile per spawned worker; every worker after the first hits the
     # disk cache.  Set AFTER `import jax` above, deliberately: jax reads the
     # variable at import, so the pytest process itself keeps the cache off
-    # (the described-device compiles of test_aot_tpu_compile.py must not be
+    # (the described-device compiles of tests/aot/ must not be
     # written to it, and turning it on for the whole suite is ROADMAP D6's
     # decision, not a side effect).
     os.environ.setdefault(
@@ -45,10 +45,12 @@ def pytest_configure(config):
         "suite)")
     config.addinivalue_line(
         "markers",
-        "slow: excluded from the tier-1 'not slow' run (which already "
-        "overruns its wall-clock budget at the seed): subprocess-spawning "
-        "fleet tests etc.; CI shards run their files without the filter, "
-        "so these still gate merges")
+        "slow: excluded from the tier-1 'not slow' run. A case is marked only "
+        "if it still takes over 60 s alone after its set-up was shared and "
+        "its forward traced once, it is no serving, training, kernel or "
+        "benchmark test of a family the benchmark has (subprocess-spawning "
+        "fleet tests, the vision zoo, the 4-D pipeline demos), and CI's "
+        "shards run its file without the filter, so it still gates merges")
 
 
 @pytest.fixture(autouse=True)
@@ -70,7 +72,11 @@ def serving_model():
     delegate here (and re-clear any leaked topology group themselves);
     the weights are seeded at build, so sharing the instance changes no
     reference tokens.  Treat it as READ-ONLY: a test that must mutate
-    weights (bfloat16(), load_state) builds its own copy."""
+    weights (bfloat16(), load_state) builds its own copy.  The one thing
+    tests leave on it is ``generate``'s own memo of its traced forward
+    (``_decode_cache["_static_fwd"]``, through the files' ``ref_greedy``):
+    it is what makes that reference two programs a shape, it reads the
+    weights where they are, and no engine looks at it."""
     import paddle_tpu as P
     from paddle_tpu.distributed.topology import set_hybrid_communicate_group
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM

@@ -276,11 +276,11 @@ def test_an_engine_steered_onto_the_chip_groups_every_pick(monkeypatch):
     monkeypatch.setattr(pangu_moe, "on_tpu", lambda: True)
     monkeypatch.setattr(pangu_moe, "expert_gmm",
                         functools.partial(gmm_module.expert_gmm, interpret=True))
-    jax.clear_caches()
+    pangu_moe._held_experts.clear_cache()
     try:
         eng, seen = served()
     finally:
-        jax.clear_caches()
+        pangu_moe._held_experts.clear_cache()
     assert eng.moe_tokens == plain.moe_tokens
     assert eng.expert_rows_grouped == eng.moe_local_picks == eng.expert_tile_rows_live > 0
     assert sum(a["expert_rows_grouped"] for a in seen) == eng.expert_rows_grouped

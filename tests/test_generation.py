@@ -46,13 +46,26 @@ def test_zero_budget_returns_empty():
     assert out.shape == [2, 0]
 
 
-def test_static_cache_matches_dynamic():
+@pytest.mark.parametrize("batch,prompt_len,new", [
+    (2, 6, 6),
+    (1, 2, 6), (1, 3, 30), (1, 5, 12), (1, 8, 48), (1, 11, 4), (1, 24, 6), (1, 40, 6),
+    (1, 250, 6)])
+def test_static_cache_matches_dynamic(serving_model, batch, prompt_len, new):
     """Fixed-size KV ring decode == growing-cache decode, with exactly TWO
-    compiled programs (prefill + decode) regardless of sequence length."""
-    m = _model()
-    ids = P.to_tensor(np.random.RandomState(3).randint(0, 512, (2, 6)).astype(np.int32))
-    ref = generate(m, ids, max_new_tokens=6)
-    out = generate(m, ids, max_new_tokens=6, use_static_cache=True)
+    compiled programs (prefill + decode) regardless of sequence length.
+
+    The serving test files' ``ref_greedy`` asks ``use_static_cache=True`` (the
+    growing caches compile every op again at every length), so the plain
+    growing-cache forward, which ``TestGrowingCacheStep`` holds to the full
+    forward, guards that reference here: ``llama_tiny`` at a batch of two, and
+    those files' own model with one prompt as they send it, at their prompt
+    lengths and new-token counts (2-40 tokens, 2-48 new) and up to the last
+    rope row (250 + 6 = ``max_position_embeddings``)."""
+    m = _model() if batch == 2 else serving_model
+    ids = P.to_tensor(np.random.RandomState(3).randint(
+        0, m.config.vocab_size, (batch, prompt_len)).astype(np.int32))
+    ref = generate(m, ids, max_new_tokens=new)
+    out = generate(m, ids, max_new_tokens=new, use_static_cache=True)
     np.testing.assert_array_equal(out.numpy(), ref.numpy())
 
 
@@ -133,10 +146,11 @@ def test_static_cache_rejects_beyond_rope_table():
 
 
 class TestGrowingCacheStep:
-    """``forward(ids, caches=[(k, v), ...])`` is the step ``generate`` runs and
-    what the serving tests hold the engine to: its logits are the full
-    forward's at the same positions, whatever the cached length (255 is
-    ``llama_tiny``'s last rope row)."""
+    """``forward(ids, caches=[(k, v), ...])`` is the step ``generate`` runs with
+    growing caches: its logits are the full forward's at the same positions,
+    whatever the cached length (255 is ``llama_tiny``'s last rope row).  The
+    serving tests hold the engine to ``generate``'s fixed-shape path, and
+    ``test_static_cache_matches_dynamic`` holds that path to this one."""
 
     @staticmethod
     def _model(**kw):

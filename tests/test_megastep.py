@@ -79,7 +79,10 @@ def ref_greedy(model, prompt, n):
     from paddle_tpu.models.generation import generate
 
     ids = P.to_tensor(np.asarray(prompt, np.int32)[None, :])
-    out = generate(model, ids, max_new_tokens=n, do_sample=False)
+    # the fixed-shape path (two programs): with growing caches every op of the
+    # forward compiles again at every length, most of this reference's seconds
+    out = generate(model, ids, max_new_tokens=n, do_sample=False,
+                   use_static_cache=True)
     return list(np.asarray(out.numpy()).reshape(-1))
 
 
@@ -976,8 +979,6 @@ class TestRowsThroughTheKernel:
         the XLA pass's."""
         import functools
 
-        import jax
-
         from paddle_tpu.distributed.topology import set_hybrid_communicate_group
         from paddle_tpu.inference import serving
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -1007,18 +1008,19 @@ class TestRowsThroughTheKernel:
         plain, _, want = run()
         assert plain.attn_rows_kernel == 0 == plain.kv_write_blocks
         # the platform is asked when a program is traced: drop the traces
-        # made for the CPU, and those made here once the test is over
+        # made for the CPU, and those made here once the test is over (of
+        # the one jitted function that asks, not the whole process's)
         monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
         monkeypatch.setattr(pa, "on_tpu", lambda: True)
         monkeypatch.setattr(pa, "paged_decode",
                             functools.partial(pd.paged_decode, interpret=True))
         monkeypatch.setattr(pa, "paged_write",
                             functools.partial(pw.paged_write, interpret=True))
-        jax.clear_caches()
+        pa.blha_attention.clear_cache()
         try:
             eng, seen, got = run()
         finally:
-            jax.clear_caches()
+            pa.blha_attention.clear_cache()
         assert got == want
         totals = [t for _, _, t in seen]
         assert totals == sorted(totals) and eng.attn_rows_kernel > 0

@@ -526,11 +526,11 @@ def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypat
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     monkeypatch.setattr(pa, "paged_decode", functools.partial(pd.paged_decode, interpret=True))
     monkeypatch.setattr(pa, "paged_write", functools.partial(pw.paged_write, interpret=True))
-    jax.clear_caches()
+    pa.blha_attention.clear_cache()
     try:
         eng, got = run()
     finally:
-        jax.clear_caches()
+        pa.blha_attention.clear_cache()
     assert got == want
     assert eng.kv_write_tokens == plain.kv_write_tokens
     # the prompts lie in one piece of 16 positions and in two; a token fed

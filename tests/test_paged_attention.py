@@ -401,6 +401,8 @@ class TestTracedSeqLens:
 # time. The reference below is the mathematics of the form it replaced: the
 # WHOLE table gathered, every row padded to ``max_q_len`` queries, one
 # softmax over the table's length, all in float32.
+import functools                # noqa: E402
+
 import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
 
@@ -411,6 +413,9 @@ G_BS, G_P, G_H, G_D = 8, 80, 4, 16       # a table of 640 positions, 64 columns 
 G_L = G_BS * G_P
 
 
+# traced ONCE a case: run op by op its forty-odd steps are forty-odd small
+# programs compiled for every case's shapes (two thirds of this file's seconds)
+@functools.partial(jax.jit, static_argnames=("S", "quant"))
 def padded_reference(q, k, v, kc, vc, enc, dec, now, cu, bt, *, S, quant="none",
                      kd=None, vd=None, pre_k=None, pre_v=None, mask=None,
                      tgt_mask=None):
@@ -599,8 +604,6 @@ def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes
 # steer ``on_tpu`` and run the kernel in interpret mode; the reference is the
 # padded form above. Blocks of 16 in a table of 24, heads of 128: a pass is
 # 8 blocks, 128 positions.
-import functools                 # noqa: E402
-
 from paddle_tpu.ops.pallas import paged_decode as pd   # noqa: E402
 from paddle_tpu.ops.pallas import paged_write as pw    # noqa: E402
 

@@ -53,7 +53,10 @@ def ref_greedy(model, prompt, n):
     from paddle_tpu.models.generation import generate
 
     ids = P.to_tensor(np.asarray(prompt, np.int32)[None, :])
-    out = generate(model, ids, max_new_tokens=n, do_sample=False)
+    # the fixed-shape path (two programs): with growing caches every op of the
+    # forward compiles again at every length, most of this reference's seconds
+    out = generate(model, ids, max_new_tokens=n, do_sample=False,
+                   use_static_cache=True)
     return list(np.asarray(out.numpy()).reshape(-1))
 
 

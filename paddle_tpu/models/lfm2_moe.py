@@ -356,18 +356,19 @@ class Lfm2MoeForCausalLM(nn.Layer):
         return w
 
     def serving_cache_spec(self):
-        """Keys and values a kv-head for the ATTENTION layers alone, and the
-        conv layers' state a slot: the ``conv_L_cache - 1`` inputs before a
-        sequence's next position, ``[L - 1, hidden]`` a layer."""
+        """Keys and values a kv-head for the ATTENTION layers alone, heads of
+        64 two to a lane tile (``lane_packing``: a block ``[KV / 2, bs, 128]``,
+        which both paged kernels take as it lies), and the conv layers' state
+        a slot: the ``conv_L_cache - 1`` inputs before a sequence's next
+        position, ``[L - 1, hidden]`` a layer."""
         from ..inference.serving_model import CacheSpec
+        from ..ops.paged_attention import lane_packing
 
         cfg = self.config
         KV, D = cfg.num_key_value_heads, cfg.head_dim
         state = (("conv", len(cfg.layers_of("conv")),
                   (cfg.conv_L_cache - 1, cfg.hidden_size)),)
-
-        def block(bs):
-            return (KV, bs, D)
+        _, block = lane_packing(KV, D)
 
         return CacheSpec(
             arrays=(("k", block), ("v", block)), layers=len(cfg.layers_of("full_attention")),
@@ -400,7 +401,8 @@ class Lfm2MoeForCausalLM(nn.Layer):
         layer's products (``held_experts``), and ONE attention layer's
         ``attn_positions_*`` / ``kv_write_*`` as models/llama.py's trunk."""
         from ..ops.paged_attention import (attention_positions, blha_attention,
-                                           cache_write_counts)
+                                           cache_write_counts, decodes_in_kernel,
+                                           writes_in_kernel)
 
         cfg = self.config
         H, KV, D, eps = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
@@ -453,13 +455,21 @@ class Lfm2MoeForCausalLM(nn.Layer):
                 hidden = hidden + ffn
             with jax.named_scope("norm"):
                 hidden = _rms(hidden, weights["norm"], eps)
-            # heads of 64 are half a lane tile: ``decodes_in_kernel`` and
-            # ``writes_in_kernel`` admit neither, so both are the XLA paths
-            live, read, _ = attention_positions(dec, now, block_size=block_size,
-                                                blocks_per_seq=bt.shape[1])
-            written, _ = cache_write_counts(dec, now, cu)
+            # what ONE attention layer attended, read and wrote, as
+            # models/llama.py's trunk counts it; the kernels are asked with
+            # the sizes of the pool's rows (heads of 64 two to a lane tile)
+            _, kv_rows, _, lanes = key_caches[0].shape
+            sizes = dict(head_dim=lanes, block_size=block_size, rows=B,
+                         blocks_per_seq=bt.shape[1])
+            live, read, in_kernel = attention_positions(
+                dec, now, block_size=block_size, blocks_per_seq=bt.shape[1],
+                kernel=decodes_in_kernel(hidden.dtype, key_caches[0].dtype, **sizes))
+            written, pieces = cache_write_counts(
+                dec, now, cu, kernel=writes_in_kernel(
+                    key_caches[0].dtype, tokens=T, kv_heads=kv_rows, **sizes))
             counts.update(attn_positions_live=live, attn_positions_read=read,
-                          kv_write_tokens=written)
+                          attn_rows_kernel=in_kernel, kv_write_tokens=written,
+                          kv_write_blocks=pieces)
             return hidden, (key_caches, value_caches, conv_state), [], counts
 
         return trunk

@@ -8,14 +8,28 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   a per-sequence ``block_tables [B, blocks_per_seq]`` maps logical positions
   to pool blocks — admission/eviction is host-side free-list bookkeeping, so
   sequences of different lengths share one compiled program.
+- Heads narrower than a lane tile lie side by side in one: a model whose
+  cache spec takes ``lane_packing``'s block keeps ``[num_blocks, KV / pack,
+  bs, D * pack]`` (heads of 64: two a row of 128 lanes), and the call reads
+  the form off the pool it is handed. The packed buffer's keys ``[T, KV, D]``
+  are already such rows ``[T, KV / pack, D * pack]``, and ``pack`` KV heads are
+  ONE head of ``D * pack`` to a group of ``pack`` times the query heads whose
+  queries are zero outside their own head's lanes: exact zeros in the scores,
+  and each query head's own values in its own lanes of the result. So the
+  write, both kernels and the XLA pass below all see a pool of whole lane
+  tiles and none knows of packing. (A row of 64 lanes is half a tile: the TPU
+  compiler gave such a pool a layout of its own and copied each array in and
+  out of every program, and neither kernel admits it; PERF.md section 6, PR
+  41.) An int8 pool is not packed: its scales are a head's.
 - One step = (this step's K/V into the pool) + (attention blocked over the
   context). Nothing grows with ``B x max_q_len x blocks_per_seq x bs``: the
   cost follows what the batch holds, not the table's shape.
   Which rows take which path:
   * rows that feed ONE token (decode rows, a prompt's one-token tail), where
     the call is one ``decodes_in_kernel`` admits (the TPU, an unquantised
-    bfloat16 pool and queries of its type, no mask or pre-cache, heads of
-    whole lane tiles, blocks of whole sublane tiles): the Pallas kernel
+    bfloat16 pool and queries of its type, no mask or pre-cache, a pool whose
+    rows are whole lane tiles (heads of 128, or of 64 two to a row), blocks
+    of whole sublane tiles): the Pallas kernel
     ``ops/pallas/paged_decode.py``. It takes the pools where they lie in
     HBM, brings a row's own context blocks to VMEM by the block table (one
     asynchronous copy a block: the layout below makes a block's KV heads one
@@ -47,8 +61,8 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   for that and the rows the kernel took; ``ServingEngine`` adds them up
   (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``).
 - The write, and why ONE layout still stands. Where ``writes_in_kernel``
-  admits the call (the TPU, an unquantised bfloat16 pool, heads of whole lane
-  tiles, blocks of whole 16-slot pieces; masks and pre-caches do not matter)
+  admits the call (the TPU, an unquantised bfloat16 pool whose rows are whole
+  lane tiles, blocks of whole 16-slot pieces; masks and pre-caches do not matter)
   the keys and values go in through the Pallas kernel
   ``ops/pallas/paged_write.py``: it takes both pools where they lie and
   returns them (aliased, in place), lists the PIECES (16 consecutive
@@ -105,7 +119,7 @@ from .pallas.paged_decode import paged_decode
 from .pallas.paged_write import PIECE, paged_write
 
 __all__ = ["blha_attention", "attention_positions", "decodes_in_kernel",
-           "cache_write_counts", "writes_in_kernel",
+           "cache_write_counts", "writes_in_kernel", "lane_packing",
            "build_padding_metadata", "rope_rotate"]
 
 _CTX_BLOCK = 512    # cache positions a pass over the context reads
@@ -158,6 +172,26 @@ def _trips(positions, per_pass: int):
     return (positions + per_pass - 1) // per_pass
 
 
+def lane_packing(kv_heads: int, head_dim: int):
+    """How a per-head K/V pool lays heads narrower than a lane tile, decided
+    from the two sizes alone: ``(pack, block)``, ``pack`` the KV heads that
+    share one 128-lane tile (``128 // head_dim`` where that is whole and
+    divides ``kv_heads``, else 1) and ``block(block_size)`` the shape of a
+    pool block that follows, ``(KV // pack, block_size, D * pack)``: head
+    ``pack * j + p`` of a position lies in lanes ``[p * D, (p + 1) * D)`` of
+    row ``j``.  At ``pack`` 1 that is the pool as it always was."""
+    pack = 128 // head_dim if 0 < head_dim < 128 and 128 % head_dim == 0 else 1
+    pack = pack if kv_heads % pack == 0 else 1
+    return pack, lambda block_size: (kv_heads // pack, block_size, head_dim * pack)
+
+
+def _own_lanes(group: int, pack: int, heads: int):
+    """[H, pack] bool: which of the ``pack`` places of a lane tile is query
+    head h's own, that of its KV head ``h // group``."""
+    place = (jnp.arange(heads, dtype=jnp.int32) // group) % pack
+    return place[:, None] == jnp.arange(pack, dtype=jnp.int32)
+
+
 def _one_token_tiles(dec, now):
     """The rows that feed ONE token, ordered by context length and cut into
     tiles of ``_ROW_TILE`` rows, so that a short row rides with short rows.
@@ -180,9 +214,10 @@ def decodes_in_kernel(q_dtype, cache_dtype, *, head_dim: int, block_size: int,
     nothing else: the platform is the TPU; the cache is an unquantised
     bfloat16 pool (the 16-bit float Mosaic takes: it refuses float16) and the
     queries are of its type; ``plain``: no mask, ``tgt_mask`` or pre-cache;
-    ``head_dim`` is whole 128-lane tiles and ``block_size`` whole sublane
-    tiles; the block table fits the kernel's scalar memory. Anything else
-    takes the blocked XLA pass."""
+    ``head_dim``, the width of the POOL's rows (``lane_packing``: a head's,
+    or two heads of 64 side by side), is whole 128-lane tiles and
+    ``block_size`` whole sublane tiles; the block table fits the kernel's
+    scalar memory. Anything else takes the blocked XLA pass."""
     return (on_tpu() and plain
             and jnp.dtype(cache_dtype) == jnp.bfloat16
             and jnp.dtype(q_dtype) == jnp.bfloat16
@@ -195,9 +230,10 @@ def writes_in_kernel(cache_dtype, *, head_dim: int, block_size: int, rows: int,
     """Whether a call's keys and values go into the pool through the Pallas
     kernel (``ops/pallas/paged_write.py``), decided as ``decodes_in_kernel``
     decides: the platform is the TPU; the cache is an unquantised bfloat16
-    pool; ``head_dim`` is whole 128-lane tiles and ``block_size`` whole
-    sublane tiles; the block table fits the kernel's scalar memory and the
-    packed buffer's ``tokens`` x ``kv_heads`` new keys and values its VMEM.
+    pool; ``head_dim`` (of the pool's rows, ``kv_heads`` of them a position)
+    is whole 128-lane tiles and ``block_size`` whole sublane tiles; the block
+    table fits the kernel's scalar memory and the packed buffer's ``tokens``
+    x ``kv_heads`` new keys and values its VMEM.
     Masks and pre-caches are the attention's and do not matter here. Anything
     else (the CPU, a float32 pool, the int8 caches) takes the scatter."""
     return (on_tpu() and jnp.dtype(cache_dtype) == jnp.bfloat16
@@ -272,11 +308,13 @@ def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
 
 
 def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
-                       block_tables, *, max_q_len: int, quant: bool,
+                       block_tables, *, max_q_len: int, scale: float, quant: bool,
                        k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask):
     """Steps 6-8 of ``blha_attention``: q [T, H, D] and this step's k, v
     [T, KV, D] against the pool, which already holds them. Returns
-    [T, H, D] float32, zeros for tokens of no live row.
+    [T, H, D] float32, zeros for tokens of no live row.  (Over a pool that
+    packs heads into a lane tile, ``D`` and ``KV`` are the POOL's: the caller
+    hands the packed problem, and ``scale`` is the heads' own.)
 
     A row attends, in this order and through one online softmax: its
     pre-cache, the cache positions [0, dec) a context block at a time, and
@@ -287,7 +325,6 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
     nb, _, bs, _ = key_cache.shape
     B, P = block_tables.shape
     S = int(max_q_len)
-    scale = 1.0 / (D ** 0.5)
     per, Lc = _context_block(bs, P)
     bt = jnp.pad(block_tables, ((0, 0), (0, (-P) % per)), constant_values=-1)
     bt = jnp.where((bt < 0) | (bt >= nb), nb, bt)                  # -> nothing
@@ -486,7 +523,7 @@ def build_padding_metadata(seq_lens_this_time):
 @jax.named_scope("paged_attention")
 def blha_attention(
     qkv,                       # [T, (H+2*KV)*D] float/bf16 (or int32 w/ qkv_out_scale)
-    key_cache,                 # [NB, KV, bs, D] (uint8 when cache_quant)
+    key_cache,                 # [NB, KV, bs, D] (uint8 when cache_quant) | [NB, KV/pack, bs, D*pack]
     value_cache,
     seq_lens_encoder,          # [B] int32: >0 while the seq is in prefill
     seq_lens_decoder,          # [B] int32: tokens already in cache
@@ -549,6 +586,15 @@ def blha_attention(
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]
     B = block_tables.shape[0]
+    # the pool says how it lays its heads: ``pack`` of them a lane tile where
+    # its rows are as wide as ``lane_packing`` makes them, else one
+    pack, _ = lane_packing(KV, D)
+    if key_cache.shape[-1] != D * pack:
+        pack = 1
+    elif pack > 1 and cache_quant != "none":
+        raise ValueError(
+            f"a pool that packs {pack} heads of {D} into a lane tile is not quantised: its "
+            f"scales are a head's, and a row of {tuple(key_cache.shape[-3:])} holds {pack}")
     stacked = None
     if layer is not None:
         if key_cache.ndim != 5:
@@ -625,8 +671,8 @@ def blha_attention(
 
         # ---- 5. K/V into the block pool -------------------------------------
         by_row = cache_quant == "none" and writes_in_kernel(
-            key_cache.dtype, head_dim=D, block_size=bs, rows=B,
-            blocks_per_seq=block_tables.shape[1], tokens=T, kv_heads=KV)
+            key_cache.dtype, head_dim=D * pack, block_size=bs, rows=B,
+            blocks_per_seq=block_tables.shape[1], tokens=T, kv_heads=KV // pack)
         if not by_row:      # the scatter's coordinates, where the parent had them
             nb = key_cache.shape[0]
             blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
@@ -644,8 +690,10 @@ def blha_attention(
             v_store = _quantize_u8(v, vsc, round_ties_away, quant_max_bound,
                                    quant_min_bound)
         else:
-            k_store = k.astype(key_cache.dtype)
-            v_store = v.astype(value_cache.dtype)
+            # heads that share a lane tile lie side by side in the packed
+            # buffer already: the pool's rows are a view of it
+            k_store = k.astype(key_cache.dtype).reshape(T, KV // pack, D * pack)
+            v_store = v.astype(value_cache.dtype).reshape(T, KV // pack, D * pack)
         if by_row:
             # row by row into the pieces of the blocks a row holds, in place:
             # what is moved follows what is live (module docstring)
@@ -657,7 +705,7 @@ def blha_attention(
             # head's D values an update: the layout the gather reads, so the
             # pool keeps one layout; a dead token's window is walked, sent
             # to block nb and dropped
-            hd = jnp.arange(KV, dtype=jnp.int32)[None, :]
+            hd = jnp.arange(KV // pack, dtype=jnp.int32)[None, :]
             key_cache = key_cache.at[blk[:, None], hd, slot[:, None]].set(
                 k_store, mode="drop")
             value_cache = value_cache.at[blk[:, None], hd, slot[:, None]].set(
@@ -671,13 +719,31 @@ def blha_attention(
     # cache would return; so the cache is read for positions [0, dec) only
     quant = cache_quant != "none"
     fresh_dt = q.dtype if quant else key_cache.dtype
+    k, v = k.astype(fresh_dt), v.astype(fresh_dt)
+    if pack > 1:
+        # ``pack`` KV heads of D are ONE of D * pack to everything below, with
+        # a group of pack * g query heads whose queries are zero outside their
+        # own head's lanes: the zeros add exact zeros to the scores, and
+        # ``p . v`` gives a query head its own head's values in its own lanes
+        # and a finite product, dropped here, in the others
+        own = _own_lanes(H // KV, pack, H)[:, :, None]             # [H, pack, 1]
+        q = jnp.where(own, q[:, :, None, :], 0).reshape(T, H, D * pack)
+        k, v = (x.reshape(T, KV // pack, D * pack) for x in (k, v))
+        if pre_key_cache is not None:
+            pre_key_cache, pre_value_cache = (
+                jnp.swapaxes(x.reshape(B, KV // pack, pack, -1, D), 2, 3).reshape(
+                    B, KV // pack, -1, D * pack)
+                for x in (pre_key_cache, pre_value_cache))
     out = _blocked_attention(
-        q, k.astype(fresh_dt), v.astype(fresh_dt), key_cache, value_cache,
+        q, k, v, key_cache, value_cache,
         seq_lens_encoder, seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
-        block_tables, max_q_len=max_q_len, quant=quant,
+        block_tables, max_q_len=max_q_len, scale=1.0 / (D ** 0.5), quant=quant,
         k_dequant=cache_k_dequant_scales, v_dequant=cache_v_dequant_scales,
         pre_k=pre_key_cache, pre_v=pre_value_cache, mask=mask,
-        tgt_mask=tgt_mask).reshape(T, H * D)
+        tgt_mask=tgt_mask)
+    if pack > 1:
+        out = jnp.sum(jnp.where(own, out.reshape(T, H, pack, D), 0), axis=2)
+    out = out.reshape(T, H * D)
     # smooth-quant epilogue: (x + shift) * smooth — the reference kernel's
     # order (shift first, then the per-channel smoothing scale)
     if out_shift is not None:

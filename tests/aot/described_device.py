@@ -148,11 +148,13 @@ def live_bytes(mem):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
 
 
-def fits_as_the_file_says(cfg, kind, compiled, margin):
+def fits_as_the_file_says(cfg, kind, compiled, margin, live_now=None):
     """The tail every family's cases share: the program leaves ``margin``
     bytes of the chip free, and its arguments and live bytes are the
-    configuration file's ``memory.compiled_for_v5e``. Returns (mem, live,
-    the file's figures)."""
+    configuration file's ``memory.compiled_for_v5e``; ``live_now`` where a PR
+    that could not edit the file took bytes out of the program: the live
+    bytes it compiles to since, no more than the file still says. Returns
+    (mem, live, the file's figures)."""
     mem = compiled.memory_analysis()
     live = live_bytes(mem)
     assert live < V5E_BYTES_LIMIT - margin, live
@@ -163,5 +165,6 @@ def fits_as_the_file_says(cfg, kind, compiled, margin):
     # ISSUE 35 they are ONE block, 11-20 KB less of 11-15 GB (the files are the
     # benchmark's, which that PR could not edit)
     assert 0 <= said["arguments"] - mem.argument_size_in_bytes < 32 * 1024
-    assert abs(said["live"] / live - 1) < 0.01, (said["live"], live)
+    want = said["live"] if live_now is None else live_now
+    assert want <= said["live"] and abs(want / live - 1) < 0.01, (said["live"], want, live)
     return mem, live, said

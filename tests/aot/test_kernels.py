@@ -92,8 +92,15 @@ def test_int8_matmul(chip, m, monkeypatch):
                    ((m, 4096), BF16), ((4096, 11008), jnp.int8), ((11008,), jnp.float32))
 
 
-@pytest.mark.parametrize("mq", [1, 64, 256], ids=["decode", "chunk64", "prefill256"])
-def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch):
+# ((B, P, bs, H, KV, D, nb) of mistral-7b-v0.3.serve1 and of lfm2-24b-a2b.serve1, T, mq)
+_SERVE1 = (32, 40, 64, 32, 8, 128, 1024)
+_PAGED_CALLS = {"decode": (_SERVE1, 32, 1), "chunk64": (_SERVE1, 256, 64),
+                "prefill256": (_SERVE1, 256, 256),
+                "heads64-chunk64": ((128, 44, 64, 32, 8, 64, 2048), 512, 64)}
+
+
+@pytest.mark.parametrize("call", _PAGED_CALLS)
+def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, call, monkeypatch):
     """The dense paged attention at the benchmark's serving geometry
     (mistral-7b-v0.3.serve1: 32 rows, tables of 40 blocks of 64, 8 kv heads
     of 128, a pool of 1024 blocks), two iterations in a scan with the pool
@@ -106,13 +113,18 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
     ISSUE 27: keep a second copy of the pool in another layout (the write,
     the gather and the kernel's operand must agree on one, or 268 MB a layer
     are copied in and out), or build anything as large as every row's whole
-    table (168 MB in bf16)."""
+    table (168 MB in bf16).  ``heads64``: lfm2-24b-a2b.serve1's attention
+    layer (128 rows, tables of 44, 8 kv heads of 64, 2,048 blocks) over the
+    pool ``lane_packing`` gives it, two heads a lane tile (ISSUE 41): the same
+    two kernels, one layout, no copy; a head a row of 64 lanes, the compiler
+    kept that pool in two layouts and copied each array in and out."""
     from paddle_tpu.ops import paged_attention as pa
 
     on_the_chip(monkeypatch)
     blha_attention = pa.blha_attention.__wrapped__     # no trace made for the CPU
-    B, P, bs, H, KV, D, nb = 32, 40, 64, 32, 8, 128, 1024
-    T = B if mq == 1 else 256
+    (B, P, bs, H, KV, D, nb), T, mq = _PAGED_CALLS[call]
+    shape = (nb,) + pa.lane_packing(KV, D)[1](bs)
+    assert shape[-1] == 128
 
     def two_iterations(qkv, kc, vc, dec, now, cu, bt, rope):
         def body(carry, _):
@@ -124,7 +136,7 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
             return (out[1], out[2]), out[0]
         return jax.lax.scan(body, (kc, vc), None, length=2)
 
-    pool, i32 = ((nb, KV, bs, D), BF16), jnp.int32
+    pool, i32 = (shape, BF16), jnp.int32
     compiled = compile_kernel(
         two_iterations, chip, ((T, (H + 2 * KV) * D), BF16), pool, pool, ((B,), i32),
         ((B,), i32), ((B + 1,), i32), ((B, P), i32),
@@ -132,18 +144,20 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, mq, monkeypatch
         donate=(1, 2))
     text = compiled.as_text()
     assert "kv_write/scatter" not in text
-    pool_bytes = nb * KV * bs * D * 2
+    pool_bytes = math.prod(shape) * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"
     # one layout of the pool, the argument's row-major order, on the
     # parameter, the write, the kernel's operand and the gather alike
-    orders = set(re.findall(r"bf16\[1024,8,64,128\]\{([0-9,]+)", text))
+    dims = ",".join(map(str, shape))
+    orders = set(re.findall(rf"bf16\[{dims}\]\{{([0-9,]+)", text))
     assert orders == {"3,2,1,0"}, orders
-    assert not re.search(r"= bf16\[1024,8,64,128\][^\n]* copy\(", text)
-    whole = B * KV * P * bs * D
-    views_of_the_pool = {(nb, KV, bs, D), (nb * KV, bs, D), (nb * KV * bs, D)}
-    shapes = {tuple(int(d) for d in dims.split(","))
-              for dims in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text)}
+    assert not re.search(rf"= bf16\[{dims}\][^\n]* copy\(", text)
+    whole = B * P * math.prod(shape[1:])
+    views_of_the_pool = {shape, (shape[0] * shape[1],) + shape[2:],
+                         (shape[0] * shape[1] * shape[2], shape[3])}
+    shapes = {tuple(int(d) for d in made.split(","))
+              for made in re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text)}
     big = [s for s in shapes - views_of_the_pool if math.prod(s) >= whole]
     assert not big, big
 

@@ -45,6 +45,9 @@ TINY = dict(
     routed_scaling_factor=1, max_position_embeddings=256, norm_eps=1e-5,
     rope_parameters={"rope_theta": 10000.0, "rope_type": "default"}, model_type="lfm2_moe",
     torch_dtype="float32")
+# the same layers with heads of 64 (4 heads, 2 KV heads): ``lane_packing`` lays
+# the two KV heads side by side, a pool block ``[1, bs, 128]``
+PAIRED = dict(TINY, hidden_size=256)
 ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
 
 # A float32 engine and the float32 reference differ by the order of their
@@ -77,6 +80,13 @@ def built():
     return _build()
 
 
+@pytest.fixture(scope="module", params=["a_head_a_row", "paired"])
+def either_pool(request, built):
+    """(cfg, model, weights) of TINY, whose heads of 16 lie a head a row of
+    the pool, and of PAIRED, whose heads of 64 lie two to a lane tile."""
+    return (TINY, *built) if request.param == "a_head_a_row" else (PAIRED, *_build(PAIRED))
+
+
 def _prompts(lens, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(1, TINY["vocab_size"], n).tolist() for n in lens]
@@ -101,9 +111,9 @@ def _serve(model, prompts, new=12, **engine):
     return eng, [(out[r], np.asarray(lps[r])) for r in rids]
 
 
-def _held_to_reference(weights, prompts, served, tol=LOGPROB_TOL):
+def _held_to_reference(weights, prompts, served, tol=LOGPROB_TOL, cfg=TINY):
     for p, (new, lps) in zip(prompts, served):
-        _, want = _ref_logprobs(weights, TINY, p, new)
+        _, want = _ref_logprobs(weights, cfg, p, new)
         assert np.abs(want - lps).max() < tol, (len(p), np.abs(want - lps).max())
 
 
@@ -228,16 +238,17 @@ def test_short_conv_on_a_packed_buffer_of_mixed_rows():
 
 
 # ------------------------------------------------------ through the engine
-def test_prefill_in_chunks_then_decode_against_the_reference(built):
+def test_prefill_in_chunks_then_decode_against_the_reference(either_pool):
     """Five prompts on four slots: the 33-token prompt crosses the 32-token
     budget (two steps) and the mixed scan feeds the others in chunks of 8
     beside decoding rows; the fifth request takes a used slot."""
-    model, weights = built
+    cfg, model, weights = either_pool
     prompts = _prompts([20, 9, 33, 5, 17])
     eng, served = _serve(model, prompts)
-    _held_to_reference(weights, prompts, served)
+    _held_to_reference(weights, prompts, served, cfg=cfg)
     assert eng.megasteps > eng.megasteps_mixed >= 1 and eng.prefill_chunks > 5
-    assert eng.slot_state[0].shape == (5, 4, 2, 64) and len(eng.caches[0]) == 1
+    assert eng.slot_state[0].shape == (5, 4, 2, cfg["hidden_size"]) and len(eng.caches[0]) == 1
+    assert eng.caches[0][0].shape[1:] == ((1, 8, 128) if cfg is PAIRED else (2, 8, 16))
     assert eng.state_summary()["slot_state"] == {"arrays": ["conv"],
                                                  "rows_fed": eng.conv_rows_fed}
     assert eng.state_summary()["prefix_cache"]["enabled"] is False      # "auto" -> off
@@ -262,15 +273,15 @@ def test_bf16_arithmetic_fails_the_float32_tolerance(built):
     assert np.abs(want - lps).max() > 10 * LOGPROB_TOL
 
 
-def test_a_slot_reused_by_a_new_request_gives_a_fresh_engines_logits(built):
+def test_a_slot_reused_by_a_new_request_gives_a_fresh_engines_logits(either_pool):
     """One slot: every request but the first is admitted into a slot whose
     conv state is the last tenant's.  Nothing resets it; a tap under position
     0 reads zero by position."""
-    model, weights = built
+    cfg, model, weights = either_pool
     prompts = _prompts([13, 1, 21, 2], seed=2)
     eng, served = _serve(model, prompts, new=9, max_batch_size=1)
     assert np.abs(np.asarray(eng.slot_state[0])).max() > 0
-    _held_to_reference(weights, prompts, served)
+    _held_to_reference(weights, prompts, served, cfg=cfg)
     for p, (new, lps) in zip(prompts[1:], served[1:]):
         fresh_new, fresh_lps = _serve(model, [p], new=9, max_batch_size=1)[1][0]
         assert fresh_new == new and np.abs(fresh_lps - lps).max() < 1e-6
@@ -461,7 +472,7 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     harvests = test_ouro._harvests(eng)
     names = ("conv_rows_fed", "moe_tokens", "moe_local_picks", "experts_touched",
              "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped",
-             "attn_positions_live", "kv_write_tokens")
+             "attn_positions_live", "kv_write_tokens", "attn_rows_kernel", "kv_write_blocks")
     assert all(getattr(eng, n) == 0 for n in names)
     for p in _prompts([20, 9]):
         eng.add_request(p, max_new_tokens=6)
@@ -473,6 +484,7 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
         last = now
     fed = 20 + 9 + 5 + 5                     # prompt tokens and the tokens fed back
     assert eng.kv_write_tokens == fed and eng.moe_tokens == 4 * fed     # 4 expert layers
+    assert eng.attn_rows_kernel == 0 == eng.kv_write_blocks     # the CPU: XLA and the scatter
     assert eng.moe_local_picks == eng.expert_tile_rows_live == 2 * eng.moe_tokens
     assert eng.expert_tile_rows >= eng.expert_tile_rows_live
     assert 0 < eng.experts_touched <= 8 * 4 * eng.launches * eng.megastep_k
@@ -489,6 +501,49 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     assert seen and all(set(names) <= set(a) for a in seen)
     for n in names:
         assert sum(a[n] for a in seen) == getattr(eng, n), n
+
+
+def test_an_engine_steered_onto_the_chip_sends_heads_of_64_through_both_kernels(monkeypatch):
+    """A bf16 PAIRED model over blocks of 16 is a call both kernels admit once
+    its heads lie two to a lane tile.  With ``on_tpu`` answering yes (the
+    kernels in interpret mode) every one-token row of every scan iteration
+    attends through ``paged_decode`` and every write is ``paged_write``'s:
+    the trunk counts them as models/llama.py's does, the tokens are the
+    unsteered engine's."""
+    from paddle_tpu.inference import serving
+    from paddle_tpu.ops import paged_attention as pa
+
+    import test_paged_attention
+
+    model, _ = _build(dict(PAIRED, torch_dtype="bfloat16"))
+    prompts = _prompts([5, 22], seed=3)
+
+    def run():
+        eng = ServingEngine(model, **dict(ENGINE, block_size=16, max_batch_size=2))
+        harvests = test_ouro._harvests(eng)
+        rids = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+        out = eng.run()
+        return eng, [h[-1] for h in harvests], [out[r] for r in rids]
+
+    plain, _, want = run()
+    assert plain.attn_rows_kernel == 0 == plain.kv_write_blocks
+    monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
+    test_paged_attention._steer_onto_the_chip(monkeypatch)
+    pa.blha_attention.clear_cache()     # the one jitted function that asks the platform
+    try:
+        eng, seen, got = run()
+    finally:
+        pa.blha_attention.clear_cache()
+    assert got == want
+    # both prompts in one prefill step (chunk rows: the XLA pass), then each
+    # row decodes its other 8 tokens a row a scan iteration
+    assert eng.attn_rows_kernel == 2 * 8 == sum(a["attn_rows_kernel"] for a in seen)
+    assert eng.attn_positions_live == plain.attn_positions_live
+    assert eng.attn_positions_read < plain.attn_positions_read
+    # the scatter's tokens; the prompts lie in one piece of 16 positions and
+    # in two, a token fed back in one
+    assert eng.kv_write_tokens == plain.kv_write_tokens == 27 + 16
+    assert eng.kv_write_blocks == 1 + 2 + 16 == sum(a["kv_write_blocks"] for a in seen)
 
 
 def test_a_model_without_state_a_slot_counts_none_and_has_none():

@@ -503,10 +503,27 @@ LAYOUTS = {
 }
 
 
-def _case(layout, group, dtype, quant, holes, pre, masks, seed=0):
+def _paired(pool, pack=2):
+    """A pool ``[nb, KV, bs, D]`` with ``pack`` KV heads side by side in a row
+    of lanes, ``[nb, KV / pack, bs, D * pack]`` (``pa.lane_packing``)."""
+    nb, KV, bs, D = pool.shape
+    return pool.reshape(nb, KV // pack, pack, bs, D).swapaxes(2, 3).reshape(
+        nb, KV // pack, bs, D * pack)
+
+
+def _a_head_a_row(pool, pack=2):
+    """``_paired`` undone."""
+    nb, rows, bs, lanes = pool.shape
+    return pool.reshape(nb, rows, bs, pack, lanes // pack).swapaxes(2, 3).reshape(
+        nb, rows * pack, bs, lanes // pack)
+
+
+def _case(layout, group, dtype, quant, holes, pre, masks, paired=False, seed=0):
+    """``paired``: heads of 64, two to a lane tile of the pool."""
     S, rows = LAYOUTS[layout]
+    D = 64 if paired else G_D
     rng = np.random.RandomState(seed + len(layout))
-    B, H, D, bs, P = len(rows), G_H, G_D, G_BS, G_P
+    B, H, bs, P = len(rows), G_H, G_BS, G_P
     KV = H // group
     dec = np.array([r[0] for r in rows], np.int32)
     now = np.array([r[1] for r in rows], np.int32)
@@ -543,6 +560,8 @@ def _case(layout, group, dtype, quant, holes, pre, masks, seed=0):
         kw["mask"] = rng.uniform(-2, 0, (B, 1, S, CTX + 40)).astype(np.float32)
         kw["tgt_mask"] = rng.uniform(-2, 0, (B, H, 1, G_L - 17)).astype(np.float32)
     cast = (lambda a: jnp.asarray(a, dtype)) if quant == "none" else jnp.asarray
+    if paired:
+        kc, vc = _paired(kc), _paired(vc)
     args = (jnp.asarray(qkv, dtype), cast(kc), cast(vc), jnp.asarray(enc),
             jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu), jnp.asarray(bt))
     kw = {n: (jnp.asarray(a, dtype) if n.startswith("pre_") else jnp.asarray(a))
@@ -551,11 +570,11 @@ def _case(layout, group, dtype, quant, holes, pre, masks, seed=0):
 
 
 def _p(layout, group=4, dtype="float32", quant="none", holes=False, pre=False,
-       masks=False):
+       masks=False, paired=False):
     name = "-".join([layout, f"g{group}", dtype, quant]
-                    + [n for n, on in (("holes", holes), ("pre", pre), ("masks", masks))
-                       if on])
-    return pytest.param(layout, group, dtype, quant, holes, pre, masks, id=name)
+                    + [n for n, on in (("holes", holes), ("pre", pre), ("masks", masks),
+                                       ("paired", paired)) if on])
+    return pytest.param(layout, group, dtype, quant, holes, pre, masks, paired, id=name)
 
 
 GEOMETRY = (
@@ -568,13 +587,21 @@ GEOMETRY = (
        _p("mixed", pre=True, masks=True, group=1),
        _p("decode_edges", pre=True, masks=True, dtype="bfloat16"),
        _p("drafts", masks=True, quant="dynamic"),
-       _p("chunk_edges", pre=True, quant="static", dtype="bfloat16")])
+       _p("chunk_edges", pre=True, quant="static", dtype="bfloat16")]
+    # heads of 64 two to a lane tile: four KV heads in two rows of the pool
+    # (a group of 2 query heads a row), two in one (a group of 4)
+    + [_p(lay, group=g, paired=True) for lay in LAYOUTS for g in (1, 2)]
+    + [_p("mixed", group=2, dtype="bfloat16", paired=True),
+       _p("mixed", group=1, holes=True, paired=True),
+       _p("chunk_edges", group=2, pre=True, paired=True),
+       _p("mixed", group=1, pre=True, masks=True, paired=True),
+       _p("decode_edges", group=2, pre=True, masks=True, dtype="bfloat16", paired=True)])
 
 
-@pytest.mark.parametrize("layout,group,dtype,quant,holes,pre,masks", GEOMETRY)
+@pytest.mark.parametrize("layout,group,dtype,quant,holes,pre,masks,paired", GEOMETRY)
 def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes, pre,
-                                              masks):
-    args, kw, g = _case(layout, group, dtype, quant, holes, pre, masks)
+                                              masks, paired):
+    args, kw, g = _case(layout, group, dtype, quant, holes, pre, masks, paired)
     qkv, _, _, enc, dec, now, cu, bt = args
     outs = pa.blha_attention(
         *args, num_heads=g["H"], kv_num_heads=g["KV"], head_dim=g["D"],
@@ -583,6 +610,9 @@ def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes
     out, kc, vc = outs[0], outs[1], outs[2]
     assert out.dtype == jnp.dtype(dtype) and out.shape == (g["T"], g["H"] * g["D"])
     H, KV, D = g["H"], g["KV"], g["D"]
+    if paired:
+        assert kc.shape == args[1].shape == (args[1].shape[0], KV // 2, g["bs"], 128)
+        kc, vc = _a_head_a_row(kc), _a_head_a_row(vc)
     f = qkv.astype(jnp.float32)
     want = padded_reference(
         f[:, :H * D].reshape(-1, H, D), f[:, H * D:(H + KV) * D].reshape(-1, KV, D),
@@ -627,10 +657,10 @@ KERNEL_LAYOUTS = {
 }
 
 
-def _kernel_case(layout, group, holes, seed=0):
+def _kernel_case(layout, group, holes, seed=0, KV=K_KV, D=K_D):
     S, rows = KERNEL_LAYOUTS[layout]
     rng = np.random.RandomState(seed + len(layout))
-    B, KV, D, bs, P = len(rows), K_KV, K_D, K_BS, K_P
+    B, bs, P = len(rows), K_BS, K_P
     H = KV * group
     dec = np.array([r[0] for r in rows], np.int32)
     now = np.array([r[1] for r in rows], np.int32)
@@ -797,12 +827,14 @@ def _outside_cases():
         pre = jnp.zeros((len(args[4]), g["KV"], 5, g["D"]), jnp.bfloat16)
         return args, g, {"pre_key_cache": pre, "pre_value_cache": pre}
 
-    def narrow_heads():      # head_dim 64: half a lane tile
-        args, kw, g = _case("mixed", 4, "bfloat16", "none", False, False, False)
-        return args, g, {}
+    def odd_narrow_heads():  # three heads of 64: no two share a lane tile
+        return *_kernel_case("mixed", 2, False, KV=3, D=64), {}
+
+    def unpaired_narrow_heads():     # the pool says how it lies: a head a row of 64
+        return *_kernel_case("mixed", 2, False, KV=4, D=64), {}
 
     return [float32_cache, float32_queries, int8_cache, with_mask, encoder_mask,
-            pre_cache, narrow_heads]
+            pre_cache, odd_narrow_heads, unpaired_narrow_heads]
 
 
 # of those, the calls whose WRITE still goes through ``paged_write``: masks,
@@ -814,8 +846,8 @@ _WRITTEN_BY_ROW = {"float32_queries", "with_mask", "encoder_mask", "pre_cache"}
 def test_a_call_outside_the_kernels_conditions_takes_the_xla_pass(monkeypatch, make):
     """Steered onto the chip or not, the attention of such a call is the
     blocked XLA pass, with no ``paged_decode`` in it. Where the pool is not
-    one the write's kernel admits either (float32, int8, half a lane tile)
-    both lower to one and the same text with no custom call at all (at PR 29
+    one the write's kernel admits either (float32, int8, a head a row of
+    half a lane tile) both lower to one and the same text with no custom call at all (at PR 29
     that text was the parent commit's byte for byte, compared by hand); the
     others differ by the ``paged_write`` call alone."""
     args, g, kw = make()
@@ -892,6 +924,11 @@ def test_decodes_in_kernel_asks_the_call_and_the_platform_only(monkeypatch):
                 dict(rows=4096, blocks_per_seq=64)):
         assert not pa.decodes_in_kernel(bf16, bf16, **{
             **dict(head_dim=128, block_size=64, rows=32, blocks_per_seq=40), **odd})
+    # ``head_dim`` is the width of the POOL's rows: 8 heads of 64 lie two to a
+    # lane tile and are asked as 4 of 128; 7 lie one a row of 64, and are refused
+    for kv, lanes in ((8, 128), (7, 64)):
+        _, block = pa.lane_packing(kv, 64)
+        assert block(64)[2] == lanes and ask(bf16, bf16, head_dim=lanes) == (lanes == 128)
 
 
 # ------------------------------------------------- the row-wise cache write
@@ -1045,6 +1082,10 @@ def test_writes_in_kernel_asks_the_pool_and_the_platform_only(monkeypatch):
                 dict(tokens=8192)):
         assert not pa.writes_in_kernel(jnp.bfloat16, **{**usual, **odd})
     assert ask(jnp.bfloat16, tokens=1024)
+    # asked with the pool's rows, as ``decodes_in_kernel`` is: heads of 64 two
+    # to a lane tile are ``kv_heads`` 4 of ``head_dim`` 128, the same values
+    (rows, _, lanes) = pa.lane_packing(8, 64)[1](64)
+    assert (rows, lanes) == (4, 128) and ask(jnp.bfloat16, kv_heads=rows, head_dim=lanes)
 
 
 def test_cache_write_counts_by_hand():
@@ -1066,3 +1107,77 @@ def test_cache_write_counts_by_hand():
     assert count([(0, 0), (5, 0)], True) == (0, 0)
     # the buffer ends inside the last row's run: 3 of its 5 tokens are live
     assert count([(0, 16), (14, 5)], True, total=19) == (19, 1 + 2)
+
+
+# ------------------------------------- heads of 64, two to a lane tile
+# A pool whose block is ``pa.lane_packing``'s holds two KV heads of 64 side by
+# side in a 128-lane row, ``[nb, KV / 2, bs, 128]``, and ``blha_attention``
+# reads the form off the pool it is handed: the packed buffer's keys are
+# already rows of such a pool, and a pair of KV heads is ONE head of 128 to a
+# group of twice the query heads whose queries are zero outside their own
+# half. So on the chip both kernels take it as it lies; everywhere else the
+# scatter and the XLA pass do (``paired`` in GEOMETRY above).
+def test_lane_packing_by_hand():
+    def ask(kv, d, bs=16):
+        pack, block = pa.lane_packing(kv, d)
+        return pack, block(bs)
+
+    assert ask(8, 64) == (2, (4, 16, 128)) and ask(2, 64) == (2, (1, 16, 128))
+    assert ask(8, 32) == (4, (2, 16, 128))
+    # heads of whole lane tiles, an odd head out, a width that fills no tile
+    for kv, d in ((8, 128), (8, 256), (3, 64), (1, 64), (6, 32), (8, 96), (8, 48)):
+        assert ask(kv, d) == (1, (kv, 16, d))
+
+
+@pytest.mark.parametrize("layout,kv,holes", [
+    pytest.param(lay, kv, holes, id=f"{lay}-kv{kv}" + ("-holes" if holes else ""))
+    for lay, kv, holes in (("mixed", 4, False), ("mixed", 2, True), ("decode_edges", 4, True),
+                           ("decode_edges", 2, False), ("chunks_only", 4, False))])
+def test_heads_of_64_take_both_kernels_two_to_a_lane_tile(monkeypatch, layout, kv, holes):
+    """Eight query heads over four (and two) KV heads of 64: the call over a
+    pool a head a row (the scatter, the XLA pass), over the paired pool on
+    the CPU (the same two over rows of 128) and over the paired pool as the
+    chip traces it (``paged_write`` given ``[T, KV / 2, 128]``, ``paged_decode``
+    a group of ``2 g`` queries of 128, in interpret mode). The pools come out
+    byte for byte the first call's, paired; the three outputs are the float32
+    reference's."""
+    H, D = 8, 64
+    args, g = _kernel_case(layout, H // kv, holes, KV=kv, D=D)
+    qkv, kc, vc, enc, dec, now, cu, bt = args
+    kw = dict(num_heads=H, kv_num_heads=kv, head_dim=D, block_size=g["bs"],
+              max_q_len=g["S"], compute_dtype=jnp.bfloat16)
+    a_row = _fresh_call(args, kw)
+    paired = (qkv, _paired(kc), _paired(vc), *args[3:])
+    here = _fresh_call(paired, kw)
+    writes, decodes = _steer_onto_the_chip(monkeypatch), []
+    inner = pa.paged_decode
+    monkeypatch.setattr(pa, "paged_decode", lambda *a, **k: (
+        decodes.append(a[0].shape), inner(*a, **k))[1])
+    there = _fresh_call(paired, kw)
+    assert writes == [(g["T"], kv // 2, 128)]
+    assert decodes == [(len(dec), kv // 2, 2 * (H // kv), 128)]
+    for got in (here, there):
+        for mine, theirs in zip(got[1:3], a_row[1:3]):
+            np.testing.assert_array_equal(_bits(mine), _bits(_paired(theirs)))
+    f = qkv.astype(jnp.float32)
+    want = np.asarray(padded_reference(
+        f[:, :H * D].reshape(-1, H, D), f[:, H * D:(H + kv) * D].reshape(-1, kv, D),
+        f[:, (H + kv) * D:].reshape(-1, kv, D), a_row[1], a_row[2], enc, dec, now, cu, bt,
+        S=g["S"])).reshape(g["T"], H * D)
+    for got in (a_row, here, there):
+        np.testing.assert_allclose(np.asarray(got[0], np.float32), want, rtol=5e-3, atol=5e-3)
+        assert not np.asarray(got[0][g["total"]:], np.float32).any()
+
+
+def test_a_paired_pool_is_not_quantised():
+    """The int8 scales are a KV head's, and a row of a paired pool holds two:
+    such a pool is refused by name (no model declares one: a spec that pairs
+    is not ``quantizable``)."""
+    args, kw, g = _case("mixed", 1, "float32", "static", False, False, False)
+    qkv, kc, vc, *rest = args
+    wide = jnp.zeros((kc.shape[0], g["KV"] // 2, g["bs"], 128), jnp.uint8)
+    qkv = jnp.zeros((g["T"], (g["H"] + 2 * g["KV"]) * 64), jnp.float32)
+    with pytest.raises(ValueError, match="packs 2 heads of 64"):
+        pa.blha_attention(qkv, wide, wide, *rest, num_heads=g["H"], kv_num_heads=g["KV"],
+                          head_dim=64, block_size=g["bs"], max_q_len=g["S"],
+                          cache_quant="static", **kw)

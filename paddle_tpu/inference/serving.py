@@ -801,6 +801,11 @@ class ServingEngine:
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
+        # a latent cache's rows an iteration whose blocked pass ran in the
+        # ``latent_rows`` kernel (ops/latent_attention.py ``rows_taken``):
+        # one-token rows and chunk rows (0 where the XLA loops ran)
+        self.latent_rows_kernel = 0
+        self.latent_chunks_kernel = 0
         self.kv_write_tokens = 0
         self.kv_write_blocks = 0
         # learned sparse attention (ops/sparse_index.py), ONE layer's count an
@@ -1575,6 +1580,12 @@ class ServingEngine:
                 "rows_kernel": self.attn_rows_kernel,
                 "kv_write_tokens": self.kv_write_tokens,
                 "kv_write_blocks": self.kv_write_blocks,
+            },
+            # a latent cache's rows that attended in the ``latent_rows`` kernel
+            # (monotone; zero on the XLA loops and for another cache)
+            "latent_attention": {
+                "rows_kernel": self.latent_rows_kernel,
+                "chunks_kernel": self.latent_chunks_kernel,
             },
             # learned sparse attention (monotone; zero for a model without
             # an indexer): ONE layer's counts, see ``dsa_queries`` above

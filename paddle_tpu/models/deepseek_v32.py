@@ -54,8 +54,8 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn.initializer import Constant
-from ..ops.latent_attention import (latent_attention, rope_half, selection_reads,
-                                    token_coords)
+from ..ops.latent_attention import (latent_attention, rope_half, rows_in_kernel,
+                                    rows_taken, selection_reads, token_coords)
 from ..ops.sparse_index import index_scores, layer_norm, select_topk, sparse_index
 from .pangu_moe import (F32, HIGHEST, LatentMoEGeometry, PanguDecoderLayer, PanguMLAttention,
                         PanguMLP, PanguMTPModule, PanguSparseMoE, PanguUltraMoEForCausalLM,
@@ -379,7 +379,10 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
         ``dsa_positions_selected`` and ``dsa_positions_read`` (the latent
         entries the attention brought for them, by the passes' own
         arithmetic: ``latent_attention.selection_reads``); and
-        ``attn_positions_live``, the context of every row fed."""
+        ``attn_positions_live``, the context of every row fed; and the rows of
+        an iteration whose attention ran in the ``latent_rows`` kernel,
+        ``latent_chunks_kernel`` (``latent_rows_kernel`` stays 0: a one-token
+        row gathers its selection)."""
         cfg = self.config
         eps, C = cfg.rms_norm_eps, cfg.kv_lora_rank
         pad = cfg.latent_cache_width - cfg.latent_width
@@ -396,6 +399,12 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "moe_tokens", "moe_local_picks", "expert_rows_grouped")}
+            kernel = rows_in_kernel(
+                hidden.dtype, lat[0].dtype, heads=cfg.num_attention_heads,
+                width=cfg.latent_cache_width, rank=C, block_size=block_size, rows=B,
+                blocks_per_seq=bt.shape[1])
+            counts["latent_rows_kernel"], counts["latent_chunks_kernel"] = rows_taken(
+                now, kernel=kernel, selected=True)
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_in"], eps)
@@ -418,7 +427,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                 if li == 0:
                     counts.update(dsa, dsa_positions_read=selection_reads(
                         dec, now, topk=cfg.index_topk, gathered=selection.idx.shape[1],
-                        block_size=block_size, blocks_per_seq=bt.shape[1]),
+                        block_size=block_size, blocks_per_seq=bt.shape[1], kernel=kernel,
+                        max_q_len=mq),
                         attn_positions_live=jnp.sum(
                             jnp.where(now > 0, dec + now, 0)).astype(jnp.int32))
                 with jax.named_scope("attn_out"):

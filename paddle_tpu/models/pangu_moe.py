@@ -40,7 +40,8 @@ from .. import nn
 from ..device import on_tpu
 from ..nn.initializer import Constant, Normal
 from ..ops.dispatch import apply
-from ..ops.latent_attention import latent_attention, rope_half
+from ..ops.latent_attention import (latent_attention, rope_half, rows_in_kernel,
+                                    rows_taken)
 from ..ops.pallas.expert_gmm import expert_gmm
 from ..profiler import SetupSpan
 
@@ -672,8 +673,11 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         scales) -> (hidden [T, E] after the final norm, caches, [], counts):
         packed tokens through every layer against the paged latent cache.
         ``counts``: ``moe_tokens`` (tokens through expert layers),
-        ``moe_local_picks`` (picks that fell on a held expert) and
-        ``expert_rows_grouped`` (those that went through the grouped product)."""
+        ``moe_local_picks`` (picks that fell on a held expert),
+        ``expert_rows_grouped`` (those that went through the grouped product),
+        and the rows of an iteration whose attention ran in the
+        ``latent_rows`` kernel, ``latent_rows_kernel`` (one token) and
+        ``latent_chunks_kernel`` (a chunk): 0 where the XLA loops ran."""
         cfg = self.config
         eps, C = cfg.rms_norm_eps, cfg.kv_lora_rank
         scale = cfg.qk_head_dim ** -0.5
@@ -694,6 +698,11 @@ class PanguUltraMoEForCausalLM(nn.Layer):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "moe_tokens", "moe_local_picks", "expert_rows_grouped")}
+            counts["latent_rows_kernel"], counts["latent_chunks_kernel"] = rows_taken(
+                now, selected=False, kernel=rows_in_kernel(
+                    hidden.dtype, lat[0].dtype, heads=cfg.num_attention_heads,
+                    width=cfg.latent_cache_width, rank=C, block_size=block_size,
+                    rows=B, blocks_per_seq=bt.shape[1]))
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_in"], eps)

@@ -12,33 +12,44 @@ caller again).  Per head and cache byte that is 2 FLOPs for every one of the
 H heads, so with 128 heads the decode side sits near the ridge of a v5e
 instead of far below it.
 
-Nothing here grows with ``B x H x max_q_len x L``.  Two passes, both blocked
-over the context with an online softmax (float32 running max, sum and
-accumulator) and a trip count that is DATA, the longest live context:
+Nothing here grows with ``B x H x max_q_len x L``.  A row attends ITS context,
+blocked, through an online softmax (float32 running max, sum and
+accumulator), in one of two forms that ``rows_in_kernel`` chooses from what
+the call shows (the TPU, a bf16 pool and queries, whole tiles):
 
-* rows that feed ONE token (decode rows, and a prompt's one-token tail): all
+* the Pallas kernel ``latent_rows`` (ops/pallas/latent_rows.py, scope
+  ``rows_kernel``), ONE call for both kinds of row: a row's ``now x H``
+  queries in tiles of 16 tokens, a one-token row one tile of ``H`` query rows;
+  each brings its own row's blocks by the table straight into VMEM, only those
+  that hold a position it may see, and the scores never leave VMEM.  A row at
+  rest costs nothing and no row waits for a longer neighbour;
+* elsewhere (the CPU, a float32 pool) two XLA loops whose trip count is DATA:
+  rows that feed ONE token (decode rows, and a prompt's one-token tail) all
   ``B`` at once, ``[B, H, C + R]`` queries against a ``[B, ctx_block, C + R]``
-  gather a pass;
-* rows that feed a CHUNK (``now > 1``: prompt chunks, speculative drafts):
-  one row at a time in a loop over the rows that carry one, its
-  ``[max_q_len x H, C + R]`` queries against ``[ctx_block, C + R]`` of its own
-  context, causal inside the chunk.  Rows without a chunk cost nothing.
+  gather a pass, for as many passes as the LONGEST of them needs; rows that
+  feed a CHUNK (``now > 1``: prompt chunks, speculative drafts) one at a time
+  in a loop over the rows that carry one, ``[max_q_len x H, C + R]`` queries
+  against ``[ctx_block, C + R]`` of the row's own context, causal inside the
+  chunk.
 
 ``selection`` (a ``Selection``, made by ops/sparse_index.py) makes the
 visible set a query's OWN, in one form for each kind of row, the faster on a
 v5e (PERF.md section 6, PR 32).  A row's ONE token attends the K positions
 ``idx`` names (those with ``ok``): ONE gather of the row's K entries through
-the table and a plain softmax over them in place of the blocked pass, so what
-it reads is K a row whatever the context (24 rows at 4-16k, K = 2,048: 1.8 ms
-against 3.0 for the blocked pass under a mask).  A chunk row keeps its blocked
-pass and attends ``position <= its own`` AND ``mask`` (row t: the packed token
-t's): it still brings every live entry, what is not selected is masked after
-the score, and costs what the dense pass costs (gathered it loses by three).
-``selection_reads`` counts what the two bring.
+the table and a plain softmax over them in place of the blocked pass, in
+either form, so what it reads is K a row whatever the context (24 rows at
+4-16k, K = 2,048: 1.8 ms against 3.0 for the blocked pass under a mask).  A
+chunk row keeps its blocked pass, kernel or loop, and attends ``position <=
+its own`` AND ``mask`` (row t: the packed token t's): it still brings every
+live entry, what is not selected is masked after the score, and costs what
+the dense pass costs (gathered it loses by three).  ``selection_reads``
+counts what the two bring, ``rows_taken`` the rows the kernel attended.
 
-Scopes (children of ``latent_attention``): ``kv_write``, ``kv_gather``,
-``scores`` (QK^T, mask, the online softmax's bookkeeping), ``values`` (PV),
-and ``select_gather`` (the selected entries of the one-token rows)."""
+Scopes (children of ``latent_attention``): ``kv_write``; ``rows_kernel`` (the
+kernel's call) or, in the loops, ``kv_gather``, ``scores`` (QK^T, mask, the
+online softmax's bookkeeping) and ``values`` (PV); and ``select_gather`` (the
+selected entries of the one-token rows, with its own ``scores`` and
+``values``)."""
 from __future__ import annotations
 
 from typing import NamedTuple, Optional
@@ -46,10 +57,13 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["latent_attention", "rope_half", "token_coords", "write_entries", "Selection",
-           "selection_reads"]
+from ..device import on_tpu
+from .pallas.latent_rows import _NEG, latent_rows, tile_tokens
 
-_NEG = -1e30
+__all__ = ["latent_attention", "rope_half", "token_coords", "write_entries", "Selection",
+           "selection_reads", "rows_in_kernel", "rows_taken"]
+
+_TABLE_WORDS = 1 << 17   # block-table entries a kernel holds in SMEM (half of it)
 
 
 def rope_half(x, cos, sin):
@@ -123,17 +137,58 @@ def _trips(n, Lc: int):
     return (n + Lc - 1) // Lc
 
 
+def rows_in_kernel(q_dtype, cache_dtype, *, heads: int, width: int, rank: int,
+                   block_size: int, rows: int, blocks_per_seq: int) -> bool:
+    """Whether a call's blocked pass runs in the Pallas kernel
+    (``ops/pallas/latent_rows.py``), decided as
+    ``ops/paged_attention.decodes_in_kernel`` decides, from what the call
+    shows and nothing else: the platform is the TPU; the pool is bfloat16 and
+    the queries are of its type; the pool's ``width`` and the ``rank`` of its
+    values are whole 128-lane tiles, ``block_size`` and the ``heads`` whole
+    sublane tiles; the block table fits the kernel's scalar memory. Anything
+    else (the CPU, a float32 pool) keeps the XLA loops."""
+    return (on_tpu()
+            and jnp.dtype(cache_dtype) == jnp.bfloat16
+            and jnp.dtype(q_dtype) == jnp.bfloat16
+            and width % 128 == 0 and rank % 128 == 0
+            and block_size % 16 == 0 and heads % 16 == 0
+            and rows * blocks_per_seq <= _TABLE_WORDS)
+
+
+def rows_taken(seq_lens_this_time, *, kernel: bool, selected: bool):
+    """The rows of one ``latent_attention`` call that attend in the kernel, as
+    int32 scalars (one-token rows, chunk rows): with ``kernel``
+    (``rows_in_kernel`` of the call) every chunk row, and every one-token row
+    unless the call is ``selected`` (a ``selection`` is given: those rows
+    gather their selected entries instead)."""
+    now = seq_lens_this_time
+    return (jnp.sum((now == 1) & (kernel and not selected)).astype(jnp.int32),
+            jnp.sum((now > 1) & kernel).astype(jnp.int32))
+
+
 def selection_reads(seq_lens_decoder, seq_lens_this_time, *, topk: int, gathered: int,
-                    block_size: int, blocks_per_seq: int, ctx_block: int = 512):
+                    block_size: int, blocks_per_seq: int, ctx_block: int = 512,
+                    kernel: bool = False, max_q_len: int = 1):
     """Latent entries one ``latent_attention(selection=)`` call with these
     lengths brings for the live queries whose context exceeds ``topk``,
     summed a QUERY (int32 scalar; the arithmetic is the passes'): the token of
     a one-token row its row's ``gathered`` (= K) entries; a chunk row's query
     all of its row's trips, ``ceil((dec + now) / Lc) x Lc``, which the row's
-    queries share."""
+    queries share. With ``kernel`` (``rows_in_kernel`` of the call, whose
+    ``max_q_len`` sets the tile) a chunk row's query brings what its TILE of
+    the row's tokens brings: the blocks up to the tile's last token,
+    ``ceil((dec + tile's end) / block_size) x block_size``."""
     dec, now = seq_lens_decoder, seq_lens_this_time
     _, Lc = _tiling(blocks_per_seq, block_size, ctx_block)
     ones = gathered * jnp.sum((now == 1) & (dec + 1 > topk))
+    if kernel:
+        tile = tile_tokens(max_q_len)
+        u = jnp.arange(int(max_q_len), dtype=jnp.int32)[None, :]     # a row's tokens
+        end = jnp.minimum(now[:, None], (u // tile + 1) * tile)
+        counted = (now[:, None] > 1) & (u < now[:, None]) & (dec[:, None] + u >= topk)
+        chunks = jnp.sum(jnp.where(
+            counted, _trips(dec[:, None] + end, block_size) * block_size, 0))
+        return (ones + chunks).astype(jnp.int32)
     sparse = jnp.clip(dec + now - topk, 0, now)         # queries at positions >= topk
     chunks = jnp.sum(jnp.where(now > 1, sparse * _trips(dec + now, Lc) * Lc, 0))
     return (ones + chunks).astype(jnp.int32)
@@ -182,8 +237,21 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
             g = cache.at[ids].get(mode="fill", fill_value=0)
             return g.reshape(ids.shape[:-1] + (Lc, W)).astype(cdt)
 
-    # ---- rows that feed one token: all B at once --------------------------
     one = now == 1
+
+    def _rows_kernel(o1, take):
+        """The rows ``take`` names through the kernel, beside the one-token
+        rows' ``o1`` where another form attended them."""
+        out = jnp.zeros((T, H, C), q.dtype)
+        if o1 is not None:
+            out = out.at[jnp.where(one, jnp.clip(cu[:-1], 0, T - 1), T)].set(o1, mode="drop")
+        with jax.named_scope("rows_kernel"):
+            return latent_rows(q, cache, out, dec, now, cu, block_tables, take,
+                               None if selection is None else selection.mask, rank=C,
+                               scale=float(scale), max_q_len=int(max_q_len),
+                               ctx_block=int(ctx_block))
+
+    # ---- rows that feed one token: all B at once --------------------------
     n_ctx1 = jnp.where(one, dec + 1, 0)
     q1 = q[jnp.clip(cu[:-1], 0, T - 1)].astype(cdt)                 # [B, H, W]
 
@@ -204,6 +272,10 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
         return (jnp.full((B, H), _NEG, jnp.float32), jnp.zeros((B, H), jnp.float32),
                 jnp.zeros((B, H, C), jnp.float32))
 
+    kernel = rows_in_kernel(q.dtype, cdt, heads=H, width=W, rank=C, block_size=bs,
+                            rows=B, blocks_per_seq=P)
+    if selection is None and kernel:
+        return _rows_kernel(None, now > 0), cache
     if selection is None:
         _, l1, acc1 = jax.lax.fori_loop(0, _trips(jnp.max(n_ctx1), Lc), one_block, carry1())
     else:
@@ -220,6 +292,8 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
     if S == 1:
         at = jnp.where(one, jnp.clip(cu[:-1], 0, T - 1), T)
         return jnp.zeros((T, H, C), q.dtype).at[at].set(o1, mode="drop"), cache
+    if kernel:
+        return _rows_kernel(o1, now > 1), cache
 
     # ---- rows that feed a chunk: one at a time -----------------------------
     rows = jnp.nonzero(now > 1, size=B, fill_value=0)[0].astype(jnp.int32)

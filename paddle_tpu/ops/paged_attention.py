@@ -114,7 +114,7 @@ import jax
 import jax.numpy as jnp
 
 from ..device import on_tpu
-from .latent_attention import _NEG, _online
+from .latent_attention import _NEG, _TABLE_WORDS, _online
 from .pallas.paged_decode import paged_decode
 from .pallas.paged_write import PIECE, paged_write
 
@@ -124,7 +124,6 @@ __all__ = ["blha_attention", "attention_positions", "decodes_in_kernel",
 
 _CTX_BLOCK = 512    # cache positions a pass over the context reads
 _ROW_TILE = 8       # one-token rows that share a trip count
-_TABLE_WORDS = 1 << 17   # block-table entries the kernel holds in SMEM (half of it)
 _WRITE_VALUES = 1 << 21  # new keys (and as many values) ``paged_write`` holds whole in VMEM
 
 

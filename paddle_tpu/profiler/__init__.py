@@ -33,7 +33,10 @@ expert layers, ``loop_tokens`` /
 ``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected`` /
 ``dsa_positions_read`` for learned sparse attention: ONE layer's, over the
 live queries whose context exceeds the model's ``index_topk``, beside
-``attn_positions_live``, the context of every row fed);
+``attn_positions_live``, the context of every row fed; ``latent_rows_kernel``
+/ ``latent_chunks_kernel`` for a latent cache: the one-token rows and the
+chunk rows an iteration whose blocked pass ran in the ``latent_rows`` kernel,
+ops/pallas/latent_rows.py; 0 where the XLA loops ran);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Set-up spans, every one a ``SetupSpan``: a ``RecordEvent`` that also leaves a
@@ -79,7 +82,9 @@ programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
 ``loop_pass`` > ``while/body/`` the layers' scopes, ``norm``, ``exit_gate``
 (one pass of a looped trunk, itself the body of the loop over the passes);
 ``latent_proj``, ``latent_attention`` > ``kv_write`` and the three under
-``while/body/`` (a latent cache), ``router``, ``experts`` (on the chip three
+``while/body/`` (a latent cache; on the chip ``rows_kernel``, the
+``latent_rows`` kernel, in place of the three, beside ``select_gather``
+where a selection is given), ``router``, ``experts`` (on the chip three
 ``expert_gmm`` kernels; elsewhere the tile loop, ``experts/while/body/``),
 ``shared_expert`` (expert layers); ``indexer`` > ``index_proj``, ``index_write``,
 ``while/body/`` {``index_gather``, ``index_scores``}, ``index_topk`` (the
@@ -93,7 +98,8 @@ Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
 ``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
 ``int8_matmul``, ``paged_decode``, ``paged_write``, ``expert_gmm`` (three a
-layer under ``experts``, on the chip: gate, up, down).
+layer under ``experts``, on the chip: gate, up, down), ``latent_rows`` (one a
+layer under ``latent_attention/rows_kernel``, on the chip).
 """
 from __future__ import annotations
 

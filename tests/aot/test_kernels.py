@@ -202,3 +202,66 @@ def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
     assert not made, made           # a loop's carry hands the stacks on; nothing makes one
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * pangu_moe._CHUNK_BYTES * 4
     assert expert_gmm.VMEM_LIMIT <= 100 << 20       # of a v5e core's 128 MiB
+
+
+# ((B, P, nb) of openpangu-ultra-moe-718b.serve1 and of deepseek-v3.2-exp.serve1,
+# T, mq, whether a selection is given)
+_DOC, _LONGDOC = (64, 136, 6144), (24, 268, 5120)
+_LATENT_CALLS = {"doc-mixed": (_DOC, 512, 64, False), "doc-prefill": (_DOC, 512, 512, False),
+                 "doc-decode": (_DOC, 64, 1, False),
+                 "longdoc-mixed": (_LONGDOC, 512, 64, True),
+                 "longdoc-prefill": (_LONGDOC, 512, 512, True),
+                 "longdoc-decode": (_LONGDOC, 24, 1, True)}
+
+
+@pytest.mark.parametrize("call", _LATENT_CALLS)
+def test_latent_attention_attends_in_one_kernel(chip, call, monkeypatch):
+    """The latent cache's attention at the two latent cells' serving geometry
+    (128 heads over entries of 640, blocks of 64), two iterations in a scan
+    with the pool in the carry, compiled as the chip will run it (ISSUE 43):
+    ONE ``latent_rows`` call an iteration takes the chunk rows and, where no
+    selection is given, the one-token rows (under a selection they gather
+    their 2,048 entries, so a decode step holds no kernel), the pool keeps
+    the argument's layout and is not copied, and the pass's scores
+    ``[tokens, 128 heads, 512 positions]`` in float32 are nowhere in the
+    program: they live in the kernel's VMEM."""
+    from paddle_tpu.ops import latent_attention as la
+
+    on_the_chip(monkeypatch)
+    (B, P, nb), T, mq, selected = _LATENT_CALLS[call]
+    H, W, C, bs, K = 128, 640, 512, 64, 2048
+
+    def two_iterations(q, entries, cache, dec, now, cu, bt, *selection):
+        def body(cache, _):
+            out, cache = la.latent_attention(
+                q, entries, cache, dec, now, cu, bt, rank=C, max_q_len=mq, scale=0.07,
+                selection=la.Selection(*selection, *(() if mq > 1 else (None,)))
+                if selected else None)
+            return cache, out
+        return jax.lax.scan(body, cache, None, length=2)
+
+    i32, shapes = jnp.int32, []
+    if selected:
+        shapes = [((B, K), i32), ((B, K), jnp.bool_)] + (
+            [((T + mq, P * bs), jnp.bool_)] if mq > 1 else [])
+    shapes = [((T, H, W), BF16), ((T, W), BF16), ((nb, bs, W), BF16), ((B,), i32),
+              ((B,), i32), ((B + 1,), i32), ((B, P), i32)] + shapes
+    if selected and mq == 1:
+        compiled = jax.jit(two_iterations, donate_argnums=2).lower(*(
+            jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes)).compile()
+    else:
+        compiled = compile_kernel(two_iterations, chip, *shapes, names=("latent_rows",),
+                                  donate=(2,))
+    text = compiled.as_text()
+    assert kernel_calls(text, "latent_rows") == int(not (selected and mq == 1))
+    # the loops' scores: a chunk row's [mq, H, Lc] and the one-token rows'
+    # [B, H, Lc] (under a selection [B, H, C] is their accumulator, C = Lc)
+    assert f"f32[{mq},128,512]" not in text
+    assert selected or f"f32[{B},128,512]" not in text
+    pool = f"{nb},{bs},{W}"
+    assert set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+)", text)) == {"2,1,0"}
+    assert not re.search(rf"= bf16\[{pool}\][^\n]* copy\(", text)
+    # what an iteration holds beside its arguments: the result, the queries'
+    # copy and (selected) the gathered entries and the mask's rows: no pass
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (300e6 if selected else 160e6), f"{temp / 1e6:.0f} MB of temporaries"

@@ -4,7 +4,8 @@ import re
 
 import pytest
 
-from described_device import compiled_program, engine_of, fits_as_the_file_says, on_the_chip
+from described_device import (compiled_program, engine_of, fits_as_the_file_says, kernel_calls,
+                              on_the_chip)
 
 
 @pytest.fixture(scope="module")
@@ -35,4 +36,14 @@ def test_a_selected_latent_models_programs_fit_the_chip(chip, dsa_engine, kind, 
         orders = set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+)", text))
         assert orders == {"2,1,0"}, (width, orders)
         assert not re.search(rf"= bf16\[{pool}\][^\n]* copy\(", text)
-    fits_as_the_file_says(cfg, kind, compiled, margin=1.5e9)
+    # ISSUE 43: the chunk rows attend in ``latent_rows`` (a decode scan's rows
+    # gather their selection: no kernel), so the loops' scores are gone from
+    # the prefill step, whose 512-token chunk row held 0.52 GB of them: it
+    # compiles to 12.17 GB live where the file (the benchmark's) says 12.69; the
+    # mixed scan's temporaries rose 0.03 GB with the mask's realigned rows, inside
+    # the file's figure to 1 %
+    layers = len(eng._weights["layers"])
+    assert kernel_calls(text, "latent_rows") == (0 if kind == "mega_K8" else layers)
+    assert "f32[64,128,512]" not in text and "f32[512,128,512]" not in text
+    fits_as_the_file_says(cfg, kind, compiled, margin=1.5e9,
+                          live_now={"step_prefill_T512": 12_166_132_224}.get(kind))

@@ -25,3 +25,22 @@ def test_a_latent_expert_models_mixed_scan_is_no_larger_than_with_the_loop(
     assert sparse and kernel_calls(compiled.as_text(), "expert_gmm") == 3 * sparse
     live = live_bytes(compiled.memory_analysis())
     assert live <= 13_223_340_544 < V5E_BYTES_LIMIT, live
+
+
+@pytest.mark.parametrize("kind", ["step_prefill_T512", "mixed_K8"])
+def test_a_latent_models_rows_attend_in_the_kernel(chip, pangu_engine, kind, monkeypatch):
+    """ISSUE 43: the prefill step and the mixed scan hold ONE ``latent_rows``
+    call a layer (chunk rows and one-token rows alike: no selection), none of
+    the XLA loops' score temporaries (a chunk row's ``[mq, 128, 512]``, the
+    one-token rows' ``[64, 128, 512]``, float32), and fit as the configuration
+    file says (its ``live`` is an upper bound since: the files are the
+    benchmark's)."""
+    on_the_chip(monkeypatch)
+    cfg, eng = pangu_engine
+    compiled = compiled_program(eng, cfg, kind, chip)
+    text = compiled.as_text()
+    assert kernel_calls(text, "latent_rows") == len(eng._weights["layers"]) == 5
+    assert "f32[64,128,512]" not in text and "f32[512,128,512]" not in text
+    said = cfg["memory"]["compiled_for_v5e"][kind]
+    live = live_bytes(compiled.memory_analysis())
+    assert 0.9 * said["live"] < live <= said["live"], (live, said)

@@ -599,8 +599,12 @@ def test_the_older_models_specs_and_program_keys_are_the_recorded_ones(family):
 # was (one more count, the expert layer one jitted function); the parent's
 # texts were 95a57733 / 5167fcff / d2dad7a5 / f9cb499e, and
 # tests/test_deepseek_v32.py holds each served token to the reference.
-DEEPSEEK_TEXTS = {"step": "4f98ecfca0efd8a7", "mega": "0684536a4b43dc6b",
-                  "mixed": "9123fdc4e0fdda89", "spec": "00bf59f8ba8b213f"}
+# And again at PR 43 (the trunk counts ``latent_rows_kernel`` and
+# ``latent_chunks_kernel``; PR 39's texts were 4f98ecfc / 0684536a /
+# 9123fdc4 / 00bf59f8; ``latent_attention`` alone lowers to what it did:
+# tests/test_latent_rows_kernel.py).
+DEEPSEEK_TEXTS = {"step": "b53c7a835eae1d46", "mega": "31e7f9584b44305a",
+                  "mixed": "2c347e4856a2ab15", "spec": "417ddd62ff9c457c"}
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0", reason="the texts are jax 0.9.0's")

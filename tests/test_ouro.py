@@ -463,12 +463,17 @@ def test_a_model_of_one_pass_says_so():
 # ``grouped_experts``); the parent's texts were a208678c / 9bc0f7a2 /
 # edb208f4 / 986e9feb.  That the mathematics stood is tests/test_pangu_moe.py's
 # (each served token against the float32 reference) and
-# tests/test_expert_gmm.py's.  ``llama`` is PR 35's.
+# tests/test_expert_gmm.py's.  And again at PR 43: the latent trunks count
+# ``latent_rows_kernel`` and ``latent_chunks_kernel`` (two more words in the
+# result block; PR 39's texts were 4a257fd7 / 0e863ba2 / 85ad8f81 /
+# 63ed4e1e), while ``latent_attention`` ALONE lowers on the CPU to the text
+# it lowered to (tests/test_latent_rows_kernel.py holds that, case by case).
+# ``llama`` is PR 35's.
 PARENT_TEXTS = {
     "llama": {"step": "779917ad04438754", "mega": "5f964e23d4c735de",
               "mixed": "bb1f19f3e0b23bcc", "spec": "3f5eb174a4edd6ad"},
-    "pangu": {"step": "4a257fd74d58f2ea", "mega": "0e863ba2e2506888",
-              "mixed": "85ad8f811532fdc3", "spec": "63ed4e1ead28d7bb"}}
+    "pangu": {"step": "d5a0624f7a91285b", "mega": "653fcb103bd83e3d",
+              "mixed": "9f4ba6ba5f40ad5c", "spec": "10b438f9f8aa62a9"}}
 
 
 def _pangu_tiny():

@@ -495,8 +495,11 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
                                           "rows_grouped": 0}
     # ... and nothing of the dense attention's, which this trunk does not run
     seen = [a for _, a in harvests]
-    assert seen and all(set(a) == {"moe_tokens", "moe_local_picks", "expert_rows_grouped"}
+    assert seen and all(set(a) == {"moe_tokens", "moe_local_picks", "expert_rows_grouped",
+                                   "latent_rows_kernel", "latent_chunks_kernel"}
                         for a in seen)
+    # the CPU: every row of the latent cache took the XLA loops
+    assert eng.state_summary()["latent_attention"] == {"rows_kernel": 0, "chunks_kernel": 0}
     assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0,
                                                 "rows_kernel": 0, "kv_write_tokens": 0,
                                                 "kv_write_blocks": 0}

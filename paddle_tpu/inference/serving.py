@@ -789,7 +789,7 @@ class ServingEngine:
         # of those picks, the ones that went through the grouped product
         # (``held_experts``: ops/pallas/expert_gmm.py; 0 where the tile loop ran)
         self.expert_rows_grouped = 0
-        # an expert layer's tile loop (models/pangu_moe.py ``held_experts``,
+        # an expert layer's tile loop (ops/held_experts.py ``held_experts``,
         # where the trunk counts it): held experts that got at least one row,
         # summed over layers and iterations; the rows the tiles multiplied,
         # and those among them that were a live pick

@@ -23,7 +23,9 @@ sampling, and asks the model three questions:
     trunk whose layers are a loop in the compiled program writes and reads
     at a traced layer index (ops/paged_attention.py ``layer=``).  ``counts``
     is a dict of int32 scalars the engine adds to its counters of the same
-    names (``{}``: none).
+    names (``{}``: none).  A trunk takes its counts from its ops, which choose
+    their own kernels and say what they did (``paged_counts``, ``latent_counts``,
+    ``held_experts``).  It seeds the names its result block carries.
 
 and ``serving_rope(max_seq_len)`` for the table the trunk reads positions
 from.  ``LlamaForCausalLM`` (models/llama.py: a list of per-head pools),

@@ -35,7 +35,7 @@ out and ``index_k`` is kept in the model's type; rope pairs by halves here
 (``rope_half``), in MLA and in the indexer.
 
 Everything the two models share has ONE definition, in models/pangu_moe.py
-(``_latent_proj``, ``held_experts``, ``_moe_ffn``, the layers' plumbing) and
+(``_latent_proj``, ``_moe_ffn``, the layers' plumbing) and
 ops/latent_attention.py; ``experts_held`` means here what it means there.
 Two forms of the same mathematics: ``forward`` (whole sequences, keys and
 values expanded a head, the selection as a mask over the causal square) and
@@ -54,8 +54,8 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn.initializer import Constant
-from ..ops.latent_attention import (latent_attention, rope_half, rows_in_kernel,
-                                    rows_taken, selection_reads, token_coords)
+from ..ops.latent_attention import (latent_attention, latent_counts, rope_half,
+                                    selection_counts, token_coords)
 from ..ops.sparse_index import index_scores, layer_norm, select_topk, sparse_index
 from .pangu_moe import (F32, HIGHEST, LatentMoEGeometry, PanguDecoderLayer, PanguMLAttention,
                         PanguMLP, PanguMTPModule, PanguSparseMoE, PanguUltraMoEForCausalLM,
@@ -372,17 +372,12 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
         scales) -> (hidden [T, E] after the final norm, caches, [], counts):
         packed tokens through every layer against the paged pool, ``caches`` =
         (latent pools, index_k pools), a layer each.  ``counts``: the expert
-        layers' ``moe_tokens`` / ``moe_local_picks`` / ``expert_rows_grouped``, and of ONE
-        layer's
-        indexer and attention, over the live queries whose context exceeds
-        ``index_topk``: ``dsa_queries``, ``dsa_positions_scored``,
-        ``dsa_positions_selected`` and ``dsa_positions_read`` (the latent
-        entries the attention brought for them, by the passes' own
-        arithmetic: ``latent_attention.selection_reads``); and
-        ``attn_positions_live``, the context of every row fed; and the rows of
-        an iteration whose attention ran in the ``latent_rows`` kernel,
-        ``latent_chunks_kernel`` (``latent_rows_kernel`` stays 0: a one-token
-        row gathers its selection)."""
+        layers' three (``_moe_ffn``), and of ONE layer's indexer and attention:
+        ``dsa_queries``, ``dsa_positions_scored``, ``dsa_positions_selected``
+        (``sparse_index``) and ``dsa_positions_read`` (``selection_counts``)
+        over the live queries whose context exceeds ``index_topk``,
+        ``attn_positions_live`` (the context of every row fed) and
+        ``latent_counts``'s two."""
         cfg = self.config
         eps, C = cfg.rms_norm_eps, cfg.kv_lora_rank
         pad = cfg.latent_cache_width - cfg.latent_width
@@ -399,12 +394,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "moe_tokens", "moe_local_picks", "expert_rows_grouped")}
-            kernel = rows_in_kernel(
-                hidden.dtype, lat[0].dtype, heads=cfg.num_attention_heads,
-                width=cfg.latent_cache_width, rank=C, block_size=block_size, rows=B,
-                blocks_per_seq=bt.shape[1])
-            counts["latent_rows_kernel"], counts["latent_chunks_kernel"] = rows_taken(
-                now, kernel=kernel, selected=True)
+            asked = dict(heads=cfg.num_attention_heads, rank=C)
+            counts.update(latent_counts(hidden.dtype, lat[0], now, bt, selected=True, **asked))
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_in"], eps)
@@ -425,10 +416,9 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                     q, entries, lat[li], dec, now, cu, bt, rank=C, max_q_len=mq,
                     scale=cfg.softmax_scale, selection=selection)
                 if li == 0:
-                    counts.update(dsa, dsa_positions_read=selection_reads(
-                        dec, now, topk=cfg.index_topk, gathered=selection.idx.shape[1],
-                        block_size=block_size, blocks_per_seq=bt.shape[1], kernel=kernel,
-                        max_q_len=mq),
+                    counts.update(dsa, **selection_counts(
+                        hidden.dtype, lat[0], dec, now, bt, selection, topk=cfg.index_topk,
+                        max_q_len=mq, **asked),
                         attn_positions_live=jnp.sum(
                             jnp.where(now > 0, dec + now, 0)).astype(jnp.int32))
                 with jax.named_scope("attn_out"):
@@ -437,10 +427,8 @@ class DeepseekV32ForCausalLM(PanguUltraMoEForCausalLM):
                 with jax.named_scope("norm"):
                     h2 = _rms(hidden, lw["ln_post"], eps)
                 if "router" in lw:
-                    ffn, picks = _moe_ffn(cfg, lw, h2, valid, router=_router_of(cfg, lw),
-                                          counts=counts)
-                    counts["moe_tokens"] += jnp.sum(valid).astype(jnp.int32)
-                    counts["moe_local_picks"] += picks
+                    ffn, _ = _moe_ffn(cfg, lw, h2, valid, router=_router_of(cfg, lw),
+                                      counts=counts)
                 else:
                     with jax.named_scope("mlp"):
                         ffn = _swiglu(h2, lw["wg"], lw["wu"], lw["wd"])

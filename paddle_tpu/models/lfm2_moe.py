@@ -23,8 +23,8 @@ expert ffn: s = sigmoid(float32(x) float32(W_r)); I = top-k(s + b) (b the
     (sum_{j in I} s_j + 1e-6) * routed_scaling_factor;
     ffn(x) = sum_{i in I, i held} w_i SwiGLU_i(x).
 
-The expert layer is models/pangu_moe.py's (``held_experts``, ``_moe_ffn`` with
-the shared expert left out where a layer has none, ``_swiglu``, ``_rms``):
+The expert layer is models/pangu_moe.py's (``_moe_ffn`` over ops/held_experts.py,
+the shared expert left out where a layer has none; ``_swiglu``, ``_rms``):
 ``experts_held`` means here what it means there, and defaults to all.
 
 Two forms of the same mathematics: ``forward`` (whole sequences: the
@@ -395,14 +395,9 @@ class Lfm2MoeForCausalLM(nn.Layer):
         packed tokens through every layer; ``caches`` = (key pools, value
         pools: an attention layer each; conv state ``[conv layers, B, L - 1,
         E]``).  ``counts``: ``conv_rows_fed`` (row-layers whose state
-        advanced), ``moe_tokens`` / ``moe_local_picks`` as the other expert
-        families count them, ``experts_touched`` / ``expert_tile_rows`` /
-        ``expert_tile_rows_live`` / ``expert_rows_grouped`` of the expert
-        layer's products (``held_experts``), and ONE attention layer's
-        ``attn_positions_*`` / ``kv_write_*`` as models/llama.py's trunk."""
-        from ..ops.paged_attention import (attention_positions, blha_attention,
-                                           cache_write_counts, decodes_in_kernel,
-                                           writes_in_kernel)
+        advanced), the expert layers' six seeded below (``_moe_ffn``,
+        ``held_experts``) and ONE attention layer's five (``paged_counts``)."""
+        from ..ops.paged_attention import blha_attention, paged_counts
 
         cfg = self.config
         H, KV, D, eps = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
@@ -448,28 +443,11 @@ class Lfm2MoeForCausalLM(nn.Layer):
                         hidden = hidden + out @ lw["wo"]
                 with jax.named_scope("norm"):
                     h2 = _rms(hidden, lw["ln_ffn"], eps)
-                ffn, picks = _ffn(cfg, lw, h2, valid, counts)
-                if picks is not None:
-                    counts["moe_tokens"] += jnp.sum(valid).astype(jnp.int32)
-                    counts["moe_local_picks"] += picks
+                ffn, _ = _ffn(cfg, lw, h2, valid, counts)
                 hidden = hidden + ffn
             with jax.named_scope("norm"):
                 hidden = _rms(hidden, weights["norm"], eps)
-            # what ONE attention layer attended, read and wrote, as
-            # models/llama.py's trunk counts it; the kernels are asked with
-            # the sizes of the pool's rows (heads of 64 two to a lane tile)
-            _, kv_rows, _, lanes = key_caches[0].shape
-            sizes = dict(head_dim=lanes, block_size=block_size, rows=B,
-                         blocks_per_seq=bt.shape[1])
-            live, read, in_kernel = attention_positions(
-                dec, now, block_size=block_size, blocks_per_seq=bt.shape[1],
-                kernel=decodes_in_kernel(hidden.dtype, key_caches[0].dtype, **sizes))
-            written, pieces = cache_write_counts(
-                dec, now, cu, kernel=writes_in_kernel(
-                    key_caches[0].dtype, tokens=T, kv_heads=kv_rows, **sizes))
-            counts.update(attn_positions_live=live, attn_positions_read=read,
-                          attn_rows_kernel=in_kernel, kv_write_tokens=written,
-                          kv_write_blocks=pieces)
+            counts.update(paged_counts(hidden.dtype, key_caches[0], dec, now, cu, bt, tokens=T))
             return hidden, (key_caches, value_caches, conv_state), [], counts
 
         return trunk

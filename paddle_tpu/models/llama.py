@@ -524,9 +524,7 @@ class LlamaForCausalLM(nn.Layer):
             jnp.float32)
 
     def serving_trunk(self, *, block_size, cache_quant="none"):
-        from ..ops.paged_attention import (attention_positions, blha_attention,
-                                           cache_write_counts, decodes_in_kernel,
-                                           writes_in_kernel)
+        from ..ops.paged_attention import blha_attention, paged_counts
 
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -588,26 +586,10 @@ class LlamaForCausalLM(nn.Layer):
                     hidden = hidden + (jax.nn.silu(g) * u) @ lw["wd"]
             with jax.named_scope("norm"):
                 hidden = rms(hidden, weights["norm"])
-            # what every layer's attention had to attend, what it read for
-            # that and how many one-token rows the kernel took, once an
-            # iteration (the layers read alike)
-            live, read, in_kernel = attention_positions(
-                dec, now, block_size=bs, blocks_per_seq=bt.shape[1],
-                kernel=decodes_in_kernel(
-                    hidden.dtype, key_caches[0].dtype, head_dim=D, block_size=bs,
-                    rows=bt.shape[0], blocks_per_seq=bt.shape[1],
-                    plain=quant == "none"))
-            # and what a layer's cache write put into the pool: the live
-            # tokens, and the block pieces the row-wise write moved for them
-            written, pieces = cache_write_counts(
-                dec, now, cu, kernel=writes_in_kernel(
-                    key_caches[0].dtype, head_dim=D, block_size=bs,
-                    rows=bt.shape[0], blocks_per_seq=bt.shape[1],
-                    tokens=token_ids.shape[0], kv_heads=KV))
-            return hidden, (key_caches, value_caches), new_scales, {
-                "attn_positions_live": live, "attn_positions_read": read,
-                "attn_rows_kernel": in_kernel,
-                "kv_write_tokens": written, "kv_write_blocks": pieces}
+            # ONE layer's counts an iteration: the layers read and are written alike
+            return hidden, (key_caches, value_caches), new_scales, paged_counts(
+                hidden.dtype, key_caches[0], dec, now, cu, bt, tokens=token_ids.shape[0],
+                plain=quant == "none")
 
         return trunk
 

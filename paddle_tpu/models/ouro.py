@@ -47,9 +47,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.initializer import Constant, Normal
 from ..ops.dispatch import apply
-from ..ops.paged_attention import (attention_positions, blha_attention,
-                                   cache_write_counts, decodes_in_kernel,
-                                   rope_rotate, writes_in_kernel)
+from ..ops.paged_attention import blha_attention, paged_counts, rope_rotate
 from ..profiler import SetupSpan
 from .pangu_moe import _rms, _swiglu          # the sandwich block's two, as openPangu has them
 
@@ -303,8 +301,7 @@ class OuroForCausalLM(nn.Layer):
         ``counts``: ``loop_tokens`` (tokens fed), ``loop_token_passes``
         (tokens x the passes each ran: a token runs a pass while its
         cumulative exit probability is under the threshold, at 1 all of
-        them), and the dense paged attention's three and its cache write's
-        two, which count ONE cache layer (all read and are written alike)."""
+        them), and ``paged_counts``'s five of ONE cache layer (all alike)."""
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         L, R, eps, bs = (cfg.num_hidden_layers, cfg.total_ut_steps, cfg.rms_norm_eps,
@@ -359,20 +356,9 @@ class OuroForCausalLM(nn.Layer):
             hidden, kc, vc, _, ran = jax.lax.fori_loop(
                 0, R, one_pass,
                 (hidden,) + tuple(caches) + (jnp.ones((T,), F32), jnp.zeros((), jnp.int32)))
-            live, read, in_kernel = attention_positions(
-                dec, now, block_size=bs, blocks_per_seq=bt.shape[1],
-                kernel=decodes_in_kernel(
-                    hidden.dtype, kc.dtype, head_dim=D, block_size=bs,
-                    rows=B, blocks_per_seq=bt.shape[1]))
-            written, pieces = cache_write_counts(
-                dec, now, cu, kernel=writes_in_kernel(
-                    kc.dtype, head_dim=D, block_size=bs, rows=B,
-                    blocks_per_seq=bt.shape[1], tokens=T, kv_heads=KV))
+            paged = paged_counts(hidden.dtype, kc, dec, now, cu, bt, tokens=T)
             return hidden, (kc, vc), [], {
                 "loop_tokens": jnp.sum(valid).astype(jnp.int32),
-                "loop_token_passes": ran,
-                "attn_positions_live": live, "attn_positions_read": read,
-                "attn_rows_kernel": in_kernel,
-                "kv_write_tokens": written, "kv_write_blocks": pieces}
+                "loop_token_passes": ran, **paged}
 
         return trunk

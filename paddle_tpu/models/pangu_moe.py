@@ -27,7 +27,6 @@ keys and values expanded a head: the published equations as they stand) and
 ops/latent_attention.py: queries absorbed into the latent space)."""
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -37,12 +36,10 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..device import on_tpu
 from ..nn.initializer import Constant, Normal
 from ..ops.dispatch import apply
-from ..ops.latent_attention import (latent_attention, rope_half, rows_in_kernel,
-                                    rows_taken)
-from ..ops.pallas.expert_gmm import expert_gmm
+from ..ops.held_experts import _swiglu, held_experts
+from ..ops.latent_attention import latent_attention, latent_counts, rope_half
 from ..profiler import SetupSpan
 
 __all__ = ["PanguUltraMoEConfig", "PanguUltraMoEModel", "PanguUltraMoEForCausalLM",
@@ -149,10 +146,6 @@ def _rms(x, w, eps):
     return (nrm * w.astype(F32)).astype(x.dtype)
 
 
-def _swiglu(x, wg, wu, wd):
-    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
-
-
 def rope_table(cfg, length):
     """[2, length, R/2] float32 (cos, sin): rotate-half, no scaling."""
     r = cfg.qk_rope_head_dim
@@ -203,182 +196,26 @@ def route(x, w_router, top_k, scale):
     return idx.astype(jnp.int32), scale * gv / (jnp.sum(gv, -1, keepdims=True) + 1e-20)
 
 
-# the grouped product (ops/pallas/expert_gmm.py): rows of a tile, the bytes of
-# sorted rows gathered at a time (one chunk unless the live picks outgrow it),
-# and the rows whose tokens the kernel holds in scalar memory
-_ROW_TILE = 32
-_CHUNK_BYTES = 16 << 20
-_ROW_WORDS = 1 << 17
-
-
-def _chunk_tiles(picks: int, n_held: int, row_bytes: int, row_tile: int,
-                 chunk_bytes: int) -> Tuple[int, int]:
-    """(tiles of ``row_tile`` rows that hold ``picks`` sorted rows whatever the
-    routing, every expert's rows starting on a tile; tiles of one chunk)."""
-    bound = -(-picks // row_tile) + n_held
-    return bound, min(bound, max(n_held, chunk_bytes // (row_tile * row_bytes)))
-
-
-def groups_in_kernel(x_dtype, w_dtype, *, hidden: int, width: int, rows: int) -> bool:
-    """Whether a call's sorted picks go through the Pallas grouped product
-    (``ops/pallas/expert_gmm.py``), decided from what the call shows and
-    nothing else, as ``ops/paged_attention.decodes_in_kernel`` decides: the
-    platform is the TPU; the rows and the experts' matrices are bfloat16;
-    ``hidden`` (E) and ``width`` (F) are whole 128-lane tiles; a chunk's
-    ``rows`` (their tokens) fit the kernel's scalar memory.  Anything else
-    (the CPU, the float32 eager ``forward``, the tiny test geometries) takes
-    the tile loop."""
-    return (on_tpu() and jnp.dtype(x_dtype) == jnp.bfloat16
-            and jnp.dtype(w_dtype) == jnp.bfloat16
-            and hidden % 128 == 0 and width % 128 == 0 and rows <= _ROW_WORDS)
-
-
-def _tiles_of(sizes, tile):
-    """Tiles of ``tile`` rows in use when every expert's rows start on one."""
-    return jnp.sum((sizes + tile - 1) // tile)
-
-
-def _tile_rows(i, tile, sizes, first_row):
-    """Tiles ``i`` (one or a vector of them) in that layout -> (the expert of
-    each, its rows' places in the sorted order ``[..., tile]``, which of them
-    are picks: none of a tile past the last in use)."""
-    tiles = (sizes + tile - 1) // tile
-    last_tile = jnp.cumsum(tiles)
-    e = jnp.minimum(jnp.searchsorted(last_tile, i, side="right"), sizes.shape[0] - 1)
-    r = (first_row[e] + (i - (last_tile[e] - tiles[e])) * tile)[..., None] + jnp.arange(tile)
-    return e, r, r < (first_row + sizes)[e][..., None]
-
-
-def _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, tile):
-    """One tile of ``tile`` rows at a time through its expert's SwiGLU, a
-    ``fori_loop`` over the tiles in use. -> (y [T, E] float32, rows multiplied)."""
-    T, E = x.shape
-    n_tiles = _tiles_of(sizes, tile)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, E), x.dtype)])
-
-    def one_tile(i, out):
-        e, r, ok = _tile_rows(i, tile, sizes, first_row)
-        r = jnp.clip(r, 0, tok_s.shape[0] - 1)
-        t = jnp.where(ok, tok_s[r], T)
-        y = _swiglu(x_pad[t], eg[e], eu[e], ed[e]).astype(F32)
-        return out.at[t].add(y * jnp.where(ok, w_s[r], 0.0)[:, None], mode="drop")
-
-    return jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((T, E), F32)), n_tiles * tile
-
-
-def grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, *, row_tile=_ROW_TILE,
-                    chunk_bytes=_CHUNK_BYTES, gmm=expert_gmm):
-    """The sorted picks through ONE grouped product an expert matrix.  Every
-    expert's rows start on a row tile, so a tile is one expert's and its spare
-    rows are zeros; the tiles in use come first.  The rows are gathered into
-    that order once and go through gate, up and down (``gmm``, the kernel of
-    ``ops/pallas/expert_gmm.py``, brings each touched expert's block from the
-    stack once; float32 results, the activation in float32); the down product
-    adds its weighted rows to their tokens in the kernel (``combine``), so
-    nothing is scattered.  A CHUNK of
-    tiles at a time, ``chunk_bytes`` of gathered rows: what is moved follows
-    the live picks and not ``T x k`` (15 of 16 sorted rows are picks of
-    experts held elsewhere in a 16-of-256 deployment); the loop over chunks
-    runs once unless the picks pile up here.
-    -> (y [T, E] float32, rows multiplied)."""
-    T, E = x.shape
-    n_held, R = eg.shape[0], tok_s.shape[0]
-    n_tiles = _tiles_of(sizes, row_tile)
-    bound, chunk = _chunk_tiles(R, n_held, E * x.dtype.itemsize, row_tile, chunk_bytes)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, E), x.dtype)])
-    gmm = functools.partial(gmm, row_tile=row_tile)
-
-    def one_chunk(c):
-        e, r, ok = _tile_rows(c * chunk + jnp.arange(chunk), row_tile, sizes, first_row)
-        r, ok = jnp.clip(r, 0, R - 1).reshape(-1), ok.reshape(-1)
-        n = jnp.clip(n_tiles - c * chunk, 0, chunk)
-        xs = x_pad[jnp.where(ok, tok_s[r], T)]
-        h = jax.nn.silu(gmm(xs, eg, e, n)) * gmm(xs, eu, e, n)
-        return gmm(h.astype(x.dtype), ed, e, n,
-                   combine=(tok_s[r], jnp.where(ok, w_s[r], 0.0), T))
-
-    if chunk == bound:
-        return one_chunk(0), n_tiles * row_tile
-    y = jax.lax.fori_loop(0, (n_tiles + chunk - 1) // chunk,
-                          lambda c, out: out + one_chunk(c), jnp.zeros((T, E), F32))
-    return y, n_tiles * row_tile
-
-
-@functools.partial(jax.jit, static_argnames=("lo", "tile", "gmm"))
-@jax.named_scope("experts")
-def _held_experts(x, idx, w, eg, eu, ed, valid, *, lo, tile, gmm):
-    """``held_experts`` without its counting, a jitted function: the expert
-    layers of a program (and the programs of a process that feed the same
-    shapes) share ONE trace, and a program lowers it, its kernels with it,
-    once and calls it a layer.  ``gmm``: the grouped product's kernel where
-    the call is admitted (static: part of what the trace is cached under),
-    else None.  -> (y, picks, held experts with a row, rows multiplied)."""
-    T, E = x.shape
-    n_held, k = eg.shape[0], idx.shape[1]
-    le = idx - lo
-    hit = (le >= 0) & (le < n_held)
-    if valid is not None:
-        hit = hit & valid[:, None]
-    le = jnp.where(hit, le, n_held).reshape(-1)
-    order = jnp.argsort(le, stable=True)
-    tok_s = (order // k).astype(jnp.int32)
-    w_s = w.reshape(-1)[order]
-    sizes = jnp.sum(le[:, None] == jnp.arange(n_held)[None, :], axis=0).astype(jnp.int32)
-    first_row = jnp.cumsum(sizes) - sizes                 # in the sorted order
-    if gmm is None:
-        y, rows = _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, min(tile, T * k))
-    else:
-        y, rows = grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, gmm=gmm)
-    return (y, jnp.sum(hit).astype(jnp.int32), jnp.sum(sizes > 0).astype(jnp.int32),
-            rows.astype(jnp.int32))
-
-
-def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None):
-    """The part of the routed result that the held experts give.
-
-    x [T, E]; idx, w [T, k] from ``route``; eg, eu [n_held, E, F], ed
-    [n_held, F, E]: the experts ``lo .. lo + n_held`` of the published range.
-    The picks that fall on a held expert are sorted by expert and each
-    expert's rows padded to whole tiles; an expert no token picked is not
-    read.  Where ``groups_in_kernel`` admits the call the tiles go through
-    ``grouped_experts`` (one grouped product an expert matrix), else one tile
-    of ``tile`` rows at a time through its expert's SwiGLU (``_tile_loop``).
-    Nothing is dropped: there is no capacity.
-    -> (y [T, E] float32, picks that fell on a held expert).
-    ``counts``: a trunk's dict of int32 scalars; to those of these names it
-    holds, the call adds what it did: ``experts_touched`` (held experts with
-    at least one row), ``expert_tile_rows`` (rows the products multiplied,
-    padding included), ``expert_tile_rows_live`` (the picks among them) and
-    ``expert_rows_grouped`` (the picks that went through the grouped
-    product: 0 from a call that took the tile loop)."""
-    (T, E), n_held, k = x.shape, eg.shape[0], idx.shape[1]
-    grouped = groups_in_kernel(
-        x.dtype, eg.dtype, hidden=E, width=eg.shape[2], rows=_ROW_TILE * _chunk_tiles(
-            T * k, n_held, E * x.dtype.itemsize, _ROW_TILE, _CHUNK_BYTES)[1])
-    y, picks, touched, rows = _held_experts(
-        x, idx, w, eg, eu, ed, valid, lo=int(lo), tile=int(tile),
-        gmm=expert_gmm if grouped else None)
-    for name, n in (("experts_touched", touched), ("expert_tile_rows", rows),
-                    ("expert_tile_rows_live", picks),
-                    ("expert_rows_grouped", picks * grouped)):
-        if counts is not None and name in counts:
-            counts[name] += n
-    return y, picks
-
-
 def _moe_ffn(cfg, p, x, valid=None, router=route, counts=None):
     """Shared expert (where the layer's weights hold one: ``sg``) + the held
     experts' part. -> (y in x's dtype, picks).
     ``router(x, w_router, top_k, scale)`` -> (idx, w): the model's routing;
-    ``counts``: ``held_experts``'s."""
+    ``counts``: a trunk's dict, ``held_experts``'s; where it holds them the layer
+    adds ``moe_tokens`` (the ``valid`` tokens through it) and ``moe_local_picks``."""
     idx, w = router(x, p["router"], cfg.num_experts_per_tok, cfg.routed_scaling_factor)
     routed, picks = held_experts(x, idx, w, p["eg"], p["eu"], p["ed"],
                                  cfg.experts_held[0], valid, counts=counts)
     if "sg" not in p:
-        return routed.astype(x.dtype), picks
-    with jax.named_scope("shared_expert"):
-        shared = _swiglu(x, p["sg"], p["su"], p["sd"])
-    return (shared.astype(F32) + routed).astype(x.dtype), picks
+        y = routed.astype(x.dtype)
+    else:
+        with jax.named_scope("shared_expert"):
+            shared = _swiglu(x, p["sg"], p["su"], p["sd"])
+        y = (shared.astype(F32) + routed).astype(x.dtype)
+    if counts is not None and "moe_tokens" in counts:
+        counts["moe_tokens"] += jnp.sum(valid).astype(jnp.int32)
+    if counts is not None and "moe_local_picks" in counts:
+        counts["moe_local_picks"] += picks
+    return y, picks
 
 
 # ------------------------------------------------------------------ the layers
@@ -672,12 +509,8 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         """trunk(weights, caches, rope, token_ids, enc, dec, now, cu, bt, mq,
         scales) -> (hidden [T, E] after the final norm, caches, [], counts):
         packed tokens through every layer against the paged latent cache.
-        ``counts``: ``moe_tokens`` (tokens through expert layers),
-        ``moe_local_picks`` (picks that fell on a held expert),
-        ``expert_rows_grouped`` (those that went through the grouped product),
-        and the rows of an iteration whose attention ran in the
-        ``latent_rows`` kernel, ``latent_rows_kernel`` (one token) and
-        ``latent_chunks_kernel`` (a chunk): 0 where the XLA loops ran."""
+        ``counts``: the names seeded below, which the expert layers fill
+        (``_moe_ffn``), and ONE layer's attention's (``latent_counts``)."""
         cfg = self.config
         eps, C = cfg.rms_norm_eps, cfg.kv_lora_rank
         scale = cfg.qk_head_dim ** -0.5
@@ -698,11 +531,8 @@ class PanguUltraMoEForCausalLM(nn.Layer):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "moe_tokens", "moe_local_picks", "expert_rows_grouped")}
-            counts["latent_rows_kernel"], counts["latent_chunks_kernel"] = rows_taken(
-                now, selected=False, kernel=rows_in_kernel(
-                    hidden.dtype, lat[0].dtype, heads=cfg.num_attention_heads,
-                    width=cfg.latent_cache_width, rank=C, block_size=block_size,
-                    rows=B, blocks_per_seq=bt.shape[1]))
+            counts.update(latent_counts(hidden.dtype, lat[0], now, bt,
+                                        heads=cfg.num_attention_heads, rank=C))
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_in"], eps)
@@ -724,9 +554,7 @@ class PanguUltraMoEForCausalLM(nn.Layer):
                 with jax.named_scope("norm"):
                     h2 = _rms(hidden, lw["ln_pre_mlp"], eps)
                 if "router" in lw:
-                    ffn, picks = _moe_ffn(cfg, lw, h2, valid, counts=counts)
-                    counts["moe_tokens"] += jnp.sum(valid).astype(jnp.int32)
-                    counts["moe_local_picks"] += picks
+                    ffn, _ = _moe_ffn(cfg, lw, h2, valid, counts=counts)
                 else:
                     with jax.named_scope("mlp"):
                         ffn = _swiglu(h2, lw["wg"], lw["wu"], lw["wd"])

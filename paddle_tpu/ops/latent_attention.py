@@ -43,7 +43,8 @@ chunk row keeps its blocked pass, kernel or loop, and attends ``position <=
 its own`` AND ``mask`` (row t: the packed token t's): it still brings every
 live entry, what is not selected is masked after the score, and costs what
 the dense pass costs (gathered it loses by three).  ``selection_reads``
-counts what the two bring, ``rows_taken`` the rows the kernel attended.
+counts what the two bring, ``rows_taken`` the rows the kernel attended
+(for a trunk: ``selection_counts`` and ``latent_counts``).
 
 Scopes (children of ``latent_attention``): ``kv_write``; ``rows_kernel`` (the
 kernel's call) or, in the loops, ``kv_gather``, ``scores`` (QK^T, mask, the
@@ -60,8 +61,8 @@ import jax.numpy as jnp
 from ..device import on_tpu
 from .pallas.latent_rows import _NEG, latent_rows, tile_tokens
 
-__all__ = ["latent_attention", "rope_half", "token_coords", "write_entries", "Selection",
-           "selection_reads", "rows_in_kernel", "rows_taken"]
+__all__ = ["latent_attention", "latent_counts", "selection_counts", "rope_half", "token_coords",
+           "write_entries", "Selection", "selection_reads", "rows_in_kernel", "rows_taken"]
 
 _TABLE_WORDS = 1 << 17   # block-table entries a kernel holds in SMEM (half of it)
 
@@ -194,6 +195,34 @@ def selection_reads(seq_lens_decoder, seq_lens_this_time, *, topk: int, gathered
     return (ones + chunks).astype(jnp.int32)
 
 
+def _rows(q_dtype, pool, bt, heads: int, rank: int) -> bool:
+    """``rows_in_kernel`` asked with what the POOL says of itself: the ONE
+    place the question is put, for the call and for the two counts below."""
+    return rows_in_kernel(q_dtype, pool.dtype, heads=heads, width=pool.shape[-1], rank=rank,
+                          block_size=pool.shape[-2], rows=bt.shape[0],
+                          blocks_per_seq=bt.shape[1])
+
+
+def latent_counts(q_dtype, pool, now, bt, *, heads: int, rank: int, selected: bool = False):
+    """For a trunk's ``counts``: the rows of ONE layer's ``latent_attention`` call
+    that attended in the kernel (``rows_taken``), the kernel asked as the call asks
+    it.  ``pool``: a layer's; ``selected``: whether the call is given a ``selection``."""
+    ones, chunks = rows_taken(now, selected=selected,
+                              kernel=_rows(q_dtype, pool, bt, heads, rank))
+    return {"latent_rows_kernel": ones, "latent_chunks_kernel": chunks}
+
+
+def selection_counts(q_dtype, pool, dec, now, bt, selection: Selection, *, heads: int,
+                     rank: int, topk: int, max_q_len: int):
+    """What ONE layer's ``latent_attention(selection=)`` call brought for its sparse
+    queries (``selection_reads``), asked likewise.  (Not in ``latent_counts``: a trunk
+    counts its rows before its layers, a selection once its first layer has made one.)"""
+    return {"dsa_positions_read": selection_reads(
+        dec, now, topk=topk, gathered=selection.idx.shape[1], block_size=pool.shape[-2],
+        blocks_per_seq=bt.shape[1], kernel=_rows(q_dtype, pool, bt, heads, rank),
+        max_q_len=max_q_len)}
+
+
 @jax.named_scope("latent_attention")
 def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
                      cu_seqlens_q, block_tables, *, rank: int, max_q_len: int,
@@ -272,8 +301,7 @@ def latent_attention(q, entries, cache, seq_lens_decoder, seq_lens_this_time,
         return (jnp.full((B, H), _NEG, jnp.float32), jnp.zeros((B, H), jnp.float32),
                 jnp.zeros((B, H, C), jnp.float32))
 
-    kernel = rows_in_kernel(q.dtype, cdt, heads=H, width=W, rank=C, block_size=bs,
-                            rows=B, blocks_per_seq=P)
+    kernel = _rows(q.dtype, cache, block_tables, H, C)
     if selection is None and kernel:
         return _rows_kernel(None, now > 0), cache
     if selection is None:

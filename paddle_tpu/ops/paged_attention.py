@@ -58,7 +58,8 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   the reference kernel. An int8 block is gathered as its integers and the
   scales are applied to the products, which is exact.
   ``attention_positions`` counts what a call had to attend, what it read
-  for that and the rows the kernel took; ``ServingEngine`` adds them up
+  for that and the rows the kernel took; a trunk takes them from
+  ``paged_counts`` and ``ServingEngine`` adds them up
   (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``).
 - The write, and why ONE layout still stands. Where ``writes_in_kernel``
   admits the call (the TPU, an unquantised bfloat16 pool whose rows are whole
@@ -118,7 +119,7 @@ from .latent_attention import _NEG, _TABLE_WORDS, _online
 from .pallas.paged_decode import paged_decode
 from .pallas.paged_write import PIECE, paged_write
 
-__all__ = ["blha_attention", "attention_positions", "decodes_in_kernel",
+__all__ = ["blha_attention", "paged_counts", "attention_positions", "decodes_in_kernel",
            "cache_write_counts", "writes_in_kernel", "lane_packing",
            "build_padding_metadata", "rope_rotate"]
 
@@ -286,6 +287,31 @@ def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
             jnp.sum(one & kernel).astype(jnp.int32))
 
 
+def _kernels(q_dtype, pool, bt, tokens: int, plain: bool):
+    """(``decodes_in_kernel``, ``writes_in_kernel``) asked with what the POOL says of
+    itself (its rows, their width, the block size; a stacked pool's layer axis is not
+    read): the ONE place the questions are put, for the call and for ``paged_counts``."""
+    kv_rows, bs, lanes = pool.shape[-3:]
+    sizes = dict(head_dim=lanes, block_size=bs, rows=bt.shape[0], blocks_per_seq=bt.shape[1])
+    return (decodes_in_kernel(q_dtype, pool.dtype, plain=plain, **sizes),
+            writes_in_kernel(pool.dtype, tokens=tokens, kv_heads=kv_rows, **sizes))
+
+
+def paged_counts(q_dtype, key_pool, dec, now, cu, bt, *, tokens: int, plain: bool = True):
+    """What ONE cache layer's ``blha_attention`` call did, for a trunk's ``counts``:
+    ``attention_positions``'s three and ``cache_write_counts``'s two, the kernels
+    asked as the call asks them.  ``q_dtype``: the queries' (``compute_dtype``);
+    ``key_pool``: a layer's key pool as the trunk holds it (plain, lane-packed or
+    stacked); ``tokens``: the packed buffer's; ``plain``: as ``decodes_in_kernel``'s."""
+    decodes, writes = _kernels(q_dtype, key_pool, bt, tokens, plain)
+    live, read, in_kernel = attention_positions(
+        dec, now, block_size=key_pool.shape[-2], blocks_per_seq=bt.shape[1], kernel=decodes)
+    written, pieces = cache_write_counts(dec, now, cu, kernel=writes)
+    return {"attn_positions_live": live, "attn_positions_read": read,
+            "attn_rows_kernel": in_kernel, "kv_write_tokens": written,
+            "kv_write_blocks": pieces}
+
+
 def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
     """``mask`` (rows in prefill) and ``tgt_mask`` (decoder rows), each
     [B, 1|H, Sq, Lm] additive with the key axis aligned at column 0 (a
@@ -308,7 +334,7 @@ def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
 
 def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
                        block_tables, *, max_q_len: int, scale: float, quant: bool,
-                       k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask):
+                       k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask, in_kernel: bool):
     """Steps 6-8 of ``blha_attention``: q [T, H, D] and this step's k, v
     [T, KV, D] against the pool, which already holds them. Returns
     [T, H, D] float32, zeros for tokens of no live row.  (Over a pool that
@@ -434,9 +460,7 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
             0, _trips(jnp.sum(now == 1).astype(jnp.int32), _ROW_TILE), tile,
             jnp.zeros((T + S, H, D), jnp.float32))
 
-    if decodes_in_kernel(q.dtype, key_cache.dtype, head_dim=D, block_size=bs,
-                         rows=B, blocks_per_seq=P,
-                         plain=not quant and bias is None and pre_k is None):
+    if in_kernel:
         # straight from the pool, which the write has already given this
         # step's token in the value the XLA pass attends from registers
         # (``fresh_dt``): positions [0, dec], no gathered copy, a row's own trips
@@ -669,9 +693,10 @@ def blha_attention(
             cache_k_dequant_scales, cache_v_dequant_scales = new_kd, new_vd
 
         # ---- 5. K/V into the block pool -------------------------------------
-        by_row = cache_quant == "none" and writes_in_kernel(
-            key_cache.dtype, head_dim=D * pack, block_size=bs, rows=B,
-            blocks_per_seq=block_tables.shape[1], tokens=T, kv_heads=KV // pack)
+        decodes, by_row = _kernels(
+            q.dtype, key_cache, block_tables, T, plain=cache_quant == "none"
+            and mask is None and tgt_mask is None and pre_key_cache is None)
+        by_row = by_row and cache_quant == "none"
         if not by_row:      # the scatter's coordinates, where the parent had them
             nb = key_cache.shape[0]
             blk = block_tables[b_idx, jnp.clip(abs_pos // bs, 0, block_tables.shape[1] - 1)]
@@ -739,7 +764,7 @@ def blha_attention(
         block_tables, max_q_len=max_q_len, scale=1.0 / (D ** 0.5), quant=quant,
         k_dequant=cache_k_dequant_scales, v_dequant=cache_v_dequant_scales,
         pre_k=pre_key_cache, pre_v=pre_value_cache, mask=mask,
-        tgt_mask=tgt_mask)
+        tgt_mask=tgt_mask, in_kernel=decodes)
     if pack > 1:
         out = jnp.sum(jnp.where(own, out.reshape(T, H, pack, D), 0), axis=2)
     out = out.reshape(T, H * D)

@@ -1,5 +1,5 @@
 """Grouped matmul for the held experts — Pallas TPU kernel that multiplies
-row tiles by the expert matrix each belongs to (``models/pangu_moe.py``
+row tiles by the expert matrix each belongs to (``ops/held_experts.py``
 chooses the calls that take it).
 
 ``x`` [tiles * row_tile, K] holds the picks sorted by expert, every expert's

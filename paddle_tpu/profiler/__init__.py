@@ -23,20 +23,20 @@ it feeds prompt chunks),
 ``engine.wait`` (stats ``reads``: the blocking reads it made of copies started
 at the dispatch, 1, and one more where a scheduled row asked for
 log-probabilities), ``engine.harvest`` (stats: what the model's trunk counted
-in the launch, ``attn_positions_live`` / ``attn_positions_read`` /
-``attn_rows_kernel`` and the cache write's ``kv_write_tokens`` /
-``kv_write_blocks`` for a dense paged cache, ``moe_tokens`` /
-``moe_local_picks`` / ``expert_rows_grouped`` (the picks that went through
-the grouped product, ops/pallas/expert_gmm.py; 0 where the tile loop ran) for
-expert layers, ``loop_tokens`` /
-``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes run,
-``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected`` /
-``dsa_positions_read`` for learned sparse attention: ONE layer's, over the
-live queries whose context exceeds the model's ``index_topk``, beside
-``attn_positions_live``, the context of every row fed; ``latent_rows_kernel``
-/ ``latent_chunks_kernel`` for a latent cache: the one-token rows and the
-chunk rows an iteration whose blocked pass ran in the ``latent_rows`` kernel,
-ops/pallas/latent_rows.py; 0 where the XLA loops ran);
+in the launch, each from the op that did it: ``paged_counts``'s five for a
+dense paged cache (ops/paged_attention.py: ``attn_positions_live`` /
+``attn_positions_read`` / ``attn_rows_kernel``, ``kv_write_tokens`` /
+``kv_write_blocks``); ``moe_tokens`` / ``moe_local_picks`` (``_moe_ffn``) and
+``expert_rows_grouped`` (ops/held_experts.py: the picks that went through the
+grouped product; 0 where the tile loop ran) for expert layers; ``loop_tokens``
+/ ``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes
+run; ``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected``
+(ops/sparse_index.py) / ``dsa_positions_read`` (``selection_counts``) for
+learned sparse attention: ONE layer's, over the live queries whose context
+exceeds the model's ``index_topk``, beside ``attn_positions_live``;
+``latent_rows_kernel`` / ``latent_chunks_kernel`` (``latent_counts``) for a
+latent cache: the one-token and the chunk rows an iteration whose blocked pass
+ran in the ``latent_rows`` kernel; 0 where the XLA loops ran);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Set-up spans, every one a ``SetupSpan``: a ``RecordEvent`` that also leaves a

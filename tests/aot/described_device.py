@@ -23,7 +23,7 @@ V5E_BYTES_LIMIT = 16_909_336_064
 # ``paddle_tpu`` whose ``on_tpu()`` chooses a branch of a TRACED program
 # (``device`` itself for ``nn/functional/flash_attention.py``'s call-time
 # import) ...
-STEERED = ("device", "models.pangu_moe", "ops.latent_attention", "ops.paged_attention",
+STEERED = ("device", "ops.held_experts", "ops.latent_attention", "ops.paged_attention",
            "ops.pallas.flash_attention", "ops.pallas.fused_norm",
            "ops.pallas.fused_ops", "ops.pallas.int8_matmul")
 # ... and those that hold the name and are left alone, each with its reason

@@ -26,6 +26,29 @@ def test_every_module_that_asks_on_tpu_is_steered_or_left_alone_with_a_reason():
     assert asking == set(STEERED) | set(LEFT_ALONE)
 
 
+def test_no_model_file_chooses_a_kernel():
+    """Which implementation of an op a call takes is the op's to decide
+    (``paddle_tpu/ops/``): no file under ``paddle_tpu/models/`` imports or
+    names ``on_tpu`` or a gate (``*_in_kernel``).  Read off the syntax trees: a
+    docstring may still say which gate an op asks."""
+    import ast
+    import pathlib
+
+    import paddle_tpu
+
+    def chooses(name):
+        return name == "on_tpu" or name.endswith("_in_kernel")
+
+    found = []
+    for f in sorted((pathlib.Path(paddle_tpu.__file__).parent / "models").rglob("*.py")):
+        for node in ast.walk(ast.parse(f.read_text())):
+            names = ([a.name for a in node.names] if isinstance(node, ast.ImportFrom)
+                     else [node.id] if isinstance(node, ast.Name)
+                     else [node.attr] if isinstance(node, ast.Attribute) else [])
+            found += [(f.name, node.lineno, n) for n in names if chooses(n)]
+    assert not found, found
+
+
 # (bh, kv_rep, seq, causal); "cell" is mistral7b.train.pretrain-2k's call
 # (4 x 32 heads over 8 KV heads), "ring" the non-causal block that ring
 # attention runs off the diagonal
@@ -176,7 +199,7 @@ def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
     (the compile refuses otherwise).  (That the mixed scan, the largest program
     of the window, is no larger than it was with the loop is a line of each
     family's own ``mixed_K8`` case.)"""
-    from paddle_tpu.models import pangu_moe
+    from paddle_tpu.ops import held_experts as he
     from paddle_tpu.ops.pallas import expert_gmm
 
     n_held, E, F, k = EXPERT_LAYERS[family]
@@ -185,7 +208,7 @@ def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
 
     def layer(x, idx, w, eg, eu, ed, valid):
         counts = {"expert_rows_grouped": jnp.zeros((), jnp.int32)}
-        y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts)
+        y, picks = he.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts)
         return y, picks, counts
 
     compiled = compile_kernel(
@@ -200,7 +223,7 @@ def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
             and not re.search(r"\] (parameter|get-tuple-element)\(", line)
             and " parameter(" not in line and " get-tuple-element(" not in line]
     assert not made, made           # a loop's carry hands the stacks on; nothing makes one
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pangu_moe._CHUNK_BYTES * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * he._CHUNK_BYTES * 4
     assert expert_gmm.VMEM_LIMIT <= 100 << 20       # of a v5e core's 128 MiB
 
 

@@ -7,9 +7,7 @@ and its COW fork), the exact selection with its ties, the routing, the expert
 layer's share of a deployment, the YaRN table, the typed refusals, the names
 in the compiled programs, the counters; all held to the plain float32
 reference (benchmark/references/mla_dsa_moe.py), which shares nothing with
-the program.  And the programs of the three families that were there before
-it lower to the text the parent commit lowers them to."""
-import hashlib
+the program."""
 import math
 
 import numpy as np
@@ -23,34 +21,19 @@ from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine, ServingFrontend
 from paddle_tpu.models import (DeepseekV32Config, DeepseekV32ForCausalLM, LlamaForCausalLM,
                                deepseek_v32, deepseek_v32_tiny, llama_tiny, pangu_moe)
+from paddle_tpu.ops.held_experts import held_experts
 from paddle_tpu.ops.latent_attention import (Selection, latent_attention, selection_reads,
                                                token_coords)
 from paddle_tpu.ops.sparse_index import index_scores, select_topk, sparse_index
 
 from benchmark.harness import loader
 
-import test_ouro
+import programs
+from programs import ENGINE
 
 FAMILY = loader.load_module("families", "mla_dsa_moe")
 REFERENCE = loader.load_module("references", "mla_dsa_moe")
-
-# a share of a deployment: 16 routed experts a layer in 4 groups of which 2
-# stay, this chip holds [4, 12); a selection of 8 positions; YaRN by 4 over 16
-YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 4, "mscale": 1, "mscale_all_dim": 1,
-        "original_max_position_embeddings": 16, "type": "yarn"}
-TINY = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
-    num_hidden_layers=3, first_k_dense_replace=1, moe_layer_freq=1, num_attention_heads=4,
-    num_key_value_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-    qk_rope_head_dim=8, v_head_dim=8, index_n_heads=4, index_head_dim=16, index_topk=8,
-    n_routed_experts=8, router_outputs=16, experts_held=[4, 12], n_shared_experts=1,
-    num_experts_per_tok=4, n_group=4, topk_group=2, norm_topk_prob=True,
-    routed_scaling_factor=2.5, scoring_func="sigmoid", topk_method="noaux_tc",
-    num_nextn_predict_layers=0, max_position_embeddings=256, rms_norm_eps=1e-6,
-    rope_theta=10000.0, rope_scaling=YARN, tie_word_embeddings=False,
-    attention_bias=False, hidden_act="silu", ep_size=1, model_type="deepseek_v32",
-    torch_dtype="float32")
-ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
+TINY = programs.TINY["deepseek"]
 
 # A float32 engine and the float32 reference differ by the order of their
 # sums alone: the absorbed scores against the expanded ones, a blocked softmax
@@ -69,11 +52,7 @@ def _no_fleet_group():
 
 
 def _build(cfg=TINY, seed=7):
-    weights = FAMILY.make_weights(cfg, seed)
-    model = FAMILY.build_model(cfg)
-    FAMILY.assign(model, weights)
-    model.eval()
-    return model, weights
+    return programs.build("deepseek", cfg, seed)
 
 
 @pytest.fixture(scope="module")
@@ -504,7 +483,7 @@ def test_the_share_adds_up():
     parts, picks = [], 0
     for lo in (0, 4, 8, 12):
         pidx, pw = deepseek_v32._router_of(cfg, p)(x, p["router"], 4, 2.5)
-        y, n = pangu_moe.held_experts(x, pidx, pw, p["eg"][lo:lo + 4], p["eu"][lo:lo + 4],
+        y, n = held_experts(x, pidx, pw, p["eg"][lo:lo + 4], p["eu"][lo:lo + 4],
                                       p["ed"][lo:lo + 4], lo, tile=8)
         parts.append(np.asarray(y))
         picks += int(n)
@@ -561,7 +540,7 @@ SCOPES = ("embed", "norm", "latent_proj", "indexer", "indexer/index_proj",
 
 @pytest.fixture(scope="module")
 def dsa_texts(built):
-    return test_ouro._lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
+    return programs.lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
 
 
 @pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
@@ -583,7 +562,7 @@ def test_lowered_program_names_the_scopes(dsa_texts, kind):
 def test_the_selections_counters_are_monotone_and_ride_the_harvest_span(built):
     model, _ = built
     eng = ServingEngine(model, **ENGINE)
-    harvests = test_ouro._harvests(eng)
+    harvests = programs.harvests(eng)
     names = ("dsa_queries", "dsa_positions_scored", "dsa_positions_selected",
              "dsa_positions_read", "moe_tokens", "moe_local_picks")
     assert all(getattr(eng, n) == 0 for n in names)
@@ -623,34 +602,3 @@ def test_a_model_without_an_indexer_counts_no_selection():
     eng.run()
     assert eng.state_summary()["sparse_attention"] == {
         "queries": 0, "positions_scored": 0, "positions_selected": 0, "positions_read": 0}
-
-
-# ------------------------------------- the families that were there before
-# sha256 (first 16 hex digits) of each program's lowered text, tiny geometry,
-# jax 0.9.0: what PR 32 touched of the shared code (``_latent_proj``'s
-# ``c_q=``, ``_moe_ffn``'s ``router=``, the configs' shared base,
-# ``latent_attention``'s ``selection=`` and its ``token_coords`` /
-# ``write_entries`` / ``_trips``, the causal LM's classes, the engine's four
-# new counters) left the other three families' programs byte for byte what
-# they were.  The twelve texts are PR 35's, not a parent's: that PR moved
-# every one on purpose (a launch's control rows cross as ONE ``int32`` block
-# that each program slices first thing, and what the host reads comes back
-# as ONE; the ``ouro`` row was 43988f27 / 9a221a04 / 63d77585 / 9cb418a3),
-# so they guard what comes AFTER it.  The ``llama`` and ``pangu`` rows are
-# tests/test_ouro.py's.
-PARENT_TEXTS = dict(test_ouro.PARENT_TEXTS, ouro={
-    "step": "0aa019dc9c4bf168", "mega": "f386f52fe27ce8f1",
-    "mixed": "9d7b11f2acd8d537", "spec": "06ca74df7df2cf6b"})
-
-
-@pytest.mark.skipif(jax.__version__ != "0.9.0", reason="the texts are jax 0.9.0's")
-@pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
-@pytest.mark.parametrize("family", ["llama", "pangu", "ouro"])
-def test_the_other_families_programs_lower_to_the_parents_text(family, kind):
-    P.seed(0)
-    model = {"llama": lambda: LlamaForCausalLM(llama_tiny()).eval(),
-             "pangu": test_ouro._pangu_tiny,
-             "ouro": lambda: test_ouro._build()[0]}[family]()
-    text = test_ouro._lowered(ServingEngine(model, spec_k=2, **ENGINE), debug_info=False,
-                              kinds=(kind,))[kind]
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_TEXTS[family][kind]

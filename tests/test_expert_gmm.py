@@ -1,4 +1,4 @@
-"""The expert layer's two forms (models/pangu_moe.py ``held_experts``): the
+"""The expert layer's two forms (ops/held_experts.py ``held_experts``): the
 tile loop, and the grouped product whose three matmuls are the Pallas kernel
 ``expert_gmm`` (ops/pallas/expert_gmm.py), here in interpret mode as
 tests/test_paged_attention.py runs ``paged_decode``.  Each against the plain
@@ -13,10 +13,10 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference import ServingEngine, serving
 from paddle_tpu.models import pangu_moe
+from paddle_tpu.ops import held_experts as he
 from paddle_tpu.ops.pallas import expert_gmm as gmm_module
 
-import test_lfm2_moe
-import test_ouro
+import programs
 
 FORMS = ["loop", "grouped"]
 COUNTS = ("experts_touched", "expert_tile_rows", "expert_tile_rows_live",
@@ -57,15 +57,15 @@ def run(monkeypatch):
         counts = {n: jnp.zeros((), jnp.int32) for n in COUNTS}
         with monkeypatch.context() as m:
             if form == "grouped":
-                m.setattr(pangu_moe, "on_tpu", lambda: True)
-                m.setattr(pangu_moe, "expert_gmm",
+                m.setattr(he, "on_tpu", lambda: True)
+                m.setattr(he, "expert_gmm",
                           functools.partial(gmm_module.expert_gmm, interpret=True))
-                m.setattr(pangu_moe, "_ROW_TILE", TILE if x.dtype == jnp.float32 else 16)
-                m.setattr(pangu_moe, "grouped_experts", functools.partial(
-                    pangu_moe.grouped_experts, row_tile=pangu_moe._ROW_TILE))
+                m.setattr(he, "_ROW_TILE", TILE if x.dtype == jnp.float32 else 16)
+                m.setattr(he, "grouped_experts", functools.partial(
+                    he.grouped_experts, row_tile=he._ROW_TILE))
                 if x.dtype == jnp.float32:
-                    m.setattr(pangu_moe, "groups_in_kernel", lambda *a, **k: True)
-            y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, lo, valid,
+                    m.setattr(he, "groups_in_kernel", lambda *a, **k: True)
+            y, picks = he.held_experts(x, idx, w, eg, eu, ed, lo, valid,
                                               tile=TILE, counts=counts)
         return np.asarray(y), int(picks), {n: int(v) for n, v in counts.items()}
     return run
@@ -109,7 +109,7 @@ def test_no_pick_is_dropped_when_every_token_picks_one_expert(run, form):
     idx = jnp.full((40, 1), 5, jnp.int32)
     w = jnp.full((40, 1), 0.5, jnp.float32)
     y, picks, counts = run(form, x, idx, w, eg, eu, ed, 4)
-    want = 0.5 * np.asarray(pangu_moe._swiglu(x, eg[1], eu[1], ed[1]))
+    want = 0.5 * np.asarray(he._swiglu(x, eg[1], eu[1], ed[1]))
     assert picks == 40 and np.abs(y - want).max() < 1e-5
     _check_counts(form, counts, [0, 40])
     none, picks, counts = run(form, x, idx, w, eg, eu, ed, 8)
@@ -186,18 +186,18 @@ def test_bfloat16_operands_stay_within_their_tolerance(run, form):
 
 
 def test_admission_is_decided_from_what_the_call_shows(monkeypatch):
-    ask = functools.partial(pangu_moe.groups_in_kernel, hidden=2048, width=1536, rows=4096)
+    ask = functools.partial(he.groups_in_kernel, hidden=2048, width=1536, rows=4096)
     assert not ask(jnp.bfloat16, jnp.bfloat16)                  # the CPU
-    monkeypatch.setattr(pangu_moe, "on_tpu", lambda: True)
+    monkeypatch.setattr(he, "on_tpu", lambda: True)
     assert ask(jnp.bfloat16, jnp.bfloat16)
     assert not ask(jnp.float32, jnp.float32) and not ask(jnp.bfloat16, jnp.float32)
-    assert not pangu_moe.groups_in_kernel(jnp.bfloat16, jnp.bfloat16, hidden=64, width=32,
+    assert not he.groups_in_kernel(jnp.bfloat16, jnp.bfloat16, hidden=64, width=32,
                                           rows=256)             # the tiny geometries
-    assert not pangu_moe.groups_in_kernel(jnp.bfloat16, jnp.bfloat16, hidden=2048,
+    assert not he.groups_in_kernel(jnp.bfloat16, jnp.bfloat16, hidden=2048,
                                           width=1536, rows=1 << 20)
     # a chunk is the tiles that hold the picks whatever the routing, or 16 MiB of rows
-    assert pangu_moe._chunk_tiles(2048, 64, 4096, 32, 16 << 20) == (128, 128)
-    assert pangu_moe._chunk_tiles(4096, 16, 15360, 32, 16 << 20) == (144, 34)
+    assert he._chunk_tiles(2048, 64, 4096, 32, 16 << 20) == (128, 128)
+    assert he._chunk_tiles(4096, 16, 15360, 32, 16 << 20) == (144, 34)
 
 
 # ------------------------------------------------------------- the kernel
@@ -254,16 +254,16 @@ def test_an_engine_steered_onto_the_chip_groups_every_pick(monkeypatch):
     pick on a held expert goes through the grouped product:
     ``expert_rows_grouped == moe_local_picks``, on the harvest spans and in
     ``state_summary()``; the unsteered engine counts the same picks and 0."""
-    cfg = dict(test_lfm2_moe.TINY, hidden_size=128, intermediate_size=128,
+    cfg = dict(programs.TINY["lfm2"], hidden_size=128, intermediate_size=128,
                moe_intermediate_size=128, num_hidden_layers=3,
-               layer_types=test_lfm2_moe.TYPES[:3], num_dense_layers=1,
+               layer_types=programs.LFM2_TYPES[:3], num_dense_layers=1,
                torch_dtype="bfloat16")
-    model, _ = test_lfm2_moe._build(cfg)
-    prompts = test_lfm2_moe._prompts([11, 5])
+    model, _ = programs.build("lfm2", cfg)
+    prompts = programs.prompts([11, 5])
 
     def served():
-        eng = ServingEngine(model, **test_lfm2_moe.ENGINE)
-        harvests = test_ouro._harvests(eng)
+        eng = ServingEngine(model, **programs.ENGINE)
+        harvests = programs.harvests(eng)
         for p in prompts:
             eng.add_request(p, max_new_tokens=5)
         eng.run()
@@ -273,18 +273,18 @@ def test_an_engine_steered_onto_the_chip_groups_every_pick(monkeypatch):
     assert plain.moe_local_picks > 0 == plain.expert_rows_grouped
     assert all(a["expert_rows_grouped"] == 0 for a in seen)
     monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
-    monkeypatch.setattr(pangu_moe, "on_tpu", lambda: True)
-    monkeypatch.setattr(pangu_moe, "expert_gmm",
+    monkeypatch.setattr(he, "on_tpu", lambda: True)
+    monkeypatch.setattr(he, "expert_gmm",
                         functools.partial(gmm_module.expert_gmm, interpret=True))
-    pangu_moe._held_experts.clear_cache()
+    he._held_experts.clear_cache()
     try:
         eng, seen = served()
     finally:
-        pangu_moe._held_experts.clear_cache()
+        he._held_experts.clear_cache()
     assert eng.moe_tokens == plain.moe_tokens
     assert eng.expert_rows_grouped == eng.moe_local_picks == eng.expert_tile_rows_live > 0
     assert sum(a["expert_rows_grouped"] for a in seen) == eng.expert_rows_grouped
-    assert eng.expert_tile_rows % pangu_moe._ROW_TILE == 0
+    assert eng.expert_tile_rows % he._ROW_TILE == 0
     assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
                                           "local_picks": eng.moe_local_picks,
                                           "rows_grouped": eng.expert_rows_grouped}

@@ -170,15 +170,11 @@ def test_a_call_the_kernel_does_not_admit_lowers_to_the_parents_text(
 def _tiny(family):
     """A bf16 latent model the kernel admits: 16 heads over a latent of 128
     (entries stored 256 wide), blocks of 16."""
-    import test_deepseek_v32
-    import test_pangu_moe
+    import programs
 
-    module = {"pangu": test_pangu_moe, "deepseek": test_deepseek_v32}[family]
-    cfg = dict(module.TINY, torch_dtype="bfloat16", kv_lora_rank=128,
+    cfg = dict(programs.TINY[family], torch_dtype="bfloat16", kv_lora_rank=128,
                num_attention_heads=16, num_key_value_heads=16)
-    model = module.FAMILY.build_model(cfg)
-    module.FAMILY.assign(model, module.FAMILY.make_weights(cfg, 7))
-    return model.eval()
+    return programs.build(family, cfg)[0]
 
 
 @pytest.mark.parametrize("family", ["pangu", "deepseek"])

@@ -23,13 +23,12 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine
-from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 
-from benchmark.harness import loader
+import programs
+from programs import ENGINE
 
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                       "launch_block_parent.json")
-ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
 TRUNK_COUNTS = ("attn_positions_live", "attn_positions_read", "attn_rows_kernel",
                 "kv_write_tokens", "kv_write_blocks", "moe_tokens", "moe_local_picks",
                 "loop_tokens", "loop_token_passes", "dsa_queries", "dsa_positions_scored",
@@ -54,30 +53,9 @@ class FakeClock:
         return self.t
 
 
-def _family_model(family, cfg):
-    module = loader.load_module("families", family)
-    model = module.build_model(cfg)
-    module.assign(model, module.make_weights(cfg, 7))
-    return model.eval()
-
-
 def _model(name):
-    """The sub-tiny model of each family, as its own test file builds it."""
-    set_hybrid_communicate_group(None)
-    P.seed(0)
-    if name in ("llama", "llama-int8", "llama-deadline"):
-        return LlamaForCausalLM(llama_tiny()).eval()
-    if name == "pangu":
-        fixture = os.path.join(loader.ROOT, "tests", "benchmark", "fixture_mla_moe")
-        return _family_model("mla_moe", loader.load_cell("tiny.mla-moe.docs",
-                                                         root=fixture).config)
-    if name == "ouro":
-        import test_ouro
-
-        return _family_model("looped_dense", test_ouro.TINY)
-    import test_deepseek_v32
-
-    return _family_model("mla_dsa_moe", test_deepseek_v32.TINY)
+    """The sub-tiny model of a scenario's family: the one whose programs are pinned."""
+    return programs.pinned_model("deepseek" if name == "dsv32" else name.partition("-")[0])
 
 
 SCENARIOS = ("llama", "llama-int8", "llama-deadline", "pangu", "ouro", "dsv32")
@@ -266,9 +244,7 @@ def test_a_result_block_carries_its_layout_through_jit():
 # ------------------------------------------------- the engine's own counters
 @pytest.fixture(scope="module")
 def llama():
-    set_hybrid_communicate_group(None)
-    P.seed(0)
-    return LlamaForCausalLM(llama_tiny()).eval()
+    return programs.pinned_model("llama")
 
 
 @pytest.mark.parametrize("logprobs", [False, True])

@@ -8,8 +8,7 @@ resumed after preemption, a row frozen inside a scan), ``short_conv`` alone
 on a packed buffer, the routing, the expert layer's shares, the typed
 refusals, the names in the compiled programs, the counters; all held to the
 plain float32 reference (benchmark/references/conv_gqa_moe.py), which shares
-nothing with the program.  And the four models that keep no such state have
-the cache specifications and program keys they had."""
+nothing with the program."""
 import re
 
 import numpy as np
@@ -28,27 +27,15 @@ from paddle_tpu.ops.short_conv import short_conv
 
 from benchmark.harness import loader
 
-import test_deepseek_v32
-import test_ouro
+import programs
+from programs import ENGINE
 
 FAMILY = loader.load_module("families", "conv_gqa_moe")
 REFERENCE = loader.load_module("references", "conv_gqa_moe")
-
-# two leading dense conv layers and one period of the pattern (attention,
-# conv, conv, conv): 5 conv layers keep state, 1 attention layer keeps blocks
-TYPES = ["conv", "conv", "full_attention", "conv", "conv", "conv", "full_attention", "conv"]
-TINY = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
-    num_hidden_layers=6, num_dense_layers=2, layer_types=TYPES, num_attention_heads=4,
-    num_key_value_heads=2, conv_L_cache=3, conv_bias=False, num_experts=8,
-    num_experts_per_tok=2, norm_topk_prob=True, use_expert_bias=True,
-    routed_scaling_factor=1, max_position_embeddings=256, norm_eps=1e-5,
-    rope_parameters={"rope_theta": 10000.0, "rope_type": "default"}, model_type="lfm2_moe",
-    torch_dtype="float32")
+TINY, TYPES = programs.TINY["lfm2"], programs.LFM2_TYPES
 # the same layers with heads of 64 (4 heads, 2 KV heads): ``lane_packing`` lays
 # the two KV heads side by side, a pool block ``[1, bs, 128]``
 PAIRED = dict(TINY, hidden_size=256)
-ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
 
 # A float32 engine and the float32 reference differ by the order of their
 # sums alone (a blocked online softmax against a whole one, experts added
@@ -67,11 +54,7 @@ def _no_fleet_group():
 
 
 def _build(cfg=TINY, seed=7):
-    weights = FAMILY.make_weights(cfg, seed)
-    model = FAMILY.build_model(cfg)
-    FAMILY.assign(model, weights)
-    model.eval()
-    return model, weights
+    return programs.build("lfm2", cfg, seed)
 
 
 @pytest.fixture(scope="module")
@@ -453,7 +436,7 @@ SCOPES = ("embed", "norm", "conv_proj", "short_conv", "conv_out", "attn_proj", "
 
 @pytest.fixture(scope="module")
 def lfm2_texts(built):
-    return test_ouro._lowered(ServingEngine(built[0], **ENGINE), debug_info=True,
+    return programs.lowered(ServingEngine(built[0], **ENGINE), debug_info=True,
                               kinds=("step", "mega", "mixed"))
 
 
@@ -469,7 +452,7 @@ def test_lowered_program_names_the_scopes(lfm2_texts, kind):
 def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     model, _ = built
     eng = ServingEngine(model, **ENGINE)
-    harvests = test_ouro._harvests(eng)
+    harvests = programs.harvests(eng)
     names = ("conv_rows_fed", "moe_tokens", "moe_local_picks", "experts_touched",
              "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped",
              "attn_positions_live", "kv_write_tokens", "attn_rows_kernel", "kv_write_blocks")
@@ -520,7 +503,7 @@ def test_an_engine_steered_onto_the_chip_sends_heads_of_64_through_both_kernels(
 
     def run():
         eng = ServingEngine(model, **dict(ENGINE, block_size=16, max_batch_size=2))
-        harvests = test_ouro._harvests(eng)
+        harvests = programs.harvests(eng)
         rids = [eng.add_request(p, max_new_tokens=9) for p in prompts]
         out = eng.run()
         return eng, [h[-1] for h in harvests], [out[r] for r in rids]
@@ -555,63 +538,3 @@ def test_a_model_without_state_a_slot_counts_none_and_has_none():
     assert eng.state_summary()["slot_state"] == {"arrays": [], "rows_fed": 0}
     assert eng.state_summary()["experts"] == {"touched": 0, "tile_rows": 0,
                                               "tile_rows_live": 0}
-
-
-# ------------------------------------- the models that were there before
-# (cache layers, the pool's arrays, state a slot, the engine's program key) of
-# each at its tiny geometry, recorded at the parent commit: state a slot added
-# nothing to what keys their programs, and their pools are what they were.
-# Their programs' lowered TEXT is held by
-# tests/test_deepseek_v32.py::test_the_other_families_programs_lower_to_the_parents_text.
-RECORDED = {
-    "llama": (2, ["k", "v"], (), (4, 32, 8, ("llama", 4, 4, 32, 128, 1e-06), "none", False, 8,
-                                  0, 12)),
-    "pangu": (3, ["latent"], (), (4, 32, 8, ("pangu_ultra_moe", 64, 4, 24, 16, 8, 8, 8, 16, 4,
-                                             (4, 8), 1, 2.5, 1e-05), "none", False, 8, 0, 12)),
-    "ouro": (12, ["k", "v"], (), (4, 32, 8, ("ouro", 4, 4, 16, 64, 3, 4, 1e-06), "none", False,
-                                  8, 0, 12)),
-    "deepseek": (3, ["latent", "index_k"], (), (
-        4, 32, 8, ("deepseek_v32", 64, 4, 24, 16, 8, 8, 8, 4, 16, 8, 16, 4, 4, 2, (4, 12), 1,
-                   2.5, 1e-06, 0.32411924819517657), "none", False, 8, 0, 12)),
-}
-
-
-@pytest.mark.parametrize("family", sorted(RECORDED))
-def test_the_older_models_specs_and_program_keys_are_the_recorded_ones(family):
-    P.seed(0)
-    model = {"llama": lambda: LlamaForCausalLM(llama_tiny()).eval(),
-             "pangu": test_ouro._pangu_tiny,
-             "ouro": lambda: test_ouro._build()[0],
-             "deepseek": lambda: test_deepseek_v32._build()[0]}[family]()
-    spec = model.serving_cache_spec()
-    eng = ServingEngine(model, **ENGINE)
-    assert (spec.layers, [n for n, _ in spec.arrays], spec.slot_state,
-            eng._program_key()) == RECORDED[family]
-    assert eng.slot_state == () and len(eng.program_caches()) == len(spec.arrays)
-
-
-# sha256 (first 16 hex digits) of DeepSeek-V3.2's four programs' lowered text,
-# tiny geometry, jax 0.9.0, taken at the PARENT commit (edbf1b5) and equal at
-# this one: the fourth family, which ``test_deepseek_v32.PARENT_TEXTS`` does
-# not hold, whose ``_moe_ffn`` (a shared expert present) and engine programs
-# this PR's optional shared expert and state a slot left byte for byte.
-# Pinned anew at PR 39 for the reason ``test_ouro.PARENT_TEXTS["pangu"]``
-# was (one more count, the expert layer one jitted function); the parent's
-# texts were 95a57733 / 5167fcff / d2dad7a5 / f9cb499e, and
-# tests/test_deepseek_v32.py holds each served token to the reference.
-# And again at PR 43 (the trunk counts ``latent_rows_kernel`` and
-# ``latent_chunks_kernel``; PR 39's texts were 4f98ecfc / 0684536a /
-# 9123fdc4 / 00bf59f8; ``latent_attention`` alone lowers to what it did:
-# tests/test_latent_rows_kernel.py).
-DEEPSEEK_TEXTS = {"step": "b53c7a835eae1d46", "mega": "31e7f9584b44305a",
-                  "mixed": "2c347e4856a2ab15", "spec": "417ddd62ff9c457c"}
-
-
-@pytest.mark.skipif(jax.__version__ != "0.9.0", reason="the texts are jax 0.9.0's")
-@pytest.mark.parametrize("kind", sorted(DEEPSEEK_TEXTS))
-def test_the_selected_attention_familys_programs_lower_to_the_parents_text(kind):
-    import hashlib
-
-    eng = ServingEngine(test_deepseek_v32._build()[0], spec_k=2, **test_deepseek_v32.ENGINE)
-    text = test_ouro._lowered(eng, debug_info=False, kinds=(kind,))[kind]
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == DEEPSEEK_TEXTS[kind]

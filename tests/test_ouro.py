@@ -5,9 +5,7 @@ the serving engine's rolled trunk over ONE paged pool with a layer axis
 cache and its COW fork, block export), the exit gate, the typed refusals, the
 names in the compiled programs, the counters; all held to the plain float32
 reference (benchmark/references/looped_dense.py), which shares nothing with
-the program.  And the programs of the two families that were there before it
-lower to the text the parent commit lowers them to."""
-import hashlib
+the program."""
 import re
 
 import numpy as np
@@ -19,23 +17,18 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine
-from paddle_tpu.inference.serving import control_layout
 from paddle_tpu.models import LlamaForCausalLM, OuroConfig, OuroForCausalLM, llama_tiny
 from paddle_tpu.models import ouro
 from paddle_tpu.ops.paged_attention import blha_attention
 
 from benchmark.harness import loader
 
+import programs
+from programs import ENGINE
+
 FAMILY = loader.load_module("families", "looped_dense")
 REFERENCE = loader.load_module("references", "looped_dense")
-
-TINY = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160, num_hidden_layers=3,
-    num_attention_heads=4, num_key_value_heads=4, head_dim=16,
-    max_position_embeddings=256, rms_norm_eps=1e-6, rope_theta=10000.0,
-    total_ut_steps=4, early_exit_threshold=1, tie_word_embeddings=False,
-    hidden_act="silu", model_type="ouro", torch_dtype="float32")
-ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
+TINY = programs.TINY["ouro"]
 
 # A float32 engine and the float32 reference differ by the order of their
 # sums alone (a blocked online softmax against a whole one, XLA's matmuls
@@ -51,11 +44,7 @@ def _no_fleet_group():
 
 
 def _build(cfg=TINY, seed=7):
-    weights = FAMILY.make_weights(cfg, seed)
-    model = FAMILY.build_model(cfg)
-    FAMILY.assign(model, weights)
-    model.eval()
-    return model, weights
+    return programs.build("ouro", cfg, seed)
 
 
 @pytest.fixture(scope="module")
@@ -326,27 +315,9 @@ SCOPES = ("embed", "loop_pass", "loop_pass/while/body", "norm", "attn_proj",
           "head", "sample")
 
 
-def _lowered(eng, debug_info, kinds=("step", "mega", "mixed", "spec")):
-    B, T, P_, C, K = eng.B, eng.T, eng.P, eng.pc, eng.megastep_k
-
-    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
-        return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
-
-    head = (eng._weights, eng.program_caches(), eng._rope)
-    low = {
-        "step": lambda: eng._step_fn.lower(*head, block("step", T), None, mq=T),
-        "mega": lambda: eng._build_megastep().lower(*head, block("mega"), None, K=K),
-        "mixed": lambda: eng._build_mixed_megastep().lower(
-            *head, block("mixed", K * C), None, K=K),
-        "spec": lambda: eng._build_spec_verify().lower(
-            *head, block("spec", eng.spec_k), None),
-    }
-    return {k: low[k]().as_text(debug_info=debug_info) for k in kinds}
-
-
 @pytest.fixture(scope="module")
 def ouro_texts(built):
-    return _lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
+    return programs.lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
 
 
 @pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
@@ -366,9 +337,9 @@ def test_the_program_does_not_grow_with_the_passes_or_the_depth(built, kind):
     all that differ)."""
     def text_of(**changed):
         model, _ = _build(dict(TINY, **changed))
-        return _lowered(ServingEngine(model, **ENGINE), False, kinds=(kind,))[kind]
+        return programs.lowered(ServingEngine(model, **ENGINE), False, kinds=(kind,))[kind]
 
-    four = _lowered(ServingEngine(built[0], **ENGINE), False, kinds=(kind,))[kind]
+    four = programs.lowered(ServingEngine(built[0], **ENGINE), False, kinds=(kind,))[kind]
     calls = len(re.findall(r"call @blha_attention", four))
     assert calls == 1
     for other in (text_of(total_ut_steps=2), text_of(num_hidden_layers=6)):
@@ -377,31 +348,10 @@ def test_the_program_does_not_grow_with_the_passes_or_the_depth(built, kind):
         assert other.count("stablehlo.dot_general") == four.count("stablehlo.dot_general")
 
 
-def _harvests(eng):
-    """[(kind of the launch, its attributes, the attributes of its
-    ``engine.harvest`` span)], filled as the engine runs."""
-    seen, launches = [], []
-    launch, phase = eng._launch_phase, eng._phase
-
-    def launched(kind, *a, **kw):
-        launches.append(kind)
-        return launch(kind, *a, **kw)
-
-    def entered(name, **attrs):
-        if name == "launch":
-            launches.append(attrs)
-        if name == "harvest":
-            seen.append((launches[-2], launches[-1], attrs))
-        return phase(name, **attrs)
-
-    eng._launch_phase, eng._phase = launched, entered
-    return seen
-
-
 def test_loop_counters_are_monotone_and_ride_the_spans(built):
     model, _ = built
     eng = ServingEngine(model, **ENGINE)
-    harvests = _harvests(eng)
+    harvests = programs.harvests(eng)
     assert (eng.loop_tokens, eng.loop_token_passes) == (0, 0)
     for p in _prompts([20, 9, 41]):
         eng.add_request(p, max_new_tokens=6)
@@ -436,66 +386,11 @@ def test_loop_counters_are_monotone_and_ride_the_spans(built):
 def test_a_model_of_one_pass_says_so():
     P.seed(0)
     eng = ServingEngine(LlamaForCausalLM(llama_tiny()).eval(), **ENGINE)
-    harvests = _harvests(eng)
+    harvests = programs.harvests(eng)
     eng.add_request([3, 17, 101], max_new_tokens=6)
     eng.run()
     assert eng.state_summary()["loop"] == {"passes": 1, "tokens": 0, "token_passes": 0}
     assert harvests and all(l["passes"] == 1 for _, l, _ in harvests)
-
-
-# ------------------------------------- the families that were there before
-# sha256 (first 16 hex digits) of each program's lowered text, tiny geometry,
-# jax 0.9.0: a change to a shared function that is meant to leave the other
-# families' programs alone (PR 30: ``blha_attention``'s ``layer=``, the
-# engine's pool, its COW copy) leaves these byte for byte what they were.
-# The ``llama`` row was pinned anew at PR 31: the dense trunk's ``counts``
-# gained ``kv_write_tokens`` and ``kv_write_blocks``.  BOTH rows are PR 35's:
-# that PR changed every program's signature on purpose (the fifteen control
-# arrays became ONE ``int32`` block sliced first thing under ``scan_carry``,
-# the tokens, masks and counts ONE result block), so the parent's texts
-# (ee99d684 / 659c864c / 5931fbbd / 0fbd7142 and 12f7caac / 46dd4ae2 /
-# f0e344e3 / f585eb67) could not stand; that the mathematics did is
-# tests/test_launch_block.py's, against the parent's recorded answers.
-# The ``pangu`` row was pinned anew at PR 39, which moved it on purpose: the
-# expert trunks count ``expert_rows_grouped`` (one more word in the result
-# block) and ``held_experts`` is a jitted function a program lowers once and
-# calls a layer (the tile loop within it a function of its own beside
-# ``grouped_experts``); the parent's texts were a208678c / 9bc0f7a2 /
-# edb208f4 / 986e9feb.  That the mathematics stood is tests/test_pangu_moe.py's
-# (each served token against the float32 reference) and
-# tests/test_expert_gmm.py's.  And again at PR 43: the latent trunks count
-# ``latent_rows_kernel`` and ``latent_chunks_kernel`` (two more words in the
-# result block; PR 39's texts were 4a257fd7 / 0e863ba2 / 85ad8f81 /
-# 63ed4e1e), while ``latent_attention`` ALONE lowers on the CPU to the text
-# it lowered to (tests/test_latent_rows_kernel.py holds that, case by case).
-# ``llama`` is PR 35's.
-PARENT_TEXTS = {
-    "llama": {"step": "779917ad04438754", "mega": "5f964e23d4c735de",
-              "mixed": "bb1f19f3e0b23bcc", "spec": "3f5eb174a4edd6ad"},
-    "pangu": {"step": "d5a0624f7a91285b", "mega": "653fcb103bd83e3d",
-              "mixed": "9f4ba6ba5f40ad5c", "spec": "10b438f9f8aa62a9"}}
-
-
-def _pangu_tiny():
-    """The sub-tiny openPangu of tests/benchmark/fixture_mla_moe."""
-    import os
-
-    fixture = os.path.join(loader.ROOT, "tests", "benchmark", "fixture_mla_moe")
-    cfg = loader.load_cell("tiny.mla-moe.docs", root=fixture).config
-    family = loader.load_module("families", "mla_moe")
-    model = family.build_model(cfg)
-    family.assign(model, family.make_weights(cfg, 7))
-    return model.eval()
-
-
-@pytest.mark.skipif(jax.__version__ != "0.9.0", reason="the texts are jax 0.9.0's")
-@pytest.mark.parametrize("family", ["llama", "pangu"])
-def test_the_other_families_programs_lower_to_the_parents_text(family):
-    P.seed(0)
-    model = LlamaForCausalLM(llama_tiny()).eval() if family == "llama" else _pangu_tiny()
-    texts = _lowered(ServingEngine(model, spec_k=2, **ENGINE), debug_info=False)
-    got = {k: hashlib.sha256(t.encode()).hexdigest()[:16] for k, t in texts.items()}
-    assert got == PARENT_TEXTS[family]
 
 
 def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypatch):

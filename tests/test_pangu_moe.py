@@ -18,30 +18,22 @@ import jax.numpy as jnp
 import paddle_tpu as P
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.inference import ServingEngine, ServingFrontend
-from paddle_tpu.inference.serving import SamplingParams, control_layout
+from paddle_tpu.inference.serving import SamplingParams
 from paddle_tpu.models import (LlamaForCausalLM, PanguUltraMoEConfig,
                                PanguUltraMoEForCausalLM, llama_tiny)
 from paddle_tpu.models import pangu_moe
+from paddle_tpu.ops.held_experts import _swiglu, held_experts
 from paddle_tpu.ops.latent_attention import latent_attention, rope_half
 
 from benchmark.harness import loader
 
+import programs
+from programs import ENGINE
+
 ROOT = loader.ROOT
 FAMILY = loader.load_module("families", "mla_moe")
 REFERENCE = loader.load_module("references", "mla_moe")
-
-# a share of a deployment: 16 routed experts a layer, this chip holds [4, 8)
-TINY = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
-    num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=4,
-    num_key_value_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-    qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=4, router_outputs=16,
-    experts_held=[4, 8], n_shared_experts=1, num_experts_per_tok=4, norm_topk_prob=True,
-    routed_scaling_factor=2.5, sandwich_norm=True, num_nextn_predict_layers=0,
-    max_position_embeddings=256, rms_norm_eps=1e-5, rope_theta=10000.0,
-    tie_word_embeddings=False, attention_bias=False, hidden_act="silu",
-    torch_dtype="float32")
-ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, megastep_k=4)
+TINY = programs.TINY["pangu"]
 
 # A float32 engine and the float32 reference differ by the order of their
 # sums alone: the absorbed scores against the expanded ones, a blocked
@@ -57,11 +49,7 @@ def _no_fleet_group():
 
 
 def _build(cfg=TINY, seed=7):
-    weights = FAMILY.make_weights(cfg, seed)
-    model = FAMILY.build_model(cfg)
-    FAMILY.assign(model, weights)
-    model.eval()
-    return model, weights
+    return programs.build("pangu", cfg, seed)
 
 
 @pytest.fixture(scope="module")
@@ -311,7 +299,7 @@ def test_the_share_adds_up():
     parts, picks = [], 0
     for lo in (0, 4, 8, 12):
         pidx, pw = pangu_moe.route(x, p["router"], 4, 2.5)
-        y, n = pangu_moe.held_experts(x, pidx, pw, p["eg"][lo:lo + 4], p["eu"][lo:lo + 4],
+        y, n = held_experts(x, pidx, pw, p["eg"][lo:lo + 4], p["eu"][lo:lo + 4],
                                       p["ed"][lo:lo + 4], lo, tile=8)
         parts.append(np.asarray(y))
         picks += int(n)
@@ -328,10 +316,10 @@ def test_no_pick_is_dropped_when_every_token_picks_one_expert():
     ed = jnp.asarray(rng.normal(size=(2, 8, 16)), jnp.float32)
     idx = jnp.full((40, 1), 5, jnp.int32)
     w = jnp.full((40, 1), 0.5, jnp.float32)
-    y, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 4, tile=16)
-    want = 0.5 * np.asarray(pangu_moe._swiglu(x, eg[1], eu[1], ed[1]))
+    y, picks = held_experts(x, idx, w, eg, eu, ed, 4, tile=16)
+    want = 0.5 * np.asarray(_swiglu(x, eg[1], eu[1], ed[1]))
     assert int(picks) == 40 and np.abs(np.asarray(y) - want).max() < 1e-5
-    none, picks = pangu_moe.held_experts(x, idx, w, eg, eu, ed, 8, tile=16)
+    none, picks = held_experts(x, idx, w, eg, eu, ed, 8, tile=16)
     assert int(picks) == 0 and not np.asarray(none).any()
 
 
@@ -370,25 +358,9 @@ SCOPES = ("embed", "norm", "latent_proj", "latent_attention", "latent_attention/
           "head", "sample")
 
 
-def _lowered(eng, debug_info):
-    B, T, P_, C, K = eng.B, eng.T, eng.P, eng.pc, eng.megastep_k
-
-    def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
-        return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
-
-    head = (eng._weights, eng.caches, eng._rope)
-    low = {
-        "step": eng._step_fn.lower(*head, block("step", T), None, mq=T),
-        "mega": eng._build_megastep().lower(*head, block("mega"), None, K=K),
-        "mixed": eng._build_mixed_megastep().lower(*head, block("mixed", K * C), None, K=K),
-        "spec": eng._build_spec_verify().lower(*head, block("spec", eng.spec_k), None),
-    }
-    return {k: v.as_text(debug_info=debug_info) for k, v in low.items()}
-
-
 @pytest.fixture(scope="module")
 def pangu_texts(built):
-    return _lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
+    return programs.lowered(ServingEngine(built[0], spec_k=2, **ENGINE), debug_info=True)
 
 
 @pytest.mark.parametrize("kind", ["step", "mega", "mixed", "spec"])
@@ -418,7 +390,7 @@ def llama_texts():
     model = LlamaForCausalLM(llama_tiny())
     model.eval()
     eng = ServingEngine(model, **GUARD_GEOMETRY)
-    return eng, _lowered(eng, debug_info=False)
+    return eng, programs.lowered(eng, debug_info=False)
 
 
 def _whole_table_shapes(text, B, P, bs, KV, D):

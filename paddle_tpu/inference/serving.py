@@ -109,6 +109,7 @@ import jax.numpy as jnp
 from ..profiler import SETUP, RecordEvent, SetupSpan
 from .faults import register_failpoint
 from .launch_block import Layout, ResultBlock
+from .serving_model import CacheKind
 
 __all__ = ["BlockManager", "ServingRequest", "ServingEngine",
            "SamplingParams", "prefix_block_hash", "prompt_block_hashes",
@@ -493,6 +494,11 @@ class ServingRequest:
     generated: List[int] = field(default_factory=list)
     logprob_values: List[float] = field(default_factory=list)
     blocks: List[int] = field(default_factory=list)
+    # a further kind of cache layer each (serving_model.py ``CacheSpec.kinds``):
+    # {table column: block} of that kind's pool, taken as the row moves and
+    # given back behind the kind's window; and the blocks reserved there
+    kind_blocks: List[Dict[int, int]] = field(default_factory=list)
+    kind_reserved: List[int] = field(default_factory=list)
     prefill_pos: int = 0          # prompt tokens already cached
     cached_prefix_tokens: int = 0  # of those, tokens REUSED from the cache
     chunks_fed: int = 0           # prompt chunks fed so far (trace index)
@@ -506,6 +512,12 @@ class ServingRequest:
     @property
     def context_len(self) -> int:
         return self.prefill_pos + len(self.generated)
+
+    @property
+    def cached_len(self) -> int:
+        """Positions whose keys and values are written: the newest sampled
+        token is fed (and cached) one step later."""
+        return self.context_len - (1 if self.generated else 0)
 
 
 # Process-wide cache of compiled serving programs, keyed by the static
@@ -552,13 +564,28 @@ class _Phase:
         return False
 
 
+def _table_names(tables: int) -> Tuple[str, ...]:
+    """The block tables' rows in a control block: ``bt``, then ``bt.1``, .. a
+    further kind of cache layer each."""
+    return ("bt",) + tuple(f"bt.{i}" for i in range(1, tables))
+
+
+def _tables_of(c: Dict, n: int):
+    """In a program: the unpacked control rows -> ``bt`` as the trunk takes it,
+    the one table or a tuple of them, a kind each."""
+    return c["bt"] if n == 1 else tuple(c[name] for name in _table_names(n))
+
+
 @lru_cache(maxsize=64)
-def control_layout(kind: str, B: int, P: int, n: int = 0) -> Layout:
+def control_layout(kind: str, B: int, P: int, n: int = 0, tables: int = 1) -> Layout:
     """The control rows a launch of ``kind`` sends up, in block order: the
     ``[B]`` scheduling rows, the five sampling rows, then the flattened
     tails (the block table, the mixed scan's prompt window).  ``n``: the
     packed token buffer's length (``step``), the prompt window's width
-    ``K x chunk`` (``mixed``), ``spec_k`` (``spec``).  The host packs by it
+    ``K x chunk`` (``mixed``), ``spec_k`` (``spec``).  ``tables``: the block
+    tables, one a KIND of cache layer (serving_model.py): a spec of one kind
+    has the one tail ``bt`` it always had, a further kind adds a tail
+    ``bt.<i>`` behind it, still in the ONE block.  The host packs by it
     and the program slices by it: static, from numbers both already key
     their shapes on."""
     rows = {
@@ -577,7 +604,8 @@ def control_layout(kind: str, B: int, P: int, n: int = 0) -> Layout:
     return Layout.of(
         *((name, (B,), "b" if name == "active" else "i") for name in rows),
         ("temps", (B,), "f"), ("top_ks", (B,)), ("top_ps", (B,), "f"),
-        ("seeds", (B,)), ("sample_pos", (B,)), *tails, ("bt", (B, P)))
+        ("seeds", (B,)), ("sample_pos", (B,)), *tails,
+        *((name, (B, P)) for name in _table_names(tables)))
 
 
 _BUILDERS = {"step": "_build_step", "mega": "_build_megastep",
@@ -624,7 +652,7 @@ class ServingEngine:
     @SetupSpan("engine.init")
     def __init__(self, model, max_batch_size: int = 4, max_seq_len: int = 256,
                  block_size: int = 16, token_budget: int = 32,
-                 num_blocks: Optional[int] = None, cache_dtype=None,
+                 num_blocks=None, cache_dtype=None,
                  cache_quant: str = "none", prefix_cache="auto",
                  megastep_k: int = 8, fault_injector=None,
                  capture_sample_probs: bool = False,
@@ -649,11 +677,23 @@ class ServingEngine:
         self.bs = int(block_size)
         self.P = (int(max_seq_len) + self.bs - 1) // self.bs  # blocks/seq
         self.max_seq_len = self.P * self.bs
-        nb = num_blocks if num_blocks is not None else self.B * self.P
-        self.blocks = BlockManager(int(nb))
         # the model says what a layer keeps in the pool (serving_model.py)
         spec = model.serving_cache_spec()
         self.cache_spec = spec
+        # a pool a KIND of cache layer (one, unless the spec names more):
+        # ``num_blocks`` a number for each of them, or {kind: number}
+        self.kinds = spec.kinds or (CacheKind("all", spec.layers),)
+        if isinstance(num_blocks, dict):
+            if set(num_blocks) - {k.name for k in self.kinds}:
+                raise ValueError(f"num_blocks names {sorted(num_blocks)}; the model's cache "
+                                 f"kinds are {[k.name for k in self.kinds]}")
+            sizes = [num_blocks.get(k.name) for k in self.kinds]
+        else:
+            sizes = [num_blocks] * len(self.kinds)
+        self.pools = [BlockManager(int(n if n is not None else self.B * self.P))
+                      for n in sizes]
+        self.blocks = self.pools[0]
+        nb = self.blocks.num_blocks
         self.KV, self.D = spec.kv_heads, spec.head_dim   # None: no per-head cache
         self.L = spec.layers      # CACHE layers: a looped model's are not its weights'
         if cache_quant not in ("none", "int8"):
@@ -674,14 +714,15 @@ class ServingEngine:
                 "scales — a second sequence sharing the block would "
                 "dequantize garbage. Use the unquantized cache with the "
                 "prefix cache, or pass prefix_cache=False")
-        if spec.slot_state and prefix_cache is True:
+        if not spec.blocks_are_positions and prefix_cache is True:
             raise ValueError(
                 f"prefix_cache cannot be used with {type(model).__name__}: "
                 + spec.why_not)
         # 'auto' = on wherever it is sound (everything but int8 and a model
-        # that keeps state a slot: adopted blocks carry no such state)
+        # whose blocks are not all its state: adopted blocks carry no state a
+        # slot, and a published prefix has lost its window layers' blocks)
         self.prefix_cache_enabled = (cache_quant != "int8"
-                                     and not spec.slot_state
+                                     and spec.blocks_are_positions
                                      and prefix_cache in ("auto", True))
         self.prefix_hit_blocks = 0      # full blocks reused from the cache
         self.prefix_miss_blocks = 0     # full prompt blocks that missed
@@ -724,10 +765,11 @@ class ServingEngine:
         self._cache_dtype = str(jnp.dtype(cache_dtype))
 
         def pool(shape):
-            block = (nb,) + tuple(shape(self.bs))
+            block = tuple(shape(self.bs))
             if spec.stacked:
-                return jnp.zeros((self.L,) + block, cache_dtype)
-            return [jnp.zeros(block, cache_dtype) for _ in range(self.L)]
+                return jnp.zeros((self.L, nb) + block, cache_dtype)
+            return [jnp.zeros((m.num_blocks,) + block, cache_dtype)
+                    for kind, m in zip(self.kinds, self.pools) for _ in range(kind.layers)]
 
         with SetupSpan("engine.init.pool") as span:
             self.caches = tuple(pool(shape) for _, shape in spec.arrays)
@@ -745,7 +787,14 @@ class ServingEngine:
             span.note(bytes=_tree_bytes((self.caches, self.cache_scales,
                                          self.slot_state)))
         self._setup_spans.append(span)
-        self.block_tables = np.full((self.B, self.P), -1, np.int32)
+        # a table a kind; ``block_tables`` is the first kind's
+        self.kind_tables = [np.full((self.B, self.P), -1, np.int32) for _ in self.kinds]
+        self.block_tables = self.kind_tables[0]
+        # further kinds: blocks reserved by admitted rows, blocks given back
+        # behind a window (monotone), times the queue's head waited on the pool
+        self.kind_reserved = [0] * len(self.kinds)
+        self.window_blocks_released = 0
+        self.admission_waits = [0] * len(self.kinds)
 
         # capture the renormalized post-top-k/top-p distribution each
         # drawn token was sampled from (ISSUE 11 satellite — speculative-
@@ -801,6 +850,10 @@ class ServingEngine:
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
+        # a trunk's counts whose names carry a kind (``attn_positions_read.window``:
+        # ONE layer of that kind's), and ``window_positions_spared`` (live context
+        # one window layer did not read): {name: total}, monotone
+        self.kind_counts: Dict[str, int] = {}
         # a latent cache's rows an iteration whose blocked pass ran in the
         # ``latent_rows`` kernel (ops/latent_attention.py ``rows_taken``):
         # one-token rows and chunk rows (0 where the XLA loops ran)
@@ -839,7 +892,7 @@ class ServingEngine:
         # batched forward.  0 (default) disarms the path entirely.
         if int(spec_k) < 0:
             raise ValueError("spec_k must be >= 0")
-        if int(spec_k) > 0 and spec.slot_state:
+        if int(spec_k) > 0 and not spec.blocks_are_positions:
             raise ValueError(
                 f"spec_k > 0 cannot be used with {type(model).__name__}: "
                 + spec.why_not)
@@ -904,6 +957,22 @@ class ServingEngine:
         itself — two models with the same architecture share programs."""
         return (self.B, self.T, self.bs, self.cache_spec.key, self.cache_quant,
                 bool(self.capture_sample_probs), self.pc, self.spec_k, self.P)
+
+    @property
+    def _reach(self) -> int:
+        """The most positions ONE launch advances a row by: the single step's
+        whole budget, a scan's ``megastep_k`` chunks."""
+        return max(self.T, self.megastep_k * self.pc)
+
+    def _kind_hold(self, k: int, need: int) -> int:
+        """The most blocks of kind ``k`` a row of ``need`` blocks holds at once."""
+        w = self.kinds[k].window
+        return need if w is None else min(need, -(-(w + self._reach) // self.bs) + 1)
+
+    def _table_rows(self) -> Dict[str, np.ndarray]:
+        """The block tables as a control block's rows (``control_layout``)."""
+        return dict(zip(_table_names(len(self.kinds)), self.kind_tables))
+
 
     def program_caches(self) -> tuple:
         """The ``caches`` argument of every program: the pool arrays, then
@@ -1008,14 +1077,15 @@ class ServingEngine:
         fwd = self._forward
         B, P = self.B, self.P
         with_probs = self.capture_sample_probs
-        fixed = control_layout("step", B, P).size
+        NT = len(self.kinds)
+        fixed = control_layout("step", B, P, 0, NT).size
 
         def step(weights, caches, rope, block, scales=None, *, mq):
             with jax.named_scope("scan_carry"):
-                c = control_layout("step", B, P, block.shape[0] - fixed).unpack(block)
+                c = control_layout("step", B, P, block.shape[0] - fixed, NT).unpack(block)
             logits, caches, new_scales, counts = fwd(
                 weights, caches, rope, c["token_ids"], c["enc"], c["dec"],
-                c["now"], c["cu"], c["bt"], mq, scales)
+                c["now"], c["cu"], _tables_of(c, NT), mq, scales)
             nxt, logprob, probs = _sample_tokens(
                 logits, c["temps"], c["top_ks"], c["top_ps"], c["seeds"],
                 c["sample_pos"], return_probs=with_probs)
@@ -1045,15 +1115,17 @@ class ServingEngine:
         fwd = self._forward
         B, P = self.B, self.P
         with_probs = self.capture_sample_probs
-        layout = control_layout("mega", B, P)
+        NT = len(self.kinds)
+        layout = control_layout("mega", B, P, 0, NT)
         n_pool = len(self.cache_spec.arrays)
         slot_state = bool(self.cache_spec.slot_state)
 
         def mega(weights, caches, rope, block, scales=None, *, K):
             with jax.named_scope("scan_carry"):
                 c = layout.unpack(block)
-            toks, dec, now, cu, occ_idx, bt = (
-                c[n] for n in ("toks", "dec", "now", "cu", "occ_idx", "bt"))
+            toks, dec, now, cu, occ_idx = (
+                c[n] for n in ("toks", "dec", "now", "cu", "occ_idx"))
+            bt = _tables_of(c, NT)
             active, remaining, dl, eos = (
                 c[n] for n in ("active", "remaining", "dl", "eos"))
             temps, top_ks, top_ps, seeds, sample_pos = (
@@ -1146,15 +1218,17 @@ class ServingEngine:
         fwd = self._forward
         B, T, C, P = self.B, self.T, self.pc, self.P
         with_probs = self.capture_sample_probs
+        NT = len(self.kinds)
 
         def mixed(weights, caches, rope, block, scales=None, *, K):
             if scales is not None:
                 raise ValueError("the mixed scan carries no int8 scales")
             with jax.named_scope("scan_carry"):
-                c = control_layout("mixed", B, P, K * C).unpack(block)
-            toks, cached, pp, pp0, plen, prompt_buf, bt = (
+                c = control_layout("mixed", B, P, K * C, NT).unpack(block)
+            toks, cached, pp, pp0, plen, prompt_buf = (
                 c[n] for n in ("toks", "cached", "pp", "pp0", "plen",
-                               "prompt_buf", "bt"))
+                               "prompt_buf"))
+            bt = _tables_of(c, NT)
             active, remaining, dl, eos = (
                 c[n] for n in ("active", "remaining", "dl", "eos"))
             temps, top_ks, top_ps, seeds, sample_pos = (
@@ -1263,16 +1337,17 @@ class ServingEngine:
         B, sk = self.B, self.spec_k
         Kp1 = sk + 1
         with_probs = self.capture_sample_probs
-        layout = control_layout("spec", B, self.P, sk)
+        NT = len(self.kinds)
+        layout = control_layout("spec", B, self.P, sk, NT)
 
         def spec_verify(weights, caches, rope, block, scales=None):
             if scales is not None:
                 raise ValueError("the verify program carries no int8 scales")
             with jax.named_scope("scan_carry"):
                 c = layout.unpack(block)
-            token_ids, dec, now, cu, bt, dlen, draft = (
-                c[n] for n in ("token_ids", "dec", "now", "cu", "bt", "dlen",
-                               "draft"))
+            token_ids, dec, now, cu, dlen, draft = (
+                c[n] for n in ("token_ids", "dec", "now", "cu", "dlen", "draft"))
+            bt = _tables_of(c, NT)
             temps, top_ks, top_ps, seeds, spos = (
                 c[n] for n in ("temps", "top_ks", "top_ps", "seeds", "sample_pos"))
             enc = jnp.zeros((B,), jnp.int32)
@@ -1386,6 +1461,8 @@ class ServingEngine:
         """Device-side copy of one pool block across every array of every
         layer's cache (the copy-on-write fork: the writer gets a private copy, the
         shared original stays read-only for its other owners)."""
+        if len(self.kinds) > 1:
+            raise ValueError("a copied block is ONE kind's: " + self.cache_spec.why_not)
         if self._cow_fn is None:
             if "cow" not in self._programs:
                 at = self._block_index
@@ -1426,7 +1503,22 @@ class ServingEngine:
                 self.blocks.fork(b)
             if not self.blocks.can_allocate(need_fresh):
                 self.blocks.free([b for b, _ in matched])  # unpin
+                self.admission_waits[0] += 1
                 break  # head-of-line waits for retirements
+            # a further kind RESERVES its worst hold (serving_model.py): the
+            # head waits while a pool's reservations are full, so a running
+            # row is never short of a block
+            holds = [self._kind_hold(k, need) for k in range(1, len(self.kinds))]
+            short = [k for k, h in enumerate(holds, 1)
+                     if self.kind_reserved[k] + h > self.pools[k].num_blocks]
+            if short:
+                self.blocks.free([b for b, _ in matched])
+                self.admission_waits[short[0]] += 1
+                break
+            for k, h in enumerate(holds, 1):
+                self.kind_reserved[k] += h
+            req.kind_reserved = holds
+            req.kind_blocks = [{} for _ in holds]
             self._queue.pop(0)
             fresh = self.blocks.allocate(need_fresh)
             if full_match:
@@ -1479,9 +1571,62 @@ class ServingEngine:
             self._publish_prefix(req)
         self.blocks.free(req.blocks)
         req.blocks = []
-        self.block_tables[req.slot] = -1
+        for k, held in enumerate(req.kind_blocks, 1):
+            self.pools[k].free(list(held.values()))
+            self.kind_reserved[k] -= req.kind_reserved[k - 1]
+        req.kind_blocks, req.kind_reserved = [], []
+        for table in self.kind_tables:
+            table[req.slot] = -1
         self._free_slots.append(req.slot)
         req.slot = -1
+
+    def _first_col(self, req: ServingRequest, k: int) -> int:
+        """The table column of kind ``k`` that holds the first key ``req``'s
+        next query attends: 0 for a kind without a window."""
+        w = self.kinds[k].window
+        return 0 if w is None else max(req.cached_len - w + 1, 0) // self.bs
+
+    def _take_blocks(self, req: ServingRequest, reach: int):
+        """Before a launch that may write ``req``'s positions up to ``reach``
+        (exclusive): in every further kind of cache layer, the blocks from
+        the one that holds the first key its next query attends up to the
+        reach's, those it does not hold yet.  Its reservation covers them."""
+        if not req.kind_blocks:
+            return
+        reach = min(reach, len(req.prompt) + req.max_new_tokens)
+        for k, held in enumerate(req.kind_blocks, 1):
+            cols = [c for c in range(self._first_col(req, k), (reach - 1) // self.bs + 1)
+                    if c not in held]
+            if cols:
+                got = self.pools[k].allocate(len(cols))
+                held.update(zip(cols, got))
+                self.kind_tables[k][req.slot, cols] = got
+
+    def _give_back(self, reqs: Sequence[ServingRequest]):
+        """After a launch's harvest: every block of a windowed kind that lies
+        wholly under the first key a row's next query attends goes back to its
+        pool (refcounted: a shared block outlives one owner), and its table
+        entry reads as no block."""
+        for req in reqs:
+            if req.slot < 0 or not req.kind_blocks:
+                continue
+            for k, held in enumerate(req.kind_blocks, 1):
+                lo = self._first_col(req, k)
+                cols = [c for c in held if c < lo]
+                if cols:
+                    self.pools[k].free([held.pop(c) for c in cols])
+                    self.kind_tables[k][req.slot, cols] = -1
+                    self.window_blocks_released += len(cols)
+
+    def _harvest_phase(self, counted: Dict[str, int]) -> _Phase:
+        """``engine.harvest`` with the launch's counts; a spec of several kinds
+        adds the engine's totals so far of blocks given back behind a window
+        (monotone) and the blocks its rows hold there now."""
+        if len(self.kinds) > 1:
+            counted = dict(counted, window_blocks_released=self.window_blocks_released,
+                           window_blocks_held=sum(
+                               len(h) for r in self._active.values() for h in r.kind_blocks))
+        return self._phase("harvest", **counted)
 
     def _retire(self, req: ServingRequest):
         req.done = True
@@ -1517,17 +1662,38 @@ class ServingEngine:
         probe shared by the fleet layer's heartbeat, the remote-replica
         state mirror, and the autoscaler (inference/fleet.py), so health
         checking and scaling decisions read the same numbers."""
-        nb = self.blocks.num_blocks
+        # every pool counts: a further kind's free blocks are those no admitted
+        # row has reserved
+        nb = sum(m.num_blocks for m in self.pools)
+        free = self.blocks.num_free + sum(
+            m.num_blocks - r for m, r in zip(self.pools[1:], self.kind_reserved[1:]))
+        held = [sum(len(r.blocks) for r in self._active.values())] + [
+            sum(len(r.kind_blocks[k]) for r in self._active.values() if r.kind_blocks)
+            for k in range(len(self.kinds) - 1)]
         return {
             "queued": [(q.rid, len(q.prompt), q.max_new_tokens)
                        for q in self._queue],
-            "active": {rid: len(r.blocks) for rid, r in self._active.items()},
+            "active": {rid: len(r.blocks) + sum(len(h) for h in r.kind_blocks)
+                       for rid, r in self._active.items()},
             "free_slots": len(self._free_slots),
-            "blocks_free": self.blocks.num_free,
+            "blocks_free": free,
             "blocks_total": nb,
+            # a pool a kind of cache layer (serving_model.py), each its own:
+            # ``blocks_held`` by running rows now, ``blocks_reserved`` by their
+            # admission (a windowed kind's worst hold), ``admission_waits`` the
+            # times the queue's head waited on this pool (monotone)
+            "pools": [{"kind": kind.name, "layers": kind.layers, "window": kind.window,
+                       "blocks_total": m.num_blocks, "blocks_free": m.num_free,
+                       "blocks_held": h, "blocks_reserved": r if k else h,
+                       "admission_waits": waits}
+                      for k, (kind, m, h, r, waits) in enumerate(zip(
+                          self.kinds, self.pools, held, self.kind_reserved,
+                          self.admission_waits))],
+            # blocks given back behind a window while their rows ran (monotone)
+            "window_blocks_released": self.window_blocks_released,
             "queue_depth": len(self._queue),
             "num_active": len(self._active),
-            "pool_utilization": (1.0 - self.blocks.num_free / nb) if nb else 0.0,
+            "pool_utilization": (1.0 - free / nb) if nb else 0.0,
             # weight-swap attribution (ISSUE 18): the fleet mirror and
             # tenant routing read these off the same state reply
             "weights_version": self.weights_version,
@@ -1581,6 +1747,10 @@ class ServingEngine:
                 "kv_write_tokens": self.kv_write_tokens,
                 "kv_write_blocks": self.kv_write_blocks,
             },
+            # the same of ONE layer of each kind, by name
+            # (``attn_positions_read.window``), and ``window_positions_spared``
+            # (monotone; {} for a spec of one kind)
+            "attention_by_kind": dict(self.kind_counts),
             # a latent cache's rows that attended in the ``latent_rows`` kernel
             # (monotone; zero on the XLA loops and for another cache)
             "latent_attention": {
@@ -1716,7 +1886,10 @@ class ServingEngine:
         launch's result block), added to the engine's counters of the same
         names; returned for the launch's ``engine.harvest`` span."""
         for name, n in got.items():
-            setattr(self, name, getattr(self, name) + n)
+            if "." in name or not hasattr(self, name):
+                self.kind_counts[name] = self.kind_counts.get(name, 0) + n
+            else:
+                setattr(self, name, getattr(self, name) + n)
         return got
 
     def _program(self, kind: str):
@@ -1987,16 +2160,18 @@ class ServingEngine:
                 tokens[pos:pos + n] = chunk
                 pos += n
                 cu[slot + 1] = pos
-            block = control_layout("step", self.B, self.P, len(tokens)).pack(dict(
+                self._take_blocks(req, int(dec[slot]) + n)
+            block = control_layout("step", self.B, self.P, len(tokens),
+                                   len(self.kinds)).pack(dict(
                 token_ids=tokens, enc=enc, dec=dec, now=now, cu=cu,
-                bt=self.block_tables, temps=temps, top_ks=top_ks, top_ps=top_ps,
-                seeds=seeds, sample_pos=spos))
+                temps=temps, top_ks=top_ks, top_ps=top_ps,
+                seeds=seeds, sample_pos=spos, **self._table_rows()))
 
         out, lps, probs, counted, _, _ = self._launch(
             "step", 1, block, [s[0] for s in sched],
             {"mq": 1 if decode_only else self.T})
         nxt = out["toks"]
-        with self._phase("harvest", **counted):
+        with self._harvest_phase(counted):
             emitted: Dict[int, List[int]] = {}
             for req, n, finishes in sched:
                 if req.in_prefill:
@@ -2033,6 +2208,7 @@ class ServingEngine:
                 hit_eos = (req.eos_token_id is not None and tok == req.eos_token_id)
                 if hit_eos or len(req.generated) >= req.max_new_tokens:
                     self._retire(req)
+            self._give_back([s[0] for s in sched])
         return emitted
 
     def _deadline_budgets(self, by_slot: Dict[int, "ServingRequest"]
@@ -2158,15 +2334,15 @@ class ServingEngine:
                                     spos)
                 pos += len(row)
                 cu[slot + 1] = pos
-            block = control_layout("spec", B, self.P, sk).pack(dict(
-                token_ids=tokens, dec=dec, now=now, cu=cu, bt=self.block_tables,
+            block = control_layout("spec", B, self.P, sk, len(self.kinds)).pack(dict(
+                token_ids=tokens, dec=dec, now=now, cu=cu,
                 dlen=dlen, draft=draft_a, temps=temps, top_ks=top_ks,
-                top_ps=top_ps, seeds=seeds, sample_pos=spos))
+                top_ps=top_ps, seeds=seeds, sample_pos=spos, **self._table_rows()))
         out, lps, probs, counted, _, _ = self._launch("spec", Kp1, block, reqs, {})
         nxt = out["toks"]       # [B, spec_k+1] redraws
         acc = out["acc"]        # [B] accepted draft-prefix lengths
 
-        with self._phase("harvest", **counted):
+        with self._harvest_phase(counted):
             emitted: Dict[int, List[int]] = {}
             for req in reqs:
                 s = req.slot
@@ -2256,18 +2432,20 @@ class ServingEngine:
                     pos += 1
                 cu[slot + 1] = pos
             dl = self._deadline_budgets(by_slot)
-            block = control_layout("mega", B, self.P).pack(dict(
+            for req in reqs:
+                self._take_blocks(req, req.cached_len + K)
+            block = control_layout("mega", B, self.P, 0, len(self.kinds)).pack(dict(
                 toks=toks, dec=dec, now=now, cu=cu, occ_idx=occ_idx,
-                bt=self.block_tables, active=active, remaining=remaining, dl=dl,
+                active=active, remaining=remaining, dl=dl,
                 eos=eos, temps=temps, top_ks=top_ks, top_ps=top_ps, seeds=seeds,
-                sample_pos=spos))
+                sample_pos=spos, **self._table_rows()))
         out, lps_o, probs_o, counted, execute_s, compiled = self._launch(
             "mega", K, block, reqs, {"K": K})
         toks_o, valid_o = out["toks"], out["valid"]       # [K, B]
         self.megasteps += 1
         self._update_tau(execute_s, K, compiled)
 
-        with self._phase("harvest", **counted):
+        with self._harvest_phase(counted):
             emitted: Dict[int, List[int]] = {}
             for req in reqs:
                 s = req.slot
@@ -2293,6 +2471,7 @@ class ServingEngine:
                            and new[-1] == req.eos_token_id)
                 if hit_eos or len(req.generated) >= req.max_new_tokens:
                     self._retire(req)
+            self._give_back(reqs)
             self._free_frozen(reqs, dl, K)
         return emitted
 
@@ -2367,11 +2546,13 @@ class ServingEngine:
                     # pp == plen marks the row as decoding from iteration 0
                     pp[slot] = pp0[slot] = plen[slot] = len(req.prompt)
             dl = self._deadline_budgets(by_slot)
-            block = control_layout("mixed", B, self.P, K * C).pack(dict(
+            for req in reqs:
+                self._take_blocks(req, req.cached_len + K * (C if req.in_prefill else 1))
+            block = control_layout("mixed", B, self.P, K * C, len(self.kinds)).pack(dict(
                 toks=toks, cached=cached, pp=pp, pp0=pp0, plen=plen,
-                prompt_buf=prompt_buf, bt=self.block_tables, active=active,
+                prompt_buf=prompt_buf, active=active,
                 remaining=remaining, dl=dl, eos=eos, temps=temps, top_ks=top_ks,
-                top_ps=top_ps, seeds=seeds, sample_pos=spos))
+                top_ps=top_ps, seeds=seeds, sample_pos=spos, **self._table_rows()))
         out, lps_o, probs_o, counted, execute_s, compiled = self._launch(
             "mixed", K, block, reqs, {"K": K}, prefill_rows=len(pre_reqs))
         toks_o, emits_o = out["toks"], out["valid"]       # [K, B]
@@ -2380,7 +2561,7 @@ class ServingEngine:
         self.megasteps_mixed += 1
         self._update_tau(execute_s, K, compiled)
 
-        with self._phase("harvest", **counted):
+        with self._harvest_phase(counted):
             emitted: Dict[int, List[int]] = {}
             for req in sorted(reqs, key=lambda r: r.slot):
                 s = req.slot
@@ -2432,6 +2613,7 @@ class ServingEngine:
                            and new[-1] == req.eos_token_id)
                 if hit_eos or len(req.generated) >= req.max_new_tokens:
                     self._retire(req)
+            self._give_back(reqs)
             self._free_frozen(reqs, dl, K)
         return emitted
 

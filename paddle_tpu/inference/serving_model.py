@@ -38,7 +38,36 @@ block table) and ``Lfm2MoeForCausalLM`` (models/lfm2_moe.py: per-head pools
 for its attention layers alone and STATE A SLOT for its conv layers, below)
 answer them.  The arrays of a spec are positional: whatever the
 engine does to a block (allocate, copy on write, keep for a shared prefix,
-free) it does to that block of every array of every layer.
+free) it does to that block of every array of every layer OF ONE KIND.
+
+A KIND of cache layer (``CacheSpec.kinds``; a spec that names none has one,
+all its layers) is a set of cache layers that share a lifetime: its own pool
+(the arrays' shapes are the spec's, the number of blocks the kind's:
+``ServingEngine(num_blocks={kind: n})``), its own ``BlockManager`` and its own
+block table ``[B, P]``.  The trunk of a spec with several kinds is handed
+``bt`` as a TUPLE of tables in the kinds' order and ``caches`` with the
+kinds' layers one after another (the first kind's first), and calls each
+layer's attention with its kind's table.  A position keeps its logical place
+in every table: entry ``p // block_size``.  The FIRST kind keeps every
+position of a request and is booked as the one pool always was: all of a
+request's blocks at admission, freed when it ends.  A further kind with a
+``window`` (``SmallThinkerForCausalLM``, models/smallthinker.py: 4,096
+positions in three layers of four) keeps the blocks that hold a row's last
+``window`` positions and GIVES THE REST BACK while the row runs: a launch
+takes the blocks it may write up to its reach before it starts, and after its
+harvest every block that lies wholly under ``cached - window + 1`` goes back
+through ``BlockManager.free`` (refcounted, so a shared block outlives one
+owner) and its table entry reads as no block; there is no ring.  Admission
+RESERVES such a kind's worst hold, ``min(blocks of the request, ceil((window
++ a launch's reach) / block_size) + 1)``, and the queue's head waits while a
+pool's reservations are full, so a running row is never short of a block and
+nothing is preempted from inside a launch; ``evict`` and a recompute from 0
+walk the window forward again and free as they go.  What takes a request's
+blocks to be ALL its positions does not hold for a spec of several kinds,
+and the engine refuses each with a typed error that says ``why_not``: the
+prefix cache (a published prefix has lost its window layers' blocks),
+speculation, block export and import; ``cache_quant="int8"`` by
+``quantizable``.
 
 State WITHOUT positions is the second kind (``CacheSpec.slot_state``): a
 fixed-size state a batch SLOT a layer, whatever the context's length (a short
@@ -63,7 +92,16 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
-__all__ = ["CacheSpec"]
+__all__ = ["CacheSpec", "CacheKind"]
+
+
+@dataclass(frozen=True)
+class CacheKind:
+    """Cache layers that share a pool, a block table and a lifetime (module
+    docstring)."""
+    name: str
+    layers: int
+    window: Optional[int] = None  # positions a layer attends; None: all of them
 
 
 @dataclass(frozen=True)
@@ -86,3 +124,20 @@ class CacheSpec:
     # one slot's state in one layer), ...); ``layers`` above stays the POOLED
     # cache layers.  The model puts it into ``key`` too.
     slot_state: Tuple[Tuple[str, int, tuple], ...] = ()
+    # kinds of cache layer (module docstring), ``layers`` their sum and the
+    # first without a window; (): one kind, every layer.  In ``key`` too.
+    kinds: Tuple[CacheKind, ...] = ()
+
+    def __post_init__(self):
+        if self.kinds and (sum(k.layers for k in self.kinds) != self.layers
+                           or self.kinds[0].window is not None or self.stacked
+                           or len({k.name for k in self.kinds}) != len(self.kinds)):
+            raise ValueError(
+                f"kinds {self.kinds} of a spec of {self.layers} cache layers: distinct "
+                "names, layers that add up, the first kind without a window, no stacked pool")
+
+    @property
+    def blocks_are_positions(self) -> bool:
+        """Whether a request's blocks of the first kind are ALL its state: no
+        state a slot and no further kind with a lifetime of its own."""
+        return not self.slot_state and len(self.kinds) <= 1

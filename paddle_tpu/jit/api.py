@@ -650,7 +650,7 @@ class TrainStep:
             if self._acquiring:
                 self._acquiring = self._compiled._cache_size() > had
                 if self._acquiring:
-                    SETUP.acquired(self._compiled.__name__, SETUP.clock() - t0,
+                    SETUP.acquired(self._compiled.__name__, SETUP.clock() - t0, since=t0,
                                    kind="train", k=1)
             for p, v in zip(self._params, new_params):
                 p._value = v
@@ -737,7 +737,7 @@ class TrainStep:
                 base_key, batch_vals, lr,
             )
             if first_call:
-                SETUP.acquired(multi.__name__, SETUP.clock() - t0, kind="train", k=K)
+                SETUP.acquired(multi.__name__, SETUP.clock() - t0, since=t0, kind="train", k=K)
             for p, v in zip(self._params, new_params):
                 p._value = v
             self._put_opt_state(new_accs, new_masters)

@@ -44,3 +44,9 @@ from .lfm2_moe import (  # noqa: F401,E402
     Lfm2MoeModel,
     lfm2_moe_tiny,
 )
+from .smallthinker import (  # noqa: F401,E402
+    SmallThinkerConfig,
+    SmallThinkerForCausalLM,
+    SmallThinkerModel,
+    smallthinker_tiny,
+)

@@ -196,6 +196,14 @@ def route(x, w_router, top_k, scale):
     return idx.astype(jnp.int32), scale * gv / (jnp.sum(gv, -1, keepdims=True) + 1e-20)
 
 
+def route_chosen(logits, top_k):
+    """The softmax-over-the-chosen form: the ``top_k`` largest of a token's router
+    ``logits`` [T, n] (float32, computed wherever the model places its router), the
+    weights a softmax over THOSE alone.  -> (idx [T, k] int32, w [T, k] float32)."""
+    gv, idx = jax.lax.top_k(logits.astype(F32), top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(gv, axis=-1)
+
+
 def _moe_ffn(cfg, p, x, valid=None, router=route, counts=None):
     """Shared expert (where the layer's weights hold one: ``sg``) + the held
     experts' part. -> (y in x's dtype, picks).

@@ -15,8 +15,17 @@ from .pallas.expert_gmm import expert_gmm
 F32 = jnp.float32
 
 
+# what stands between an expert's gate product and its up product, by name
+# (static in ``_held_experts``'s trace): SwiGLU's ``silu``, ReGLU's ``relu``
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _glu(x, wg, wu, wd, act=jax.nn.silu):
+    return (act(x @ wg) * (x @ wu)) @ wd
+
+
 def _swiglu(x, wg, wu, wd):
-    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+    return _glu(x, wg, wu, wd)
 
 
 # the grouped product (ops/pallas/expert_gmm.py): rows of a tile, the bytes of
@@ -65,8 +74,8 @@ def _tile_rows(i, tile, sizes, first_row):
     return e, r, r < (first_row + sizes)[e][..., None]
 
 
-def _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, tile):
-    """One tile of ``tile`` rows at a time through its expert's SwiGLU, a
+def _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, tile, act=jax.nn.silu):
+    """One tile of ``tile`` rows at a time through its expert's gated unit, a
     ``fori_loop`` over the tiles in use. -> (y [T, E] float32, rows multiplied)."""
     T, E = x.shape
     n_tiles = _tiles_of(sizes, tile)
@@ -76,14 +85,14 @@ def _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, tile):
         e, r, ok = _tile_rows(i, tile, sizes, first_row)
         r = jnp.clip(r, 0, tok_s.shape[0] - 1)
         t = jnp.where(ok, tok_s[r], T)
-        y = _swiglu(x_pad[t], eg[e], eu[e], ed[e]).astype(F32)
+        y = _glu(x_pad[t], eg[e], eu[e], ed[e], act).astype(F32)
         return out.at[t].add(y * jnp.where(ok, w_s[r], 0.0)[:, None], mode="drop")
 
     return jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((T, E), F32)), n_tiles * tile
 
 
 def grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, *, row_tile=_ROW_TILE,
-                    chunk_bytes=_CHUNK_BYTES, gmm=expert_gmm):
+                    chunk_bytes=_CHUNK_BYTES, gmm=expert_gmm, act=jax.nn.silu):
     """The sorted picks through ONE grouped product an expert matrix.  Every
     expert's rows start on a row tile, so a tile is one expert's and its spare
     rows are zeros; the tiles in use come first.  The rows are gathered into
@@ -109,7 +118,7 @@ def grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, *, row_tile=_RO
         r, ok = jnp.clip(r, 0, R - 1).reshape(-1), ok.reshape(-1)
         n = jnp.clip(n_tiles - c * chunk, 0, chunk)
         xs = x_pad[jnp.where(ok, tok_s[r], T)]
-        h = jax.nn.silu(gmm(xs, eg, e, n)) * gmm(xs, eu, e, n)
+        h = act(gmm(xs, eg, e, n)) * gmm(xs, eu, e, n)
         return gmm(h.astype(x.dtype), ed, e, n,
                    combine=(tok_s[r], jnp.where(ok, w_s[r], 0.0), T))
 
@@ -120,9 +129,9 @@ def grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, *, row_tile=_RO
     return y, n_tiles * row_tile
 
 
-@functools.partial(jax.jit, static_argnames=("lo", "tile", "gmm"))
+@functools.partial(jax.jit, static_argnames=("lo", "tile", "gmm", "activation"))
 @jax.named_scope("experts")
-def _held_experts(x, idx, w, eg, eu, ed, valid, *, lo, tile, gmm):
+def _held_experts(x, idx, w, eg, eu, ed, valid, *, lo, tile, gmm, activation="silu"):
     """``held_experts`` without its counting, a jitted function: the expert
     layers of a program (and the programs of a process that feed the same
     shapes) share ONE trace, and a program lowers it, its kernels with it,
@@ -141,15 +150,17 @@ def _held_experts(x, idx, w, eg, eu, ed, valid, *, lo, tile, gmm):
     w_s = w.reshape(-1)[order]
     sizes = jnp.sum(le[:, None] == jnp.arange(n_held)[None, :], axis=0).astype(jnp.int32)
     first_row = jnp.cumsum(sizes) - sizes                 # in the sorted order
+    act = ACTIVATIONS[activation]
     if gmm is None:
-        y, rows = _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, min(tile, T * k))
+        y, rows = _tile_loop(x, tok_s, w_s, sizes, first_row, eg, eu, ed, min(tile, T * k), act)
     else:
-        y, rows = grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, gmm=gmm)
+        y, rows = grouped_experts(x, tok_s, w_s, sizes, first_row, eg, eu, ed, gmm=gmm, act=act)
     return (y, jnp.sum(hit).astype(jnp.int32), jnp.sum(sizes > 0).astype(jnp.int32),
             rows.astype(jnp.int32))
 
 
-def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None):
+def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None,
+                 activation="silu"):
     """The part of the routed result that the held experts give.
 
     x [T, E]; idx, w [T, k] from ``route``; eg, eu [n_held, E, F], ed
@@ -158,7 +169,9 @@ def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None):
     expert's rows padded to whole tiles; an expert no token picked is not
     read.  Where ``groups_in_kernel`` admits the call the tiles go through
     ``grouped_experts`` (one grouped product an expert matrix), else one tile
-    of ``tile`` rows at a time through its expert's SwiGLU (``_tile_loop``).
+    of ``tile`` rows at a time through its expert's gated unit (``_tile_loop``).
+    ``activation`` names what stands between the gate and the up product
+    (``ACTIVATIONS``: ``silu`` a SwiGLU, ``relu`` a ReGLU).
     Nothing is dropped: there is no capacity.
     -> (y [T, E] float32, picks that fell on a held expert).
     ``counts``: a trunk's dict of int32 scalars; to those of these names it
@@ -173,7 +186,7 @@ def held_experts(x, idx, w, eg, eu, ed, lo, valid=None, tile=128, counts=None):
             T * k, n_held, E * x.dtype.itemsize, _ROW_TILE, _CHUNK_BYTES)[1])
     y, picks, touched, rows = _held_experts(
         x, idx, w, eg, eu, ed, valid, lo=int(lo), tile=int(tile),
-        gmm=expert_gmm if grouped else None)
+        gmm=expert_gmm if grouped else None, activation=activation)
     for name, n in (("experts_touched", touched), ("expert_tile_rows", rows),
                     ("expert_tile_rows_live", picks),
                     ("expert_rows_grouped", picks * grouped)):

@@ -119,7 +119,7 @@ from .latent_attention import _NEG, _TABLE_WORDS, _online
 from .pallas.paged_decode import paged_decode
 from .pallas.paged_write import PIECE, paged_write
 
-__all__ = ["blha_attention", "paged_counts", "attention_positions", "decodes_in_kernel",
+__all__ = ["blha_attention", "paged_counts", "attention_positions", "first_key", "decodes_in_kernel",
            "cache_write_counts", "writes_in_kernel", "lane_packing",
            "build_padding_metadata", "rope_rotate"]
 
@@ -259,9 +259,15 @@ def cache_write_counts(seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, *,
             jnp.sum(pieces * kernel).astype(jnp.int32))
 
 
+def first_key(pos, window):
+    """The first position a query at ``pos`` attends under ``window`` (it counts the
+    query's own position); 0 with no window."""
+    return 0 if window is None else jnp.maximum(pos - (window - 1), 0)
+
+
 def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
                         block_size: int, blocks_per_seq: int,
-                        kernel: bool = False):
+                        kernel: bool = False, window=None):
     """What one ``blha_attention`` call with these lengths attends and what
     it reads for that, as int32 scalars (live, read, rows_kernel): ``live``
     the context of every row fed, ``dec + now``; ``read`` the cache positions
@@ -271,10 +277,29 @@ def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
     rows reads its longest row's passes for all of its ``_ROW_TILE`` rows and
     a chunk row its own. With ``kernel`` (``decodes_in_kernel`` of the call) a
     one-token row reads its own context, this step's token with it, rounded
-    up to a block: the kernel fetches no block past the row's end."""
+    up to a block: the kernel fetches no block past the row's end.  Under a
+    ``window`` every walk starts at the pass (the kernel: the block) that holds
+    the first key its first query attends, so ``read`` falls short of ``live``
+    by what the window spared."""
     dec, now = seq_lens_decoder, seq_lens_this_time
     _, Lc = _context_block(block_size, blocks_per_seq)
     live = jnp.sum(jnp.where(now > 0, dec + now, 0))
+    if window is not None:
+        one = now == 1
+        # every walk less the passes (the kernel: the blocks) wholly behind its first key
+        chunks = jnp.sum(jnp.where(
+            now > 1, (_trips(dec, Lc) - first_key(dec, window) // Lc) * Lc + now, 0))
+        if kernel:
+            ones = jnp.sum(jnp.where(one, _trips(dec + 1, block_size)
+                                     - first_key(dec, window) // block_size, 0)) * block_size
+        else:
+            _, live_t, cached = _one_token_tiles(dec, now)
+            first = jnp.min(jnp.where(live_t, first_key(cached, window),
+                                      jnp.iinfo(jnp.int32).max), axis=1) // Lc
+            ones = (jnp.sum(jnp.maximum(_trips(jnp.max(cached, axis=1), Lc) - first, 0))
+                    * _ROW_TILE * Lc + jnp.sum(one))
+        return (live.astype(jnp.int32), (ones + chunks).astype(jnp.int32),
+                jnp.sum(one & kernel).astype(jnp.int32))
     chunks = jnp.sum(jnp.where(now > 1, _trips(dec, Lc) * Lc + now, 0))
     one = now == 1
     if kernel:
@@ -297,15 +322,18 @@ def _kernels(q_dtype, pool, bt, tokens: int, plain: bool):
             writes_in_kernel(pool.dtype, tokens=tokens, kv_heads=kv_rows, **sizes))
 
 
-def paged_counts(q_dtype, key_pool, dec, now, cu, bt, *, tokens: int, plain: bool = True):
+def paged_counts(q_dtype, key_pool, dec, now, cu, bt, *, tokens: int, plain: bool = True,
+                 window=None):
     """What ONE cache layer's ``blha_attention`` call did, for a trunk's ``counts``:
     ``attention_positions``'s three and ``cache_write_counts``'s two, the kernels
     asked as the call asks them.  ``q_dtype``: the queries' (``compute_dtype``);
     ``key_pool``: a layer's key pool as the trunk holds it (plain, lane-packed or
-    stacked); ``tokens``: the packed buffer's; ``plain``: as ``decodes_in_kernel``'s."""
+    stacked); ``tokens``: the packed buffer's; ``plain``: as ``decodes_in_kernel``'s;
+    ``window``: the call's."""
     decodes, writes = _kernels(q_dtype, key_pool, bt, tokens, plain)
     live, read, in_kernel = attention_positions(
-        dec, now, block_size=key_pool.shape[-2], blocks_per_seq=bt.shape[1], kernel=decodes)
+        dec, now, block_size=key_pool.shape[-2], blocks_per_seq=bt.shape[1], kernel=decodes,
+        window=window)
     written, pieces = cache_write_counts(dec, now, cu, kernel=writes)
     return {"attn_positions_live": live, "attn_positions_read": read,
             "attn_rows_kernel": in_kernel, "kv_write_tokens": written,
@@ -334,7 +362,8 @@ def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
 
 def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
                        block_tables, *, max_q_len: int, scale: float, quant: bool,
-                       k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask, in_kernel: bool):
+                       k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask, in_kernel: bool,
+                       window=None):
     """Steps 6-8 of ``blha_attention``: q [T, H, D] and this step's k, v
     [T, KV, D] against the pool, which already holds them. Returns
     [T, H, D] float32, zeros for tokens of no live row.  (Over a pool that
@@ -343,7 +372,11 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
 
     A row attends, in this order and through one online softmax: its
     pre-cache, the cache positions [0, dec) a context block at a time, and
-    this step's own tokens (causal among themselves) from k and v."""
+    this step's own tokens (causal among themselves) from k and v.  Under a
+    ``window`` a query at position t attends ``t - window + 1 .. t``: a row's
+    walk starts at the pass that holds its first query's first key and masks
+    inside it, each query of a chunk by its own first key; what lies behind
+    is not gathered, so the table may name no block there."""
     T, H, D = q.shape
     KV = k.shape[1]
     g = H // KV
@@ -440,15 +473,21 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
             if pre_k is not None:
                 carry = attend(carry, qt, pre_k[rows].astype(k.dtype),
                                pre_v[rows].astype(v.dtype), on, cols(b, 0, pre_len))
+            lo = first_key(cached, window)         # this step's token is at ``cached``
 
             def block(j, carry):
                 kb, vb = gather(
                     jax.lax.dynamic_slice_in_dim(ids, j * per, per, axis=1))
-                vis = ((j * Lc + kpos)[None, :] < cached[:, None])[:, None, None, :]
-                return attend(carry, qt, kb, vb, vis,
+                at = (j * Lc + kpos)[None, :]
+                vis = at < cached[:, None]
+                if window is not None:
+                    vis = vis & (at >= lo[:, None])
+                return attend(carry, qt, kb, vb, vis[:, None, None, :],
                               cols(b, pre_len + j * Lc, Lc), kd, vd)
 
-            carry = jax.lax.fori_loop(0, _trips(jnp.max(cached), Lc), block, carry)
+            first = (0 if window is None else
+                     jnp.min(jnp.where(live, lo, jnp.iinfo(jnp.int32).max)) // Lc)
+            carry = jax.lax.fori_loop(first, _trips(jnp.max(cached), Lc), block, carry)
             bb = None if b is None else jnp.take_along_axis(
                 b, (pre_len + cached)[:, None, None, None], axis=-1)
             o = finish(attend(carry, qt, k1[rows], v1[rows], on, bb))
@@ -465,7 +504,8 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
         # step's token in the value the XLA pass attends from registers
         # (``fresh_dt``): positions [0, dec], no gathered copy, a row's own trips
         o = paged_decode(q1, key_cache, value_cache,
-                         jnp.where(now == 1, dec + 1, 0), block_tables, scale=scale)
+                         jnp.where(now == 1, dec + 1, 0), block_tables, scale=scale,
+                         window=window)
         out = jnp.zeros((T + S, H, D), jnp.float32).at[
             jnp.where(now == 1, cu[:-1], T + S)].set(o.reshape(B, H, D), mode="drop")
     else:
@@ -499,17 +539,27 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
             carry = attend(carry, qt, pre_k[r].astype(k.dtype),
                            pre_v[r].astype(v.dtype), True, cols(b, 0, pre_len))
 
+        if window is not None:
+            sq = jnp.tile(qi, g)[:, None]               # a query's place in the chunk
+
         def block(j, carry):
             kb, vb = gather(jax.lax.dynamic_slice_in_dim(ids, j * per, per))
-            return attend(carry, qt, kb, vb, j * Lc + kpos < base,
+            at = j * Lc + kpos
+            vis = at < base
+            if window is not None:                      # each query its own first key
+                vis = vis[None, :] & (at[None, :] > base + sq - window)
+            return attend(carry, qt, kb, vb, vis,
                           cols(b, pre_len + j * Lc, Lc), kd, vd)
 
-        carry = jax.lax.fori_loop(0, _trips(base, Lc), block, carry)
+        carry = jax.lax.fori_loop(first_key(base, window) // Lc, _trips(base, Lc), block, carry)
         # this step's tokens: causal inside the chunk
-        sq = jnp.tile(qi, g)[:, None]
-        carry = attend(carry, qt, own(k_pad), own(v_pad),
-                       (qi[None, :] <= sq) & (qi[None, :] < nq),
-                       cols(b, pre_len + base, S))
+        if window is None:
+            sq = jnp.tile(qi, g)[:, None]
+        own_k, own_v = own(k_pad), own(v_pad)
+        vis = (qi[None, :] <= sq) & (qi[None, :] < nq)
+        if window is not None:
+            vis = vis & (qi[None, :] > sq - window)
+        carry = attend(carry, qt, own_k, own_v, vis, cols(b, pre_len + base, S))
         o = jnp.swapaxes(finish(carry).reshape(H, S, D), 0, 1)     # [S, H, D]
         old = jax.lax.dynamic_slice_in_dim(out, at, S, axis=0)
         o = jnp.where((qi < nq)[:, None, None], o, old)
@@ -542,7 +592,7 @@ def build_padding_metadata(seq_lens_this_time):
 @partial(jax.jit, static_argnames=(
     "num_heads", "kv_num_heads", "head_dim", "block_size", "max_q_len",
     "use_neox_style", "cache_quant", "round_ties_away", "compute_dtype",
-    "has_out_quant"))
+    "has_out_quant", "window"))
 @jax.named_scope("paged_attention")
 def blha_attention(
     qkv,                       # [T, (H+2*KV)*D] float/bf16 (or int32 w/ qkv_out_scale)
@@ -581,6 +631,7 @@ def blha_attention(
     quant_max_bound: float = 127.0,
     quant_min_bound: float = -127.0,
     layer=None,                # int32 scalar: which layer of a stacked pool
+    window=None,               # static: a query at t attends t - window + 1 .. t (None: 0 .. t)
 ):
     """One serving attention step over the paged cache.
 
@@ -590,6 +641,12 @@ def blha_attention(
     its own row-major order, which costs nothing, and the table's entries
     are moved to the layer's blocks: the write, the gather and the
     ``paged_decode`` kernel go by block number and touch no other layer's.
+
+    ``window`` (static): a sliding window over the context. It is no mask a
+    kernel must refuse: it is a first block and a first position. The blocked
+    pass and ``paged_decode`` start a row's walk at the block that holds its
+    first key and mask inside it, so the table's entries behind the window may
+    name no block (an engine gives them back: inference/serving_model.py).
 
     Returns (out [T, H*D], key_cache', value_cache',
              k_quant_scales', v_quant_scales', k_dequant_scales',
@@ -764,7 +821,7 @@ def blha_attention(
         block_tables, max_q_len=max_q_len, scale=1.0 / (D ** 0.5), quant=quant,
         k_dequant=cache_k_dequant_scales, v_dequant=cache_v_dequant_scales,
         pre_k=pre_key_cache, pre_v=pre_value_cache, mask=mask,
-        tgt_mask=tgt_mask, in_kernel=decodes)
+        tgt_mask=tgt_mask, in_kernel=decodes, window=window)
     if pack > 1:
         out = jnp.sum(jnp.where(own, out.reshape(T, H, pack, D), 0), axis=2)
     out = out.reshape(T, H * D)

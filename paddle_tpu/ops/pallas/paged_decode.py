@@ -38,7 +38,7 @@ BLOCKS_PER_PASS = 8
 
 def _kernel(len_ref, bt_ref, live_ref, q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref, *,
-            per: int, scale: float):
+            per: int, scale: float, window):
     b = pl.program_id(0)
     rows = len_ref.shape[0]
     nb, KV, bs, D = k_hbm.shape
@@ -47,13 +47,26 @@ def _kernel(len_ref, bt_ref, live_ref, q_ref, k_hbm, v_hbm, o_ref,
     L = per * bs
     n = len_ref[b]
 
+    def first_block(row):
+        """The table column a windowed row's walk starts at: the block that holds
+        the first key its one query (at ``length - 1``) attends."""
+        return jnp.maximum(len_ref[row] - window, 0) // bs
+
     def blocks(row, j, slot):
         """Pass j of ``row`` into ``slot``: for each of its blocks whether it
         holds a live position, whether the table names a block of the pool,
-        and the two copies that bring it."""
-        left = len_ref[row] - j * L
+        and the two copies that bring it.  (Without a window every expression
+        is the one the kernel always had: its programs do not move.)"""
+        if window is None:
+            left = len_ref[row] - j * L
+        else:
+            col0 = first_block(row) + j * per
+            left = len_ref[row] - col0 * bs
         for i in range(per):
-            blk = bt_ref[row * P + j * per + i]
+            if window is None:
+                blk = bt_ref[row * P + j * per + i]
+            else:
+                blk = bt_ref[row * P + jnp.minimum(col0 + i, P - 1)]
             wanted = i * bs < left
             there = wanted & (blk >= 0) & (blk < nb)
             at = jnp.clip(blk, 0, nb - 1)
@@ -87,7 +100,11 @@ def _kernel(len_ref, bt_ref, live_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(n > 0)
     def _():
-        trips = (n + L - 1) // L
+        if window is None:
+            trips = (n + L - 1) // L
+        else:
+            base = first_block(b) * bs      # the first position the walk brings
+            trips = (n - base + L - 1) // L
         nxt = live_ref[b + 1]
 
         # the first live row fetches for itself; blocks a pass does not fetch
@@ -115,7 +132,11 @@ def _kernel(len_ref, bt_ref, live_ref, q_ref, k_hbm, v_hbm, o_ref,
                 fetch(nxt, 0, 1 - slot)
 
             wait(b, j, slot)
-            visible = (j * L + jax.lax.broadcasted_iota(jnp.int32, (G, L), 1)) < n
+            if window is None:
+                visible = (j * L + jax.lax.broadcasted_iota(jnp.int32, (G, L), 1)) < n
+            else:
+                at = base + j * L + jax.lax.broadcasted_iota(jnp.int32, (G, L), 1)
+                visible = (at < n) & (at >= n - window)
             for h in range(KV):
                 k = kbuf[slot, :, h].reshape(L, D)
                 v = vbuf[slot, :, h].reshape(L, D)
@@ -146,14 +167,19 @@ def _kernel(len_ref, bt_ref, live_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_decode(q, key_cache, value_cache, lengths, block_tables, *,
                  scale: float, blocks_per_pass: int = BLOCKS_PER_PASS,
-                 interpret: bool = False):
+                 interpret: bool = False, window=None):
     """q [B, KV, g, D] (a row's one token, its query heads by KV head) against
     positions ``[0, lengths[b])`` of row b's context in the pools
     ``[num_blocks, KV, block_size, D]``, found through ``block_tables [B, P]``
     (an entry outside the pool reads as zeros). q and the pools share one
     16-bit float type; ``D`` is whole 128-lane tiles and ``block_size`` whole
-    sublane tiles of it. Returns [B, KV, g, D] float32, zeros for a row of
-    length 0."""
+    sublane tiles of it. ``g`` need be no sublane tile: the queries are padded
+    to one here, never the pool (7 query heads a KV head ride as 8).
+    ``window`` (static): row b's token, at ``lengths[b] - 1``, attends the last
+    ``window`` positions alone; its fetch list starts at the block that holds
+    the first of them, so a row at 16 k under a window of 4,096 brings 65
+    blocks and not 256, and the table may name no block behind it.
+    Returns [B, KV, g, D] float32, zeros for a row of length 0."""
     B, KV, g, D = q.shape
     nb, _, bs, _ = key_cache.shape
     P = block_tables.shape[1]
@@ -170,7 +196,8 @@ def paged_decode(q, key_cache, value_cache, lengths, block_tables, *,
                             jnp.full((1,), B, jnp.int32)])
     row = lambda b, *_: (b, 0, 0, 0)
     out = pl.pallas_call(
-        functools.partial(_kernel, per=per, scale=float(scale)),
+        functools.partial(_kernel, per=per, scale=float(scale),
+                          window=None if window is None else int(window)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),

@@ -36,7 +36,12 @@ learned sparse attention: ONE layer's, over the live queries whose context
 exceeds the model's ``index_topk``, beside ``attn_positions_live``;
 ``latent_rows_kernel`` / ``latent_chunks_kernel`` (``latent_counts``) for a
 latent cache: the one-token and the chunk rows an iteration whose blocked pass
-ran in the ``latent_rows`` kernel; 0 where the XLA loops ran);
+ran in the ``latent_rows`` kernel; 0 where the XLA loops ran; for a model of
+several KINDS of cache layer (inference/serving_model.py) ONE layer of each
+kind's ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` and
+``window_positions_spared`` (the live context behind the first key a fed row's
+first query attends), and the engine's totals as the span STARTS,
+``window_blocks_released`` (monotone) and ``window_blocks_held``);
 ``train_step.call`` (stats ``step``, ``steps``).
 
 Set-up spans, every one a ``SetupSpan``: a ``RecordEvent`` that also leaves a
@@ -269,14 +274,18 @@ class SetupLedger:
         return self._append(next(self._ids), inner.id if inner is not None else None,
                             name, attrs, t0, seconds)
 
-    def acquired(self, program: str, seconds: float, **attrs) -> dict:
+    def acquired(self, program: str, seconds: float, since=None, **attrs) -> dict:
         """``program.acquire``, after the fact: the call of the jitted function
         ``program`` that has just returned took ``seconds`` and had to trace,
         lower and compile or read it.  The compile ledger's newest unclaimed
-        row of that name is its own, and its numbers ride the row."""
+        row of that name is its own, and its numbers ride the row.  ``since``:
+        this ledger's clock when the call began, where the caller read it: a
+        row from before it is another program's of the same name (an engine's
+        ``step`` left unclaimed beside a train step's), and is not claimed."""
         end = self.clock()
         for c in reversed(self.compiles):
-            if c["fun_name"] == program and not c["acquired"]:
+            if (c["fun_name"] == program and not c["acquired"]
+                    and (since is None or c["t0"] >= since - 0.01)):
                 c["acquired"] = True
                 attrs.update({k: c[k] for k in
                               _COMPILE_SECONDS + ("cache_hit", "cache_read_s")})

@@ -115,13 +115,15 @@ def compiled_program(eng, cfg, kind, chip):
         return jax.ShapeDtypeStruct(tuple(shape or a.shape), a.dtype, sharding=chip)
 
     def block(launch, n=0):
-        return jax.ShapeDtypeStruct((control_layout(launch, B, P, n).size,), jnp.int32,
-                                    sharding=chip)
+        return jax.ShapeDtypeStruct((control_layout(launch, B, P, n, len(eng.kinds)).size,),
+                                    jnp.int32, sharding=chip)
 
     if eng.cache_spec.stacked:          # one array [cache layers, blocks, ...]
         pools = tuple(sds(a, a.shape[:1] + (nb,) + a.shape[2:]) for a in eng.caches)
-    else:
-        pools = tuple([sds(a, (nb,) + a.shape[1:]) for a in layers]
+    else:                               # a pool a KIND of cache layer, each of its own size
+        sizes = [nb[kind.name] if isinstance(nb, dict) else nb
+                 for kind in eng.kinds for _ in range(kind.layers)]
+        pools = tuple([sds(a, (n,) + a.shape[1:]) for a, n in zip(layers, sizes)]
                       for layers in eng.caches)
     head = (jax.tree_util.tree_map(sds, eng._weights),
             pools + tuple(sds(s) for s in eng.slot_state), sds(eng._rope))

@@ -42,7 +42,7 @@ ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, m
 
 # family -> its module under benchmark/families (llama is built from its class)
 MODULES = {"pangu": "mla_moe", "ouro": "looped_dense", "deepseek": "mla_dsa_moe",
-           "lfm2": "conv_gqa_moe"}
+           "lfm2": "conv_gqa_moe", "smallthinker": "swa_gqa_moe"}
 
 # LFM2: two leading dense conv layers and one period of the pattern (attention,
 # conv, conv, conv): 5 conv layers keep state, 1 attention layer keeps blocks
@@ -91,6 +91,17 @@ TINY = {
         routed_scaling_factor=1, max_position_embeddings=256, norm_eps=1e-5,
         rope_parameters={"rope_theta": 10000.0, "rope_type": "default"},
         model_type="lfm2_moe", torch_dtype="float32"),
+    # SmallThinker: one period of the layout (global without rope, then three
+    # window layers with it), a window of 24 positions = 3 blocks of ENGINE's 8
+    "smallthinker": dict(
+        vocab_size=256, hidden_size=64, head_dim=16, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, moe_ffn_hidden_size=32,
+        moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+        moe_primary_router_apply_softmax=True, norm_topk_prob=True, sliding_window_size=24,
+        sliding_window_layout=[0, 1, 1, 1, 0, 1, 1, 1], rope_layout=[0, 1, 1, 1, 0, 1, 1, 1],
+        rope_theta=10000.0, rope_scaling=None, max_position_embeddings=256,
+        rms_norm_eps=1e-6, tie_word_embeddings=False,
+        model_name="smallthinker_21b_instruct", torch_dtype="float32"),
 }
 
 
@@ -123,7 +134,7 @@ def pinned_model(family):
 def pinned_engine(family):
     """Its engine with every program it can lower (a model with state a slot
     refuses speculation: its ``spec`` program lowers at no drafts)."""
-    spec_k = 0 if family == "lfm2" else 2
+    spec_k = 0 if family in ("lfm2", "smallthinker") else 2
     return ServingEngine(pinned_model(family), spec_k=spec_k, **ENGINE)
 
 
@@ -137,7 +148,8 @@ def lowered(eng, debug_info, kinds=KINDS):
     B, T, P_, C, K = eng.B, eng.T, eng.P, eng.pc, eng.megastep_k
 
     def block(kind, n=0):        # the ONE control array a launch sends up (ISSUE 35)
-        return jax.ShapeDtypeStruct((control_layout(kind, B, P_, n).size,), jnp.int32)
+        return jax.ShapeDtypeStruct(
+            (control_layout(kind, B, P_, n, len(eng.kinds)).size,), jnp.int32)
 
     head = (eng._weights, eng.program_caches(), eng._rope)
     low = {

@@ -1181,3 +1181,164 @@ def test_a_paired_pool_is_not_quantised():
         pa.blha_attention(qkv, wide, wide, *rest, num_heads=g["H"], kv_num_heads=g["KV"],
                           head_dim=64, block_size=g["bs"], max_q_len=g["S"],
                           cache_quant="static", **kw)
+
+
+# ------------------------------------------------------- a sliding window
+# ``blha_attention(window=)``: the blocked XLA pass, the ``paged_decode`` kernel in
+# interpret mode (groups of 7 query heads a key/value head, a fetch list that
+# starts at the window's first block), the table's holes behind the window, and
+# what ``paged_counts`` says the window spared.  The reference is a dense
+# attention under an explicit mask over what the pools hold after the call.
+def _window_case(rows, *, bs, P, KV, group, D, dtype, seed=0, behind=None, window=None):
+    """rows [(dec, now)] -> blha's arguments over random pools and a shuffled
+    table; ``behind``: what the entries wholly behind ``window`` read as (None:
+    the blocks they always named; -1: no block, as an engine leaves them)."""
+    rng = np.random.RandomState(seed)
+    B, H = len(rows), KV * group
+    dec = np.array([r[0] for r in rows], np.int32)
+    now = np.array([r[1] for r in rows], np.int32)
+    cu = np.concatenate([[0], np.cumsum(now)]).astype(np.int32)
+    T = int(cu[-1]) + 2
+    nb = B * P
+    bt = rng.permutation(nb).reshape(B, P).astype(np.int32)
+    for b in range(B):
+        bt[b, -(-(dec[b] + now[b]) // bs):] = -1
+        if behind is not None:
+            bt[b, :max(dec[b] - window + 1, 0) // bs] = behind
+    qkv = jnp.asarray(rng.uniform(-1, 1, (T, (H + 2 * KV) * D)), dtype)
+    kc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), dtype)
+    vc = jnp.asarray(rng.uniform(-1, 1, (nb, KV, bs, D)), dtype)
+    args = (qkv, kc, vc, jnp.asarray(np.where(now > 1, now, 0).astype(np.int32)),
+            jnp.asarray(dec), jnp.asarray(now), jnp.asarray(cu), jnp.asarray(bt))
+    return args, dict(num_heads=H, kv_num_heads=KV, head_dim=D, block_size=bs,
+                      max_q_len=int(max(now.max(), 1)), compute_dtype=dtype)
+
+
+def _window_dense(args, kw, kc, vc, window):
+    """Every live token's attention from the pools AFTER the call (they hold
+    this step's keys and values too), under an explicit causal-and-window mask."""
+    qkv, _, _, _, dec, now, cu, bt = (np.asarray(a, np.float32) if i < 3 else np.asarray(a)
+                                      for i, a in enumerate(args))
+    H, KV, D, bs = kw["num_heads"], kw["kv_num_heads"], kw["head_dim"], kw["block_size"]
+    kc, vc = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
+    out = np.zeros((qkv.shape[0], H, D), np.float32)
+    for b in range(len(dec)):
+        n = int(dec[b] + now[b])
+        lo = 0 if window is None else max(int(dec[b]) - window + 1, 0)
+        pos = np.arange(lo, n)                      # nothing behind ``lo`` is ever attended
+        blk, slot = bt[b, pos // bs], pos % bs
+        k, v = kc[blk, :, slot], vc[blk, :, slot]   # [L, KV, D]
+        for i in range(int(now[b])):
+            t = int(cu[b]) + i
+            q = qkv[t, :H * D].reshape(KV, H // KV, D)
+            at = int(dec[b]) + i
+            ok = (pos <= at) & ((pos > at - window) if window is not None else True)
+            s = np.einsum("kgd,lkd->kgl", q, k) * D ** -0.5
+            s = np.where(ok[None, None], s, -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            out[t] = np.einsum("kgl,lkd->kgd", p, v).reshape(H, D)
+    return out.reshape(qkv.shape[0], H * D)
+
+
+# window 24 over blocks of 8 in a table of 20 (a pass is the whole table on the
+# CPU): one-token rows short of, at and far past the window; chunk rows whose
+# queries each have their own first key, one longer than the window itself
+XLA_ROWS = [(0, 1), (23, 1), (24, 1), (100, 1), (0, 0), (130, 1), (0, 30), (50, 8),
+            (120, 5), (3, 1), (17, 12), (64, 1)]
+
+
+@pytest.mark.parametrize("behind", [None, -1], ids=["blocks_kept", "blocks_given_back"])
+def test_the_blocked_pass_attends_the_window_alone(behind):
+    """Each query at t attends t - 23 .. t, whether the table still names the
+    blocks behind the window or reads -1 there (what an engine leaves after it
+    has given them back): what lies behind is never gathered."""
+    W = 24
+    args, kw = _window_case(XLA_ROWS, bs=8, P=20, KV=2, group=3, D=16, dtype=jnp.float32,
+                     behind=behind, window=W)
+    out, kc, vc, *_ = pa.blha_attention(*args, window=W, **kw)
+    full, kc0, vc0, *_ = pa.blha_attention(*args, **kw)
+    want = _window_dense(args, kw, kc, vc, W)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+    # the window is the difference: a row past it reads other values without it
+    if behind is None:
+        np.testing.assert_allclose(np.asarray(full), _window_dense(args, kw, kc0, vc0, None),
+                                   rtol=2e-5, atol=2e-5)
+        assert np.abs(np.asarray(full) - np.asarray(out)).max() > 1e-2
+    np.testing.assert_array_equal(_bits(kc), _bits(kc0))      # the write knows no window
+
+
+def test_a_large_context_block_starts_its_walk_at_the_windows_pass(monkeypatch):
+    """Passes of 32 positions over contexts of hundreds: the walk starts at the
+    pass that holds the first key, and ``attention_positions`` says so."""
+    monkeypatch.setattr(pa, "_CTX_BLOCK", 32)
+    W = 24
+    rows = [(300, 1), (200, 1), (10, 1), (290, 8), (0, 0), (150, 1), (95, 1), (31, 1), (33, 4)]
+    args, kw = _window_case(rows, bs=8, P=48, KV=2, group=2, D=16, dtype=jnp.float32,
+                     behind=-1, window=W)
+    out, kc, vc, *_ = _fresh_call(args, dict(kw, window=W))
+    np.testing.assert_allclose(np.asarray(out), _window_dense(args, kw, kc, vc, W),
+                               rtol=2e-5, atol=2e-5)
+    dec, now = args[4], args[5]
+    live, read, _ = pa.attention_positions(dec, now, block_size=8, blocks_per_seq=48, window=W)
+    _, read_all, _ = pa.attention_positions(dec, now, block_size=8, blocks_per_seq=48)
+    assert int(live) == sum(d + n for d, n in rows if n)
+    # by hand: the chunk rows walk from the pass of dec - 23; the one tile of
+    # eight one-token rows (sorted by length) from the pass its shortest starts at
+    chunk = ((290 + 31) // 32 - (290 - 23) // 32) * 32 + 8 + ((33 + 31) // 32 - 0) * 32 + 4
+    ones = ((300 + 31) // 32 - 0) * 8 * 32 + 6
+    assert int(read) == chunk + ones < int(read_all)
+
+
+# the kernel: blocks of 16 in a table of 24, heads of 128, a pass of 8 blocks;
+# a window of 100 positions = 7 blocks: rows short of it, at it, past it by
+# less and by more than a pass, and to the table's end
+K_ROWS = [(0, 1), (99, 1), (100, 1), (0, 0), (229, 1), (383, 1), (37, 1), (150, 8), (131, 1)]
+
+
+@pytest.mark.parametrize("group", [7, 4], ids=lambda g: f"g{g}")
+def test_the_kernel_takes_groups_of_seven_and_a_windows_first_block(monkeypatch, group):
+    """``paged_decode`` in interpret mode, 7 query heads a key/value head (padded
+    to a sublane tile of 8 inside the kernel, never in the pool): a row's fetch
+    list starts at the block that holds ``length - window`` and the table names
+    NO block behind it; the result is the dense reference's, and both pools come
+    out bit for bit as the scatter leaves them."""
+    W = 100
+    args, kw = _window_case(K_ROWS, bs=16, P=24, KV=2, group=group, D=128, dtype=jnp.bfloat16,
+                     behind=-1, window=W)
+    scatter = pa.blha_attention(*args, window=W, **kw)          # the CPU: XLA pass, scatter
+    writes = _steer_onto_the_chip(monkeypatch)
+    seen = []
+    inner = pa.paged_decode
+    monkeypatch.setattr(pa, "paged_decode", lambda *a, **k: (seen.append(k), inner(*a, **k))[1])
+    out, kc, vc, *_ = _fresh_call(args, dict(kw, window=W))
+    assert len(writes) == 1 and [k["window"] for k in seen] == [W]
+    want = _window_dense(args, kw, kc, vc, W)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(scatter[0], np.float32),
+                               rtol=1e-2, atol=1e-2)
+    np.testing.assert_array_equal(_bits(kc), _bits(scatter[1]))
+    np.testing.assert_array_equal(_bits(vc), _bits(scatter[2]))
+    counts = pa.paged_counts(jnp.bfloat16, args[1], args[4], args[5], args[6], args[7],
+                             tokens=args[0].shape[0], window=W)
+    # a one-token row brings the blocks from its window's first to its own: 16 k
+    # under a window of 4,096 is 65 blocks of 251 by the same arithmetic
+    ones = [d for d, n in K_ROWS if n == 1]
+    assert int(counts["attn_rows_kernel"]) == len(ones)
+    by_hand = sum((d + 16) // 16 - max(d - W + 1, 0) // 16 for d in ones) * 16
+    # the chunk row rides the XLA pass, whose pass is the whole table of 24 x 16 here
+    assert int(counts["attn_positions_read"]) == by_hand + 24 * 16 + 8
+    assert (16000 + 64) // 64 - (16000 - 4095) // 64 == 65
+
+
+def test_the_kernel_without_a_window_is_the_kernel_it_was():
+    """``window=None`` traces the kernel as before this argument existed: the
+    same jaxpr as a call that does not pass it."""
+    args, _ = _window_case([(40, 1), (0, 0), (300, 1)], bs=16, P=24, KV=2, group=4, D=128,
+                    dtype=jnp.bfloat16)
+    q = jnp.zeros((3, 2, 4, 128), jnp.bfloat16)
+    lengths = jnp.asarray([41, 0, 301], jnp.int32)
+    call = functools.partial(pd.paged_decode, scale=0.1, interpret=True)
+    a = jax.make_jaxpr(lambda *x: call(*x))(q, args[1], args[2], lengths, args[7])
+    b = jax.make_jaxpr(lambda *x: call(*x, window=None))(q, args[1], args[2], lengths, args[7])
+    assert str(a) == str(b)

@@ -400,3 +400,17 @@ def test_stages_and_remainder_sum_to_the_interval_asked_for(since, until):
         assert (inner["name"], inner["self_s"], inner["compile_s"]) == ("inner", 4.0, 1.0)
         assert rep["other"]["count"] == 1 and rep["other"]["backend_s"] == 0.5
     assert rep["text"].splitlines()[0].startswith(f"set-up: {hi - lo:.2f} s")
+
+
+def test_a_call_claims_no_compile_row_from_before_it_began():
+    """An engine's ``step`` compiled and left unclaimed (a lowering compiled by
+    hand, a described-device compile) is no train step's: ``acquired(since=)``
+    claims only a row that began after the call did."""
+    ledger = SetupLedger()
+    ledger.compiles.append({"fun_name": "step", "trace_s": 0.1, "lower_s": 0.1, "backend_s": 0.5,
+                            "cache_hit": True, "cache_read_s": 0.06, "t0": ledger.clock() - 60.0,
+                            "acquired": False})
+    t0 = ledger.clock()
+    row = ledger.acquired("step", 0.0, since=t0, kind="train", k=1)
+    assert "backend_s" not in row["attrs"] and not ledger.compiles[-1]["acquired"]
+    assert "backend_s" in ledger.acquired("step", 0.0, kind="step", k=1)["attrs"]

@@ -850,6 +850,9 @@ class ServingEngine:
         self.attn_positions_live = 0
         self.attn_positions_read = 0
         self.attn_rows_kernel = 0
+        # chunk rows (``now > 1``) an iteration that attended in the ``paged_chunk``
+        # kernel (ops/paged_attention.py ``chunks_in_kernel``; 0 on the XLA pass)
+        self.attn_chunks_kernel = 0
         # a trunk's counts whose names carry a kind (``attn_positions_read.window``:
         # ONE layer of that kind's), and ``window_positions_spared`` (live context
         # one window layer did not read): {name: total}, monotone
@@ -1744,6 +1747,7 @@ class ServingEngine:
                 "positions_live": self.attn_positions_live,
                 "positions_read": self.attn_positions_read,
                 "rows_kernel": self.attn_rows_kernel,
+                "chunks_kernel": self.attn_chunks_kernel,
                 "kv_write_tokens": self.kv_write_tokens,
                 "kv_write_blocks": self.kv_write_blocks,
             },

@@ -396,7 +396,7 @@ class Lfm2MoeForCausalLM(nn.Layer):
         pools: an attention layer each; conv state ``[conv layers, B, L - 1,
         E]``).  ``counts``: ``conv_rows_fed`` (row-layers whose state
         advanced), the expert layers' six seeded below (``_moe_ffn``,
-        ``held_experts``) and ONE attention layer's five (``paged_counts``)."""
+        ``held_experts``) and ONE attention layer's six (``paged_counts``)."""
         from ..ops.paged_attention import blha_attention, paged_counts
 
         cfg = self.config
@@ -447,7 +447,8 @@ class Lfm2MoeForCausalLM(nn.Layer):
                 hidden = hidden + ffn
             with jax.named_scope("norm"):
                 hidden = _rms(hidden, weights["norm"], eps)
-            counts.update(paged_counts(hidden.dtype, key_caches[0], dec, now, cu, bt, tokens=T))
+            counts.update(paged_counts(hidden.dtype, key_caches[0], dec, now, cu, bt, tokens=T,
+                                       heads=H, max_q_len=mq))
             return hidden, (key_caches, value_caches, conv_state), [], counts
 
         return trunk

@@ -589,7 +589,7 @@ class LlamaForCausalLM(nn.Layer):
             # ONE layer's counts an iteration: the layers read and are written alike
             return hidden, (key_caches, value_caches), new_scales, paged_counts(
                 hidden.dtype, key_caches[0], dec, now, cu, bt, tokens=token_ids.shape[0],
-                plain=quant == "none")
+                heads=H, max_q_len=mq, plain=quant == "none")
 
         return trunk
 

@@ -301,7 +301,7 @@ class OuroForCausalLM(nn.Layer):
         ``counts``: ``loop_tokens`` (tokens fed), ``loop_token_passes``
         (tokens x the passes each ran: a token runs a pass while its
         cumulative exit probability is under the threshold, at 1 all of
-        them), and ``paged_counts``'s five of ONE cache layer (all alike)."""
+        them), and ``paged_counts``'s six of ONE cache layer (all alike)."""
         cfg = self.config
         H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         L, R, eps, bs = (cfg.num_hidden_layers, cfg.total_ut_steps, cfg.rms_norm_eps,
@@ -356,7 +356,8 @@ class OuroForCausalLM(nn.Layer):
             hidden, kc, vc, _, ran = jax.lax.fori_loop(
                 0, R, one_pass,
                 (hidden,) + tuple(caches) + (jnp.ones((T,), F32), jnp.zeros((), jnp.int32)))
-            paged = paged_counts(hidden.dtype, kc, dec, now, cu, bt, tokens=T)
+            paged = paged_counts(hidden.dtype, kc, dec, now, cu, bt, tokens=T, heads=H,
+                                 max_q_len=mq)
             return hidden, (kc, vc), [], {
                 "loop_tokens": jnp.sum(valid).astype(jnp.int32),
                 "loop_token_passes": ran, **paged}

@@ -315,8 +315,9 @@ class SmallThinkerForCausalLM(nn.Layer):
         the global layers' first, then the window layers'; ``bt`` a table a
         kind in that order (ONE table where the config has one kind).
         ``counts``: the expert layers' six (``held_experts``), ONE global
-        layer's five (``paged_counts``), and by kind ONE layer's
-        ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` with
+        layer's six (``paged_counts``), and by kind ONE layer's
+        ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` /
+        ``attn_chunks_kernel.<kind>`` with
         ``window_positions_spared``: the live context behind the first key a
         row's first query attends, which ONE window layer did not read."""
         from ..ops.paged_attention import first_key, blha_attention, paged_counts
@@ -368,11 +369,12 @@ class SmallThinkerForCausalLM(nn.Layer):
                 if not layers:
                     continue
                 got = paged_counts(hidden.dtype, key_caches[cache_of[layers[0]]], dec, now, cu,
-                                   tables[windowed], tokens=T, window=W if windowed else None)
-                if kind == "global":          # the five every paged trunk carries
+                                   tables[windowed], tokens=T, heads=H, max_q_len=mq,
+                                   window=W if windowed else None)
+                if kind == "global":          # the six every paged trunk carries
                     counts.update(got)
-                counts[f"attn_positions_live.{kind}"] = got["attn_positions_live"]
-                counts[f"attn_positions_read.{kind}"] = got["attn_positions_read"]
+                for name in ("attn_positions_live", "attn_positions_read", "attn_chunks_kernel"):
+                    counts[f"{name}.{kind}"] = got[name]
             if cfg.layers_of(True):
                 counts["window_positions_spared"] = jnp.sum(
                     jnp.where(now > 0, first_key(dec, W), 0)).astype(jnp.int32)

@@ -49,9 +49,18 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
     pay for the longest one and a (tile, context block) pair without a live
     position is never gathered;
   * rows that feed a CHUNK (``now > 1``: prompt chunks, the prefill step,
-    speculative drafts), in every call: the same XLA pass one row at a time
-    in a loop over the rows that carry one, ``[max_q_len, H, D]`` queries
-    against the row's own context; rows without a chunk cost nothing.
+    speculative drafts), where the call is one ``chunks_in_kernel`` admits
+    (what ``decodes_in_kernel`` asks, and the list of work items within the
+    kernel's scalar memory): the Pallas kernel ``ops/pallas/paged_chunk.py``,
+    ONE call for all of them. A tile of a row's tokens is a work item; a KV
+    head's ``g`` query heads of the tile are one matrix of ``g x tokens`` rows
+    against that head's keys; an item walks ITS row's blocks by the table,
+    from the block that holds its first token's first key (a window's first
+    block) to its last token's, this step's own tokens read from the pool as
+    ``paged_decode`` reads them; scores never leave VMEM;
+  * the same rows of any other call: the XLA pass one row at a time in a loop
+    over the rows that carry one, ``[max_q_len, H, D]`` queries against the
+    row's own context; rows without a chunk cost nothing.
   On the XLA pass this step's own tokens are attended from registers as one
   trailing block (causal inside a chunk), so the cache is read for
   ``[0, dec)`` only and an int8 cache's fresh tokens stay unquantised, as in
@@ -60,7 +69,8 @@ Design (SURVEY §7.1: kernels collapse onto XLA):
   ``attention_positions`` counts what a call had to attend, what it read
   for that and the rows the kernel took; a trunk takes them from
   ``paged_counts`` and ``ServingEngine`` adds them up
-  (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``).
+  (``attn_positions_live`` / ``_read``, ``attn_rows_kernel``,
+  ``attn_chunks_kernel``).
 - The write, and why ONE layout still stands. Where ``writes_in_kernel``
   admits the call (the TPU, an unquantised bfloat16 pool whose rows are whole
   lane tiles, blocks of whole 16-slot pieces; masks and pre-caches do not matter)
@@ -116,16 +126,18 @@ import jax.numpy as jnp
 
 from ..device import on_tpu
 from .latent_attention import _NEG, _TABLE_WORDS, _online
+from .pallas.paged_chunk import padded_heads, paged_chunk, tile_tokens
 from .pallas.paged_decode import paged_decode
 from .pallas.paged_write import PIECE, paged_write
 
 __all__ = ["blha_attention", "paged_counts", "attention_positions", "first_key", "decodes_in_kernel",
-           "cache_write_counts", "writes_in_kernel", "lane_packing",
+           "chunks_in_kernel", "cache_write_counts", "writes_in_kernel", "lane_packing",
            "build_padding_metadata", "rope_rotate"]
 
 _CTX_BLOCK = 512    # cache positions a pass over the context reads
 _ROW_TILE = 8       # one-token rows that share a trip count
 _WRITE_VALUES = 1 << 21  # new keys (and as many values) ``paged_write`` holds whole in VMEM
+_PASS_VALUES = 1 << 22   # keys (and as many values) of a pass ``paged_chunk`` holds, twice, in VMEM
 
 
 def rope_rotate(x, cos, sin, neox: bool):
@@ -242,6 +254,26 @@ def writes_in_kernel(cache_dtype, *, head_dim: int, block_size: int, rows: int,
             and tokens * kv_heads * head_dim <= _WRITE_VALUES)
 
 
+def chunks_in_kernel(q_dtype, cache_dtype, *, head_dim: int, block_size: int, rows: int,
+                     blocks_per_seq: int, tokens: int, kv_heads: int,
+                     plain: bool = True) -> bool:
+    """Whether a call's CHUNK rows (``now > 1``) attend through the Pallas kernel
+    (``ops/pallas/paged_chunk.py``), decided as ``decodes_in_kernel`` decides and
+    from the same things: the platform is the TPU; the cache is an unquantised
+    bfloat16 pool and the queries are of its type; ``plain``; ``head_dim`` (of the
+    POOL's rows, ``kv_heads`` of them a position) is whole 128-lane tiles and
+    ``block_size`` whole sublane tiles; the block table and the list of work
+    items (four words a tile of a row's tokens: at most ``tokens / 16 + rows``)
+    fit the kernel's scalar memory, a pass of ``_CTX_BLOCK`` positions its VMEM.
+    Anything else takes the blocked XLA pass a row at a time."""
+    return (on_tpu() and plain
+            and jnp.dtype(cache_dtype) == jnp.bfloat16
+            and jnp.dtype(q_dtype) == jnp.bfloat16
+            and head_dim % 128 == 0 and block_size % 16 == 0
+            and rows * blocks_per_seq + 4 * (tokens // 16 + rows) <= _TABLE_WORDS
+            and _CTX_BLOCK * kv_heads * head_dim <= _PASS_VALUES)
+
+
 def cache_write_counts(seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, *,
                        kernel: bool = False):
     """What one ``blha_attention`` call with these lengths writes into ONE
@@ -267,7 +299,8 @@ def first_key(pos, window):
 
 def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
                         block_size: int, blocks_per_seq: int,
-                        kernel: bool = False, window=None):
+                        kernel: bool = False, window=None, chunk_tile=None,
+                        max_q_len: int = 1):
     """What one ``blha_attention`` call with these lengths attends and what
     it reads for that, as int32 scalars (live, read, rows_kernel): ``live``
     the context of every row fed, ``dec + now``; ``read`` the cache positions
@@ -277,33 +310,37 @@ def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
     rows reads its longest row's passes for all of its ``_ROW_TILE`` rows and
     a chunk row its own. With ``kernel`` (``decodes_in_kernel`` of the call) a
     one-token row reads its own context, this step's token with it, rounded
-    up to a block: the kernel fetches no block past the row's end.  Under a
-    ``window`` every walk starts at the pass (the kernel: the block) that holds
+    up to a block: the kernel fetches no block past the row's end.  With
+    ``chunk_tile`` (``chunks_in_kernel`` of the call: the tokens of a
+    ``paged_chunk`` work item, of rows that feed ``max_q_len`` at most) a
+    chunk row reads what its tiles' trips bring: each the blocks from the one
+    that holds its first token's first key to its last token's.  Under a
+    ``window`` every walk starts at the pass (the kernels: the block) that holds
     the first key its first query attends, so ``read`` falls short of ``live``
     by what the window spared."""
     dec, now = seq_lens_decoder, seq_lens_this_time
     _, Lc = _context_block(block_size, blocks_per_seq)
     live = jnp.sum(jnp.where(now > 0, dec + now, 0))
-    if window is not None:
-        one = now == 1
-        # every walk less the passes (the kernel: the blocks) wholly behind its first key
+    one = now == 1
+    if chunk_tile is None:
+        # a row's passes less those wholly behind its first key, its own tokens from registers
         chunks = jnp.sum(jnp.where(
             now > 1, (_trips(dec, Lc) - first_key(dec, window) // Lc) * Lc + now, 0))
-        if kernel:
-            ones = jnp.sum(jnp.where(one, _trips(dec + 1, block_size)
-                                     - first_key(dec, window) // block_size, 0)) * block_size
-        else:
-            _, live_t, cached = _one_token_tiles(dec, now)
-            first = jnp.min(jnp.where(live_t, first_key(cached, window),
-                                      jnp.iinfo(jnp.int32).max), axis=1) // Lc
-            ones = (jnp.sum(jnp.maximum(_trips(jnp.max(cached, axis=1), Lc) - first, 0))
-                    * _ROW_TILE * Lc + jnp.sum(one))
-        return (live.astype(jnp.int32), (ones + chunks).astype(jnp.int32),
-                jnp.sum(one & kernel).astype(jnp.int32))
-    chunks = jnp.sum(jnp.where(now > 1, _trips(dec, Lc) * Lc + now, 0))
-    one = now == 1
+    else:
+        at = jnp.arange(0, max(int(max_q_len), 1), chunk_tile, dtype=jnp.int32)[None, :]
+        d, n = dec[:, None], now[:, None]                   # a tile's first token: ``at``
+        walked = (_trips(d + jnp.minimum(n, at + chunk_tile), block_size)
+                  - first_key(d + at, window) // block_size)
+        chunks = jnp.sum(jnp.where((n > 1) & (at < n), walked, 0)) * block_size
     if kernel:
-        ones = jnp.sum(jnp.where(one, _trips(dec + 1, block_size), 0)) * block_size
+        ones = jnp.sum(jnp.where(one, _trips(dec + 1, block_size)
+                                 - first_key(dec, window) // block_size, 0)) * block_size
+    elif window is not None:
+        _, live_t, cached = _one_token_tiles(dec, now)
+        first = jnp.min(jnp.where(live_t, first_key(cached, window),
+                                  jnp.iinfo(jnp.int32).max), axis=1) // Lc
+        ones = (jnp.sum(jnp.maximum(_trips(jnp.max(cached, axis=1), Lc) - first, 0))
+                * _ROW_TILE * Lc + jnp.sum(one))
     else:
         _, _, cached = _one_token_tiles(dec, now)
         ones = (jnp.sum(_trips(jnp.max(cached, axis=1), Lc)) * _ROW_TILE * Lc
@@ -313,31 +350,38 @@ def attention_positions(seq_lens_decoder, seq_lens_this_time, *,
 
 
 def _kernels(q_dtype, pool, bt, tokens: int, plain: bool):
-    """(``decodes_in_kernel``, ``writes_in_kernel``) asked with what the POOL says of
-    itself (its rows, their width, the block size; a stacked pool's layer axis is not
-    read): the ONE place the questions are put, for the call and for ``paged_counts``."""
+    """(``decodes_in_kernel``, ``writes_in_kernel``, ``chunks_in_kernel``) asked with
+    what the POOL says of itself (its rows, their width, the block size; a stacked
+    pool's layer axis is not read): the ONE place the questions are put, for the call
+    and for ``paged_counts``."""
     kv_rows, bs, lanes = pool.shape[-3:]
     sizes = dict(head_dim=lanes, block_size=bs, rows=bt.shape[0], blocks_per_seq=bt.shape[1])
     return (decodes_in_kernel(q_dtype, pool.dtype, plain=plain, **sizes),
-            writes_in_kernel(pool.dtype, tokens=tokens, kv_heads=kv_rows, **sizes))
+            writes_in_kernel(pool.dtype, tokens=tokens, kv_heads=kv_rows, **sizes),
+            chunks_in_kernel(q_dtype, pool.dtype, tokens=tokens, kv_heads=kv_rows,
+                             plain=plain, **sizes))
 
 
-def paged_counts(q_dtype, key_pool, dec, now, cu, bt, *, tokens: int, plain: bool = True,
-                 window=None):
+def paged_counts(q_dtype, key_pool, dec, now, cu, bt, *, tokens: int, heads: int,
+                 max_q_len: int, plain: bool = True, window=None):
     """What ONE cache layer's ``blha_attention`` call did, for a trunk's ``counts``:
-    ``attention_positions``'s three and ``cache_write_counts``'s two, the kernels
-    asked as the call asks them.  ``q_dtype``: the queries' (``compute_dtype``);
-    ``key_pool``: a layer's key pool as the trunk holds it (plain, lane-packed or
-    stacked); ``tokens``: the packed buffer's; ``plain``: as ``decodes_in_kernel``'s;
-    ``window``: the call's."""
-    decodes, writes = _kernels(q_dtype, key_pool, bt, tokens, plain)
+    ``attention_positions``'s three, the chunk rows ``paged_chunk`` took and
+    ``cache_write_counts``'s two, the kernels asked as the call asks them.
+    ``q_dtype``: the queries' (``compute_dtype``); ``key_pool``: a layer's key pool
+    as the trunk holds it (plain, lane-packed or stacked); ``tokens``: the packed
+    buffer's; ``heads``, ``max_q_len``, ``window``: the call's ``num_heads`` and
+    its own two; ``plain``: as ``decodes_in_kernel``'s."""
+    decodes, writes, chunks = _kernels(q_dtype, key_pool, bt, tokens, plain)
+    chunks = chunks and max_q_len > 1
     live, read, in_kernel = attention_positions(
         dec, now, block_size=key_pool.shape[-2], blocks_per_seq=bt.shape[1], kernel=decodes,
-        window=window)
+        window=window, max_q_len=max_q_len,
+        chunk_tile=tile_tokens(heads // key_pool.shape[-3], max_q_len) if chunks else None)
     written, pieces = cache_write_counts(dec, now, cu, kernel=writes)
     return {"attn_positions_live": live, "attn_positions_read": read,
-            "attn_rows_kernel": in_kernel, "kv_write_tokens": written,
-            "kv_write_blocks": pieces}
+            "attn_rows_kernel": in_kernel,
+            "attn_chunks_kernel": jnp.sum((now > 1) & chunks).astype(jnp.int32),
+            "kv_write_tokens": written, "kv_write_blocks": pieces}
 
 
 def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
@@ -363,7 +407,7 @@ def _additive_bias(mask, tgt_mask, enc, now, S: int, width: int):
 def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
                        block_tables, *, max_q_len: int, scale: float, quant: bool,
                        k_dequant, v_dequant, pre_k, pre_v, mask, tgt_mask, in_kernel: bool,
-                       window=None):
+                       chunks: bool, window=None):
     """Steps 6-8 of ``blha_attention``: q [T, H, D] and this step's k, v
     [T, KV, D] against the pool, which already holds them. Returns
     [T, H, D] float32, zeros for tokens of no live row.  (Over a pool that
@@ -499,6 +543,7 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
             0, _trips(jnp.sum(now == 1).astype(jnp.int32), _ROW_TILE), tile,
             jnp.zeros((T + S, H, D), jnp.float32))
 
+    Hp = padded_heads(H) if chunks and S > 1 else H
     if in_kernel:
         # straight from the pool, which the write has already given this
         # step's token in the value the XLA pass attends from registers
@@ -506,12 +551,22 @@ def _blocked_attention(q, k, v, key_cache, value_cache, enc, dec, now, cu,
         o = paged_decode(q1, key_cache, value_cache,
                          jnp.where(now == 1, dec + 1, 0), block_tables, scale=scale,
                          window=window)
-        out = jnp.zeros((T + S, H, D), jnp.float32).at[
-            jnp.where(now == 1, cu[:-1], T + S)].set(o.reshape(B, H, D), mode="drop")
+        # (where the chunk rows' kernel follows, a token's heads as ITS copies
+        # want them: whole tiles)
+        out = jnp.zeros((T + S, Hp, D), jnp.float32).at[
+            jnp.where(now == 1, cu[:-1], T + S), :H].set(o.reshape(B, H, D), mode="drop")
     else:
         out = one_token_tiles()
+        if Hp > H:
+            out = jnp.pad(out, ((0, 0), (0, Hp - H), (0, 0)))
     if S == 1:
         return out[:T]
+    if chunks:
+        # all of them in ONE call, straight from the pool as the one-token
+        # rows' kernel reads it: a row's own blocks by the table, from the block
+        # of its first token's first key
+        return paged_chunk(q, key_cache, value_cache, out, dec, now, cu, block_tables,
+                           scale=scale, max_q_len=S, window=window)[:T, :H]
 
     # ---- rows that feed a chunk: one at a time ------------------------------
     chunk_rows = jnp.nonzero(now > 1, size=B, fill_value=0)[0].astype(jnp.int32)
@@ -659,9 +714,9 @@ def blha_attention(
     (``while/body/``) ``kv_gather`` (a block's gather, an int8 block's
     integers), ``scores`` (QK^T, masks, the online softmax's bookkeeping),
     ``values`` (PV); what is under none is unpacking, token coordinates and
-    the return to the packed buffer. Where the one-token rows go through
-    the kernel they are in none of the three: a device trace shows the
-    custom call by its name, ``paged_decode``.
+    the return to the packed buffer. Where rows go through a kernel they
+    are in none of the three: a device trace shows the custom call by its
+    name, ``paged_decode`` (one-token rows) or ``paged_chunk`` (chunk rows).
     """
     H, KV, D, bs = num_heads, kv_num_heads, head_dim, block_size
     T = qkv.shape[0]
@@ -750,7 +805,7 @@ def blha_attention(
             cache_k_dequant_scales, cache_v_dequant_scales = new_kd, new_vd
 
         # ---- 5. K/V into the block pool -------------------------------------
-        decodes, by_row = _kernels(
+        decodes, by_row, chunks = _kernels(
             q.dtype, key_cache, block_tables, T, plain=cache_quant == "none"
             and mask is None and tgt_mask is None and pre_key_cache is None)
         by_row = by_row and cache_quant == "none"
@@ -821,7 +876,7 @@ def blha_attention(
         block_tables, max_q_len=max_q_len, scale=1.0 / (D ** 0.5), quant=quant,
         k_dequant=cache_k_dequant_scales, v_dequant=cache_v_dequant_scales,
         pre_k=pre_key_cache, pre_v=pre_value_cache, mask=mask,
-        tgt_mask=tgt_mask, in_kernel=decodes, window=window)
+        tgt_mask=tgt_mask, in_kernel=decodes, chunks=chunks, window=window)
     if pack > 1:
         out = jnp.sum(jnp.where(own, out.reshape(T, H, pack, D), 0), axis=2)
     out = out.reshape(T, H * D)

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["latent_rows", "TILE_TOKENS", "tile_tokens"]
+__all__ = ["latent_rows", "TILE_TOKENS", "tile_tokens", "work_items"]
 
 _NEG = -1e30            # a masked score (``ops/latent_attention.py`` takes it from here)
 TILE_TOKENS = 16        # tokens of a row a work item attends (x H query rows)
@@ -49,6 +49,30 @@ _SUBLANES = 8           # rows of an int32 tile
 
 def tile_tokens(max_q_len: int) -> int:
     return min(TILE_TOKENS, int(max_q_len))
+
+
+def work_items(seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, take, *, tokens: int,
+               tile: int):
+    """The work items of a call, compacted: (count [1], items [4 x N]) with
+    ``items`` = row | packed offset of the first token | its position | tokens,
+    N = ``tokens // tile + rows``: the tiles of ``tile`` tokens of the rows
+    ``take`` [B] names, in the rows' order. (A row's live tokens are those
+    before ``cu[-1]``, as the cache write has it.)"""
+    dec = seq_lens_decoder.astype(jnp.int32)
+    now = seq_lens_this_time.astype(jnp.int32)
+    cu = cu_seqlens_q.astype(jnp.int32)
+    B, T = dec.shape[0], int(tokens)
+    N = T // tile + B
+    live = jnp.clip(jnp.minimum(now, jnp.minimum(cu[-1], T) - cu[:-1]), 0)
+    tiles = jnp.where(take, (live + tile - 1) // tile, 0)
+    ends = jnp.cumsum(tiles)
+    k = jnp.arange(N, dtype=jnp.int32)
+    row = jnp.clip(jnp.searchsorted(ends, k, side="right").astype(jnp.int32), 0, B - 1)
+    first = (k - (ends - tiles)[row]) * tile
+    start = jnp.clip(cu[row] + first, 0, T - 1)
+    n = jnp.clip(live[row] - first, 1, tile)
+    count = jnp.minimum(ends[-1], N).astype(jnp.int32)
+    return count.reshape(1), jnp.concatenate([row, start, dec[row] + first, n])
 
 
 def _kernel(count_ref, items_ref, bt_ref, q_hbm, kv_hbm, *rest,
@@ -273,22 +297,8 @@ def latent_rows(q, cache, out, seq_lens_decoder, seq_lens_this_time, cu_seqlens_
     L = per * bs
     N = T // TQ + B
     mask_rows = -(-TQ // _SUBLANES) * _SUBLANES
-    dec = seq_lens_decoder.astype(jnp.int32)
-    now = seq_lens_this_time.astype(jnp.int32)
-    cu = cu_seqlens_q.astype(jnp.int32)
-
-    # ---- the work items, compacted: a row's tiles in the rows' order --------
-    # (a row's live tokens are those before ``cu[-1]``, as the cache write has it)
-    live = jnp.clip(jnp.minimum(now, jnp.minimum(cu[-1], T) - cu[:-1]), 0)
-    tiles = jnp.where(take, (live + TQ - 1) // TQ, 0)
-    ends = jnp.cumsum(tiles)
-    k = jnp.arange(N, dtype=jnp.int32)
-    row = jnp.clip(jnp.searchsorted(ends, k, side="right").astype(jnp.int32), 0, B - 1)
-    first = (k - (ends - tiles)[row]) * TQ
-    start = jnp.clip(cu[row] + first, 0, T - 1)
-    n = jnp.clip(live[row] - first, 1, TQ)
-    count = jnp.minimum(ends[-1], N).astype(jnp.int32)
-    items = jnp.concatenate([row, start, dec[row] + first, n])
+    count, items = work_items(seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, take,
+                              tokens=T, tile=TQ)
 
     Pp = P + (-P) % per
     bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, Pp - P)),
@@ -333,4 +343,4 @@ def latent_rows(q, cache, out, seq_lens_decoder, seq_lens_this_time, cu_seqlens_
             vmem_limit_bytes=2 * _vmem_bytes(TQ, H, W, C, L, masked)),
         name="latent_rows",
         interpret=interpret,
-    )(count.reshape(1), items, bt, *operands)
+    )(count, items, bt, *operands)

@@ -23,9 +23,11 @@ it feeds prompt chunks),
 ``engine.wait`` (stats ``reads``: the blocking reads it made of copies started
 at the dispatch, 1, and one more where a scheduled row asked for
 log-probabilities), ``engine.harvest`` (stats: what the model's trunk counted
-in the launch, each from the op that did it: ``paged_counts``'s five for a
+in the launch, each from the op that did it: ``paged_counts``'s six for a
 dense paged cache (ops/paged_attention.py: ``attn_positions_live`` /
-``attn_positions_read`` / ``attn_rows_kernel``, ``kv_write_tokens`` /
+``attn_positions_read`` / ``attn_rows_kernel`` / ``attn_chunks_kernel`` (the
+one-token and the chunk rows an iteration that attended in the ``paged_decode``
+and the ``paged_chunk`` kernel; 0 where the XLA pass ran), ``kv_write_tokens`` /
 ``kv_write_blocks``); ``moe_tokens`` / ``moe_local_picks`` (``_moe_ffn``) and
 ``expert_rows_grouped`` (ops/held_experts.py: the picks that went through the
 grouped product; 0 where the tile loop ran) for expert layers; ``loop_tokens``
@@ -38,7 +40,8 @@ exceeds the model's ``index_topk``, beside ``attn_positions_live``;
 latent cache: the one-token and the chunk rows an iteration whose blocked pass
 ran in the ``latent_rows`` kernel; 0 where the XLA loops ran; for a model of
 several KINDS of cache layer (inference/serving_model.py) ONE layer of each
-kind's ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` and
+kind's ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` /
+``attn_chunks_kernel.<kind>`` and
 ``window_positions_spared`` (the live context behind the first key a fed row's
 first query attends), and the engine's totals as the span STARTS,
 ``window_blocks_released`` (monotone) and ``window_blocks_held``);
@@ -80,8 +83,8 @@ at run time), one vocabulary for every model family:
 chip the write is the ``paged_write`` kernel inside it) and,
 under ``while/body/`` once for each loop around them (row tiles or chunk
 rows, then context blocks), ``kv_gather`` ``scores`` ``values`` (on the
-chip a one-token row is in none of the three: it attends inside the
-``paged_decode`` kernel); ``attn_out``,
+chip a row is in none of the three: a one-token row attends inside the
+``paged_decode`` kernel, a chunk row inside ``paged_chunk``); ``attn_out``,
 ``mlp``, ``norm``, ``head``, ``sample``, ``scan_carry`` (the serving
 programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
 ``loop_pass`` > ``while/body/`` the layers' scopes, ``norm``, ``exit_gate``
@@ -102,7 +105,9 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
 ``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
-``int8_matmul``, ``paged_decode``, ``paged_write``, ``expert_gmm`` (three a
+``int8_matmul``, ``paged_decode``, ``paged_write``, ``paged_chunk`` (one a cache
+layer under ``paged_attention``, on the chip, where a row may feed more than
+one token), ``expert_gmm`` (three a
 layer under ``experts``, on the chip: gate, up, down), ``latent_rows`` (one a
 layer under ``latent_attention/rows_kernel``, on the chip).
 """

@@ -35,8 +35,9 @@ def test_a_conv_state_models_programs_fit_the_chip(chip, lfm2_engine, kind, monk
     eight layers, a pool of 2,048 blocks x keys and values x two attention
     layers, conv state ``[8, 128, 2, 2048]`` a slot) compiled as the chip will
     run them: heads of 64 lie two to a lane tile of the pool (ISSUE 41), so
-    each attention layer's one-token rows take ``paged_decode`` and its write
-    ``paged_write``, once a layer; a pool array ``[2048, 4, 64, 128]`` keeps
+    each attention layer's one-token rows take ``paged_decode``, its write
+    ``paged_write`` and (the prefill step, the mixed scan) its chunk rows
+    ``paged_chunk`` over the packed problem, once a layer; a pool array ``[2048, 4, 64, 128]`` keeps
     ONE layout, the argument's row-major order, and is copied in or out of no
     program (a head a row, ``[2048, 8, 64, 64]``, the compiler gave it a
     layout of its own and copied each array in and out of every program: 8
@@ -58,6 +59,9 @@ def test_a_conv_state_models_programs_fit_the_chip(chip, lfm2_engine, kind, monk
     text = compiled.as_text()
     attention = len(eng.caches[0])
     assert kernel_calls(text, "paged_decode") == kernel_calls(text, "paged_write") == attention
+    assert kernel_calls(text, "paged_chunk") == (
+        attention if kind in ("step_prefill_T512", "mixed_K8") else 0)
+    assert "kv_gather" not in text and "paged_attention/while" not in text
     state_copies = len(re.findall(r"= bf16\[8,128,2,2048\][^\n]* copy\(", text))
     assert state_copies == (1 if kind.startswith("mega") else 0)
     pool = rf"bf16\[{nb},4,{bs},128\]"

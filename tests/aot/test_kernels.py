@@ -129,9 +129,12 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, call, monkeypat
     of 128, a pool of 1024 blocks), two iterations in a scan with the pool
     in the carry as the engine's scans hold it, compiled as the chip will
     run it: whether a row feeds one token is data, so every one of the three
-    holds the one-token rows' ``paged_decode`` kernel (the chunk rows' pass
-    is XLA) and, since ISSUE 31, the cache write's ``paged_write`` kernel,
-    which takes the pool where it lies and returns it. What the chip's
+    holds the one-token rows' ``paged_decode`` kernel, since ISSUE 31 the cache
+    write's ``paged_write`` kernel, which takes the pool where it lies and
+    returns it, and since ISSUE 46, where a row may feed more than one token,
+    the chunk rows' ``paged_chunk`` kernel: ONE call an iteration, no loop over
+    chunk rows or context blocks left under ``paged_attention`` (no
+    ``kv_gather``), and a pass's float32 scores nowhere in the program. What the chip's
     compiler must not do is what it did before
     ISSUE 27: keep a second copy of the pool in another layout (the write,
     the gather and the kernel's operand must agree on one, or 268 MB a layer
@@ -167,6 +170,9 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, call, monkeypat
         donate=(1, 2))
     text = compiled.as_text()
     assert "kv_write/scatter" not in text
+    assert kernel_calls(text, "paged_chunk") == int(mq > 1)
+    assert "kv_gather" not in text and "paged_attention/while" not in text
+    assert not re.search(r"f32\[\d+,\d+,512\]", text)          # [KV, g x S, Lc] scores
     pool_bytes = math.prod(shape) * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes // 2, f"{temp / 1e6:.0f} MB of temporaries"

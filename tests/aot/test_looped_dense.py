@@ -20,7 +20,9 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     whole (48 layers' weights stacked, four passes, a pool of 192 cache
     layers x 6,144 tokens: 9.66 GB) compiled as the chip will run them. Both
     loops are loops of the program: ONE ``paged_decode`` call and ONE
-    ``paged_write`` call in its text, and no scatter under ``kv_write``.
+    ``paged_write`` call in its text, ONE ``paged_chunk`` call where a row may
+    feed more than one token (the stacked pool's layer is block numbers moved
+    before the call), no scatter under ``kv_write`` and no gather.
     The pool is written and read in place at a traced layer index: one
     layout of it (its stacked form and the same bytes seen as layers x blocks),
     no copy, no temporary the size of one cache layer; the stacked weights
@@ -33,7 +35,8 @@ def test_a_looped_models_programs_keep_one_pool_in_place(chip, ouro_engine, kind
     compiled = compiled_program(eng, cfg, kind, chip)
     text = compiled.as_text()
     assert kernel_calls(text, "paged_decode") == 1 and kernel_calls(text, "paged_write") == 1
-    assert "kv_write/scatter" not in text
+    assert kernel_calls(text, "paged_chunk") == int(kind != "mega_K8")
+    assert "kv_write/scatter" not in text and "kv_gather" not in text
     pool = (192, nb, 16, eng.bs, 128)
     assert eng.caches[0].shape == (192, 2) + pool[2:] and nb * eng.bs == 6144
     stacked = ",".join(map(str, pool))

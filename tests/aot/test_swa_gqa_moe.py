@@ -26,9 +26,12 @@ def test_a_model_of_two_cache_kinds_fits_the_chip_as_its_file_says(chip, swa_eng
     the chip will run them: 5.56 B parameters with every expert of twelve layers,
     a pool of 3,456 blocks for the three global layers and one of 2,560 for the
     nine window layers (keys and values ``[blocks, 4, 64, 128]`` a layer), TWO
-    block tables in the ONE control block.  Heads of 128 keep both kernels and 7
+    block tables in the ONE control block.  Heads of 128 keep the kernels and 7
     query heads a key/value head ride ``paged_decode`` as a sublane tile of 8:
-    one ``paged_decode`` and one ``paged_write`` a cache layer, window or not; a
+    one ``paged_decode`` and one ``paged_write`` a cache layer, window or not,
+    and where a row may feed more than one token (the prefill step, the mixed
+    scan) one ``paged_chunk``, 28 heads a token riding as 32 in its copies, with
+    no loop over chunk rows or context blocks left under ``paged_attention``; a
     pool array keeps ONE layout, the argument's row-major order, and is copied
     in or out of no program; the expert layer is three grouped products
     (``expert_gmm`` three times a layer); ``arguments`` and ``live`` are the
@@ -45,6 +48,9 @@ def test_a_model_of_two_cache_kinds_fits_the_chip_as_its_file_says(chip, swa_eng
     compiled = compiled_program(eng, cfg, kind, chip)
     text = compiled.as_text()
     assert kernel_calls(text, "paged_decode") == kernel_calls(text, "paged_write") == 12
+    assert kernel_calls(text, "paged_chunk") == (12 if kind in ("step_prefill_T512", "mixed_K8")
+                                                 else 0)
+    assert "kv_gather" not in text and "paged_attention/while" not in text
     assert kernel_calls(text, "expert_gmm") == 3 * 12
     for blocks in (nb["global"], nb["window"]):
         pool = rf"bf16\[{blocks},4,{bs},128\]"

@@ -511,16 +511,18 @@ def test_an_engine_steered_onto_the_chip_sends_heads_of_64_through_both_kernels(
     plain, _, want = run()
     assert plain.attn_rows_kernel == 0 == plain.kv_write_blocks
     monkeypatch.setattr(serving, "_PROGRAM_CACHE", {})
-    test_paged_attention._steer_onto_the_chip(monkeypatch)
+    test_paged_attention._steer_onto_the_chip(monkeypatch, chunks=True)
     pa.blha_attention.clear_cache()     # the one jitted function that asks the platform
     try:
         eng, seen, got = run()
     finally:
         pa.blha_attention.clear_cache()
     assert got == want
-    # both prompts in one prefill step (chunk rows: the XLA pass), then each
-    # row decodes its other 8 tokens a row a scan iteration
+    # both prompts in one prefill step (chunk rows: ``paged_chunk``, over the packed
+    # pool as the caller hands it), then each row decodes its other 8 tokens a row a
+    # scan iteration
     assert eng.attn_rows_kernel == 2 * 8 == sum(a["attn_rows_kernel"] for a in seen)
+    assert eng.attn_chunks_kernel == 2 == sum(a["attn_chunks_kernel"] for a in seen)
     assert eng.attn_positions_live == plain.attn_positions_live
     assert eng.attn_positions_read < plain.attn_positions_read
     # the scatter's tokens; the prompts lie in one piece of 16 positions and

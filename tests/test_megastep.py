@@ -958,31 +958,34 @@ class TestRowsThroughTheKernel:
         assert mine, f"no {kind} launch"
         for attrs, _ in mine:
             assert set(attrs) == {"attn_positions_live", "attn_positions_read",
-                                  "attn_rows_kernel", "kv_write_tokens",
-                                  "kv_write_blocks"}
+                                  "attn_rows_kernel", "attn_chunks_kernel",
+                                  "kv_write_tokens", "kv_write_blocks"}
             assert attrs["attn_rows_kernel"] == 0 < attrs["attn_positions_live"]
+            assert attrs["attn_chunks_kernel"] == 0
             # the CPU's path is the scatter: tokens written, no piece moved
             assert attrs["kv_write_blocks"] == 0 < attrs["kv_write_tokens"]
         assert eng.attn_rows_kernel == 0 == eng.kv_write_blocks
         assert eng.kv_write_tokens == sum(a["kv_write_tokens"] for _, a, _ in seen)
         assert eng.state_summary()["attention"] == {
             "positions_live": eng.attn_positions_live,
-            "positions_read": eng.attn_positions_read, "rows_kernel": 0,
+            "positions_read": eng.attn_positions_read, "rows_kernel": 0, "chunks_kernel": 0,
             "kv_write_tokens": eng.kv_write_tokens, "kv_write_blocks": 0}
 
     def test_an_engine_steered_onto_the_chip_counts_its_decoding_rows(self, monkeypatch):
         """A bf16 model with heads of 128 and blocks of 16 is a call the
         kernel admits; with ``on_tpu`` answering yes (and the kernel in
         interpret mode) every decoding row of every scan iteration goes
-        through it: the counter is monotone, equals the rows decoded, the
-        kernel path reads less than the XLA pass's tiles, and the tokens are
-        the XLA pass's."""
+        through it, and the prefill step's two chunk rows through
+        ``paged_chunk``: the counters are monotone, equal the rows decoded and
+        the chunk rows fed, the kernel path reads less than the XLA pass's
+        tiles, and the tokens are the XLA pass's."""
         import functools
 
         from paddle_tpu.distributed.topology import set_hybrid_communicate_group
         from paddle_tpu.inference import serving
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         from paddle_tpu.ops import paged_attention as pa
+        from paddle_tpu.ops.pallas import paged_chunk as pc
         from paddle_tpu.ops.pallas import paged_decode as pd
         from paddle_tpu.ops.pallas import paged_write as pw
 
@@ -1016,6 +1019,8 @@ class TestRowsThroughTheKernel:
                             functools.partial(pd.paged_decode, interpret=True))
         monkeypatch.setattr(pa, "paged_write",
                             functools.partial(pw.paged_write, interpret=True))
+        monkeypatch.setattr(pa, "paged_chunk",
+                            functools.partial(pc.paged_chunk, interpret=True))
         pa.blha_attention.clear_cache()
         try:
             eng, seen, got = run()
@@ -1025,9 +1030,12 @@ class TestRowsThroughTheKernel:
         totals = [t for _, _, t in seen]
         assert totals == sorted(totals) and eng.attn_rows_kernel > 0
         assert eng.attn_rows_kernel == sum(a["attn_rows_kernel"] for _, a, _ in seen)
-        # both prompts in one prefill step (chunk rows: the XLA pass), then
+        # both prompts in one prefill step (chunk rows: ``paged_chunk``), then
         # each row decodes its other 8 tokens a row a scan iteration
         assert eng.attn_rows_kernel == 2 * 8
+        assert eng.attn_chunks_kernel == 2 == sum(a["attn_chunks_kernel"] for _, a, _ in seen)
+        assert plain.attn_chunks_kernel == 0
+        assert eng.state_summary()["attention"]["chunks_kernel"] == 2
         assert eng.attn_positions_live == plain.attn_positions_live
         assert eng.attn_positions_read < plain.attn_positions_read
         assert eng.state_summary()["attention"]["rows_kernel"] == 16

@@ -165,6 +165,7 @@ KERNEL_NAMES = {
     "fused_ops.py": ["fused_rope", "swiglu_fwd", "swiglu_bwd"],
     "int8_matmul.py": ["int8_matmul"],
     "latent_rows.py": ["latent_rows"],
+    "paged_chunk.py": ["paged_chunk"],
     "paged_decode.py": ["paged_decode"],
     "paged_write.py": ["paged_write"],
 }

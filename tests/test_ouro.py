@@ -365,8 +365,8 @@ def test_loop_counters_are_monotone_and_ride_the_spans(built):
     assert eng.loop_tokens == 70 + 15 and eng.loop_token_passes == 4 * eng.loop_tokens
     assert eng.state_summary()["loop"] == {"passes": 4, "tokens": 85, "token_passes": 340}
     names = {"loop_tokens", "loop_token_passes", "attn_positions_live",
-             "attn_positions_read", "attn_rows_kernel", "kv_write_tokens",
-             "kv_write_blocks"}
+             "attn_positions_read", "attn_rows_kernel", "attn_chunks_kernel",
+             "kv_write_tokens", "kv_write_blocks"}
     assert {k for k, _, _ in harvests} >= {"step", "mega", "mixed"}
     assert all(set(h) == names for _, _, h in harvests)
     assert all(l["passes"] == 4 and l["kind"] == k for k, l, _ in harvests)
@@ -403,6 +403,7 @@ def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypat
 
     from paddle_tpu.inference import serving
     from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops.pallas import paged_chunk as pc
     from paddle_tpu.ops.pallas import paged_decode as pd
     from paddle_tpu.ops.pallas import paged_write as pw
 
@@ -426,6 +427,7 @@ def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypat
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     monkeypatch.setattr(pa, "paged_decode", functools.partial(pd.paged_decode, interpret=True))
     monkeypatch.setattr(pa, "paged_write", functools.partial(pw.paged_write, interpret=True))
+    monkeypatch.setattr(pa, "paged_chunk", functools.partial(pc.paged_chunk, interpret=True))
     pa.blha_attention.clear_cache()
     try:
         eng, got = run()
@@ -437,3 +439,6 @@ def test_a_looped_engine_steered_onto_the_chip_writes_its_layer_by_row(monkeypat
     # back in one
     assert eng.kv_write_blocks == 1 + 2 + 12
     assert eng.attn_rows_kernel == 12
+    # the prefill step's two chunk rows, ONE cache layer's: the stacked pool's layer is
+    # block numbers moved before the call, so ``paged_chunk`` rides it unchanged
+    assert eng.attn_chunks_kernel == 2 and plain.attn_chunks_kernel == 0

@@ -634,6 +634,7 @@ def test_blocked_pass_matches_the_padded_form(layout, group, dtype, quant, holes
 # steer ``on_tpu`` and run the kernel in interpret mode; the reference is the
 # padded form above. Blocks of 16 in a table of 24, heads of 128: a pass is
 # 8 blocks, 128 positions.
+from paddle_tpu.ops.pallas import paged_chunk as pc    # noqa: E402
 from paddle_tpu.ops.pallas import paged_decode as pd   # noqa: E402
 from paddle_tpu.ops.pallas import paged_write as pw    # noqa: E402
 
@@ -694,9 +695,12 @@ def _fresh_call(args, kw):
         *args, **arrays)
 
 
-def _steer_onto_the_chip(monkeypatch):
+def _steer_onto_the_chip(monkeypatch, chunks=False):
     """``on_tpu`` answers yes and both kernels run in interpret mode; returns
-    the list that grows by one with every ``paged_write`` call traced."""
+    the list that grows by one with every ``paged_write`` call traced.
+    ``chunks``: the chunk rows' kernel too (``paged_chunk``, interpreted); else
+    they keep the XLA pass, as this file's cases compare them (that kernel's
+    are tests/test_paged_chunk_kernel.py)."""
     calls = []
 
     def write(*a, **k):
@@ -707,6 +711,10 @@ def _steer_onto_the_chip(monkeypatch):
     monkeypatch.setattr(pa, "paged_decode",
                         functools.partial(pd.paged_decode, interpret=True))
     monkeypatch.setattr(pa, "paged_write", write)
+    if chunks:
+        monkeypatch.setattr(pa, "paged_chunk", functools.partial(pc.paged_chunk, interpret=True))
+    else:
+        monkeypatch.setattr(pa, "chunks_in_kernel", lambda *a, **k: False)
     return calls
 
 
@@ -1320,7 +1328,8 @@ def test_the_kernel_takes_groups_of_seven_and_a_windows_first_block(monkeypatch,
     np.testing.assert_array_equal(_bits(kc), _bits(scatter[1]))
     np.testing.assert_array_equal(_bits(vc), _bits(scatter[2]))
     counts = pa.paged_counts(jnp.bfloat16, args[1], args[4], args[5], args[6], args[7],
-                             tokens=args[0].shape[0], window=W)
+                             tokens=args[0].shape[0], heads=kw["num_heads"], max_q_len=kw["max_q_len"],
+                             window=W)
     # a one-token row brings the blocks from its window's first to its own: 16 k
     # under a window of 4,096 is 65 blocks of 251 by the same arithmetic
     ones = [d for d, n in K_ROWS if n == 1]

@@ -473,8 +473,8 @@ def test_expert_counters_are_monotone_and_ride_the_harvest_span(built):
     # the CPU: every row of the latent cache took the XLA loops
     assert eng.state_summary()["latent_attention"] == {"rows_kernel": 0, "chunks_kernel": 0}
     assert eng.state_summary()["attention"] == {"positions_live": 0, "positions_read": 0,
-                                                "rows_kernel": 0, "kv_write_tokens": 0,
-                                                "kv_write_blocks": 0}
+                                                "rows_kernel": 0, "chunks_kernel": 0,
+                                                "kv_write_tokens": 0, "kv_write_blocks": 0}
     assert sum(a["moe_tokens"] for a in seen) == eng.moe_tokens
     assert sum(a["moe_local_picks"] for a in seen) == eng.moe_local_picks
 
@@ -522,12 +522,13 @@ def test_attention_counters_are_monotone_and_ride_the_harvest_span():
         "positions_live": eng.attn_positions_live,
         "positions_read": eng.attn_positions_read,
         "rows_kernel": 0,                  # the CPU: every row took the XLA pass
+        "chunks_kernel": 0,
         "kv_write_tokens": 3 + 5 + 2,      # ... and the scatter wrote every token fed
         "kv_write_blocks": 0}
     seen = [a for _, a in harvests]
     assert len(seen) == 2
-    assert all(set(a) == {"attn_positions_live", "attn_positions_read",
-                          "attn_rows_kernel", "kv_write_tokens", "kv_write_blocks"}
+    assert all(set(a) == {"attn_positions_live", "attn_positions_read", "attn_rows_kernel",
+                          "attn_chunks_kernel", "kv_write_tokens", "kv_write_blocks"}
                for a in seen)
     assert eng.attn_rows_kernel == sum(a["attn_rows_kernel"] for a in seen) == 0
     assert sum(a["attn_positions_live"] for a in seen) == eng.attn_positions_live

@@ -840,9 +840,12 @@ class ServingEngine:
         self.expert_rows_grouped = 0
         # an expert layer's tile loop (ops/held_experts.py ``held_experts``,
         # where the trunk counts it): held experts that got at least one row,
-        # summed over layers and iterations; the rows the tiles multiplied,
-        # and those among them that were a live pick
+        # summed over layers and iterations; the tiles in use (over
+        # ``experts_touched``, tiles an expert: 1 where the layout of the
+        # sorted rows fits the routing); the rows the tiles multiplied, and
+        # those among them that were a live pick
         self.experts_touched = 0
+        self.expert_tiles = 0
         self.expert_tile_rows = 0
         self.expert_tile_rows_live = 0
         # state a slot: (row, layer) pairs whose state an iteration advanced
@@ -1733,6 +1736,7 @@ class ServingEngine:
             # the expert layers' tile loop, where a trunk counts it (monotone)
             "experts": {
                 "touched": self.experts_touched,
+                "tiles": self.expert_tiles,
                 "tile_rows": self.expert_tile_rows,
                 "tile_rows_live": self.expert_tile_rows_live,
             },

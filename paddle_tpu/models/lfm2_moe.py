@@ -395,7 +395,7 @@ class Lfm2MoeForCausalLM(nn.Layer):
         packed tokens through every layer; ``caches`` = (key pools, value
         pools: an attention layer each; conv state ``[conv layers, B, L - 1,
         E]``).  ``counts``: ``conv_rows_fed`` (row-layers whose state
-        advanced), the expert layers' six seeded below (``_moe_ffn``,
+        advanced), the expert layers' seven seeded below (``_moe_ffn``,
         ``held_experts``) and ONE attention layer's six (``paged_counts``)."""
         from ..ops.paged_attention import blha_attention, paged_counts
 
@@ -414,7 +414,8 @@ class Lfm2MoeForCausalLM(nn.Layer):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
                 "conv_rows_fed", "moe_tokens", "moe_local_picks", "experts_touched",
-                "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped")}
+                "expert_tiles", "expert_tile_rows", "expert_tile_rows_live",
+                "expert_rows_grouped")}
             for li, lw in enumerate(weights["layers"]):
                 with jax.named_scope("norm"):
                     h = _rms(hidden, lw["ln_op"], eps)

@@ -212,7 +212,8 @@ def _moe_ffn(cfg, p, x, valid=None, router=route, counts=None):
     adds ``moe_tokens`` (the ``valid`` tokens through it) and ``moe_local_picks``."""
     idx, w = router(x, p["router"], cfg.num_experts_per_tok, cfg.routed_scaling_factor)
     routed, picks = held_experts(x, idx, w, p["eg"], p["eu"], p["ed"],
-                                 cfg.experts_held[0], valid, counts=counts)
+                                 cfg.experts_held[0], valid, counts=counts,
+                                 routed=p["router"].shape[-1])
     if "sg" not in p:
         y = routed.astype(x.dtype)
     else:

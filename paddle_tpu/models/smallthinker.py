@@ -144,7 +144,7 @@ def _experts(cfg, p, u, logits, valid=None, counts=None):
     """The held experts' part of MoE(u; r). -> y in u's dtype."""
     idx, w = route_chosen(logits, cfg.moe_num_active_primary_experts)
     y, picks = held_experts(u, idx, w, p["eg"], p["eu"], p["ed"], cfg.experts_held[0],
-                            valid, counts=counts, activation="relu")
+                            valid, counts=counts, activation="relu", routed=logits.shape[-1])
     if counts is not None:
         counts["moe_tokens"] += (jnp.sum(valid).astype(jnp.int32) if valid is not None
                                  else u.shape[0])
@@ -314,7 +314,7 @@ class SmallThinkerForCausalLM(nn.Layer):
         packed tokens through every layer; ``caches`` = (key pools, value pools),
         the global layers' first, then the window layers'; ``bt`` a table a
         kind in that order (ONE table where the config has one kind).
-        ``counts``: the expert layers' six (``held_experts``), ONE global
+        ``counts``: the expert layers' seven (``held_experts``), ONE global
         layer's six (``paged_counts``), and by kind ONE layer's
         ``attn_positions_live.<kind>`` / ``attn_positions_read.<kind>`` /
         ``attn_chunks_kernel.<kind>`` with
@@ -339,8 +339,8 @@ class SmallThinkerForCausalLM(nn.Layer):
             with jax.named_scope("embed"):
                 hidden = weights["embed"][token_ids]
             counts = {name: jnp.zeros((), jnp.int32) for name in (
-                "moe_tokens", "moe_local_picks", "experts_touched", "expert_tile_rows",
-                "expert_tile_rows_live", "expert_rows_grouped")}
+                "moe_tokens", "moe_local_picks", "experts_touched", "expert_tiles",
+                "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped")}
             for li, lw in enumerate(weights["layers"]):
                 windowed = cfg.windowed(li)
                 with jax.named_scope("norm"):

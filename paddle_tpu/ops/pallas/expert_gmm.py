@@ -8,7 +8,14 @@ spare rows zeros.  ``w`` [n_held, K, N] stays where it lies in HBM: the
 weights' ``BlockSpec`` indexes the stack by the tile's expert (scalar
 prefetch), so Pallas's pipeline has the next tile's block on its way while
 this one multiplies, and consecutive tiles of one expert bring its block
-once.  The grid runs over the column blocks and then the ``n_tiles`` tiles in
+once.  The pipeline looks ONE grid step ahead: an expert of one tile has the
+next expert's matrix arriving while its rows multiply, and the call is bound
+by the read; an expert of two starts the next matrix at its SECOND tile, and
+the next expert's first tile then multiplies with nothing in flight (64
+experts of ``[2560, 768]`` and 44 rows each: 0.41 ms a product at tiles of 32,
+0.335 at tiles of 64; PERF.md section 6, PR 47).  So ``row_tile`` is the
+caller's, chosen from the call's geometry so that an expert is one tile
+(``ops/held_experts.py`` ``layout``); the kernel takes any.  The grid runs over the column blocks and then the ``n_tiles`` tiles in
 use (a traced number): what the kernel reads follows the experts that have a
 row, and a tile past the last live one costs nothing and is not written.
 One product a call, float32 accumulation over all of K.  The last product of

@@ -30,7 +30,10 @@ one-token and the chunk rows an iteration that attended in the ``paged_decode``
 and the ``paged_chunk`` kernel; 0 where the XLA pass ran), ``kv_write_tokens`` /
 ``kv_write_blocks``); ``moe_tokens`` / ``moe_local_picks`` (``_moe_ffn``) and
 ``expert_rows_grouped`` (ops/held_experts.py: the picks that went through the
-grouped product; 0 where the tile loop ran) for expert layers; ``loop_tokens``
+grouped product; 0 where the tile loop ran) for expert layers, and where the
+trunk seeds them ``experts_touched`` / ``expert_tiles`` (held experts with a
+row, and the row tiles in use: tiles an expert, 1 where the layout of the
+sorted rows fits the routing) / ``expert_tile_rows`` / ``expert_tile_rows_live``; ``loop_tokens``
 / ``loop_token_passes`` for a looped trunk: tokens fed, and tokens x passes
 run; ``dsa_queries`` / ``dsa_positions_scored`` / ``dsa_positions_selected``
 (ops/sparse_index.py) / ``dsa_positions_read`` (``selection_counts``) for

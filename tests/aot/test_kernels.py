@@ -191,8 +191,9 @@ def test_paged_attention_keeps_one_pool_and_no_whole_table(chip, call, monkeypat
     assert not big, big
 
 
-# (held experts, E, F, k) of lfm2-24b-a2b.serve1 and openpangu-ultra-moe-718b.serve1
-EXPERT_LAYERS = {"lfm2": (64, 2048, 1536, 4), "openpangu": (16, 7680, 2048, 8)}
+# (held experts, E, F, k, routed experts) of lfm2-24b-a2b.serve1 and
+# openpangu-ultra-moe-718b.serve1
+EXPERT_LAYERS = {"lfm2": (64, 2048, 1536, 4, 64), "openpangu": (16, 7680, 2048, 8, 256)}
 
 
 @pytest.mark.parametrize("family", sorted(EXPERT_LAYERS))
@@ -208,13 +209,14 @@ def test_the_expert_layer_is_three_grouped_products(chip, family, monkeypatch):
     from paddle_tpu.ops import held_experts as he
     from paddle_tpu.ops.pallas import expert_gmm
 
-    n_held, E, F, k = EXPERT_LAYERS[family]
+    n_held, E, F, k, routed = EXPERT_LAYERS[family]
     on_the_chip(monkeypatch)
     T = 512
 
     def layer(x, idx, w, eg, eu, ed, valid):
         counts = {"expert_rows_grouped": jnp.zeros((), jnp.int32)}
-        y, picks = he.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts)
+        y, picks = he.held_experts(x, idx, w, eg, eu, ed, 0, valid, counts=counts,
+                                   routed=routed)
         return y, picks, counts
 
     compiled = compile_kernel(

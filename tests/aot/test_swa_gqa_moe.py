@@ -19,6 +19,20 @@ def swa_engine():
     return engine_of("benchmark/configs/smallthinker-21b-a3b.serve1.json")
 
 
+def _expert_layout(eng, kind, text):
+    """The expert layers' operands in a compiled program's text: the sorted
+    rows a chunk gathers are ``layout``'s (row tile x chunk, E), and the loop
+    over chunks is in the text only where a chunk is less than the bound."""
+    from paddle_tpu.ops import held_experts as he
+
+    tokens = {"step_prefill_T512": eng.T, "mixed_K8": eng.T}.get(kind, eng.B)
+    row_tile, bound, chunk = he.layout(tokens, 6, 64, 64, 2 * 2560)
+    assert (row_tile, bound, chunk) == ((64, 112, 72) if tokens == 512 else (32, 73, 73))
+    assert re.search(rf"bf16\[{row_tile * chunk},2560\]", text)
+    assert not re.search(rf"bf16\[{32 * 102},2560\]", text)      # the parent's chunk of tile 32
+    assert ("experts/while" in text) == (chunk < bound)
+
+
 @pytest.mark.parametrize("kind", PROGRAMS)
 def test_a_model_of_two_cache_kinds_fits_the_chip_as_its_file_says(chip, swa_engine, kind,
                                                                     monkeypatch):
@@ -34,7 +48,11 @@ def test_a_model_of_two_cache_kinds_fits_the_chip_as_its_file_says(chip, swa_eng
     no loop over chunk rows or context blocks left under ``paged_attention``; a
     pool array keeps ONE layout, the argument's row-major order, and is copied
     in or out of no program; the expert layer is three grouped products
-    (``expert_gmm`` three times a layer); ``arguments`` and ``live`` are the
+    (``expert_gmm`` three times a layer) over a layout that follows the call
+    (ISSUE 47: 512 tokens x 6 picks over 64 experts are 48 rows an expert, so a
+    row tile of 64 and a chunk of the 64 expected tiles and eight more; the
+    decode scans' 48 tokens a tile of 32 and a chunk of the bound, no loop);
+    ``arguments`` and ``live`` are the
     configuration file's ``memory.compiled_for_v5e``, and the fullest program
     stands over 90 % of the chip."""
     on_the_chip(monkeypatch)
@@ -52,6 +70,7 @@ def test_a_model_of_two_cache_kinds_fits_the_chip_as_its_file_says(chip, swa_eng
                                                  else 0)
     assert "kv_gather" not in text and "paged_attention/while" not in text
     assert kernel_calls(text, "expert_gmm") == 3 * 12
+    _expert_layout(eng, kind, text)
     for blocks in (nb["global"], nb["window"]):
         pool = rf"bf16\[{blocks},4,{bs},128\]"
         assert set(re.findall(pool + r"\{([0-9,]+)", text)) == {"3,2,1,0"}

@@ -19,7 +19,7 @@ from paddle_tpu.ops.pallas import expert_gmm as gmm_module
 import programs
 
 FORMS = ["loop", "grouped"]
-COUNTS = ("experts_touched", "expert_tile_rows", "expert_tile_rows_live",
+COUNTS = ("experts_touched", "expert_tiles", "expert_tile_rows", "expert_tile_rows_live",
           "expert_rows_grouped")
 TILE = 8        # rows of a tile, both forms: small enough that the cases cross it
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -52,21 +52,21 @@ def run(monkeypatch):
     """run(form, ...) -> (y, picks, counts): ``held_experts`` taking ``form``.
     The grouped form is steered as a test steers ``paged_decode``: the
     platform answers yes, the kernel runs in interpret mode, and the row tile
-    is this file's; a float32 call is admitted by the test alone."""
-    def run(form, x, idx, w, eg, eu, ed, lo, valid=None):
+    is the ONE this file leaves the layout's rule to choose (or ``row_tiles``);
+    a float32 call is admitted by the test alone."""
+    def run(form, x, idx, w, eg, eu, ed, lo, valid=None, routed=None, row_tiles=None):
         counts = {n: jnp.zeros((), jnp.int32) for n in COUNTS}
         with monkeypatch.context() as m:
             if form == "grouped":
                 m.setattr(he, "on_tpu", lambda: True)
                 m.setattr(he, "expert_gmm",
                           functools.partial(gmm_module.expert_gmm, interpret=True))
-                m.setattr(he, "_ROW_TILE", TILE if x.dtype == jnp.float32 else 16)
-                m.setattr(he, "grouped_experts", functools.partial(
-                    he.grouped_experts, row_tile=he._ROW_TILE))
+                m.setattr(he, "_ROW_TILES",
+                          row_tiles or (TILE if x.dtype == jnp.float32 else 16,))
                 if x.dtype == jnp.float32:
                     m.setattr(he, "groups_in_kernel", lambda *a, **k: True)
             y, picks = he.held_experts(x, idx, w, eg, eu, ed, lo, valid,
-                                              tile=TILE, counts=counts)
+                                       tile=TILE, counts=counts, routed=routed)
         return np.asarray(y), int(picks), {n: int(v) for n, v in counts.items()}
     return run
 
@@ -78,6 +78,7 @@ def _tile_rows(sizes, tile=TILE):
 def _check_counts(form, counts, sizes, tile=TILE):
     picks = sum(sizes)
     assert counts == {"experts_touched": sum(s > 0 for s in sizes),
+                      "expert_tiles": _tile_rows(sizes, tile) // tile,
                       "expert_tile_rows": _tile_rows(sizes, tile),
                       "expert_tile_rows_live": picks,
                       "expert_rows_grouped": picks if form == "grouped" else 0}
@@ -195,9 +196,44 @@ def test_admission_is_decided_from_what_the_call_shows(monkeypatch):
                                           rows=256)             # the tiny geometries
     assert not he.groups_in_kernel(jnp.bfloat16, jnp.bfloat16, hidden=2048,
                                           width=1536, rows=1 << 20)
-    # a chunk is the tiles that hold the picks whatever the routing, or 16 MiB of rows
-    assert he._chunk_tiles(2048, 64, 4096, 32, 16 << 20) == (128, 128)
-    assert he._chunk_tiles(4096, 16, 15360, 32, 16 << 20) == (144, 34)
+
+
+# (tokens, k, held, routed, bytes of a row) of the four cells that run the layer: their
+# mixed scans' 512 packed tokens -> (rows of a tile, the bound in tiles, tiles of a chunk)
+CELLS = {
+    "smallthinker21b.serve.mixed-length": ((512, 6, 64, 64, 2 * 2560), (64, 112, 72)),
+    "lfm2-24b.serve.chat-batch": ((512, 4, 64, 64, 2 * 2048), (32, 128, 128)),
+    "openpangu718b.serve.doc-batch": ((512, 8, 16, 256, 2 * 7680), (32, 144, 34)),
+    "deepseekv32.serve.longdoc-batch": ((512, 8, 16, 256, 2 * 7168), (32, 144, 36)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_layout_follows_the_calls_geometry(cell):
+    """The rule at the four cells: an expert's expected rows (tokens x k /
+    routed: 48, 32, 16, 16) are ONE tile, the least of 32 / 64 / 128 that holds
+    them; the chunk holds every tile even routing puts in use and eight more,
+    so the loop over chunks does not run again at any of them (mixed-length's
+    44-48 rows an expert were two tiles of 32, and two trips)."""
+    (tokens, k, held, routed, row_bytes), want = CELLS[cell]
+    row_tile, bound, chunk = he.layout(tokens, k, held, routed, row_bytes)
+    assert (row_tile, bound, chunk) == want
+    rows = -(-tokens * k // routed)
+    assert rows <= row_tile and (row_tile == 32 or rows > row_tile // 2)
+    in_use = held * -(-rows // row_tile)            # under even routing: one an expert
+    assert in_use == held and in_use + he._SPARE_TILES <= chunk <= bound
+    assert row_tile * chunk <= he._ROW_WORDS
+
+
+@pytest.mark.parametrize("tokens,k,held,routed,want", [
+    (48, 6, 64, 64, (32, 73, 73)),          # mixed-length's decode scan: the bound, no loop
+    (128, 4, 64, 64, (32, 80, 80)),         # chat-batch's
+    (64, 8, 16, 256, (32, 32, 32)),         # doc-batch's
+    (4096, 8, 64, 64, (128, 320, 264)),     # past the largest tile: four tiles an expert
+    (512, 8, 256, 256, (32, 384, 264)),     # every one of 256 held
+])
+def test_the_layout_at_other_shapes(tokens, k, held, routed, want):
+    assert he.layout(tokens, k, held, routed, 2 * 2048) == want
 
 
 # ------------------------------------------------------------- the kernel
@@ -208,31 +244,124 @@ def _tiles(rng, tiles, tm, K, N, n_held, dtype=jnp.float32):
     return x, w, te
 
 
+ROW_TILES = [8, 32, 64]     # this file's small one, and the layout's two that cells run
+
+
+@pytest.mark.parametrize("tm", ROW_TILES)
 @pytest.mark.parametrize("columns", [None, 128])
-def test_the_kernel_multiplies_each_tile_by_its_expert(columns):
+def test_the_kernel_multiplies_each_tile_by_its_expert(columns, tm):
     rng = np.random.default_rng(7)
-    x, w, te = _tiles(rng, 6, 8, 64, 256, 3)
-    got = np.asarray(gmm_module.expert_gmm(x, w, te, jnp.int32(4), row_tile=8,
+    x, w, te = _tiles(rng, 6, tm, 64, 256, 3)
+    got = np.asarray(gmm_module.expert_gmm(x, w, te, jnp.int32(4), row_tile=tm,
                                            columns=columns, interpret=True))
-    want = jnp.einsum("tmk,tkn->tmn", x.reshape(6, 8, 64), w[te], precision=HIGHEST)
+    want = jnp.einsum("tmk,tkn->tmn", x.reshape(6, tm, 64), w[te], precision=HIGHEST)
     # the four tiles in use; the two past them were not written
-    assert np.abs(got[:32] - np.asarray(want).reshape(-1, 256)[:32]).max() < 2e-5
+    assert np.abs(got[:4 * tm] - np.asarray(want).reshape(-1, 256)[:4 * tm]).max() < 2e-5
 
 
+@pytest.mark.parametrize("tm", ROW_TILES)
 @pytest.mark.parametrize("n_tiles", [0, 3, 5])
-def test_the_kernel_adds_weighted_rows_to_their_tokens(n_tiles):
+def test_the_kernel_adds_weighted_rows_to_their_tokens(n_tiles, tm):
+    """The combining call: a spare row (weight 0, any token in range) adds
+    nothing, a tile after the last one in use is not read, and with none in
+    use the result is zeros."""
     rng = np.random.default_rng(8)
-    x, w, te = _tiles(rng, 5, 8, 64, 128, 4)
-    token = jnp.asarray(rng.integers(0, 12, 40), jnp.int32)
-    weight = jnp.asarray(rng.uniform(0, 1, 40) * (rng.uniform(size=40) < 0.7), jnp.float32)
-    got = np.asarray(gmm_module.expert_gmm(x, w, te, jnp.int32(n_tiles), row_tile=8,
+    rows = 5 * tm
+    x, w, te = _tiles(rng, 5, tm, 64, 128, 4)
+    token = jnp.asarray(rng.integers(0, 12, rows), jnp.int32)
+    weight = jnp.asarray(rng.uniform(0, 1, rows) * (rng.uniform(size=rows) < 0.7), jnp.float32)
+    got = np.asarray(gmm_module.expert_gmm(x, w, te, jnp.int32(n_tiles), row_tile=tm,
                                            combine=(token, weight, 12), interpret=True))
-    y = np.asarray(jnp.einsum("tmk,tkn->tmn", x.reshape(5, 8, 64), w[te],
-                              precision=HIGHEST)).reshape(40, 128)
+    y = np.asarray(jnp.einsum("tmk,tkn->tmn", x.reshape(5, tm, 64), w[te],
+                              precision=HIGHEST)).reshape(rows, 128)
     want = np.zeros((12, 128), np.float32)
-    for r in range(n_tiles * 8):
+    for r in range(n_tiles * tm):
         want[int(token[r])] += float(weight[r]) * y[r]
-    assert got.shape == (12, 128) and np.abs(got - want).max() < 2e-5
+    assert got.shape == (12, 128) and np.abs(got - want).max() < 5e-5
+
+
+# ------------------------------------------------- the grouped form's layout
+INTERPRETED = functools.partial(gmm_module.expert_gmm, interpret=True)
+
+
+def _picks_of(rng, sizes, lo=0):
+    """One pick a token, ``sizes[e]`` tokens on held expert ``e``, shuffled."""
+    picks_of = rng.permutation(np.repeat(np.arange(len(sizes)), sizes))
+    n = len(picks_of)
+    return (jnp.asarray(picks_of[:, None] + lo, jnp.int32),
+            jnp.asarray(rng.uniform(0.1, 1.0, size=(n, 1)), jnp.float32))
+
+
+@pytest.mark.parametrize("chunk", [None, 4])
+@pytest.mark.parametrize("row_tile", [32, 64])
+def test_grouped_rows_at_the_layouts_row_tiles(row_tile, chunk):
+    """``grouped_experts`` at the two row tiles the cells run, a chunk that
+    holds the bound (no loop) and one of four tiles (two or three trips, the
+    last with tiles after the last live one): experts of 70 and 150 rows lie
+    across tiles at both, one has no row, two share nothing of their tile with
+    another (their spare rows add nothing)."""
+    sizes = [3, 70, 0, 2, 150]
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(size=(sum(sizes), 32)), jnp.float32)
+    eg, eu, ed = _weights(rng, 5, 32, 16)
+    idx, w = _picks_of(rng, sizes)
+    in_use = sum(-(-s // row_tile) for s in sizes)
+    bound = he._bound(sum(sizes), 5, row_tile)
+    assert in_use <= bound and (chunk is None or chunk < in_use)
+    y, picks, touched, tiles, rows = he._held_experts(
+        x, idx, w, eg, eu, ed, None, lo=0, tile=TILE, gmm=INTERPRETED,
+        grouping=(row_tile, chunk or bound))
+    assert np.abs(np.asarray(y) - _plain(x, idx, w, eg, eu, ed, 0)).max() < 5e-5
+    assert (int(picks), int(touched), int(tiles), int(rows)) == (
+        sum(sizes), 4, in_use, in_use * row_tile)
+
+
+def test_picks_that_pile_up_run_the_chunk_loop_again_and_none_is_dropped(run, monkeypatch):
+    """4 of 16 routed experts held and 40 tokens that ALL pick one of them: a
+    chunk by expectation is four tiles (an expert expects 3 rows, one tile of
+    8), the picks are five, so the loop over chunks makes a second trip, and
+    every pick gets its expert."""
+    rng = np.random.default_rng(10)
+    x = jnp.asarray(rng.normal(size=(40, 16)), jnp.float32)
+    eg, eu, ed = _weights(rng, 4, 16, 8)
+    idx = jnp.full((40, 1), 9, jnp.int32)
+    w = jnp.full((40, 1), 0.5, jnp.float32)
+    # a chunk by expectation at this tiny size: no spare tiles, no floor of bytes under it
+    for name, value in (("_ROW_TILES", (TILE,)), ("_SPARE_TILES", 0), ("_CHUNK_BYTES", 0)):
+        monkeypatch.setattr(he, name, value)
+    assert he.layout(40, 1, 4, 16, 64) == (TILE, 9, 4)
+    y, picks, counts = run("grouped", x, idx, w, eg, eu, ed, 8, routed=16)
+    want = 0.5 * np.asarray(he._swiglu(x, eg[1], eu[1], ed[1]))
+    assert picks == 40 and np.abs(y - want).max() < 1e-5
+    _check_counts("grouped", counts, [0, 40, 0, 0])
+    assert counts["expert_tiles"] == 5 > 4
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_an_expert_is_one_tile_at_mixed_lengths_routing(run, form):
+    """mixed-length's routing, 470 live tokens of 512 with 6 picks each over 64
+    held experts by a seeded router: 44 rows an expert (the fullest 60), which
+    the layout's tile of 64 holds whole: ``expert_tiles / experts_touched`` is
+    1, where tiles of 32 made it 2.  (The tile loop's tile is the caller's,
+    this file's 8.)"""
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(512, 32)), jnp.float32)
+    eg, eu, ed = _weights(rng, 64, 32, 16)
+    idx, w = pangu_moe.route_chosen(jnp.asarray(rng.normal(size=(512, 64)), jnp.float32), 6)
+    valid = jnp.arange(512) < 470
+    sizes = [int(np.sum(np.asarray(idx)[:470] == e)) for e in range(64)]
+    assert 32 <= min(sizes) and max(sizes) <= 64 and sum(sizes) == 6 * 470
+    assert he.layout(512, 6, 64, 64, 4 * 32)[0] == 64
+    with jax.default_matmul_precision("highest"):
+        y, picks, counts = run(form, x, idx, w, eg, eu, ed, 0, valid, routed=64,
+                               row_tiles=he._ROW_TILES)
+    assert picks == 6 * 470
+    _check_counts(form, counts, sizes, 64 if form == "grouped" else TILE)
+    assert form == "loop" or counts["expert_tiles"] == counts["experts_touched"] == 64
+    assert sum(-(-s // 32) for s in sizes) == 127           # what tiles of 32 made of it
+    want = np.zeros((512, 32), np.float32)
+    want[:470] = _plain(x[:470], idx[:470], w[:470], eg, eu, ed, 0)
+    assert np.abs(y - want).max() < 1e-4
 
 
 def test_column_blocks_are_whole_lane_tiles_that_divide_the_width():
@@ -284,7 +413,9 @@ def test_an_engine_steered_onto_the_chip_groups_every_pick(monkeypatch):
     assert eng.moe_tokens == plain.moe_tokens
     assert eng.expert_rows_grouped == eng.moe_local_picks == eng.expert_tile_rows_live > 0
     assert sum(a["expert_rows_grouped"] for a in seen) == eng.expert_rows_grouped
-    assert eng.expert_tile_rows % he._ROW_TILE == 0
+    # the tiny programs' few tokens an expert: the layout's least tile
+    assert eng.expert_tile_rows == 32 * eng.expert_tiles > 0
+    assert sum(a["expert_tiles"] for a in seen) == eng.expert_tiles >= eng.experts_touched
     assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
                                           "local_picks": eng.moe_local_picks,
                                           "rows_grouped": eng.expert_rows_grouped}

@@ -454,7 +454,7 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     eng = ServingEngine(model, **ENGINE)
     harvests = programs.harvests(eng)
     names = ("conv_rows_fed", "moe_tokens", "moe_local_picks", "experts_touched",
-             "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped",
+             "expert_tiles", "expert_tile_rows", "expert_tile_rows_live", "expert_rows_grouped",
              "attn_positions_live", "kv_write_tokens", "attn_rows_kernel", "kv_write_blocks")
     assert all(getattr(eng, n) == 0 for n in names)
     for p in _prompts([20, 9]):
@@ -475,7 +475,8 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     # step, then 5 decode iterations of 2 rows, over 5 conv layers
     assert eng.conv_rows_fed == 5 * (2 + 2 * 5)
     assert eng.state_summary()["experts"] == {
-        "touched": eng.experts_touched, "tile_rows": eng.expert_tile_rows,
+        "touched": eng.experts_touched, "tiles": eng.expert_tiles,
+        "tile_rows": eng.expert_tile_rows,
         "tile_rows_live": eng.expert_tile_rows_live}
     assert eng.state_summary()["moe"] == {"tokens": eng.moe_tokens,
                                           "local_picks": eng.moe_local_picks,
@@ -538,5 +539,5 @@ def test_a_model_without_state_a_slot_counts_none_and_has_none():
     eng.run()
     assert eng.slot_state == () and eng.program_caches() == tuple(eng.caches)
     assert eng.state_summary()["slot_state"] == {"arrays": [], "rows_fed": 0}
-    assert eng.state_summary()["experts"] == {"touched": 0, "tile_rows": 0,
+    assert eng.state_summary()["experts"] == {"touched": 0, "tiles": 0, "tile_rows": 0,
                                               "tile_rows_live": 0}

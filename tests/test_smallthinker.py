@@ -365,7 +365,8 @@ def test_the_counters_are_monotone_and_ride_the_harvest_span(built):
     model, _ = built
     eng = ServingEngine(model, **{**ENGINE, "num_blocks": POOLS})
     harvests = programs.harvests(eng)
-    names = ("moe_tokens", "moe_local_picks", "experts_touched", "expert_tile_rows",
+    names = ("moe_tokens", "moe_local_picks", "experts_touched", "expert_tiles",
+             "expert_tile_rows",
              "expert_tile_rows_live", "expert_rows_grouped", "attn_positions_live",
              "attn_positions_read", "kv_write_tokens")
     by_kind = ("attn_positions_live.global", "attn_positions_read.global",

@@ -564,6 +564,16 @@ class _Phase:
         return False
 
 
+def head_logits(rows, weights):
+    """In a program: the rows a launch samples -> their logits [n, V], by the
+    weights' ``"head"`` [hidden, vocab], or where a model ties its head and
+    names none, by the table ``"embed"`` [vocab, hidden] itself (contracted
+    over its columns: no second matrix)."""
+    if "head" in weights:
+        return rows @ weights["head"]
+    return jnp.einsum("ne,ve->nv", rows, weights["embed"])
+
+
 def _table_names(tables: int) -> Tuple[str, ...]:
     """The block tables' rows in a control block: ``bt``, then ``bt.1``, .. a
     further kind of cache layer each."""
@@ -1059,7 +1069,7 @@ class ServingEngine:
             # one logits row per batch slot: its LAST packed token
             with jax.named_scope("head"):
                 rows = jnp.clip(cu[1:] - 1, 0, token_ids.shape[0] - 1)
-                logits = hidden[rows] @ weights["head"]  # [B, V]
+                logits = head_logits(hidden[rows], weights)  # [B, V]
             return logits, caches, new_scales, counts
 
         return forward, trunk
@@ -1369,7 +1379,7 @@ class ServingEngine:
                 idx = jnp.clip(
                     cu[:-1][:, None] + jnp.minimum(j, dlen[:, None]),
                     0, token_ids.shape[0] - 1)
-                lg = (hidden[idx.reshape(-1)] @ weights["head"]).reshape(
+                lg = head_logits(hidden[idx.reshape(-1)], weights).reshape(
                     B, Kp1, -1)
             # redraw every position under the non-spec key stream (the
             # sample index advances by exactly one per position; Kp1 is

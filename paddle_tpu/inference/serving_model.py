@@ -4,7 +4,9 @@ sampling, and asks the model three questions:
 
 ``serving_weights(dtype)``
     the weight pytree the compiled programs take as an argument.  It has a
-    ``"head"`` leaf ``[hidden, vocab]``: the engine heads the rows it samples.
+    ``"head"`` leaf ``[hidden, vocab]``: the engine heads the rows it samples;
+    a model whose head IS its table ``"embed"`` ``[vocab, hidden]`` may name no
+    ``"head"``, and the engine heads by the table (``serving.head_logits``).
 ``serving_cache_spec()``
     a :class:`CacheSpec`: which arrays a CACHE layer keeps in the block pool
     and the shape of one block of each, so the engine can allocate the pool,

@@ -50,3 +50,9 @@ from .smallthinker import (  # noqa: F401,E402
     SmallThinkerModel,
     smallthinker_tiny,
 )
+from .cohere2_moe import (  # noqa: F401,E402
+    Cohere2MoeConfig,
+    Cohere2MoeForCausalLM,
+    Cohere2MoeModel,
+    cohere2_moe_tiny,
+)

@@ -39,19 +39,11 @@ import jax
 import jax.numpy as jnp
 
 from .latent_attention import Selection, write_entries
+from .norms import layer_norm  # noqa: F401  the selector's key norm; re-exported
 
 __all__ = ["sparse_index", "index_scores", "select_topk", "layer_norm"]
 
 F32 = jnp.float32
-
-
-def layer_norm(x, gain, bias, eps):
-    """LayerNorm over the last axis in float32, back in x's dtype."""
-    xf = x.astype(F32)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps) * gain.astype(F32)
-            + bias.astype(F32)).astype(x.dtype)
 
 
 def index_scores(q, w, k):

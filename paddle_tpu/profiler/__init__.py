@@ -97,7 +97,8 @@ programs); ``post_norm`` (a sandwich block's norm on a sublayer's output);
 ``latent_rows`` kernel, in place of the three, beside ``select_gather``
 where a selection is given), ``router``, ``experts`` (on the chip three
 ``expert_gmm`` kernels; elsewhere the tile loop, ``experts/while/body/``),
-``shared_expert`` (expert layers); ``indexer`` > ``index_proj``, ``index_write``,
+``shared_expert`` (expert layers; ``shared_experts`` where a parallel block
+averages several as one wide unit); ``indexer`` > ``index_proj``, ``index_write``,
 ``while/body/`` {``index_gather``, ``index_scores``}, ``index_topk`` (the
 selector of learned sparse attention, ops/sparse_index.py);
 ``attention`` >

@@ -62,6 +62,40 @@ def _seed_everything():
     yield
 
 
+def _mappings() -> int:
+    """This process's memory mappings (0 where /proc says nothing)."""
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """A compiled CPU program holds memory mappings for as long as it lives
+    (an engine's set of programs some 2,700 of them), jax's caches and the
+    engines' process-wide ``_PROGRAM_CACHE`` keep every program a test built,
+    and the kernel gives a process 65,530 (``vm.max_map_count``): a worker
+    that had run three or four engine-heavy files died of a segmentation fault
+    or an abort inside XLA's NEXT compile or serialize, whichever test that
+    fell in (tests/test_smallthinker.py, test_lfm2_moe.py, test_megastep.py and
+    then test_serving_engine.py in one process reach it at the 102nd test, on
+    PR 47's tree as on PR 48's; PERF.md section 6, PR 48 (9)).  Past 20,000 after a
+    module both caches are let go of: live engines keep their own programs,
+    anything else compiles again when it is next called."""
+    yield
+    if _mappings() > 20_000:
+        import gc
+
+        import jax
+        from paddle_tpu.inference import serving
+
+        serving._PROGRAM_CACHE.clear()
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture(scope="session")
 def serving_model():
     """The canonical sub-tiny serving-test model (1 layer, 64 hidden,

@@ -42,7 +42,8 @@ ENGINE = dict(max_batch_size=4, max_seq_len=96, block_size=8, token_budget=32, m
 
 # family -> its module under benchmark/families (llama is built from its class)
 MODULES = {"pangu": "mla_moe", "ouro": "looped_dense", "deepseek": "mla_dsa_moe",
-           "lfm2": "conv_gqa_moe", "smallthinker": "swa_gqa_moe"}
+           "lfm2": "conv_gqa_moe", "smallthinker": "swa_gqa_moe",
+           "cohere2": "parallel_swa_moe"}
 
 # LFM2: two leading dense conv layers and one period of the pattern (attention,
 # conv, conv, conv): 5 conv layers keep state, 1 attention layer keeps blocks
@@ -102,6 +103,22 @@ TINY = {
         rope_theta=10000.0, rope_scaling=None, max_position_embeddings=256,
         rms_norm_eps=1e-6, tie_word_embeddings=False,
         model_name="smallthinker_21b_instruct", torch_dtype="float32"),
+    # Command A+: one period of the layout (three window layers with interleaved
+    # rope, then a global one without), a window of 24 positions = 3 blocks of
+    # ENGINE's 8; a share of a deployment: 16 routed experts a layer of which this
+    # chip holds [4, 12), four shared experts averaged, a tied head
+    "cohere2": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=32, num_hidden_layers=4,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"], sliding_window=24,
+        num_experts=8, router_outputs=16, experts_held=[4, 12], num_experts_per_tok=4,
+        num_shared_experts=4, shared_expert_combination_strategy="average",
+        expert_selection_fn="sigmoid", norm_topk_prob=True, first_k_dense_replace=0,
+        logit_scale=1, layer_norm_eps=1e-5, position_embedding_type="rope_gptj",
+        rotary_pct=1, rope_theta=10000.0, use_parallel_block=True, use_qk_norm=False,
+        use_gated_activation=True, hidden_act="silu", attention_bias=False,
+        tie_word_embeddings=True, max_position_embeddings=256,
+        model_type="cohere2_moe", torch_dtype="float32"),
 }
 
 
@@ -134,7 +151,7 @@ def pinned_model(family):
 def pinned_engine(family):
     """Its engine with every program it can lower (a model with state a slot
     refuses speculation: its ``spec`` program lowers at no drafts)."""
-    spec_k = 0 if family in ("lfm2", "smallthinker") else 2
+    spec_k = 0 if family in ("lfm2", "smallthinker", "cohere2") else 2
     return ServingEngine(pinned_model(family), spec_k=spec_k, **ENGINE)
 
 

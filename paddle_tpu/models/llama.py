@@ -27,6 +27,7 @@ from .. import nn
 from ..distributed.fleet.mp_layers import ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding
 from ..nn import functional as F
 from ..ops.dispatch import apply
+from ..ops.pallas.fused_ops import rope_fused, swiglu_fused
 from ..profiler import SetupSpan
 from ..tensor import manipulation as M
 from ..tensor.tensor import Tensor
@@ -84,15 +85,12 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_offset=0):
     scalar Tensor (traced — the static-cache decode path slices the rope
     window with lax.dynamic_slice).
 
-    Default path is the jnp rotation — measured on v5e, XLA fuses it into the
-    surrounding projections as fast as the Pallas rope kernel and without the
-    custom-call layout copies (0.4354 vs 0.4325 MFU on the 1B bench).
-    Set PADDLE_TPU_FUSED_LLAMA=1 to route through ops/pallas/fused_ops.py.
-    At Mistral-7B widths under recompute the switch (rope and SwiGLU kernels
-    together) WON its pair: 27,713 against 25,935 tokens/s on
-    mistral7b.train.pretrain-2k (PR 28; ROADMAP S7 makes it the path)."""
-    import os
-
+    An integer offset turns q and k by the table's rows ``[offset, offset +
+    S)`` through ``rope_fused``: one op with its own backward (the same
+    rotation with ``-sin``), which ``ops/pallas/fused_ops.py`` runs as its
+    kernel or as its reference form by what the call shows. On
+    mistral7b.train.pretrain-2k the kernels (this one and the SwiGLU's) read
+    32,786 tokens/s against the 30,387 of the jnp chains they replaced (PR 50)."""
     if isinstance(position_offset, Tensor):
         def f_dyn(qv, kv, c, s, off):
             S = qv.shape[1]
@@ -112,32 +110,23 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_offset=0):
         return apply(lambda *a: tuple(f_dyn(*a)), q, k, cos, sin, position_offset,
                      op_name="fused_rope_dyn", n_outs=2)
 
-    if os.environ.get("PADDLE_TPU_FUSED_LLAMA") == "1":
-        from ..ops.pallas.fused_ops import rope_fused
+    def f(qv, kv, c, s):
+        S = qv.shape[1]
+        return tuple(rope_fused(qv, kv, c[position_offset : position_offset + S],
+                                s[position_offset : position_offset + S]))
 
-        def f(qv, kv, c, s):
-            S = qv.shape[1]
-            cw = c[position_offset : position_offset + S]
-            sw = s[position_offset : position_offset + S]
-            return tuple(rope_fused(qv, kv, cw, sw))
-
-        return apply(f, q, k, cos, sin, op_name="fused_rope", n_outs=2)
-
-    def rope(x, c, s):
-        S = x.shape[1]
-        c = c[position_offset : position_offset + S][None, :, None, :]  # [1,S,1,D/2]
-        s_ = s[position_offset : position_offset + S][None, :, None, :]
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        return jnp.concatenate([x1 * c - x2 * s_, x2 * c + x1 * s_], axis=-1).astype(x.dtype)
-
-    return apply(lambda qv, kv, c, s: (rope(qv, c, s), rope(kv, c, s)),
-                 q, k, cos, sin, op_name="fused_rope", n_outs=2)
+    return apply(f, q, k, cos, sin, op_name="fused_rope", n_outs=2)
 
 
 def _hcg():
     from ..distributed.topology import get_hybrid_communicate_group
 
     return get_hybrid_communicate_group()
+
+
+def _mp_active():
+    hcg = _hcg()
+    return hcg is not None and hcg.axis_size("mp") > 1
 
 
 class LlamaAttention(nn.Layer):
@@ -160,21 +149,11 @@ class LlamaAttention(nn.Layer):
         self.v_proj = ColumnParallelLinear(h, self.num_kv_heads * self.head_dim, has_bias=False, gather_output=False)
         self.o_proj = RowParallelLinear(self.num_heads * self.head_dim, h, has_bias=False, input_is_parallel=True)
 
-    def _mp_active(self):
-        hcg = _hcg()
-        return hcg is not None and hcg.axis_size("mp") > 1
-
     def forward(self, hidden, cos, sin, attn_mask=None, cache=None):
-        import os
-
         b, s = hidden.shape[0], hidden.shape[1]
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        # PADDLE_TPU_FUSED_QKV=1 (here and in LlamaMLP) won its pair on
-        # mistral7b.train.pretrain-2k, 26,545 against 25,935 tokens/s (PR 28;
-        # ROADMAP S7 makes it the path and deletes the variable)
-        fuse_train = os.environ.get("PADDLE_TPU_FUSED_QKV", "0") == "1"
         with jax.named_scope("attn_proj"):
-            if ((s == 1 and cache is not None) or fuse_train) and not self._mp_active():
+            if s == 1 and cache is not None and not _mp_active():
                 # decode step: ONE fused qkv matmul — the weight concat is loop-
                 # invariant, so XLA hoists it out of the decode scan and the step
                 # streams one [h, (nh+2·nkv)·hd] weight (measured 621→773 GB/s
@@ -190,6 +169,8 @@ class LlamaAttention(nn.Layer):
                 k = M.reshape(qkv[:, :, qd:qd + kd], [b, s, nkv, hd])
                 v = M.reshape(qkv[:, :, qd + kd:], [b, s, nkv, hd])
             else:
+                # a longer step: three products (joined they read 32,182
+                # against 32,455 tokens/s on mistral7b.train.pretrain-2k: PR 50)
                 q = M.reshape(self.q_proj(hidden), [b, s, nh, hd])
                 k = M.reshape(self.k_proj(hidden), [b, s, nkv, hd])
                 v = M.reshape(self.v_proj(hidden), [b, s, nkv, hd])
@@ -310,20 +291,7 @@ class LlamaMLP(nn.Layer):
         self.down_proj = RowParallelLinear(m, h, has_bias=False, input_is_parallel=True)
 
     def forward(self, x):
-        # swiglu: XLA fuses silu*mul into the projections (measured equal to
-        # the Pallas kernel minus its layout copies; see apply_rotary_pos_emb)
-        import os
-
-        if os.environ.get("PADDLE_TPU_FUSED_LLAMA") == "1":
-            from ..ops.pallas.fused_ops import swiglu_fused
-
-            gated = apply(lambda a, b: swiglu_fused(a, b),
-                          self.gate_proj(x), self.up_proj(x), op_name="swiglu")
-            return self.down_proj(gated)
-        hcg = _hcg()
-        mp_on = hcg is not None and hcg.axis_size("mp") > 1
-        fuse_train = os.environ.get("PADDLE_TPU_FUSED_QKV", "0") == "1"
-        if (x.shape[1] == 1 or fuse_train) and not mp_on:
+        if x.shape[1] == 1 and not _mp_active():
             # decode step: gate|up as ONE streamed weight (concat hoisted
             # out of the decode scan; measured 621→773 GB/s)
             m = self.gate_proj.weight.shape[1]
@@ -335,7 +303,12 @@ class LlamaMLP(nn.Layer):
             gu = apply(gu_fused, x, self.gate_proj.weight, self.up_proj.weight,
                        op_name="gate_up_fused")
             return self.down_proj(F.silu(gu[:, :, :m]) * gu[:, :, m:])
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        # a step of more than one token: two products, then the activation as
+        # ONE op with its own backward (with the rotation's, 32,786 against
+        # 30,387 tokens/s on mistral7b.train.pretrain-2k; gate|up joined
+        # into a kernel that reads the halves read 32,455: PR 50)
+        gated = apply(swiglu_fused, self.gate_proj(x), self.up_proj(x), op_name="swiglu")
+        return self.down_proj(gated)
 
 
 class LlamaDecoderLayer(nn.Layer):

@@ -10,10 +10,15 @@ split/concat/mul/add chain the jnp forms lower to.
 Rope backward is rope with negated sin (a rotation by -theta), so the same
 kernel serves fwd and bwd. SwiGLU backward is a second single-pass kernel
 recomputing sigmoid from the saved inputs (no activation stash in HBM).
+
+Kernel or reference form is decided here, from what a call shows
+(``_in_kernel``): the Llama trunk's forward calls ``rope_fused`` and
+``swiglu_fused`` whatever it runs on.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +30,26 @@ from ...device import on_tpu
 __all__ = ["rope_fused", "swiglu_fused"]
 
 
-def _on_tpu(interpret: bool) -> bool:
-    return interpret or on_tpu()
+def _in_kernel(interpret: bool, dims_ok: bool) -> bool:
+    """Whether a call takes its Pallas kernel or its reference form, from what
+    it can observe: the dims (``_rows_ok``, ``_dims_ok``), the platform, and
+    no hybrid mesh with an axis over 1. Under a mesh GSPMD partitions the
+    reference form: a Mosaic call inside a GSPMD program needs a ``shard_map``
+    (PR 21), and that form waits for a four-chip train cell (ROADMAP)."""
+    from ...distributed.topology import get_hybrid_communicate_group
+
+    if not dims_ok:
+        return False
+    if interpret:
+        return True
+    hcg = get_hybrid_communicate_group()
+    return on_tpu() and (hcg is None or hcg.mesh.size == 1)
+
+
+def _rows_ok(rows: int, block: int) -> bool:
+    # Mosaic takes a block's rows in whole sublane tiles of 8, or all of them:
+    # a prompt of 7 or 100 tokens takes the reference form
+    return block % 8 == 0 or block == rows
 
 
 # ---------------------------------------------------------------- fused rope
@@ -98,11 +121,13 @@ def rope_fused(q, k, cos, sin, interpret: bool = False):
 
 
 def _dims_ok(q, k) -> bool:
-    return q.shape[-1] % 2 == 0 and q.shape[1] == k.shape[1]
+    s = q.shape[1]
+    return (q.shape[-1] % 2 == 0 and s == k.shape[1]
+            and all(_rows_ok(s, _pick_s_block(s, x.shape[2], x.shape[3])) for x in (q, k)))
 
 
 def _rope_fwd(q, k, cos, sin, interpret):
-    if _on_tpu(interpret) and _dims_ok(q, k):
+    if _in_kernel(interpret, _dims_ok(q, k)):
         out = tuple(_rope_pallas(q, k, cos, sin, interpret))
     else:
         out = _rope_ref(q, k, cos, sin)
@@ -113,7 +138,7 @@ def _rope_bwd(interpret, res, g):
     cos, sin = res
     gq, gk = g
     # d/dx of a rotation by theta is a rotation of the cotangent by -theta
-    if _on_tpu(interpret) and _dims_ok(gq, gk):
+    if _in_kernel(interpret, _dims_ok(gq, gk)):
         dq, dk = _rope_pallas(gq, gk, cos, -sin, interpret)
     else:
         dq, dk = _rope_ref(gq, gk, cos, -sin)
@@ -189,8 +214,13 @@ def swiglu_fused(a, b, interpret: bool = False):
     return out
 
 
+def _swiglu_rows_ok(a) -> bool:
+    n = math.prod(a.shape[:-1])
+    return _rows_ok(n, _grid_2d(n, a.shape[-1]))
+
+
 def _swiglu_fwd(a, b, interpret):
-    if _on_tpu(interpret):
+    if _in_kernel(interpret, _swiglu_rows_ok(a)):
         shape = a.shape
         out = _swiglu_pallas(a.reshape(-1, shape[-1]), b.reshape(-1, shape[-1]),
                              interpret).reshape(shape)
@@ -202,7 +232,7 @@ def _swiglu_fwd(a, b, interpret):
 
 def _swiglu_bwd(interpret, res, g):
     a, b = res
-    if _on_tpu(interpret):
+    if _in_kernel(interpret, _swiglu_rows_ok(a)):
         shape = a.shape
         da, db = _swiglu_bwd_pallas(a.reshape(-1, shape[-1]), b.reshape(-1, shape[-1]),
                                     g.reshape(-1, shape[-1]), interpret)

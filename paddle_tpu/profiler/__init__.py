@@ -108,7 +108,9 @@ step, which shares ``embed`` ``attn_proj`` ``attn_out`` ``mlp`` ``norm``
 
 Kernels (``pallas_call(name=)``, the name of the custom call's device event):
 ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``rms_norm``,
-``rms_norm_residual``, ``fused_rope``, ``swiglu_fwd``, ``swiglu_bwd``,
+``rms_norm_residual``, ``fused_rope`` (q and k apart under ``attention``),
+``swiglu_fwd``, ``swiglu_bwd`` (under ``mlp``; the three in every train step of
+the dense trunk on one chip: 12, 4 and 2 a step at depth 2 under recompute),
 ``int8_matmul``, ``paged_decode``, ``paged_write``, ``paged_chunk`` (one a cache
 layer under ``paged_attention``, on the chip, where a row may feed more than
 one token), ``expert_gmm`` (three a

@@ -106,6 +106,42 @@ def test_rms_norm_residual(chip, hidden, dtype):
                    chip, x, x, ((hidden,), dtype))
 
 
+# mistral7b.train.pretrain-2k's calls: 4 x 2,048 tokens, 32 heads over 8 KV
+# heads of 128, an intermediate width of 14,336
+_TRAIN_Q, _TRAIN_K, _TRAIN_ACT = (4, 2048, 32, 128), (4, 2048, 8, 128), (8192, 14336)
+
+
+@pytest.mark.parametrize("kernel", ["fused_rope", "swiglu_fwd", "swiglu_bwd"])
+def test_the_training_forward_rotation_and_swiglu(chip, kernel, monkeypatch):
+    """The three kernels of ``ops/pallas/fused_ops.py`` alone at the train
+    cell's shapes in bf16, reached as the Llama trunk reaches them (``rope_fused``,
+    ``swiglu_fused`` and its backward, the platform answering yes): the
+    predicate takes the kernel at these dims, Mosaic takes its blocks, and
+    nothing is copied around it (no temporaries: one read of each input, one
+    write of each output)."""
+    from paddle_tpu.ops.pallas import fused_ops as fo
+
+    on_the_chip(monkeypatch)
+    table = ((_TRAIN_Q[1], _TRAIN_Q[3] // 2), jnp.float32)
+    act = (_TRAIN_ACT, BF16)
+    if kernel == "fused_rope":
+        compiled = compile_kernel(fo.rope_fused, chip, (_TRAIN_Q, BF16), (_TRAIN_K, BF16),
+                                  table, table, names=(kernel,))
+    elif kernel == "swiglu_fwd":
+        compiled = compile_kernel(fo.swiglu_fused, chip, act, act, names=(kernel,))
+    else:
+        def loss(a, b, g):      # under the trunk's scope, which names the event
+            with jax.named_scope("mlp"):
+                return jnp.sum(fo.swiglu_fused(a, b) * g)
+
+        compiled = compile_kernel(jax.grad(loss, argnums=(0, 1)), chip, act, act, act,
+                                  names=(kernel,))
+    calls = {k: kernel_calls(compiled.as_text(), k)
+             for k in ("fused_rope", "swiglu_fwd", "swiglu_bwd")}
+    assert {k: n for k, n in calls.items() if n} == {kernel: 2 if kernel == "fused_rope" else 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("m", [8, 512])
 def test_int8_matmul(chip, m, monkeypatch):
     from paddle_tpu.ops.pallas import int8_matmul as im

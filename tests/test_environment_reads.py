@@ -22,11 +22,6 @@ ALLOWED = {
     "PADDLE_TPU_SYNTHETIC_N": ("vision/datasets.py", "how many synthetic samples"),
     "PADDLE_TPU_PROFILE_DIR": ("profiler/__init__.py", "where traces are written"),
     "PADDLE_TPU_AUTOTUNE_CACHE": ("ops/pallas/autotune.py", "where tuned block sizes are kept"),
-    # measured winners waiting to become the path (ROADMAP S7; PERF.md section 6, PR 28)
-    "PADDLE_TPU_FUSED_LLAMA": ("models/llama.py", "Pallas rope and SwiGLU in the training "
-                               "forward: +6.9 % on mistral7b.train.pretrain-2k"),
-    "PADDLE_TPU_FUSED_QKV": ("models/llama.py", "q|k|v and gate|up as one matmul each in "
-                             "the training forward: +2.4 % on the same cell"),
     # debts (ROADMAP D5)
     "PADDLE_TPU_ATTN": ("nn/functional/flash_attention.py",
                         "the platform chooses the kernel; tests/test_chip_smoke.py runs the "
